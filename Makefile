@@ -3,7 +3,8 @@
 
 PY ?= python
 
-.PHONY: lint lint-fast test baseline lint-all lint-hot-report bench-smoke
+.PHONY: lint lint-fast test baseline lint-all lint-hot-report bench-smoke \
+	chip-smoke
 
 # --format github under Actions so findings annotate the PR diff;
 # --time-budget keeps the gate honest about staying per-push fast
@@ -29,6 +30,13 @@ baseline:       ## rewrite tools/ptlint_baseline.json (should only shrink)
 test:           ## tier-1 test suite (CPU)
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow' \
 		--continue-on-collection-errors -p no:cacheprovider
+
+# chip-smoke only PRINTS the two commands: they need a TPU and are run
+# through the chip tool, one foreground process per call — nothing in
+# `make test` or CI tries to reach a chip
+chip-smoke:     ## how to prove the main paths still start on the chip
+	@echo "one chip:   python chip_smoke.py"
+	@echo "four chips: python chip_smoke.py --chips 4"
 
 # bench-smoke: prefix-share hit rate + mixed-length bucketed run + the
 # fused-vs-unfused comparison; the bucketed leg FAILS on any prefill

@@ -2057,4 +2057,6 @@ def _cli() -> dict:
 
 
 if __name__ == "__main__":
+    from paddle_tpu.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print(json.dumps(_cli()))

@@ -44,4 +44,6 @@ def main(steps=5):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -29,6 +29,8 @@ def main(steps=50, batch=32):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=50)
     main(p.parse_args().steps)

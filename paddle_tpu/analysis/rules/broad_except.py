@@ -7,8 +7,8 @@ silent behavior changes. The serving engine's step boundary showed the
 legitimate shape: catch broadly, but ATTACH the error to the failed
 requests. Compliance here is syntactic: the handler body must contain a
 `raise`, or a call whose name looks like logging/warning
-(`logging.*`, `logger.*`, `warnings.warn`, `_warn_fallback`,
-`traceback.print_exc`, ...). Anything genuinely-broad by design takes
+(`logging.*`, `logger.*`, `warnings.warn`, `traceback.print_exc`,
+...). Anything genuinely-broad by design takes
 a `# ptlint: disable=EXC001 — <why>` with a one-line justification.
 """
 from __future__ import annotations
@@ -23,7 +23,7 @@ BROAD_TYPES = {"Exception", "BaseException"}
 
 def _looks_like_logging(name: str) -> bool:
     """True for logging/warning-shaped call names: logging.info,
-    logger.debug, warnings.warn, _warn_fallback, traceback.print_exc.
+    logger.debug, warnings.warn, traceback.print_exc.
     Segment-anchored so catalog/dialog/backlog don't count as 'log'."""
     for seg in name.split("."):
         s = seg.lower().lstrip("_")

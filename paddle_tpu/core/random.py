@@ -19,20 +19,29 @@ import numpy as np
 
 class Generator:
     def __init__(self, seed: int = 0):
-        self._seed = seed
-        self._key = jax.random.key(seed)
+        self.manual_seed(seed)
 
     def manual_seed(self, seed: int) -> "Generator":
         self._seed = seed
-        self._key = jax.random.key(seed)
+        # the key is built on first use: making one initialises a jax
+        # backend, and the module-level default generator must not do
+        # that at import (a chip belongs to ONE process — a launcher
+        # parent that merely imports the package would take it from its
+        # own children)
+        self._key = None
         return self
 
+    def _live_key(self) -> jax.Array:
+        if self._key is None:
+            self._key = jax.random.key(self._seed)
+        return self._key
+
     def split(self) -> jax.Array:
-        self._key, sub = jax.random.split(self._key)
+        self._key, sub = jax.random.split(self._live_key())
         return sub
 
     def get_state(self):
-        return jax.random.key_data(self._key)
+        return jax.random.key_data(self._live_key())
 
     def set_state(self, state):
         self._key = jax.random.wrap_key_data(np.asarray(state))

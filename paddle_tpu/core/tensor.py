@@ -85,15 +85,11 @@ class Tensor:
 
     @property
     def place(self) -> Place:
-        devs = getattr(self._data, "devices", None)
-        if devs is not None:
-            try:
-                return Place(next(iter(self._data.devices())))
-            # ptlint: disable=EXC001 — devices() on tracers/committed
-            # arrays raises jax-version-dependent types; any failure
-            # means "no concrete placement", the default below
-            except Exception:
-                pass
+        # a concrete jax.Array knows its devices; a tracer (inside
+        # to_static/jit) has no placement yet and reports the default
+        if isinstance(self._data, jax.Array) and \
+                not isinstance(self._data, jax.core.Tracer):
+            return Place(next(iter(self._data.devices())))
         return _default_place()
 
     @property

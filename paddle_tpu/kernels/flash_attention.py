@@ -836,31 +836,34 @@ def _use_pallas(x):
         return True
     if _interpret():  # testing: run the kernels in interpret mode anywhere
         return True
-    # Concrete arrays know their devices; tracers (inside jit) compile for
-    # the default backend — probing x.devices() on a tracer raises, which
-    # previously disabled the Pallas path in every jitted step.
-    try:
-        plat = next(iter(x.devices())).platform
-    # ptlint: disable=EXC001 — devices() on a tracer raises a jax-version-
-    # dependent type; tracing means "compile for the default backend"
-    except Exception:
-        plat = jax.default_backend()
-    return plat not in ("cpu",)
+    return _platform_of(x) != "cpu"
 
 
-_warned_fallbacks = set()
+def _platform_of(x) -> str:
+    """Platform an op on `x` compiles for: a concrete array's own
+    device; a tracer (inside jit) has none and compiles for the default
+    backend."""
+    if isinstance(x, jax.core.Tracer):
+        return jax.default_backend()
+    return next(iter(x.devices())).platform
 
 
-def _warn_fallback(site: str, exc: Exception):
-    """Log once per call site when the Pallas kernel falls back to the exact
-    path — a silent fallback turns an O(S) kernel into O(S^2) memory and
-    would hide real kernel regressions (round-1 VERDICT weak item 3)."""
-    if site not in _warned_fallbacks:
-        _warned_fallbacks.add(site)
+_warned_gates = set()
+
+
+def _warn_gate(site: str, q, k, causal, layout):
+    """Log once per call site when the SHAPE gate (`_pallas_ok`) sends a
+    Pallas-enabled call down the exact path for a shape that path was
+    not designed for (`_intentional_exact`) — an O(S) kernel becomes
+    O(S^2) memory there, so the choice should be visible. A kernel that
+    fails to lower or run raises; nothing is caught."""
+    if site not in _warned_gates and _use_pallas(q) \
+            and not _intentional_exact(q, k, causal, layout):
+        _warned_gates.add(site)
         import logging
         logging.getLogger("paddle_tpu.kernels").warning(
-            "flash attention Pallas kernel unavailable at %s "
-            "(falling back to exact attention): %s", site, exc)
+            "flash attention shape gate at %s takes the exact path: "
+            "q=%s k=%s causal=%s", site, q.shape, k.shape, causal)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -874,6 +877,20 @@ def flash_attention_fwd(q, k, v, causal=False, scale=None, layout="bshd"):
     activations head-major (einsum-form attention) skip the relayout
     copies around the custom-call (see _to_folded)."""
     return _flash_impl(q, k, v, causal, scale, layout)
+
+
+def flash_attention_sharded(q, k, v, mesh, spec, causal=True, scale=None):
+    """`flash_attention_fwd` under a mesh. GSPMD refuses a Mosaic kernel
+    ("Mosaic kernels cannot be automatically partitioned"), so each
+    device runs the kernel on its own shard through shard_map. `spec` is
+    the one PartitionSpec of q, k, v and the output ([B, S, H, hd]):
+    batch and heads may split — every shard keeps whole GQA groups, so H
+    and the KV head count must both divide by the head axis — sequence
+    and hd stay whole. Differentiable like the kernel it wraps."""
+    fn = lambda q_, k_, v_: flash_attention_fwd(  # noqa: E731
+        q_, k_, v_, causal, scale)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def block_aligned(s: int) -> bool:
@@ -1045,35 +1062,25 @@ def _ref_any(q, k, v, causal=False, scale=None, mask=None, layout="bshd"):
 
 def _flash_impl(q, k, v, causal, scale, layout="bshd"):
     if _pallas_ok(q, k, causal, layout):
-        try:
-            # GQA k/v go in UNEXPANDED — the kernel's BlockSpec index_map
-            # folds each q head onto its kv group
-            return flash_attention_padded(q, k, v, causal=causal,
-                                          scale=scale, layout=layout,
-                                          interpret=_interpret())
-        except Exception as e:
-            _warn_fallback("flash_fwd", e)
-    elif _use_pallas(q) and not _intentional_exact(q, k, causal, layout):
-        _warn_fallback("flash_gate", ValueError(
-            f"unsupported shape q={q.shape} k={k.shape} causal={causal}"))
+        # GQA k/v go in UNEXPANDED — the kernel's BlockSpec index_map
+        # folds each q head onto its kv group
+        return flash_attention_padded(q, k, v, causal=causal,
+                                      scale=scale, layout=layout,
+                                      interpret=_interpret())
+    _warn_gate("flash_gate", q, k, causal, layout)
     return _ref_any(q, k, v, causal=causal, scale=scale, layout=layout)
 
 
 def _flash_fwd_rule(q, k, v, causal, scale, layout="bshd"):
     if _pallas_ok(q, k, causal, layout):
-        try:
-            out, lse = flash_attention_padded(q, k, v, causal=causal,
-                                              scale=scale, return_lse=True,
-                                              layout=layout,
-                                              interpret=_interpret())
-            # residuals keep the ORIGINAL k/v (their static head count tells
-            # the bwd how to reduce GQA grads); expansion is re-done there
-            return out, (q, k, v, out, lse)
-        except Exception as e:
-            _warn_fallback("flash_fwd_vjp", e)
-    elif _use_pallas(q) and not _intentional_exact(q, k, causal, layout):
-        _warn_fallback("flash_gate_vjp", ValueError(
-            f"unsupported shape q={q.shape} k={k.shape} causal={causal}"))
+        out, lse = flash_attention_padded(q, k, v, causal=causal,
+                                          scale=scale, return_lse=True,
+                                          layout=layout,
+                                          interpret=_interpret())
+        # residuals keep the ORIGINAL k/v (their static head count tells
+        # the bwd how to reduce GQA grads); expansion is re-done there
+        return out, (q, k, v, out, lse)
+    _warn_gate("flash_gate_vjp", q, k, causal, layout)
     return (_ref_any(q, k, v, causal=causal, scale=scale, layout=layout),
             (q, k, v, None, None))
 
@@ -1081,14 +1088,11 @@ def _flash_fwd_rule(q, k, v, causal, scale, layout="bshd"):
 def _flash_bwd_rule(causal, scale, layout, res, g):
     q, k, v, out, lse = res
     if lse is not None:
-        try:
-            # GQA handled inside the wrapper (native resident kernel or
-            # expand+reduce for the streamed paths)
-            return flash_attention_padded_bwd(
-                q, k, v, out, lse, g, causal=causal, scale=scale,
-                layout=layout, interpret=_interpret())
-        except Exception as e:  # e.g. VMEM overflow at extreme seq
-            _warn_fallback("flash_bwd", e)
+        # GQA handled inside the wrapper (native resident kernel or
+        # expand+reduce for the streamed paths)
+        return flash_attention_padded_bwd(
+            q, k, v, out, lse, g, causal=causal, scale=scale,
+            layout=layout, interpret=_interpret())
     _, vjp = jax.vjp(lambda q_, k_, v_: _ref_any(
         q_, k_, v_, causal=causal, scale=scale, layout=layout), q, k, v)
     return vjp(g)
@@ -1124,27 +1128,21 @@ def _key_mask4(key_mask):
 
 def _flash_masked_impl(q, k, v, key_mask, scale, layout="bshd"):
     if _use_pallas(q):
-        try:
-            return flash_attention_padded(q, k, v, causal=False,
-                                          scale=scale, key_mask=key_mask,
-                                          layout=layout,
-                                          interpret=_interpret())
-        except Exception as e:
-            _warn_fallback("flash_masked_fwd", e)
+        return flash_attention_padded(q, k, v, causal=False,
+                                      scale=scale, key_mask=key_mask,
+                                      layout=layout,
+                                      interpret=_interpret())
     return _ref_any(q, k, v, scale=scale, layout=layout,
                     mask=_key_mask4(key_mask))
 
 
 def _flash_masked_fwd_rule(q, k, v, key_mask, scale, layout="bshd"):
     if _use_pallas(q):
-        try:
-            out, lse = flash_attention_padded(q, k, v, causal=False,
-                                              scale=scale, key_mask=key_mask,
-                                              return_lse=True, layout=layout,
-                                              interpret=_interpret())
-            return out, (q, k, v, key_mask, out, lse)
-        except Exception as e:
-            _warn_fallback("flash_masked_fwd_vjp", e)
+        out, lse = flash_attention_padded(q, k, v, causal=False,
+                                          scale=scale, key_mask=key_mask,
+                                          return_lse=True, layout=layout,
+                                          interpret=_interpret())
+        return out, (q, k, v, key_mask, out, lse)
     out = _ref_any(q, k, v, scale=scale, layout=layout,
                    mask=_key_mask4(key_mask))
     return out, (q, k, v, key_mask, None, None)
@@ -1155,13 +1153,10 @@ def _flash_masked_bwd_rule(scale, layout, res, g):
     q, k, v, key_mask, out, lse = res
     d_mask = np.zeros(key_mask.shape, jax.dtypes.float0)
     if lse is not None:
-        try:
-            dq, dk, dv = flash_attention_padded_bwd(
-                q, k, v, out, lse, g, causal=False, scale=scale,
-                key_mask=key_mask, layout=layout, interpret=_interpret())
-            return dq, dk, dv, d_mask
-        except Exception as e:
-            _warn_fallback("flash_masked_bwd", e)
+        dq, dk, dv = flash_attention_padded_bwd(
+            q, k, v, out, lse, g, causal=False, scale=scale,
+            key_mask=key_mask, layout=layout, interpret=_interpret())
+        return dq, dk, dv, d_mask
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _ref_any(q_, k_, v_, scale=scale, layout=layout,
                                     mask=_key_mask4(key_mask)),

@@ -253,15 +253,9 @@ def sep_attention(q, k, v, mesh: Mesh, impl: str = "ring",
     # nested inside another (partial-manual) shard_map — e.g. the pp
     # pipeline — the inner shard_map must be built from the context's
     # AbstractMesh (whose pp axis is already Manual), not the concrete mesh
-    try:
-        ctx = jax.sharding.get_abstract_mesh()
-        if ctx is not None and ctx.shape_tuple and any(
-                t == jax.sharding.AxisType.Manual for t in ctx.axis_types):
-            mesh = ctx
-    # ptlint: disable=EXC001 — the abstract-mesh API differs across jax
-    # versions; probe failure means "no context mesh", keep the concrete one
-    except Exception:
-        pass
+    ctx = jax.sharding.get_abstract_mesh()
+    if ctx.manual_axes:
+        mesh = ctx
     spec = _sep_specs(mesh)
     body = (_ring_attention_local if impl == "ring"
             else _ulysses_attention_local)

@@ -68,21 +68,11 @@ def rms_norm_pallas(x, weight, epsilon: float = 1e-6, block_rows: int = 256):
 def rms_norm(x, weight=None, epsilon: float = 1e-6):
     """Dispatch: Pallas on TPU (when enabled + weight present), ref otherwise."""
     from ..core.flags import flag
+    from .flash_attention import _platform_of
 
-    try:
-        plat = next(iter(x.devices())).platform
-    # ptlint: disable=EXC001 — devices() on a tracer raises a jax-version-
-    # dependent type; tracing means "compile for the default backend"
-    except Exception:  # tracer inside jit: compiles for the default backend
-        plat = jax.default_backend()
-    on_tpu = plat not in ("cpu",)
+    on_tpu = _platform_of(x) != "cpu"
     if flag("FLAGS_use_pallas") and on_tpu and weight is not None and x.shape[-1] % 128 == 0:
-        try:
-            return rms_norm_pallas(x, weight, epsilon)
-        # ptlint: disable=EXC001 — any Pallas lowering failure (interpret
-        # contexts, unsupported shapes) falls back to the reference impl
-        except Exception:
-            pass  # fall back to the reference path (e.g. interpret contexts)
+        return rms_norm_pallas(x, weight, epsilon)
     return rms_norm_ref(x, weight, epsilon)
 
 

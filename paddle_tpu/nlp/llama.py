@@ -28,7 +28,9 @@ import numpy as _np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..kernels.flash_attention import flash_attention_fwd
+from ..kernels.flash_attention import (_pallas_available,
+                                       flash_attention_fwd,
+                                       flash_attention_sharded)
 from ..kernels.rms_norm import rms_norm_ref, rms_norm_train
 from ..kernels.rope import rope_freqs, apply_rope_half
 
@@ -212,6 +214,13 @@ def _attention(x, lp, cfg: LlamaConfig, cos, sin, mesh=None):
             and "sep" in mesh.axis_names and mesh.shape["sep"] > 1):
         from ..kernels.ring_attention import sep_attention
         o = sep_attention(q, k, v, mesh, impl=cfg.attn_impl, causal=True)
+    elif cfg.use_flash and mesh is not None and _pallas_available() \
+            and not in_manual_axis("pp"):
+        # GSPMD cannot partition the Mosaic kernel: run it per
+        # (batch, head) shard. Off-TPU meshes keep the global call below
+        # (the exact path partitions under plain GSPMD), as _make_norm does
+        o = flash_attention_sharded(
+            q, k, v, mesh, P(("dp", "sharding"), None, "mp", None))
     elif cfg.use_flash:
         o = flash_attention_fwd(q, k, v, True, None)
     else:
@@ -247,16 +256,8 @@ def in_manual_axis(*names) -> bool:
     (e.g. the compiled-pipeline stage body, manual over 'pp') — a nested
     shard_map over the remaining auto axes is unsupported there, so the
     mesh-aware fused kernels must fall back to their jnp formulations."""
-    for n in names:
-        try:
-            jax.lax.axis_index(n)
-            return True
-        # ptlint: disable=EXC001 — axis_index on an unbound axis raises a
-        # jax-version-dependent type (NameError today); unbound IS the
-        # probe result, not a failure
-        except Exception:
-            continue
-    return False
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    return any(n in manual for n in names)
 
 
 def _make_norm(cfg: LlamaConfig, mesh):
@@ -265,7 +266,6 @@ def _make_norm(cfg: LlamaConfig, mesh):
     through to jnp inside the shard, as before). Inside a pipeline
     stage (manual over pp) the jnp path keeps GSPMD partitioning the
     remaining axes."""
-    from ..kernels.flash_attention import _pallas_available
     from ..kernels.rms_norm import rms_norm_train_sharded
     if mesh is None:
         return lambda h, w: rms_norm_train(h, w, cfg.rms_norm_eps, True)

@@ -43,6 +43,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec
 
 if TYPE_CHECKING:  # annotation-only: the nlp -> serving edge stays lazy
     from ..serving.cache import PrefixCacheIndex
@@ -657,7 +658,14 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, table, positions,
         # produce garbage that is never read — their pool writes are
         # dropped and their logits never selected)
         from ..kernels import flash_attention as fa
-        o = fa._flash_impl(q, kq, vq, True, None)
+        if mesh is not None and fa._pallas_available():
+            # GSPMD cannot partition the Mosaic kernel: each device runs
+            # it on its head shard, like the ragged kernel below
+            o = fa.flash_attention_sharded(
+                q, kq, vq, mesh,
+                PartitionSpec(None, None, mesh_axis, None))
+        else:
+            o = fa._flash_impl(q, kq, vq, True, None)
     else:
         # decode AND cached-prefix suffix prefill: gather through the
         # table with per-query causal visibility (j <= position)

@@ -90,21 +90,28 @@ def _rpa_kernel(*refs, bs: int, scale: float, quantized: bool,
                 suffix: bool = False, nchunks: int = 0):
     """One (row, query-tile, block-chunk) grid step of the ragged kernel.
 
+    Every in-kernel value is 2-D with hd as its lane dim — the shapes
+    the TPU compiler tiles: per kv head `kv`, G = Pt*rep query rows
+    (query-major: row i is query i // rep, group member i % rep;
+    `ragged_paged_attention` folds q that way around the call) against
+    the block's [bs, hd] strided load `k_ref[0, :, kv, :]`.
+
     Refs (per BlockSpec):
-      pos_ref/val_ref [1, Pt] int32 — this tile's query positions /
-      validity; q_ref [1, Pt, H, hd]; k_ref/v_ref [1, bs, KV, hd] — THE
-      pool block this chunk's index map resolved from the prefetched
-      table; o_ref [1, Pt, H, hd]; scratch acc [Pt, H, hd] f32,
-      m/l [Pt, H] f32. `live_ref` is per (row, tile): a tile's chain
-      walk stops at ITS OWN last visible block, not the row's.
-      `quantized` adds ks_ref/vs_ref [N] f32 per-block dequant scales
-      to the scalar prefetch: the block's codes dequantize right after
-      the pipeline DMA lands them in VMEM — the fused-dequant gather.
+      pos_ref/val_ref [1, 1, G, 1] int32 — this tile's per-row query
+      positions / validity; q_ref [1, 1, KV, G, hd]; k_ref/v_ref
+      [1, bs, KV, hd] — THE pool block this chunk's index map resolved
+      from the prefetched table; o_ref [1, 1, KV, G, hd]; scratch acc
+      [KV, G, hd] f32, m/l [KV, G, 1] f32. `live_ref` is per
+      (row, tile): a tile's chain walk stops at ITS OWN last visible
+      block, not the row's. `quantized` adds ks_ref/vs_ref [N] f32
+      per-block dequant scales to the scalar prefetch: the block's
+      codes dequantize right after the pipeline DMA lands them in VMEM
+      — the fused-dequant gather.
 
     `suffix` adds the speculative verify's in-register suffix slab:
     sk_ref/sv_ref [1, S, KV, hd] (this row's not-yet-committed K/V —
-    the packed draft chain or tree) and svis_ref [1, Pt, S] int32 (per-
-    query slab visibility: the chain's causal triangle or the tree's
+    the packed draft chain or tree) and svis_ref [1, 1, G, S] int32
+    (per-row slab visibility: the chain's causal triangle or the tree's
     ancestor mask). The grid grows ONE extra chunk (c == nchunks, past
     the table width): the pool sweep stays the int8-gathered block loop
     unchanged, and the final chunk folds the slab's scores into the
@@ -128,6 +135,31 @@ def _rpa_kernel(*refs, bs: int, scale: float, quantized: bool,
         sk_ref = sv_ref = svis_ref = None
     r, t, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nlive = live_ref[r, t]
+    KV = q_ref.shape[2]
+
+    def _fold(kv, k, v, vis):
+        # one kv head's G query rows against T keys k/v [T, hd] f32:
+        # scores, then the flash-style online-softmax update of that
+        # head's running max / sum / accumulator
+        q = q_ref[0, 0, kv].astype(jnp.float32) * scale       # [G, hd]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(vis, s, _NEG_INF)                       # [G, T]
+        m_prev = m_ref[kv]                                    # [G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # exp(s - m) alone is 1.0 for fully-masked rows (s == m ==
+        # _NEG_INF) — the explicit vis select keeps them at zero
+        p = jnp.where(vis, jnp.exp(s - m_new), 0.0)
+        l_ref[kv] = l_ref[kv] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[kv] = acc_ref[kv] * alpha + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_ref[kv] = m_new
+
+    def _finalize():
+        l = l_ref[...]
+        o = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
+        o_ref[0, 0] = o.astype(o_ref.dtype)
 
     @pl.when(c == 0)
     def _init():
@@ -137,94 +169,46 @@ def _rpa_kernel(*refs, bs: int, scale: float, quantized: bool,
 
     @pl.when(c < nlive)
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32) * scale          # [P, H, hd]
-        if quantized:
-            # dequantize THIS chunk's block under its prefetched scale
-            # (chain chunk c of row r is pool block tab[r, c] — live,
-            # since c < nlive here): the same quantization.kv math the
-            # XLA path applies after its gather
-            b = jnp.maximum(tab_ref[r, c], 0)
-            k = kvq.dequantize(k_ref[0], ks_ref[b])       # [bs, KV, hd]
-            v = kvq.dequantize(v_ref[0], vs_ref[b])
-        else:
-            k = k_ref[0].astype(jnp.float32)              # [bs, KV, hd]
-            v = v_ref[0].astype(jnp.float32)
-        P, H, hd = q.shape
-        KV = k.shape[1]
-        rep = H // KV
-        # grouped-GQA scores against this ONE pool block: query head
-        # h = kv*rep + r_h reads kv head kv — the same head grouping as
-        # q.reshape(B, P, KV, rep, hd) in the XLA path
-        qg = q.reshape(P, KV, rep, hd)
-        s = jnp.einsum("pkrd,tkd->pkrt", qg, k,
-                       preferred_element_type=jnp.float32)
-        s = s.reshape(P, H, bs)
-        # per-query causal visibility at ABSOLUTE key position
+        # per-row causal visibility at ABSOLUTE key position
         # j = c*bs + t (chain position, not pool position), masked by
         # query validity so padded rows accumulate nothing
-        kpos = c * bs + jax.lax.broadcasted_iota(jnp.int32, (P, bs), 1)
-        vis = (kpos <= pos_ref[0][:, None]) & \
-              (val_ref[0] != 0)[:, None]                  # [P, bs]
-        s = jnp.where(vis[:, None, :], s, _NEG_INF)
-        m_prev = m_ref[...]                               # [P, H]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        # exp(s - m) alone is 1.0 for fully-masked rows (s == m ==
-        # _NEG_INF) — the explicit vis multiply keeps them at zero
-        p = jnp.exp(s - m_new[:, :, None])
-        p = jnp.where(vis[:, None, :], p, 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("pkrt,tkd->pkrd", p.reshape(P, KV, rep, bs), v,
-                        preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, :, None] \
-            + pv.reshape(P, H, hd)
-        m_ref[...] = m_new
+        G = pos_ref.shape[2]
+        kpos = c * bs + jax.lax.broadcasted_iota(jnp.int32, (G, bs), 1)
+        vis = (kpos <= pos_ref[0, 0]) & (val_ref[0, 0] != 0)   # [G, bs]
+        if quantized:
+            # chain chunk c of row r is pool block tab[r, c] — live,
+            # since c < nlive here
+            b = jnp.maximum(tab_ref[r, c], 0)
+        for kv in range(KV):
+            if quantized:
+                # dequantize THIS chunk's block under its prefetched
+                # scale: the same quantization.kv math the XLA path
+                # applies after its gather
+                k = kvq.dequantize(k_ref[0, :, kv, :], ks_ref[b])
+                v = kvq.dequantize(v_ref[0, :, kv, :], vs_ref[b])
+            else:
+                k = k_ref[0, :, kv, :].astype(jnp.float32)    # [bs, hd]
+                v = v_ref[0, :, kv, :].astype(jnp.float32)
+            _fold(kv, k, v, vis)
 
     if suffix:
         # the slab chunk (c == nchunks, past every pool block): fold
         # the suffix slab's scores into the SAME online softmax. Slab
         # rows are full precision (verify-then-commit: these K/V have
         # not been quantized or committed yet), visibility is the
-        # prefetched per-query slab mask AND query validity.
+        # per-row slab mask AND query validity.
         @pl.when(c == nchunks)
         def _suffix_fold():
-            q = q_ref[0].astype(jnp.float32) * scale      # [P, H, hd]
-            k = sk_ref[0].astype(jnp.float32)             # [S, KV, hd]
-            v = sv_ref[0].astype(jnp.float32)
-            P, H, hd = q.shape
-            S, KV, _ = k.shape
-            rep = H // KV
-            qg = q.reshape(P, KV, rep, hd)
-            s = jnp.einsum("pkrd,skd->pkrs", qg, k,
-                           preferred_element_type=jnp.float32)
-            s = s.reshape(P, H, S)
-            vis = (svis_ref[0] != 0) & \
-                  (val_ref[0] != 0)[:, None]              # [P, S]
-            s = jnp.where(vis[:, None, :], s, _NEG_INF)
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[:, :, None])
-            p = jnp.where(vis[:, None, :], p, 0.0)
-            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-            pv = jnp.einsum("pkrs,skd->pkrd",
-                            p.reshape(P, KV, rep, S), v,
-                            preferred_element_type=jnp.float32)
-            acc_ref[...] = acc_ref[...] * alpha[:, :, None] \
-                + pv.reshape(P, H, hd)
-            m_ref[...] = m_new
-            l = l_ref[...]
-            o = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)[:, :, None]
-            o_ref[0] = o.astype(o_ref.dtype)
+            vis = (svis_ref[0, 0] != 0) & (val_ref[0, 0] != 0)  # [G, S]
+            for kv in range(KV):
+                _fold(kv, sk_ref[0, :, kv, :].astype(jnp.float32),
+                      sv_ref[0, :, kv, :].astype(jnp.float32), vis)
+            _finalize()
         return
 
     # finalize at the row's last LIVE chunk (c == 0 for an all-padded
     # row: init just zeroed the accumulators, so the row emits zeros)
-    @pl.when(c == jnp.maximum(nlive - 1, 0))
-    def _finalize():
-        l = l_ref[...]
-        o = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)[:, :, None]
-        o_ref[0] = o.astype(o_ref.dtype)
+    pl.when(c == jnp.maximum(nlive - 1, 0))(_finalize)
 
 
 def _shard_specs(mesh_axis: str, quantized: bool, suffix: bool):
@@ -353,10 +337,10 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
     suffix = suffix_k is not None
 
     def _tile_map(r, t, c, tab, live, *scales):
-        return (r, t)
-
-    def _tile3_map(r, t, c, tab, live, *scales):
         return (r, t, 0, 0)
+
+    def _tile_head_map(r, t, c, tab, live, *scales):
+        return (r, t, 0, 0, 0)
 
     def _kv_map(r, t, c, tab, live, *scales):
         # chunk c of (row r, tile t) reads pool block table[r, c]; DEAD
@@ -369,9 +353,6 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
     def _suffix_map(r, t, c, tab, live, *scales):
         # the row's whole slab, fetched once per (row, tile)
         return (r, 0, 0, 0)
-
-    def _svis_map(r, t, c, tab, live, *scales):
-        return (r, t, 0)
 
     nscal = 4 if quantized else 2
     args = [table, live]
@@ -388,20 +369,43 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
         # shard (H/tp query heads, KV/tp kv heads, same rep = H/KV), so
         # the kernel body and every index map run unchanged; mesh-off,
         # the local shapes ARE the global ones
-        q_l, kp_l = ops[nscal + 2], ops[nscal + 3]
+        scal, (pos_l, val_l, q_l, kp_l, vp_l, *suf) = \
+            ops[:nscal], ops[nscal:]
         Hl, KVl = q_l.shape[2], kp_l.shape[2]
+        rep = Hl // KVl
+        G = Pt * rep
+
+        # The TPU compiler tiles the two minor dims of every block and
+        # refuses the reshape that would split heads into (KV, rep)
+        # inside the kernel, so q is folded here: each kv head's rep
+        # query heads ride the query ROW axis ([R, T, KV, Pt*rep, hd],
+        # query-major) and the per-query operands repeat rep times to
+        # match. The pool keeps its own layout — no copy of it is made.
+        def _rows(x):
+            # [R, P, *f] per query -> [R, T, G, *f] per kernel row
+            f = x.shape[2:]
+            x = jnp.broadcast_to(x.reshape(R, T, Pt, 1, *f),
+                                 (R, T, Pt, rep, *f))
+            return x.reshape(R, T, G, *f)
+
+        ops = [*scal, _rows(pos_l[:, :, None]), _rows(val_l[:, :, None]),
+               q_l.reshape(R, T, Pt, KVl, rep, hd)
+                  .transpose(0, 1, 3, 2, 4, 5).reshape(R, T, KVl, G, hd),
+               kp_l, vp_l]
         in_specs = [
-            pl.BlockSpec((1, Pt), _tile_map),
-            pl.BlockSpec((1, Pt), _tile_map),
-            pl.BlockSpec((1, Pt, Hl, hd), _tile3_map),
+            pl.BlockSpec((1, 1, G, 1), _tile_map),
+            pl.BlockSpec((1, 1, G, 1), _tile_map),
+            pl.BlockSpec((1, 1, KVl, G, hd), _tile_head_map),
             pl.BlockSpec((1, bs, KVl, hd), _kv_map),
             pl.BlockSpec((1, bs, KVl, hd), _kv_map),
         ]
         if suffix:
+            sk_l, sv_l, svis_l = suf
+            ops += [sk_l, sv_l, _rows(svis_l)]
             in_specs += [
                 pl.BlockSpec((1, S, KVl, hd), _suffix_map),
                 pl.BlockSpec((1, S, KVl, hd), _suffix_map),
-                pl.BlockSpec((1, Pt, S), _svis_map),
+                pl.BlockSpec((1, 1, G, S), _tile_map),
             ]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             # int8 pools prefetch the per-block dequant scales next to
@@ -412,11 +416,11 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
             # finalizes
             grid=(R, T, M + 1 if suffix else M),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, Pt, Hl, hd), _tile3_map),
+            out_specs=pl.BlockSpec((1, 1, KVl, G, hd), _tile_head_map),
             scratch_shapes=[
-                pltpu.VMEM((Pt, Hl, hd), jnp.float32),
-                pltpu.VMEM((Pt, Hl), jnp.float32),
-                pltpu.VMEM((Pt, Hl), jnp.float32),
+                pltpu.VMEM((KVl, G, hd), jnp.float32),
+                pltpu.VMEM((KVl, G, 1), jnp.float32),
+                pltpu.VMEM((KVl, G, 1), jnp.float32),
             ],
         )
         call = pl.pallas_call(
@@ -425,10 +429,16 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
                               quantized=quantized, suffix=suffix,
                               nchunks=M),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((R, P, Hl, hd), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((R, T, KVl, G, hd), q.dtype),
             interpret=interpret,
         )
-        return call(*ops)
+        # the package enables jax_enable_x64 globally; traced with it on,
+        # the weak-typed float constants and index math lower as 64-bit,
+        # which the TPU compiler refuses
+        with jax.enable_x64(False):
+            o = call(*ops)
+        return o.reshape(R, T, KVl, Pt, rep, hd) \
+            .transpose(0, 1, 3, 2, 4, 5).reshape(R, P, Hl, hd)
 
     if mesh is None:
         return _kernel_call(*args)
@@ -437,10 +447,8 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
         raise ValueError(
             f"head counts (H={H}, KV={KV}) must divide the mesh axis "
             f"{mesh_axis!r} size {size} to shard the ragged kernel")
-    from jax.experimental.shard_map import shard_map
-
-    # check_rep=False: pallas_call has no replication rule; the specs
+    # check_vma=False: pallas_call has no replication rule; the specs
     # above are the ground truth
     in_specs, out_spec = _shard_specs(mesh_axis, quantized, suffix)
-    return shard_map(_kernel_call, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_spec, check_rep=False)(*args)
+    return jax.shard_map(_kernel_call, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_spec, check_vma=False)(*args)

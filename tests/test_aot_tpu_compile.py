@@ -1,0 +1,169 @@
+"""The main paths' Pallas kernels, compiled for a TPU v5e that is
+described, not attached (on-chip-measurement guide, section 2,
+rehearsal 3): the chip's own compiler is installed here, and it refuses
+here what it would refuse there — block shapes the tiling cannot hold,
+reshapes Mosaic cannot lay out, kernels GSPMD cannot partition, programs
+that do not fit VMEM. Interpret mode sees none of that.
+
+Every case is one kernel at the flagship widths of
+`bench.py::flagship_2b_cfg` (H 32, KV 8, hd 128, d 4096, block 16,
+table width 128, bf16). A compile that passes is not a chip run; it only
+keeps what `chip_smoke.py` needs from breaking between chip runs.
+"""
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.core import flags
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+H, KV, HD, D, BS, M = 32, 8, 128, 4096, 16, 128
+N = 8 * M                                     # pool blocks: 8 slots
+BF = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _real_kernels_no_cache():
+    """Take every kernel gate's TPU branch from this CPU host, and keep
+    the persistent compile cache out of it: an entry compiled for a
+    described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    flags.set_flags({"FLAGS_pallas_force": True})
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+    flags.set_flags({"FLAGS_pallas_force": False})
+
+
+def _compile(fn, shardings, *shapes):
+    """Compile fn for the described chip(s); returns the program text."""
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=sh)
+             for (s, d), sh in zip(shapes, shardings)]
+    return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+def _ragged(topo, R, Pq, kv_dtype=BF, slab=0, mesh=False):
+    from paddle_tpu.nlp.ragged_attention import ragged_paged_attention
+    if mesh:
+        m = Mesh(np.array(topo.devices), ("mp",))
+        rep, head = NamedSharding(m, P()), \
+            NamedSharding(m, P(None, None, "mp", None))
+    else:
+        m, rep = None, SingleDeviceSharding(topo.devices[0])
+        head = rep
+    shapes = [((R, Pq, H, HD), BF), ((N, BS, KV, HD), kv_dtype),
+              ((N, BS, KV, HD), kv_dtype), ((R, M), jnp.int32),
+              ((R, Pq), jnp.int32), ((R, Pq), jnp.bool_)]
+    sh = [head, head, head, rep, rep, rep]
+    names = []
+    if kv_dtype == jnp.int8:
+        names += ["k_scale", "v_scale"]
+        shapes += [((N,), jnp.float32)] * 2
+        sh += [rep, rep]
+    if slab:
+        names += ["suffix_k", "suffix_v", "suffix_vis"]
+        shapes += [((R, slab, KV, HD), BF)] * 2 + [((R, Pq, slab), jnp.bool_)]
+        sh += [head, head, rep]
+
+    def fn(q, kp, vp, tab, pos, val, *rest):
+        return ragged_paged_attention(q, kp, vp, tab, pos, val,
+                                      interpret=False, mesh=m,
+                                      **dict(zip(names, rest)))
+
+    return _compile(fn, sh, *shapes)
+
+
+def _flash(topo, mesh=False):
+    from paddle_tpu.kernels import flash_attention as fa
+    S = 2048
+    if mesh:
+        m = Mesh(np.array(topo.devices).reshape(2, 2), ("sharding", "mp"))
+        spec = P("sharding", None, "mp", None)
+        attn = lambda q, k, v: fa.flash_attention_sharded(  # noqa: E731
+            q, k, v, m, spec)
+        sh, B = NamedSharding(m, spec), 2
+    else:
+        attn = lambda q, k, v: fa.flash_attention_fwd(      # noqa: E731
+            q, k, v, True)
+        sh, B = SingleDeviceSharding(topo.devices[0]), 1
+    loss = lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32))  # noqa: E731
+    return _compile(jax.value_and_grad(loss, (0, 1, 2)), [sh] * 3,
+                    ((B, S, H, HD), BF), ((B, S, KV, HD), BF),
+                    ((B, S, KV, HD), BF))
+
+
+def _rms_norm(topo):
+    from paddle_tpu.kernels.rms_norm import rms_norm_train
+    one = SingleDeviceSharding(topo.devices[0])
+    loss = lambda x, w: jnp.sum(                             # noqa: E731
+        rms_norm_train(x, w, 1e-5, True).astype(jnp.float32))
+    return _compile(jax.value_and_grad(loss, (0, 1)), [one, one],
+                    ((8, 2048, D), BF), ((D,), BF))
+
+
+def _adam8(topo):
+    from paddle_tpu.optimizer.quant_state import adamw_q_fused
+    one = SingleDeviceSharding(topo.devices[0])
+    tx = adamw_q_fused(1e-4, weight_decay=0.1, clip_norm=1.0)
+    leaf = jax.ShapeDtypeStruct((D, D), BF)
+    state = jax.eval_shape(tx.init, {"w": leaf})
+    avals = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        ({"w": leaf}, state, {"w": leaf}))
+    return jax.jit(tx.apply_fused).lower(*avals).compile().as_text()
+
+
+CASES = {
+    "ragged-decode": lambda t: _ragged(t, 8, 1),
+    "ragged-prefill-bucket-512": lambda t: _ragged(t, 1, 512),
+    "ragged-prefill-bucket-8": lambda t: _ragged(t, 8, 8),
+    "ragged-fused-mixed": lambda t: _ragged(t, 9, 128),
+    "ragged-int8-decode": lambda t: _ragged(t, 8, 1, jnp.int8),
+    "ragged-int8-prefill": lambda t: _ragged(t, 2, 512, jnp.int8),
+    "ragged-suffix-slab": lambda t: _ragged(t, 8, 5, slab=5),
+    "ragged-suffix-slab-int8": lambda t: _ragged(t, 8, 7, jnp.int8, slab=7),
+    "ragged-shard_map-4dev": lambda t: _ragged(t, 8, 1, mesh=True),
+    "ragged-shard_map-4dev-prefill": lambda t: _ragged(t, 1, 512,
+                                                       mesh=True),
+    "flash-fwd-bwd-2048": _flash,
+    "flash-shard_map-4dev": lambda t: _flash(t, mesh=True),
+    "rms_norm-fwd-bwd-4096": _rms_norm,
+    "adam8-fused-update": _adam8,
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_compiles_for_v5e(topo, case):
+    assert "tpu_custom_call" in CASES[case](topo)
+
+
+def test_importing_the_package_initialises_no_backend():
+    """A chip belongs to one process: a launcher parent that imports the
+    package must not take it from the child it is about to start."""
+    code = ("import paddle_tpu, paddle_tpu.serving, "
+            "paddle_tpu.distributed.launch.main\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, list(xla_bridge._backends)\n")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
