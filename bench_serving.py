@@ -1598,11 +1598,12 @@ def main(n_requests: int = 16, max_new: int = 8, max_batch: int = 4,
             **{k: v for k, v in kw.items() if k != "budgets"})
     slo = None
     if workload == "slo":
-        # sampled device timing must be nearly free: a discarded leg
-        # burns process warm-up, then an ABBA sequence — sampling off,
-        # on, on, off — so each side runs once early and once late and
-        # first-order warm-state drift cancels from the pooled tok/s
-        # (the --trace-overhead methodology, applied to the fence)
+        # device-time recording must be nearly free (it is fed by the
+        # ticks' own stamps; no fence): a discarded leg burns process
+        # warm-up, then an ABBA sequence — recording off, on, on, off —
+        # so each side runs once early and once late and first-order
+        # warm-state drift cancels from the pooled tok/s (the
+        # --trace-overhead methodology)
         kw_on = dict(kw, profile_sample_every=4)
         _serve(params, cfg, prompts, fused_prefill=True, **kw)
         u1 = _serve(params, cfg, prompts, fused_prefill=True, **kw)
@@ -1616,19 +1617,18 @@ def main(n_requests: int = 16, max_new: int = 8, max_batch: int = 4,
         recompiles = sum(x["recompiles"] for x in (u1, s1, s2, u2))
         if samples < 1:
             raise RuntimeError(
-                "slo gate: the sampled legs fenced ZERO steps — the "
-                "overhead comparison is vacuous (sample_every too "
-                "large for this workload?)")
+                "slo gate: the recording legs recorded ZERO steps — "
+                "the overhead comparison is vacuous")
         if recompiles:
             raise RuntimeError(
                 f"slo gate: {recompiles} post-warmup recompiles across "
-                f"the sampling legs — the fence touched the "
+                f"the recording legs — the profiler touched the "
                 f"compiled-shape memo")
         if ratio < 0.97:
             raise RuntimeError(
                 f"slo gate: sampled run at {ratio:.3f}x the "
                 f"sampling-off tok/s (floor 0.97x) — the device-time "
-                f"fence is no longer cheap enough to leave on")
+                f"recording is no longer cheap enough to leave on")
         slo = {
             "slo_tok_s_sampling_off": round(tok_off, 1),
             "slo_tok_s_sampling_on": round(tok_on, 1),

@@ -49,6 +49,12 @@ HOT_ROOTS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("nlp/paged.py",
      ("step", "run", "_step_fused", "_step_spec", "_forward_spec",
       "forward_paged", "_prefill_pending", "_run_standalone_unit",
+      # the tick helper (`_Tick`): every kind of tick runs inside it,
+      # and the graph cannot follow a `with` statement into the
+      # context manager's methods. Its one device touch, `fence`,
+      # is the documented capture-window exception: it blocks only
+      # while an operator's capture window is armed
+      "__enter__", "__exit__", "phase", "fence", "call_s", "device_s",
       # the KV migration hop: export coalesces one device_get while
       # the source engine's loop is paused on it; import scatters into
       # the destination pool between its steps — both on serving ticks
@@ -88,9 +94,14 @@ HOT_ROOTS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     # over HTTP, spec stats read through as_dict plumbing, trace spans
     # opened on request handles) — pinned as roots so a host sync in
     # them still taxes no step
-    ("serving/profiling.py", ("arm_capture", "capture_active")),
+    # ... and what the tick helper emits through, reached from `_Tick`
+    # through its batcher attribute: the profiler's gate and sample,
+    # the flight record and its close, the sink's device-lane span
+    ("serving/profiling.py", ("arm_capture", "capture_active",
+                              "should_fence", "record")),
     ("serving/speculative.py", ("accept_rate", "tokens_per_step")),
-    ("serving/trace.py", ("start", "finish", "alias", "now")),
+    ("serving/trace.py", ("start", "finish", "alias", "now", "record",
+                          "close", "span")),
 )
 
 def derive_hot_paths(project: Project):

@@ -12,6 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .naming import named_jit
+
 
 def rms_norm_ref(x, weight=None, epsilon: float = 1e-6):
     """Reference path (CPU + fallback). Accumulates in f32 for bf16 inputs —
@@ -133,7 +135,7 @@ def _rows(x, blk):
     return xr, pad
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+@named_jit("rms_norm", static_argnames=("eps", "interpret"))
 def _rms_fwd_pallas(x, weight, eps, interpret=False):
     from jax.experimental import pallas as pl
 
@@ -152,13 +154,14 @@ def _rms_fwd_pallas(x, weight, eps, interpret=False):
             out_shape=[jax.ShapeDtypeStruct((n, d), x.dtype),
                        jax.ShapeDtypeStruct((n, 1), jnp.float32)],
             interpret=interpret,
+            name="rms_norm",
         )(xr, weight.reshape(1, d))
     nrows = n - pad
     return (out[:nrows].reshape(x.shape) if pad else out.reshape(x.shape),
             rstd[:nrows])
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@named_jit("rms_norm_bwd", static_argnames=("interpret",))
 def _rms_bwd_pallas(x, weight, rstd, dy, interpret=False):
     from jax.experimental import pallas as pl
 
@@ -181,6 +184,7 @@ def _rms_bwd_pallas(x, weight, rstd, dy, interpret=False):
             out_shape=[jax.ShapeDtypeStruct((n, d), x.dtype),
                        jax.ShapeDtypeStruct((1, d), jnp.float32)],
             interpret=interpret,
+            name="rms_norm_bwd",
         )(xr, weight.reshape(1, d), rr, dyr)
     nrows = n - pad
     dx = dx[:nrows].reshape(x.shape) if pad else dx.reshape(x.shape)

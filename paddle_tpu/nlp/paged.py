@@ -33,6 +33,7 @@ backend).
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import time
 from collections import OrderedDict
@@ -50,6 +51,7 @@ if TYPE_CHECKING:  # annotation-only: the nlp -> serving edge stays lazy
 
 from ..kernels.rms_norm import rms_norm_ref
 from ..kernels.rope import rope_freqs, apply_rope_half
+from ..profiler import RecordEvent
 from ..quantization import kv as kvq
 from . import llama
 from .generation import (_wq, _mlp_cached, _final_head_cached, _sample,
@@ -637,44 +639,51 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, table, positions,
     H, KV, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                  cfg.head_dim)
     cd = cfg.dtype
-    q = (x @ _wq(lp, "q_proj", cd)).reshape(B, P, H, hd)
-    k = (x @ _wq(lp, "k_proj", cd)).reshape(B, P, KV, hd)
-    v = (x @ _wq(lp, "v_proj", cd)).reshape(B, P, KV, hd)
-    q, k = apply_rope_half(q, k, cos, sin, positions)
-    if pks is None:
-        pk = _write_pool(pk, table, positions, k, valid)
-        pv = _write_pool(pv, table, positions, v, valid)
-        kq, vq = k, v
-    else:
-        pk, pks, kq = _write_pool_int8(pk, pks, table, positions, k, valid)
-        pv, pvs, vq = _write_pool_int8(pv, pvs, table, positions, v, valid)
-        # every consumer sees the quantize→dequantize roundtrip of this
-        # call's own writes — a later cached-prefix read of the same
-        # blocks sees the same KV values (warm == cold by construction)
-        kq, vq = kq.astype(cd), vq.astype(cd)
-    if is_prefill:
-        # the prompt attends only to itself: plain causal self-attention
-        # over the right-padded batch (rows past each request's length
-        # produce garbage that is never read — their pool writes are
-        # dropped and their logits never selected)
-        from ..kernels import flash_attention as fa
-        if mesh is not None and fa._pallas_available():
-            # GSPMD cannot partition the Mosaic kernel: each device runs
-            # it on its head shard, like the ragged kernel below
-            o = fa.flash_attention_sharded(
-                q, kq, vq, mesh,
-                PartitionSpec(None, None, mesh_axis, None))
+    with jax.named_scope("attn_qkv"):
+        q = (x @ _wq(lp, "q_proj", cd)).reshape(B, P, H, hd)
+        k = (x @ _wq(lp, "k_proj", cd)).reshape(B, P, KV, hd)
+        v = (x @ _wq(lp, "v_proj", cd)).reshape(B, P, KV, hd)
+        q, k = apply_rope_half(q, k, cos, sin, positions)
+    with jax.named_scope("kv_pool_write"):
+        if pks is None:
+            pk = _write_pool(pk, table, positions, k, valid)
+            pv = _write_pool(pv, table, positions, v, valid)
+            kq, vq = k, v
         else:
-            o = fa._flash_impl(q, kq, vq, True, None)
-    else:
-        # decode AND cached-prefix suffix prefill: gather through the
-        # table with per-query causal visibility (j <= position)
-        o = _paged_gqa_attention(q, pk, pv, table, positions, valid,
-                                 impl=attention_impl, k_scale=pks,
-                                 v_scale=pvs, mesh=mesh,
-                                 mesh_axis=mesh_axis)
-    return (o.reshape(B, P, H * hd) @ _wq(lp, "o_proj", cd)), pk, pv, \
-        pks, pvs
+            pk, pks, kq = _write_pool_int8(pk, pks, table, positions, k,
+                                           valid)
+            pv, pvs, vq = _write_pool_int8(pv, pvs, table, positions, v,
+                                           valid)
+            # every consumer sees the quantize→dequantize roundtrip of
+            # this call's own writes — a later cached-prefix read of the
+            # same blocks sees the same KV values (warm == cold by
+            # construction)
+            kq, vq = kq.astype(cd), vq.astype(cd)
+    with jax.named_scope("attn_kernel"):
+        if is_prefill:
+            # the prompt attends only to itself: plain causal
+            # self-attention over the right-padded batch (rows past each
+            # request's length produce garbage that is never read — their
+            # pool writes are dropped and their logits never selected)
+            from ..kernels import flash_attention as fa
+            if mesh is not None and fa._pallas_available():
+                # GSPMD cannot partition the Mosaic kernel: each device
+                # runs it on its head shard, like the ragged kernel below
+                o = fa.flash_attention_sharded(
+                    q, kq, vq, mesh,
+                    PartitionSpec(None, None, mesh_axis, None))
+            else:
+                o = fa._flash_impl(q, kq, vq, True, None)
+        else:
+            # decode AND cached-prefix suffix prefill: gather through the
+            # table with per-query causal visibility (j <= position)
+            o = _paged_gqa_attention(q, pk, pv, table, positions, valid,
+                                     impl=attention_impl, k_scale=pks,
+                                     v_scale=pvs, mesh=mesh,
+                                     mesh_axis=mesh_axis)
+    with jax.named_scope("attn_out"):
+        o = o.reshape(B, P, H * hd) @ _wq(lp, "o_proj", cd)
+    return o, pk, pv, pks, pvs
 
 
 def forward_paged(params, tokens, cache: PagedKVCache, positions, valid,
@@ -691,7 +700,8 @@ def forward_paged(params, tokens, cache: PagedKVCache, positions, valid,
     # rope spans the per-request table width (max reachable position),
     # NOT the whole pool — the pool is ~B x larger by construction
     T_rope = cache.table.shape[1] * cache.k.shape[2]
-    x = jnp.take(params["embed_tokens"], tokens, axis=0).astype(cd)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed_tokens"], tokens, axis=0).astype(cd)
     cos, sin = rope_freqs(cfg.head_dim, T_rope, cfg.rope_theta, jnp.float32)
     visible_len = positions[:, -1] + 1
 
@@ -701,34 +711,41 @@ def forward_paged(params, tokens, cache: PagedKVCache, positions, valid,
         # quantization jaxpr (None adds no carry leaves), keeping the
         # fp path byte-identical with quantization off
         x, pk_all, pv_all, ks_all, vs_all, li = carry
-        pk = lax.dynamic_slice_in_dim(pk_all, li, 1, 0)[0]
-        pv = lax.dynamic_slice_in_dim(pv_all, li, 1, 0)[0]
-        ks = None if ks_all is None else \
-            lax.dynamic_slice_in_dim(ks_all, li, 1, 0)[0]
-        vs = None if vs_all is None else \
-            lax.dynamic_slice_in_dim(vs_all, li, 1, 0)[0]
-        h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
+        with jax.named_scope("kv_pool_read"):
+            pk = lax.dynamic_slice_in_dim(pk_all, li, 1, 0)[0]
+            pv = lax.dynamic_slice_in_dim(pv_all, li, 1, 0)[0]
+            ks = None if ks_all is None else \
+                lax.dynamic_slice_in_dim(ks_all, li, 1, 0)[0]
+            vs = None if vs_all is None else \
+                lax.dynamic_slice_in_dim(vs_all, li, 1, 0)[0]
+        with jax.named_scope("attn_qkv"):
+            h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
         a, pk, pv, ks, vs = _attention_paged(
             h, lp, cfg, cos, sin, pk, pv, cache.table, positions, valid,
             is_prefill, attention_impl, ks, vs, mesh=mesh,
             mesh_axis=mesh_axis)
-        pk_all = lax.dynamic_update_slice_in_dim(pk_all, pk[None], li, 0)
-        pv_all = lax.dynamic_update_slice_in_dim(pv_all, pv[None], li, 0)
-        if ks_all is not None:
-            ks_all = lax.dynamic_update_slice_in_dim(ks_all, ks[None],
-                                                     li, 0)
-            vs_all = lax.dynamic_update_slice_in_dim(vs_all, vs[None],
-                                                     li, 0)
-        x = x + a
-        h = rms_norm_ref(x, lp["post_attention_layernorm"],
-                         cfg.rms_norm_eps)
-        x = x + _mlp_cached(h, lp, cfg)
+        with jax.named_scope("kv_pool_write"):
+            pk_all = lax.dynamic_update_slice_in_dim(pk_all, pk[None], li,
+                                                     0)
+            pv_all = lax.dynamic_update_slice_in_dim(pv_all, pv[None], li,
+                                                     0)
+            if ks_all is not None:
+                ks_all = lax.dynamic_update_slice_in_dim(
+                    ks_all, ks[None], li, 0)
+                vs_all = lax.dynamic_update_slice_in_dim(
+                    vs_all, vs[None], li, 0)
+        with jax.named_scope("mlp"):
+            x = x + a
+            h = rms_norm_ref(x, lp["post_attention_layernorm"],
+                             cfg.rms_norm_eps)
+            x = x + _mlp_cached(h, lp, cfg)
         return (x, pk_all, pv_all, ks_all, vs_all, li + 1), None
 
     (x, pk, pv, ks, vs, _), _ = lax.scan(
         body, (x, cache.k, cache.v, cache.k_scale, cache.v_scale,
                jnp.int32(0)), params["layers"])
-    logits = _final_head_cached(params, x, cfg)
+    with jax.named_scope("lm_head"):
+        logits = _final_head_cached(params, x, cfg)
     new_len = jnp.maximum(cache.lengths, visible_len)
     return logits, PagedKVCache(pk, pv, cache.table, new_len, ks, vs)
 
@@ -796,6 +813,136 @@ def paged_generate(params, tokens, lengths, cfg: llama.LlamaConfig,
     out = jnp.concatenate([first[:, None], rest.T.astype(jnp.int32)],
                           axis=1)
     return out, allocator, owned
+
+
+class _Tick:
+    """One device-call tick of the batcher, described once.
+
+    Every kind of tick (decode, fused, prefill, spec_draft, spec_verify)
+    runs inside `with _Tick(batcher, mode, ...) as tick:` and names its
+    phases with `tick.phase("pack" | "dispatch" | "wait" | "commit")`.
+    From that one description come
+
+      * the flight record, written BEFORE the call (the tick that raises
+        stays the ring's last record, unclosed) and closed after it with
+        the phase times (`pack_s`, `dispatch_s`, `wait_s`, `commit_s`),
+        where on the clock dispatch began and the read-back returned
+        (`t_dispatch`, `t_synced`), whether the tick synced at all, and
+        the slots still decoding when it ended (`live_after`);
+      * `RecordEvent` spans `serve.tick` (tagged with the record's `seq`
+        and the mode) and `serve.<phase>`, which a running jax profiler
+        trace puts on its host plane, on the device events' clock;
+      * the fault injector's gate, after the record is written;
+      * for a tick that synced, the profiler's per-shape sample
+        (`device_s = dispatch_s + wait_s`) and the sink's device-lane
+        span: the time from issue to read-back, measured by the sync
+        the tick makes anyway.
+
+    One code path yields span and stamp, so the two cannot drift. Host
+    values only (SYNC001 polices it): the one device touch is `fence`,
+    which runs only inside an armed capture window."""
+
+    def __init__(self, cb: "ContinuousBatcher", mode: str, touched,
+                 shape: Tuple[int, int], **fields):
+        """`touched`: every rid the call touches (the gate's view);
+        `shape`: the (bucket, units) of the profiler's shape key;
+        `fields` go on the flight record as they are."""
+        self.cb, self.mode = cb, mode
+        self.rids = [int(r) for r in touched]
+        self.bucket, self.units = int(shape[0]), int(shape[1])
+        self.fields = fields
+        self.stamps = {"pack": 0.0, "dispatch": 0.0, "wait": 0.0,
+                       "commit": 0.0}
+        self.t_dispatch: Optional[float] = None
+        self.t_synced: Optional[float] = None
+
+    def __enter__(self) -> "_Tick":
+        cb = self.cb
+        seq = cb.flight.record(
+            self.mode, active_slots=sum(cb.active),
+            queue_depth=len(cb.queue), pending=len(cb._pending),
+            free_slots=cb.free_slots(),
+            free_blocks=cb.alloc.free_blocks, **self.fields)
+        # the fault injector's seam at the device-call boundary: a no-op
+        # in production, after the record so that an injected failure's
+        # tick is the ring's last record, like a real one's
+        if cb._fault is not None:
+            cb._fault.check(self.mode, self.rids)
+        self.captured = cb.profiler.should_fence()
+        self.recording = self.captured or cb.profiler.sample_every > 0
+        self._span = RecordEvent("serve.tick", seq=seq, mode=self.mode)
+        self._span.begin()
+        return self
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        """Span `serve.<name>` and the stamp `<name>_s`, from one pair
+        of clock reads. A phase entered twice adds up."""
+        span = RecordEvent("serve." + name)
+        span.begin()
+        t0 = time.perf_counter()
+        if name == "dispatch" and self.t_dispatch is None:
+            self.t_dispatch = t0
+        try:
+            yield
+        finally:
+            t1 = time.perf_counter()
+            self.stamps[name] += t1 - t0
+            span.end()
+        if name == "wait":
+            self.t_synced = t1
+
+    def fence(self, outputs) -> None:
+        """Inside an armed capture window (`arm_capture`,
+        `capture_profile()`, `POST /debug/profile`) drain the call just
+        issued, so that a tick that reads nothing back is measured too.
+        THE DOCUMENTED SYNC001 CAPTURE-WINDOW EXCEPTION: an operator
+        asked for these ticks to be fenced; outside a window this is
+        one attribute test and no tick ever blocks on the device here
+        (tests/test_tick.py counts the calls)."""
+        if self.captured:
+            with self.phase("wait"):
+                jax.block_until_ready(outputs)
+
+    @property
+    def call_s(self) -> float:
+        """Host wall of the call so far: packing, issue, read-back."""
+        return (self.stamps["pack"] + self.stamps["dispatch"]
+                + self.stamps["wait"])
+
+    @property
+    def device_s(self) -> Optional[float]:
+        """Issue to read-back, for a synced tick the profiler records;
+        None otherwise."""
+        if self.t_synced is None or not self.recording:
+            return None
+        return self.stamps["dispatch"] + self.stamps["wait"]
+
+    def __exit__(self, etype, exc, tb) -> bool:
+        self._span.end()
+        if etype is not None:
+            return False            # the record stays unclosed
+        cb, st = self.cb, self.stamps
+        cb.flight.close(
+            pack_s=st["pack"], dispatch_s=st["dispatch"],
+            wait_s=st["wait"], commit_s=st["commit"],
+            t_dispatch=self.t_dispatch, t_synced=self.t_synced,
+            synced=self.t_synced is not None,
+            live_after=sum(cb.active))
+        device_s = self.device_s
+        if device_s is not None:
+            cb.profiler.record(
+                mode=self.mode, bucket=self.bucket, units=self.units,
+                impl=cb.attention_impl, weight_dtype=cb.weight_dtype,
+                kv_dtype=cb.kv_dtype, device_s=device_s,
+                host_s=st["dispatch"], detail={"rids": self.rids})
+            if cb._trace is not None:
+                cb._trace.span(
+                    "device." + self.mode, dur=device_s, lane="device",
+                    t1=self.t_synced, mode=self.mode, bucket=self.bucket,
+                    units=self.units, host_s=round(st["dispatch"], 6),
+                    impl=cb.attention_impl, replica_id=cb.replica_id)
+        return False
 
 
 class ContinuousBatcher:
@@ -1159,11 +1306,10 @@ class ContinuousBatcher:
         # the serving package eagerly.
         from ..serving.profiling import StepProfiler
         from ..serving.trace import FlightRecorder, TraceSink
-        # sampled device-time attribution: every Nth device-call tick
-        # (profile_sample_every; 0 disables) is fenced with
-        # block_until_ready and its device wall lands in bounded
-        # per-shape histograms — see _profile_t0/_profile_commit for
-        # the documented SYNC001 sample gate
+        # device-time attribution: every tick that reads its result back
+        # hands the time from issue to read-back to bounded per-shape
+        # histograms (profile_sample_every=0 turns that off; no value
+        # costs a sync) — see _Tick; only an armed capture window fences
         self.profiler = StepProfiler(sample_every=profile_sample_every)
         if trace is True:
             # mirror the engine's bool API: True means "a default sink"
@@ -1719,9 +1865,9 @@ class ContinuousBatcher:
         onto the decode chunk or standalone, cold or continuing — and,
         on the FIRST chunk, how many prompt tokens the prefix cache
         skipped (the cached-prefix skip the timeline makes visible).
-        `device_dur` (seconds) rides along when the sampled profiler
-        fenced this call: the chunk's DEVICE wall next to its host
-        wall, so a capture window's timelines attribute regressions to
+        `device_dur` (seconds) rides along when the tick synced and the
+        profiler records: the call's DEVICE wall (issue to read-back)
+        next to its host wall, so timelines attribute regressions to
         the kernel vs host scheduling."""
         self.prefill_chunk_calls += len(items)
         if self._trace is None:
@@ -1736,67 +1882,17 @@ class ContinuousBatcher:
                 cached_tokens=rec.cached_len if start == rec.cached_len
                 else 0, **extra)
 
-    def _record_tick(self, mode: str, **fields) -> None:
-        """Append one flight-recorder record for this step tick: the
-        scheduler's decision plus pool/queue state, recorded BEFORE the
-        device call so the tick that raises is the ring's last record."""
-        self.flight.record(
-            mode, active_slots=sum(self.active),
-            queue_depth=len(self.queue), pending=len(self._pending),
-            free_slots=self.free_slots(),
-            free_blocks=self.alloc.free_blocks, **fields)
+    def _decode_ctx(self, slots) -> List[int]:
+        """Keys each of `slots` attends to in this tick's first decode
+        step: its prompt, its tokens so far, the one being written."""
+        return [len(self.slot_tokens[s]) + len(self.outputs[self.slot_req[s]])
+                for s in slots]
 
-    def _profile_t0(self):
-        """The sampled-profiler gate, taken once per device-call tick:
-        returns a perf_counter start time when THIS tick is fenced
-        (every `profile_sample_every`th tick, or any tick of an armed
-        capture window), None otherwise. The unfenced path is one
-        locked counter bump — no device work, no syncs."""
-        return time.perf_counter() if self.profiler.should_fence() \
-            else None
-
-    def _profile_commit(self, t0, outputs, *, mode: str, bucket: int,
-                        units: int, rids) -> Optional[float]:
-        """Fence an ALREADY-ISSUED device call and attribute its walls:
-        host_s is dispatch wall (the call returning control), device_s
-        is call-start → block_until_ready completion. Records into the
-        profiler's per-(mode, bucket, units, impl, qkey) histograms
-        and, when a sink is attached, a device-lane trace span so
-        timelines carry device wall next to host wall. Returns
-        device_s, or None for an unfenced tick.
-
-        THE DOCUMENTED SYNC001 SAMPLE GATE: `jax.block_until_ready`
-        here is a deliberate host↔device sync — one fenced step in
-        `profile_sample_every`, never in the unfenced path, and the
-        compiled-shape memo keys never see the profiler (zero
-        post-warmup recompiles holds with sampling on — gated by
-        `bench_serving.py --slo`)."""
-        if t0 is None:
-            return None
-        host_s = time.perf_counter() - t0
-        jax.block_until_ready(outputs)
-        device_s = time.perf_counter() - t0
-        self.profiler.record(
-            mode=mode, bucket=int(bucket), units=int(units),
-            impl=self.attention_impl, weight_dtype=self.weight_dtype,
-            kv_dtype=self.kv_dtype, device_s=device_s, host_s=host_s,
-            detail={"rids": [int(r) for r in rids]})
-        if self._trace is not None:
-            self._trace.span(
-                "device." + mode, dur=device_s, lane="device",
-                mode=mode, bucket=int(bucket), units=int(units),
-                host_s=round(host_s, 6), impl=self.attention_impl,
-                replica_id=self.replica_id)
-        return device_s
-
-    def _gate(self, mode: str, rids, probe: bool = False) -> None:
-        """Fault-injection hook at the device-call boundary: a no-op in
-        production (no injector), the chaos harness's seam in tests and
-        `bench_serving.py --chaos`. Called AFTER `_record_tick` so an
-        injected failure's tick is the flight ring's last record, like
-        a real device fault's would be."""
+    def _probe_gate(self, rid: int) -> None:
+        """Fault-injection hook of the quarantine probes (a tick's own
+        gate is in `_Tick.__enter__`): a no-op without an injector."""
         if self._fault is not None:
-            self._fault.check(mode, rids, probe=probe)
+            self._fault.check("probe", [rid], probe=True)
 
     # -- bucketed / chunked / batched prefill -----------------------------
     def _bucket_for(self, S: int) -> int:
@@ -1840,8 +1936,8 @@ class ContinuousBatcher:
         cfg, impl = self.cfg, self.attention_impl
         mesh, max_ = self._mesh, self._mesh_axis()
 
-        def prefill(params, rows, k, v, ks, vs, table, positions, valid,
-                    lengths):
+        def serve_prefill_step(params, rows, k, v, ks, vs, table, positions,
+                               valid, lengths):
             sub = PagedKVCache(k, v, table, lengths, ks, vs)
             logits, sub = forward_paged(params, rows, sub, positions,
                                         valid, cfg, is_prefill=cold,
@@ -1849,7 +1945,7 @@ class ContinuousBatcher:
                                         mesh_axis=max_)
             return logits, sub.k, sub.v, sub.k_scale, sub.v_scale
 
-        return jax.jit(prefill)
+        return jax.jit(serve_prefill_step)
 
     def _prefill_exe(self, G: int, Pb: int, cold: bool):
         """Memoized COMPILED prefill per (group, bucket, phase) shape.
@@ -2129,12 +2225,11 @@ class ContinuousBatcher:
         self.prefill_pad_tokens += Gp * Pb - real
         return rows, pos, val, tab, li
 
-    def _prefill_call(self, items: Sequence[Tuple[_Admission, int, int]],
-                      Pb: int, cold: bool):
-        """Run ONE compiled standalone prefill over a unit's rows.
-        Returns (logits [Gp, Pb, V], last real index per row [Gp])."""
-        Gp = self._group_pad(len(items))
-        rows, pos, val, tab, li = self._pack_prefill_rows(items, Pb, Gp)
+    def _prefill_call(self, packed, cold: bool):
+        """Issue ONE compiled standalone prefill over a unit's packed
+        rows (`_pack_prefill_rows`). Returns logits [Gp, Pb, V]."""
+        rows, pos, val, tab, _li = packed
+        Gp, Pb = rows.shape
         exe = self._prefill_exe(Gp, Pb, cold)
         logits, k, v, ks, vs = exe(
             self.params, jnp.asarray(rows), self.cache.k, self.cache.v,
@@ -2142,7 +2237,7 @@ class ContinuousBatcher:
             jnp.asarray(pos), jnp.asarray(val),
             jnp.zeros((Gp,), jnp.int32))
         self.cache = self.cache._replace(k=k, v=v, k_scale=ks, v_scale=vs)
-        return logits, li
+        return logits
 
     def _units(self,
                recs: Sequence[_Admission]) -> List[List[_Admission]]:
@@ -2293,11 +2388,9 @@ class ContinuousBatcher:
         return self._unit_view(unit, [entry_of[id(r)] for r in unit])
 
     def _finish_unit(self, entries, firsts) -> None:
-        """Commit a unit whose FINAL chunk just computed: one readback
-        of every first token at once, then activate each record."""
-        # ptlint: disable=SYNC001 — the unit's single coalesced
-        # readback (docstring): one sync per prefill unit, not per token
-        firsts = np.asarray(firsts)
+        """Commit a unit whose FINAL chunk just computed: activate each
+        record with its first token (`firsts`: host values, read back
+        once per unit by the caller's wait phase)."""
         for entry, first in zip(entries, firsts):
             self._commit(entry[0], int(first))
             self._pending.remove(entry)
@@ -2306,38 +2399,46 @@ class ContinuousBatcher:
         """Run ONE standalone prefill call for the head pending unit —
         the PR4 path: nothing decodes while it runs, so it only ever
         executes when the decode set is empty (nothing to stall) or
-        fusion is off (`decode_stall_steps` then counts the cost)."""
+        fusion is off (`decode_stall_steps` then counts the cost). The
+        one kind of tick that may not sync: a non-final chunk reads
+        nothing back, and its device time shows in the next tick's
+        wait."""
         entries, items, bucket, cold, final = self._pop_unit()
         Gp = self._group_pad(len(items))
         unit_rids = [r.rid for r, _, _ in items]
-        self._record_tick(
-            "prefill", rids=unit_rids, bucket=bucket,
-            group_pad=Gp, cold=cold, final=final,
-            stalls_decode=any(self.active),
-            compile_hit=(Gp, bucket, cold, self.attention_impl)
-            + self._skey + self._qkey + self._mkey
-            in self._prefill_cache)
-        self._gate("prefill", unit_rids)
-        t0 = time.perf_counter()
-        self._apply_cow([e[0] for e in entries if e[1] == 0])
-        t_prof = self._profile_t0()
-        logits, li = self._prefill_call(items, bucket, cold)
-        dev_s = self._profile_commit(
-            t_prof, (logits, self.cache.k, self.cache.v),
-            mode="prefill", bucket=bucket,
-            units=self._group_pad(len(items)), rids=unit_rids)
-        if final:
-            # ragged last-token logits per row, ONE readback per unit
-            # (inside _finish_unit) — li came packed with the rows
-            g = len(items)
-            last = jnp.argmax(
-                logits[jnp.arange(g), jnp.asarray(li[:g])], axis=-1)
-            self._finish_unit(entries, last)
-        else:
-            entries[0][1] += 1
-        self._trace_chunks(items, bucket, fused=False,
-                           dur=time.perf_counter() - t0,
-                           device_dur=dev_s)
+        with _Tick(
+                self, "prefill", unit_rids, (bucket, Gp), rids=unit_rids,
+                bucket=bucket, group_pad=Gp, cold=cold, final=final,
+                prefill_spans=[[start, end] for _, start, end in items],
+                stalls_decode=any(self.active),
+                compile_hit=(Gp, bucket, cold, self.attention_impl)
+                + self._skey + self._qkey + self._mkey
+                in self._prefill_cache) as tick:
+            with tick.phase("pack"):
+                self._apply_cow([e[0] for e in entries if e[1] == 0])
+                packed = self._pack_prefill_rows(items, bucket, Gp)
+            with tick.phase("dispatch"):
+                logits = self._prefill_call(packed, cold)
+                if final:
+                    # ragged last-token logits per row — li came packed
+                    # with the rows
+                    g = len(items)
+                    last = jnp.argmax(
+                        logits[jnp.arange(g), jnp.asarray(packed[4][:g])],
+                        axis=-1)
+            tick.fence((logits, self.cache.k, self.cache.v))
+            if final:
+                with tick.phase("wait"):
+                    # ONE readback per unit: every first token at once
+                    last = np.asarray(last)  # ptlint: disable=SYNC001 — the unit's single coalesced readback, one sync per prefill unit
+            with tick.phase("commit"):
+                if final:
+                    self._finish_unit(entries, last)
+                else:
+                    entries[0][1] += 1
+                self._trace_chunks(items, bucket, fused=False,
+                                   dur=tick.call_s,
+                                   device_dur=tick.device_s)
 
     def _fail_pending(self) -> None:
         """A failed prefill/fused call must not leak blocks OR silently
@@ -2396,7 +2497,7 @@ class ContinuousBatcher:
         fault injector) raises; returning means the slot is clean.
         Failure-path only: never called on the hot path."""
         rid = self.slot_req[slot]
-        self._gate("probe", [rid], probe=True)
+        self._probe_gate(rid)
         act = [False] * self.B
         act[slot] = True
         out = self._chunk_exe()(
@@ -2423,7 +2524,7 @@ class ContinuousBatcher:
         if entry is None:
             return
         _, toks, stop, mn = entry
-        self._gate("probe", [rid], probe=True)
+        self._probe_gate(rid)
         try:
             rec = self._prepare_admission(-1, rid, toks, stop, mn,
                                           quiet=True)
@@ -2432,8 +2533,9 @@ class ContinuousBatcher:
         try:
             start, end, bucket = rec.chunks[0]
             self._apply_cow([rec])
-            logits, _ = self._prefill_call([(rec, start, end)], bucket,
-                                           cold=start == 0)
+            logits = self._prefill_call(
+                self._pack_prefill_rows([(rec, start, end)], bucket, 1),
+                cold=start == 0)
             jax.block_until_ready(logits)
         finally:
             self._rollback([rec])
@@ -2487,12 +2589,12 @@ class ContinuousBatcher:
                     unwritten.update(rec.inserted)
         return groups, bucket0
 
-    def _step_fused(self):
+    def _step_fused(self, decoding) -> None:
         """Piggyback up to `fused_units` pending prefill units on this
         step's decode chunk: ONE compiled call advances every active
         slot by its chunk AND prefills the selected same-bucket
-        admission chunks. Returns the decode chunk's tokens [B, chunk]
-        (host copy)."""
+        admission chunks; then deliver the `decoding` slots' tokens."""
+        committed = False
         try:
             groups, bucket = self._pop_fused_units()
             # every selected unit pads to the SAME group size so the
@@ -2500,70 +2602,79 @@ class ContinuousBatcher:
             # finite warmed ladder whatever mix of units rides
             Gp = max(self._group_pad(len(items))
                      for _, items, _ in groups)
-            decode_rids = [self.slot_req[s] for s in range(self.B)
-                           if self.active[s]]
+            decode_rids = [self.slot_req[s] for s in decoding]
             unit_rids = [[r.rid for r, _, _ in items]
                          for _, items, _ in groups]
-            self._record_tick(
-                "fused", units=unit_rids, decode_rids=decode_rids,
-                bucket=bucket, group_pad=Gp, rows=len(groups) * Gp,
-                compile_hit=(len(groups) * Gp, bucket,
-                             self.attention_impl) + self._skey
-                + self._qkey + self._mkey in self._fused_cache)
-            self._gate("fused",
-                       decode_rids + [r for u in unit_rids for r in u])
-            t0 = time.perf_counter()
-            self._apply_cow([e[0] for entries, _, _ in groups
-                             for e in entries if e[1] == 0])
-            packs = [self._pack_prefill_rows(items, bucket, Gp)
-                     for _, items, _ in groups]
-            rows, pos, val, tab, li = (
-                np.concatenate([p[i] for p in packs], axis=0)
-                for i in range(5))
-            exe = self._fused_exe(len(groups) * Gp, bucket)
-            if self._dev_state is None:
-                self._dev_state = self._upload_slot_state()
-            active, budget, stop = self._dev_state
-            t_prof = self._profile_t0()
-            (k, v, ks, vs, lengths, tok, budget, active, toks,
-             pfirst) = exe(
-                self.params, self.cache.k, self.cache.v,
-                self.cache.k_scale, self.cache.v_scale,
-                self.cache.table, self.cache.lengths, self.cur_tok,
-                active, budget, stop, jnp.asarray(rows),
-                jnp.asarray(pos), jnp.asarray(val), jnp.asarray(tab),
-                jnp.asarray(li))
-            dev_s = self._profile_commit(
-                t_prof, (k, v, toks, pfirst), mode="fused",
-                bucket=bucket, units=len(groups),
-                rids=decode_rids + [r for u in unit_rids for r in u])
-            # one host sync serves BOTH the decode chunk's tokens and
-            # the prefill rows' first tokens — and, dispatch being
-            # async, surfaces any device-side failure HERE, before the
-            # batcher state commits below
-            toks, pfirst = jax.device_get((toks, pfirst))  # ptlint: disable=SYNC001 — single per-step sync, decode + prefill readbacks coalesced
+            with _Tick(
+                    self, "fused",
+                    decode_rids + [r for u in unit_rids for r in u],
+                    (bucket, len(groups)), units=unit_rids,
+                    decode_rids=decode_rids, bucket=bucket, group_pad=Gp,
+                    rows=len(groups) * Gp, chunk=self.chunk,
+                    decode_ctx=self._decode_ctx(decoding),
+                    prefill_spans=[[start, end] for _, items, _ in groups
+                                   for _, start, end in items],
+                    compile_hit=(len(groups) * Gp, bucket,
+                                 self.attention_impl) + self._skey
+                    + self._qkey + self._mkey in self._fused_cache
+                    ) as tick:
+                with tick.phase("pack"):
+                    self._apply_cow([e[0] for entries, _, _ in groups
+                                     for e in entries if e[1] == 0])
+                    packs = [self._pack_prefill_rows(items, bucket, Gp)
+                             for _, items, _ in groups]
+                    rows, pos, val, tab, li = (
+                        np.concatenate([p[i] for p in packs], axis=0)
+                        for i in range(5))
+                    exe = self._fused_exe(len(groups) * Gp, bucket)
+                    if self._dev_state is None:
+                        self._dev_state = self._upload_slot_state()
+                    active, budget, stop = self._dev_state
+                with tick.phase("dispatch"):
+                    (k, v, ks, vs, lengths, tok, budget, active, toks,
+                     pfirst) = exe(
+                        self.params, self.cache.k, self.cache.v,
+                        self.cache.k_scale, self.cache.v_scale,
+                        self.cache.table, self.cache.lengths,
+                        self.cur_tok, active, budget, stop,
+                        jnp.asarray(rows), jnp.asarray(pos),
+                        jnp.asarray(val), jnp.asarray(tab),
+                        jnp.asarray(li))
+                tick.fence((k, v, toks, pfirst))
+                with tick.phase("wait"):
+                    # one host sync serves BOTH the decode chunk's
+                    # tokens and the prefill rows' first tokens — and,
+                    # dispatch being async, surfaces any device-side
+                    # failure HERE, before the batcher state commits
+                    toks, pfirst = jax.device_get((toks, pfirst))  # ptlint: disable=SYNC001 — single per-step sync, decode + prefill readbacks coalesced
+                # decode state untouched up to here: a failure rolls
+                # the pending units back (below)
+                committed = True
+                with tick.phase("commit"):
+                    self.cache = self.cache._replace(
+                        k=k, v=v, k_scale=ks, v_scale=vs, lengths=lengths)
+                    self.cur_tok = tok
+                    self._dev_state = (active, budget, stop)
+                    self.fused_steps += 1
+                    self.fused_unit_count += len(groups)
+                    # commit IN ORDER: group g's real rows sit at
+                    # [g*Gp, g*Gp+|items|) of the concatenated prefill
+                    # batch, so pfirst slices per group
+                    for g, (entries, items, final) in enumerate(groups):
+                        if final:
+                            self._finish_unit(
+                                entries,
+                                pfirst[g * Gp:g * Gp + len(items)])
+                        else:
+                            entries[0][1] += 1
+                        self._trace_chunks(items, bucket, fused=True,
+                                           dur=tick.call_s,
+                                           device_dur=tick.device_s)
+                    self._emit_chunk(decoding, toks)
         except Exception:
-            # decode state untouched (the assignments below never ran)
-            self._fail_pending()
+            if not committed:
+                self._fail_pending()
             raise
-        self.cache = self.cache._replace(k=k, v=v, k_scale=ks,
-                                         v_scale=vs, lengths=lengths)
-        self.cur_tok = tok
-        self._dev_state = (active, budget, stop)
-        self.fused_steps += 1
-        self.fused_unit_count += len(groups)
-        fused_dur = time.perf_counter() - t0
-        # commit IN ORDER: group g's real rows sit at [g*Gp, g*Gp+|items|)
-        # of the concatenated prefill batch, so pfirst slices per group
-        for g, (entries, items, final) in enumerate(groups):
-            if final:
-                self._finish_unit(entries,
-                                  pfirst[g * Gp:g * Gp + len(items)])
-            else:
-                entries[0][1] += 1
-            self._trace_chunks(items, bucket, fused=True, dur=fused_dur,
-                               device_dur=dev_s)
-        return toks
 
     def _retire(self, slot: int) -> None:
         rid = self.slot_req[slot]
@@ -2661,16 +2772,17 @@ class ContinuousBatcher:
         the fused chunk's first token so the two cannot diverge (token
         parity between them is by construction)."""
         eos = -1 if self.eos is None else int(self.eos)
-        nxt = jnp.argmax(logits_row, axis=-1).astype(jnp.int32)
-        nxt = jnp.where(act, nxt, tok)
-        lengths = lengths + act.astype(jnp.int32)
-        budget = budget - act.astype(jnp.int32)
-        # deactivate ON DEVICE the moment a slot's budget runs
-        # out or it emits eos / its own stop id — a fixed-size
-        # chunk must not keep writing past the slot's ALLOCATED
-        # blocks (the table row's padding points at block 0,
-        # i.e. someone else's cache)
-        act = act & (budget > 0) & (nxt != eos) & (nxt != stop)
+        with jax.named_scope("sample"):
+            nxt = jnp.argmax(logits_row, axis=-1).astype(jnp.int32)
+            nxt = jnp.where(act, nxt, tok)
+            lengths = lengths + act.astype(jnp.int32)
+            budget = budget - act.astype(jnp.int32)
+            # deactivate ON DEVICE the moment a slot's budget runs
+            # out or it emits eos / its own stop id — a fixed-size
+            # chunk must not keep writing past the slot's ALLOCATED
+            # blocks (the table row's padding points at block 0,
+            # i.e. someone else's cache)
+            act = act & (budget > 0) & (nxt != eos) & (nxt != stop)
         return nxt, lengths, budget, act
 
     def _decode_step_body(self, params, stop):
@@ -2697,7 +2809,8 @@ class ContinuousBatcher:
     def _build_chunk(self):
         chunk = self.chunk
 
-        def run_chunk(params, cache, tok, active, lengths, budget, stop):
+        def serve_decode_step(params, cache, tok, active, lengths, budget,
+                              stop):
             step = self._decode_step_body(params, stop)
             (cache, tok, lengths, budget, act), toks = jax.lax.scan(
                 step, (cache, tok, lengths, budget, active), None,
@@ -2706,7 +2819,7 @@ class ContinuousBatcher:
             # them in again without a host round-trip
             return cache, tok, lengths, budget, act, toks.T   # [B, chunk]
 
-        return jax.jit(run_chunk)
+        return jax.jit(serve_decode_step)
 
     def _chunk_exe(self):
         """Memoized COMPILED plain decode chunk, AOT-lowered like the
@@ -2750,8 +2863,9 @@ class ContinuousBatcher:
         mesh, max_ = self._mesh, self._mesh_axis()
         maxpos = self.M * self.bs - 1
 
-        def run_fused(params, k, v, ks, vs, table, lengths, tok, active,
-                      budget, stop, prows, ppos, pval, ptab, plast):
+        def serve_fused_step(params, k, v, ks, vs, table, lengths, tok,
+                             active, budget, stop, prows, ppos, pval, ptab,
+                             plast):
             Gp, Pb = prows.shape
             # decode rows ride the prefill chunk's width: token in
             # column 0 at the slot's current position, the rest padding
@@ -2770,8 +2884,9 @@ class ContinuousBatcher:
                 jnp.concatenate([dval, pval], 0), cfg, is_prefill=False,
                 attention_impl=impl, mesh=mesh, mesh_axis=max_)
             # ragged last-token logits per prefill row → first tokens
-            pfirst = jnp.argmax(logits[B:][jnp.arange(Gp), plast],
-                                axis=-1).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                pfirst = jnp.argmax(logits[B:][jnp.arange(Gp), plast],
+                                    axis=-1).astype(jnp.int32)
             nxt, lengths, budget, active = self._emit_one(
                 logits[:B, 0], tok, active, lengths, budget, stop)
             cache = PagedKVCache(sub.k, sub.v, table, lengths,
@@ -2785,7 +2900,7 @@ class ContinuousBatcher:
                     lengths, tok, budget, active,
                     toks.T, pfirst)                       # toks [B, chunk]
 
-        return jax.jit(run_fused)
+        return jax.jit(serve_fused_step)
 
     def _fused_exe(self, Gp: int, Pb: int):
         """Memoized COMPILED fused chunk per (prefill rows, bucket)
@@ -3214,84 +3329,85 @@ class ContinuousBatcher:
             self._spec_cache[key] = exe
         return exe
 
-    def _step_spec(self):
+    def _step_spec(self, decoding):
         """One speculative decode tick: the draft proposes spec_k
         tokens per active slot off the truncated stack, the target
         verifies all k+1 positions in one call and commits only the
-        accepted rows. Returns (out_toks [B, k+1], n_emit [B]) as host
-        arrays — ONE host sync per tick, like the fused path."""
-        decode_rids = [self.slot_req[s] for s in range(self.B)
-                       if self.active[s]]
-        if self._dev_state is None:
-            self._dev_state = self._upload_slot_state()
-        active, budget, stop = self._dev_state
-        if self._spec_ok_dev is None:
-            # per-slot spec participation (quarantine fallback: opted-
-            # out victims decode plain through the same verify call) —
-            # refreshed only when admit/retire changes slot occupancy
-            self._spec_ok_dev = jnp.asarray(
-                [self.slot_req[s] is not None
-                 and self.slot_req[s] not in self._no_spec
-                 for s in range(self.B)])
+        accepted rows, and the `decoding` slots' tokens are delivered.
+        Returns (out_toks [B, k+1], n_emit [B]) as host arrays.
+        Two device calls, so two ticks: the draft's reads nothing back
+        (its device time shows in the verify's wait), the verify's
+        makes the ONE host sync, like the fused path."""
+        decode_rids = [self.slot_req[s] for s in decoding]
+        decode_ctx = self._decode_ctx(decoding)
         c = self.cache
-        self._record_tick(
-            "spec_draft", rids=decode_rids, k=self.spec_k,
-            compile_hit=self._spec_key("draft") in self._spec_cache)
-        self._gate("spec_draft", decode_rids)
-        t0 = time.perf_counter()
-        t_prof = self._profile_t0()
-        drafts = self._spec_draft_exe()(
-            self.params, self._spec_dlayers, c.k, c.v, c.k_scale,
-            c.v_scale, c.table, c.lengths, self.cur_tok, active)
-        self._profile_commit(t_prof, drafts, mode="spec_draft",
-                             bucket=self.spec_k, units=0,
-                             rids=decode_rids)
-        draft_s = time.perf_counter() - t0
-        self._record_tick(
-            "spec_verify", rids=decode_rids, k=self.spec_k,
-            compile_hit=self._spec_key("verify") in self._spec_cache)
-        self._gate("spec_verify", decode_rids)
-        t1 = time.perf_counter()
-        t_prof = self._profile_t0()
-        (pk, pv, ks, vs, lengths, last, budget, active2, out, n_emit,
-         n_acc) = self._spec_verify_exe()(
-            self.params, c.k, c.v, c.k_scale, c.v_scale, c.table,
-            c.lengths, self.cur_tok, drafts, active, budget, stop,
-            self._spec_ok_dev)
-        dev_s = self._profile_commit(
-            t_prof, (pk, out, n_emit), mode="spec_verify",
-            bucket=self.spec_k, units=0, rids=decode_rids)
-        # one host sync serves tokens, counts AND acceptance — and,
-        # dispatch being async, surfaces any device-side failure HERE,
-        # before the batcher state commits below
-        out, n_emit, n_acc = jax.device_get((out, n_emit, n_acc))  # ptlint: disable=SYNC001 — single per-step sync, token + acceptance readbacks coalesced
-        verify_s = time.perf_counter() - t1
-        self.cache = PagedKVCache(pk, pv, c.table, lengths, ks, vs)
-        self.cur_tok = last
-        self._dev_state = (active2, budget, stop)
-        spec_slots = sum(1 for s in range(self.B) if self.active[s]
-                         and self.slot_req[s] not in self._no_spec)
-        self.spec.record_step(drafted=self.spec_k * spec_slots,
-                              accepted=int(n_acc.sum()),
-                              emitted=int(n_emit.sum()),
-                              slots=len(decode_rids),
-                              depths=[int(n_acc[s])
-                                      for s in range(self.B)
-                                      if self.active[s]
-                                      and self.slot_req[s]
-                                      not in self._no_spec])
-        if self._trace is not None:
-            self._trace.span("spec_draft", dur=draft_s, k=self.spec_k,
-                             slots=len(decode_rids),
-                             replica_id=self.replica_id)
-            for s in range(self.B):
-                if self.active[s]:
+        with _Tick(
+                self, "spec_draft", decode_rids, (self.spec_k, 0),
+                rids=decode_rids, k=self.spec_k, decode_ctx=decode_ctx,
+                compile_hit=self._spec_key("draft") in self._spec_cache
+                ) as tick:
+            with tick.phase("pack"):
+                if self._dev_state is None:
+                    self._dev_state = self._upload_slot_state()
+                active, budget, stop = self._dev_state
+                if self._spec_ok_dev is None:
+                    # per-slot spec participation (quarantine fallback:
+                    # opted-out victims decode plain through the same
+                    # verify call) — refreshed only when admit/retire
+                    # changes slot occupancy
+                    self._spec_ok_dev = jnp.asarray(
+                        [self.slot_req[s] is not None
+                         and self.slot_req[s] not in self._no_spec
+                         for s in range(self.B)])
+            with tick.phase("dispatch"):
+                drafts = self._spec_draft_exe()(
+                    self.params, self._spec_dlayers, c.k, c.v, c.k_scale,
+                    c.v_scale, c.table, c.lengths, self.cur_tok, active)
+            tick.fence(drafts)
+            draft_s = tick.call_s
+        with _Tick(
+                self, "spec_verify", decode_rids, (self.spec_k, 0),
+                rids=decode_rids, k=self.spec_k, decode_ctx=decode_ctx,
+                compile_hit=self._spec_key("verify") in self._spec_cache
+                ) as tick:
+            with tick.phase("dispatch"):
+                (pk, pv, ks, vs, lengths, last, budget, active2, out,
+                 n_emit, n_acc) = self._spec_verify_exe()(
+                    self.params, c.k, c.v, c.k_scale, c.v_scale, c.table,
+                    c.lengths, self.cur_tok, drafts, active, budget, stop,
+                    self._spec_ok_dev)
+            tick.fence((pk, out, n_emit))
+            with tick.phase("wait"):
+                # one host sync serves tokens, counts AND acceptance —
+                # and, dispatch being async, surfaces any device-side
+                # failure HERE, before the batcher state commits below
+                out, n_emit, n_acc = jax.device_get((out, n_emit, n_acc))  # ptlint: disable=SYNC001 — single per-step sync, token + acceptance readbacks coalesced
+            with tick.phase("commit"):
+                self.cache = PagedKVCache(pk, pv, c.table, lengths, ks, vs)
+                self.cur_tok = last
+                self._dev_state = (active2, budget, stop)
+                spec_slots = [s for s in decoding
+                              if self.slot_req[s] not in self._no_spec]
+                self.spec.record_step(
+                    drafted=self.spec_k * len(spec_slots),
+                    accepted=int(n_acc.sum()), emitted=int(n_emit.sum()),
+                    slots=len(decode_rids),
+                    depths=[int(n_acc[s]) for s in spec_slots])
+                if self._trace is not None:
+                    self._trace.span(
+                        "spec_draft", dur=draft_s, k=self.spec_k,
+                        slots=len(decode_rids),
+                        replica_id=self.replica_id)
+                    dev_s = tick.device_s
                     extra = {} if dev_s is None \
                         else {"device_dur": round(dev_s, 6)}
-                    self._trace_emit(
-                        self.slot_req[s], "spec_verify", dur=verify_s,
-                        accepted=int(n_acc[s]), emitted=int(n_emit[s]),
-                        k=self.spec_k, **extra)
+                    for s in decoding:
+                        self._trace_emit(
+                            self.slot_req[s], "spec_verify",
+                            dur=tick.call_s, accepted=int(n_acc[s]),
+                            emitted=int(n_emit[s]), k=self.spec_k,
+                            **extra)
+                self._emit_spec(decoding, out, n_emit)
         return out, n_emit
 
     def _spec_any(self) -> bool:
@@ -3330,74 +3446,84 @@ class ContinuousBatcher:
         step() (the prefill's first token included), `finished` lists
         rids that completed this step (their blocks are already back in
         the pool). A step with nothing in flight is a cheap no-op."""
-        self._admit()
+        with RecordEvent("serve.admit"):
+            self._admit()
         if any(self.active):
             # slots committed by a fused admission AFTER the device call
             # must not read this chunk's token rows — they were inactive
             # (masked) rows during the call itself
             decoding = [s for s in range(self.B) if self.active[s]]
-            if self.speculative and not self._fuse_now() \
-                    and self._spec_any():
-                # speculative tick: draft + verify emit up to spec_k+1
-                # tokens per slot. Admission pressure still rides the
-                # PR 5 fused path (the `_fuse_now` tick above runs a
-                # plain chunk + piggybacked prefill — greedy tokens
-                # are schedule-invariant, so mixing the two step kinds
-                # never changes output)
-                out, n_emit = self._step_spec()
-                self._emit_spec(decoding, out, n_emit)
-                self._admit()
-                return self._drain_emitted()
             if self._fuse_now():
-                toks = self._step_fused()
+                # admission pressure rides the PR 5 fused path even
+                # under speculation (a plain chunk + piggybacked
+                # prefill — greedy tokens are schedule-invariant, so
+                # mixing the step kinds never changes output)
+                self._step_fused(decoding)
+            elif self.speculative and self._spec_any():
+                # speculative tick: draft + verify emit up to spec_k+1
+                # tokens per slot
+                self._step_spec(decoding)
             else:
-                decode_rids = [self.slot_req[s] for s in decoding]
-                self._record_tick(
-                    "decode", rids=decode_rids,
-                    compile_hit=(self.chunk, self.attention_impl)
-                    + self._skey + self._qkey + self._mkey
-                    in self._chunk_cache)
-                self._gate("decode", decode_rids)
+                self._step_decode(decoding)
+            with RecordEvent("serve.admit"):
+                self._admit()
+        return self._drain_emitted()
+
+    def _step_decode(self, decoding) -> None:
+        """The plain decode chunk: `chunk` tokens for every active slot
+        in one compiled call, one host sync, then delivery."""
+        decode_rids = [self.slot_req[s] for s in decoding]
+        with _Tick(
+                self, "decode", decode_rids, (self.chunk, 0),
+                rids=decode_rids, chunk=self.chunk,
+                decode_ctx=self._decode_ctx(decoding),
+                compile_hit=(self.chunk, self.attention_impl)
+                + self._skey + self._qkey + self._mkey
+                in self._chunk_cache) as tick:
+            with tick.phase("pack"):
                 if self._dev_state is None:
                     self._dev_state = self._upload_slot_state()
                 active, budget, stop = self._dev_state
-                t_prof = self._profile_t0()
+            with tick.phase("dispatch"):
                 (self.cache, self.cur_tok, lengths, budget, active,
                  toks) = self._chunk_exe()(
                     self.params, self.cache, self.cur_tok, active,
                     self.cache.lengths, budget, stop)
-                self._profile_commit(
-                    t_prof, (self.cache.k, self.cur_tok, toks),
-                    mode="decode", bucket=self.chunk, units=0,
-                    rids=decode_rids)
+            tick.fence((self.cache.k, self.cur_tok, toks))
+            with tick.phase("wait"):
+                # one host sync per decode chunk — the per-token loop
+                # of the commit reads this numpy copy, never the device
+                toks = np.asarray(toks)  # ptlint: disable=SYNC001 — single per-chunk sync, hoisted out of the per-token loop
+            with tick.phase("commit"):
                 self.cache = self.cache._replace(lengths=lengths)
                 # steady state: the chunk's own outputs are next chunk's
                 # inputs; _retire/_commit null this when the host diverges
                 self._dev_state = (active, budget, stop)
-                # one host sync per decode chunk — the per-token loop
-                # below reads this numpy copy, never the device
-                toks = np.asarray(toks)  # ptlint: disable=SYNC001 — single per-chunk sync, hoisted out of the per-token loop
-            for slot in decoding:
-                rid = self.slot_req[slot]
-                for j in range(self.chunk):
-                    if self.budget[slot] <= 0:
-                        break
-                    t = int(toks[slot, j])
-                    self.outputs[rid].append(t)
-                    self.budget[slot] -= 1
-                    if ((self.eos is not None and t == self.eos)
-                            or t == self.stop[slot]):
-                        break
-                out = self.outputs[rid]
-                done = (self.budget[slot] <= 0 or
-                        (self.eos is not None and out and
-                         out[-1] == self.eos) or
-                        (self.stop[slot] >= 0 and out and
-                         out[-1] == self.stop[slot]))
-                if done:
-                    self._retire(slot)
-            self._admit()
-        return self._drain_emitted()
+                self._emit_chunk(decoding, toks)
+
+    def _emit_chunk(self, decoding, toks) -> None:
+        """Deliver one chunk's tokens [B, chunk] (host copy) to the
+        `decoding` slots — the host mirror of the device stopping rule —
+        and retire the slots that finished."""
+        for slot in decoding:
+            rid = self.slot_req[slot]
+            for j in range(self.chunk):
+                if self.budget[slot] <= 0:
+                    break
+                t = int(toks[slot, j])
+                self.outputs[rid].append(t)
+                self.budget[slot] -= 1
+                if ((self.eos is not None and t == self.eos)
+                        or t == self.stop[slot]):
+                    break
+            out = self.outputs[rid]
+            done = (self.budget[slot] <= 0 or
+                    (self.eos is not None and out and
+                     out[-1] == self.eos) or
+                    (self.stop[slot] >= 0 and out and
+                     out[-1] == self.stop[slot]))
+            if done:
+                self._retire(slot)
 
     def _drain_emitted(self):
         """The step() return contract: (emitted rid -> new tokens,

@@ -237,6 +237,12 @@ def _shard_specs(mesh_axis: str, quantized: bool, suffix: bool):
     return specs, head
 
 
+# jitted under its own name: the kernel's instruction, and so its event
+# in a device trace, is named after the function that encloses the custom
+# call (kernels/naming.py), and one jitted object lets every step program
+# that calls it at the same shapes share one trace of the kernel
+@functools.partial(jax.jit, static_argnames=("q_tile", "interpret", "mesh",
+                                             "mesh_axis"))
 def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
                            *, k_scale=None, v_scale=None,
                            suffix_k=None, suffix_v=None, suffix_vis=None,
@@ -431,6 +437,7 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((R, T, KVl, G, hd), q.dtype),
             interpret=interpret,
+            name="ragged_paged_attention",
         )
         # the package enables jax_enable_x64 globally; traced with it on,
         # the weak-typed float constants and index math lower as 64-bit,

@@ -173,7 +173,7 @@ def make_train_step(cfg, tx, mesh: Optional[Mesh] = None,
             "grad_accum_steps composes with num_microbatches inside the pp "
             "schedule — use num_microbatches when pp > 1")
 
-    def step_fn(state: TrainState, tokens):
+    def train_step(state: TrainState, tokens):
         if pp:
             if pp_virtual > 1:
                 lfn = lambda p, t: model.loss_fn(  # noqa: E731
@@ -229,7 +229,7 @@ def make_train_step(cfg, tx, mesh: Optional[Mesh] = None,
         return TrainState(state.step + 1, new_params, new_opt), metrics
 
     if mesh is None:
-        return jax.jit(step_fn, donate_argnums=(0,) if donate else ())
+        return jax.jit(train_step, donate_argnums=(0,) if donate else ())
 
     specs = state_specs(cfg, tx, pp=pp, model=model)
     state_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
@@ -239,7 +239,7 @@ def make_train_step(cfg, tx, mesh: Optional[Mesh] = None,
     metric_sh = {"loss": NamedSharding(mesh, P()),
                  "grad_norm": NamedSharding(mesh, P()),
                  "step": NamedSharding(mesh, P())}
-    return jax.jit(step_fn,
+    return jax.jit(train_step,
                    in_shardings=(state_sh, batch_sh),
                    out_shardings=(state_sh, metric_sh),
                    donate_argnums=(0,) if donate else ())

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ..kernels.naming import named_jit
+
 BLOCK = 256
 
 # blocks per lax.map chunk: 65536 * 256 = 16M params; each chunk holds
@@ -253,6 +255,8 @@ def _fused_adamw_kernel(sc_ref, g_ref, p_ref, mc_ref, ms_ref, vc_ref,
     vso_ref[...] = amax * (1.0 / F8_MAX)
 
 
+@named_jit("adam8bit_update",
+           static_argnames=("b1", "b2", "eps", "wd", "interpret"))
 def _fused_leaf_update(scalars, g, p, mq, vq, *, b1, b2, eps, wd,
                        interpret=False):
     """Run the fused kernel over one leaf. g/p keep their shapes (flatten
@@ -306,6 +310,7 @@ def _fused_leaf_update(scalars, g, p, mq, vq, *, b1, b2, eps, wd,
             ],
             input_output_aliases={2: 0, 3: 1, 4: 2, 5: 3, 6: 4},
             interpret=interpret,
+            name="adam8bit_update",
         )(scalars, gf, pf, mq.codes, mq.scale, vq.codes, vq.scale)
     pnew = po.reshape(-1)[:p.size].reshape(p.shape)
     return pnew, _QTensor(mc, ms), _QTensor(vc, vs)

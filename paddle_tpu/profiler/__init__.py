@@ -160,16 +160,20 @@ class RecordEvent:
 
     Reusable: one RecordEvent may go through many begin()/end() cycles
     (the serving engine opens the same-named span every decode step), so
-    a fresh TraceAnnotation is created per begin."""
+    a fresh TraceAnnotation is created per begin. Keyword attributes
+    (plain host values: `seq=12, mode="fused"`) go on to the
+    TraceAnnotation, which stores them as the event's stats; with no
+    trace running they cost nothing."""
 
-    def __init__(self, name: str, event_type=None):
+    def __init__(self, name: str, event_type=None, **attrs):
         self.name = name
+        self._attrs = attrs
         self._ann = None
 
     def begin(self):
         if self._ann is not None:
             raise RuntimeError(f"RecordEvent {self.name!r} already begun")
-        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self._attrs)
         self._ann.__enter__()
 
     def end(self):

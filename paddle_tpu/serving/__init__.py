@@ -51,10 +51,10 @@ per-replica labels, `POST /admin/reset_breaker`,
 objectives evaluated over dual rolling windows into burn rates and
 OK/WARN/BREACH verdicts — `health()["slo"]`, `slo_burn_rate_*`
 gauges, `slo_breaches_total` counters, fleet rollup in the Router),
-`profiling` (sampled device-time attribution: every Nth step fenced
-with block_until_ready into per-shape device-wall histograms, plus
-on-demand capture windows whose device spans land in the trace
-timelines), `speculative` (self-speculative decoding config +
+`profiling` (device-time attribution: every tick that reads its
+result back files its issue-to-read-back wall, stamped by the batcher's
+tick helper, into per-shape histograms, with no fence; on-demand
+capture windows fence the ticks an operator asks for), `speculative` (self-speculative decoding config +
 acceptance accounting: the draft-and-verify pipeline behind
 `ServingEngine(speculative=True, spec_k=, draft_layers=)` — a
 truncated-layer draft proposes k tokens, the target verifies all k+1

@@ -67,11 +67,20 @@ objectives over dual rolling windows — burn rates and OK/WARN/BREACH
 verdicts in `health()["slo"]`, `slo_burn_rate_*` gauges and
 `slo_breaches_total` counters in the exposition, `slo_breach` trace
 events for request correlation; a BREACH is detail, never an outage
-signal (SLOs degrade, supervision decides). The batcher's sampled
-step profiler fences every Nth device call (`profile_sample_every=`)
-to attribute DEVICE wall per compiled shape, and
-`capture_profile(steps=K)` fences a whole window on demand so trace
-timelines carry device wall next to host wall.
+signal (SLOs degrade, supervision decides). The batcher's step
+profiler attributes DEVICE wall per compiled shape from the stamps of
+every tick that reads its result back (`profile_sample_every=0` turns
+it off; no value costs a sync), and `capture_profile(steps=K)` fences
+a whole window on demand so that ticks which read nothing back are
+measured too.
+
+Phases on the profiler's clock: the loop opens `engine.housekeeping`
+(reap, admission, gauges: the section under the lock) and
+`engine.deliver` (`_dispatch`: the token bridge and `on_token`) as
+`RecordEvent` spans beside `serving.step_s`, and the batcher opens
+`serve.tick` and its phases inside the step (nlp/paged.py `_Tick`), so
+a jax profiler trace names what the host did in every gap between
+device programs.
 """
 from __future__ import annotations
 
@@ -81,6 +90,7 @@ import time
 from typing import Dict, Iterator, List, Optional, Tuple
 
 from .kvtransfer import KVSnapshot, check_compatible
+from ..profiler import RecordEvent
 from .metrics import LATENCY_BUCKETS, MetricsRegistry
 from .request import GenerationRequest, RequestState
 from .scheduler import AdmissionQueue, QueueFullError
@@ -915,8 +925,8 @@ class ServingEngine:
     def capture_profile(self, steps: int = 8,
                         timeout: Optional[float] = 30.0) -> Dict:
         """On-demand device-time capture window: fence the next
-        `steps` batcher ticks (every device call, not just sampled
-        ones), block until the window closes (bounded by `timeout` —
+        `steps` batcher ticks (every device call, also one that reads
+        nothing back), block until the window closes (bounded by `timeout` —
         an IDLE engine produces no ticks, so the report then comes
         back with ``capture.complete`` False), and return the
         profiler's report: per-shape device-wall histograms plus one
@@ -1012,12 +1022,13 @@ class ServingEngine:
                     # supervisor teardown: hand the in-flight set's KV
                     # out as snapshots before anything else reshapes it
                     self._drain_export_locked()
-                self._reap_queued_locked()
-                self._reap_running_locked()
-                self._release_parked_locked()
-                self._process_imports_locked()
-                self._admit_locked()
-                self._update_gauges_locked()
+                with RecordEvent("engine.housekeeping"):
+                    self._reap_queued_locked()
+                    self._reap_running_locked()
+                    self._release_parked_locked()
+                    self._process_imports_locked()
+                    self._admit_locked()
+                    self._update_gauges_locked()
                 if (not self._running and not len(self.queue)
                         and not self._imports):
                     if self._parked:
@@ -1082,7 +1093,8 @@ class ServingEngine:
             # False just dispatches tokens to already-failed handles
             if self._wedged:
                 continue      # stranded set already failed; don't dispatch
-            self._dispatch(emitted, finished, step_dt=timer.elapsed)
+            with RecordEvent("engine.deliver"):
+                self._dispatch(emitted, finished, step_dt=timer.elapsed)
 
     def _reap_queued_locked(self) -> None:
         now = self._clock()

@@ -1,42 +1,43 @@
-"""paddle_tpu.serving.profiling — sampled device-time attribution for
-the continuous batcher.
+"""paddle_tpu.serving.profiling — device-time attribution for the
+continuous batcher, per compiled shape.
 
-The PR 7 trace timelines attribute per-chunk time as HOST wall per
-call — which, with async dispatch, measures how long the host took to
-*issue* the work, not how long the device took to *do* it. A TTFT
+A tick's host wall with async dispatch measures how long the host took
+to *issue* the work, not how long the device took to *do* it. A TTFT
 regression could therefore be the Pallas ragged kernel, the XLA
-fallback, or host-side scheduling, and the timeline could not say
-which. This module closes that gap two ways:
+fallback, or host-side scheduling. This module keeps the two apart:
 
-  * **Sampled steps** — every Nth step tick (``sample_every``, default
-    64; 0 disables) the batcher wraps the already-issued device call
-    with a ``jax.block_until_ready`` fence and records the measured
-    device wall per shape key ``(mode, bucket, units, impl,
-    weight_dtype, kv_dtype)`` into bounded per-shape histograms. One
-    fenced step in N costs ~1/N of a step of extra latency on the
-    sampled tick and NOTHING on the other N-1 (the sample gate is the
-    documented SYNC001 exception: the fence never runs in the unfenced
-    path, and the compiled-shape memo keys never see the profiler).
-  * **Capture windows** — ``arm_capture(steps=K)`` fences the next K
-    ticks unconditionally and retains one record per fenced step
-    (mode, composition, host vs device wall). The engine merges those
-    spans (and per-chunk ``device_dur`` annotations) back into the
-    TraceSink so ``to_chrome_trace()`` timelines carry device wall
-    next to host wall, and ``ServingEngine.capture_profile()`` /
-    ``POST /debug/profile`` return the report over HTTP.
+  * **Every tick that syncs** — a tick that decodes reads its tokens
+    back right after its call, and a prefill unit reads its first
+    tokens back on its final chunk: the time from issue to read-back
+    IS the device wall of the tick, and the batcher's tick helper
+    stamps it anyway (`dispatch_s + wait_s` of the flight record). The
+    helper hands each such tick to `record(...)`, which files it under
+    its shape key ``(mode, bucket, units, impl, weight_dtype,
+    kv_dtype)`` in bounded per-shape histograms. No fence, no extra
+    sync: ``sample_every`` only turns the recording on (any value
+    above 0; the default) or off (0). The one tick that does not sync,
+    a standalone prefill's non-final chunk, is not recorded: its device
+    time shows in the next tick's wait.
+  * **Capture windows** — ``arm_capture(steps=K)`` marks the next K
+    ticks: the batcher fences each of them with a
+    ``jax.block_until_ready`` right after its call (the one place it
+    does, and only because an operator asked), so that even a tick
+    that would not sync is measured, and one record per step is
+    retained (mode, composition, host vs device wall).
+    ``ServingEngine.capture_profile()`` / ``POST /debug/profile``
+    return the report over HTTP.
 
 Attribution convention: ``host_s`` is dispatch wall (the device call
 returning control to the host — enqueue cost), ``device_s`` is
-call-start to fence-completion (everything the step put on the
-device, drained). On an async backend ``device_s >= host_s`` and the
-difference is the device-side remainder the old timelines could not
-see; on CPU jax the two nearly coincide — the *fields* are what make
-regressions attributable.
+call-start to read-back (everything the step put on the device,
+drained). On an async backend ``device_s >= host_s`` and the
+difference is the device-side remainder; on CPU jax the two nearly
+coincide — the *fields* are what make regressions attributable.
 
 Dependency-free on purpose (stdlib only, like `serving.trace` and
-`serving.slo`): the batcher owns the jax fence; this module only does
-host-side counting, so `tools/trace_report.py` and the tests can
-reason about reports without jax.
+`serving.slo`): the batcher owns the stamps and the fence; this module
+only does host-side counting, so `tools/trace_report.py` and the tests
+can reason about reports without jax.
 """
 from __future__ import annotations
 
@@ -89,26 +90,28 @@ class _ShapeStats:
 
 
 class StepProfiler:
-    """Sampled device-time profiler for `ContinuousBatcher` step ticks.
+    """Per-shape device-time profiler for `ContinuousBatcher` ticks.
 
     The batcher asks `should_fence()` once per device-call tick; True
-    means "fence THIS call and report the measurement" — every
-    `sample_every`th tick, plus every tick of an armed capture window.
-    After fencing it calls `record(...)` with the measured walls and
-    the tick's shape key; capture-window ticks additionally retain a
-    per-step record for timeline merging. All host-side arithmetic
-    under one lock; `arm_capture` is callable from any thread (the
-    engine's `capture_profile` and the frontend's `/debug/profile`
-    arm it while the engine thread steps).
+    means "an armed capture window covers THIS call: fence it". After
+    a tick that synced (by its own read-back, or by that fence) it
+    calls `record(...)` with the stamped walls and the tick's shape
+    key; capture-window ticks additionally retain a per-step record
+    for timeline merging. All host-side arithmetic under one lock;
+    `arm_capture` is callable from any thread (the engine's
+    `capture_profile` and the frontend's `/debug/profile` arm it while
+    the engine thread steps).
     """
 
     def __init__(self, sample_every: int = 64):
         if int(sample_every) < 0:
             raise ValueError("sample_every must be >= 0 (0 disables)")
+        # 0 turns the per-shape recording off; any other value records
+        # every tick that syncs (the cadence went with the fence)
         self.sample_every = int(sample_every)
         self._lock = threading.Lock()
         self._tick = 0          # device-call ticks seen
-        self.samples = 0        # fenced ticks measured
+        self.samples = 0        # ticks recorded
         self.dropped_keys = 0   # shapes past the retention bound
         self._shapes: Dict[Tuple, _ShapeStats] = {}
         # capture window: ticks remaining + retained per-step records
@@ -117,24 +120,19 @@ class StepProfiler:
         self._capture_total = 0
         self._capture_cancelled = False
 
-    # ---- the per-tick gate (hot path: one int compare in the common
-    #      unfenced case) -------------------------------------------------
+    # ---- the per-tick gate (hot path: one locked increment) -------------
     def should_fence(self) -> bool:
-        """Advance the tick counter and decide whether the batcher
-        fences THIS device call: every `sample_every`th tick, or any
-        tick while a capture window is armed. The unfenced path costs
-        one locked increment and compare — nothing touches the device."""
+        """Advance the tick counter and say whether an armed capture
+        window covers THIS device call. Outside a window the answer is
+        always False: nothing here ever touches the device."""
         with self._lock:
             self._tick += 1
-            if self._capture_left > 0:
-                return True
-            return (self.sample_every > 0
-                    and self._tick % self.sample_every == 0)
+            return self._capture_left > 0
 
     def record(self, *, mode: str, bucket: int, units: int, impl: str,
                weight_dtype: str, kv_dtype: str, device_s: float,
                host_s: float, detail: Optional[Dict] = None) -> bool:
-        """One fenced tick's measurement, attributed to its shape key.
+        """One synced tick's measurement, attributed to its shape key.
         `detail` (rids/unit composition) is retained only for capture-
         window steps. Returns True when this record CLOSED an armed
         capture window (the waiter's wake-up signal)."""
@@ -164,8 +162,8 @@ class StepProfiler:
 
     # ---- capture windows -------------------------------------------------
     def arm_capture(self, steps: int) -> None:
-        """Fence the next `steps` ticks unconditionally and retain one
-        record per fenced step. Re-arming extends an open window;
+        """Fence the next `steps` ticks and retain one record per
+        fenced step. Re-arming extends an open window;
         records of a previous completed window are replaced."""
         if int(steps) < 1:
             raise ValueError("capture steps must be >= 1")

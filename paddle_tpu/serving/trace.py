@@ -21,14 +21,18 @@ cheap enough to leave on in production:
   * `FlightRecorder` — a bounded ring of one record per batcher step
     tick (mode chosen, unit composition, bucket / group pad, free
     slots / blocks, compile-memo hit or miss), recorded *before* the
-    device call so the tick that raises is the last record in the ring.
+    device call so the tick that raises is the last record in the ring,
+    and closed after it with how the tick went (`close`: phase times,
+    whether it synced, slots still live).
     The engine's step-level exception boundary dumps the ring plus
     allocator / queue state to JSON on failure.
 
 Timestamps come from `time.perf_counter` — the same clock
 `MetricsRegistry.timer` measures with — so serving timelines line up
-with the `serving.step_s` histogram and, when a jax profiler capture is
-running, with the host `RecordEvent` spans on the XPlane timeline.
+with the `serving.step_s` histogram. The same phases are ALSO opened as
+`paddle_tpu.profiler.RecordEvent` spans by the batcher's tick helper
+(`serve.tick` and its children), which puts them on the profiler's
+clock beside the device's events whenever a jax profiler trace runs.
 
 Dependency-free on purpose (no jax import, like `serving.cache`):
 `nlp.paged` may construct a `FlightRecorder` without pulling the
@@ -46,8 +50,8 @@ __all__ = ["TraceSink", "FlightRecorder"]
 
 # Chrome-trace lanes for events that are not anchored to a batch slot:
 # requests still queued (no slot yet), the engine's per-step spans, and
-# the DEVICE-wall spans a profiler capture window measures (kept on
-# their own lane so host wall and device wall render side by side).
+# the DEVICE-wall spans of the batcher's ticks (kept on their own lane
+# so host wall and device wall render side by side).
 # Batch slots use tid = slot index (0..max_batch-1), far below these.
 _DEVICE_TID = 9997
 _QUEUE_TID = 9998
@@ -168,14 +172,16 @@ class TraceSink:
                 del self._alias[rid]
 
     def span(self, name: str, dur: float, lane: str = "steps",
-             **attrs) -> None:
+             t1: Optional[float] = None, **attrs) -> None:
         """Record one engine-level span (e.g. ``engine.step``) ending
-        now and lasting `dur` seconds — the sink-side twin of a
-        `MetricsRegistry.timer` observation. `lane` picks the Chrome
-        lane: "steps" (default) or "device" (the device-wall spans a
-        profiler capture window measures, rendered next to the host
-        step spans so the two walls are visually comparable)."""
-        t1 = self._clock()
+        at `t1` on the sink's clock (default: now) and lasting `dur`
+        seconds — the sink-side twin of a `MetricsRegistry.timer`
+        observation. `lane` picks the Chrome lane: "steps" (default) or
+        "device" (a tick's device wall, issue to read-back, rendered
+        next to the host step spans so the two walls are visually
+        comparable)."""
+        if t1 is None:
+            t1 = self._clock()
         with self._lock:
             self._spans.append({"kind": name, "t": t1 - dur, "dur": dur,
                                 "lane": lane, "attrs": dict(attrs)})
@@ -355,15 +361,26 @@ class FlightRecorder:
         with self._lock:
             return self._seq
 
-    def record(self, mode: str, **fields) -> None:
+    def record(self, mode: str, **fields) -> int:
         """Append one step record: `mode` is the scheduler's decision
         for the tick ("decode" | "fused" | "prefill"), `fields` carry
         the tick's composition and pool state (JSON-safe host values
-        only)."""
+        only). Returns the record's `seq`."""
         with self._lock:
-            self._ring.append({"seq": self._seq, "t": self._clock(),
+            seq = self._seq
+            self._ring.append({"seq": seq, "t": self._clock(),
                                "mode": mode, **fields})
             self._seq += 1
+            return seq
+
+    def close(self, **fields) -> None:
+        """Add to the LAST record what is only known once its tick has
+        ended (phase times, slots still live). A tick that raises never
+        gets here: its record stays as it was written before the call,
+        without `closed`."""
+        with self._lock:
+            if self._ring:
+                self._ring[-1].update(fields, closed=True)
 
     def records(self) -> List[Dict[str, Any]]:
         """The retained records, oldest first (copies — safe to

@@ -182,7 +182,8 @@ class TestVerifyThenCommit:
             len0 = int(np.asarray(cb.cache.lengths)[0])
             bud0 = cb.budget[0]
             pre = np.asarray(cb.cache.k.astype(jnp.float32))
-            out, n_emit = cb._step_spec()
+            chain = cb.slot_blocks[0]
+            out, n_emit = cb._step_spec([0])
             n = int(n_emit[0])
             assert 1 <= n <= cb.spec_k + 1
             if n < min(cb.spec_k + 1, bud0):
@@ -190,12 +191,10 @@ class TestVerifyThenCommit:
             post = np.asarray(cb.cache.k.astype(jnp.float32))
             changed = {tuple(c) for c in np.argwhere(
                 np.any(pre != post, axis=(0, 3, 4)))}
-            chain = cb.slot_blocks[0]
             expect = {(chain[p // cb.bs], p % cb.bs)
                       for p in range(len0, len0 + n)}
             assert changed == expect, \
                 "a rejected (or phantom) row wrote the pool"
-            cb._emit_spec([0], out, n_emit)
         assert saw_rejection
 
     def test_state_matches_plain_run(self, setup):
@@ -319,17 +318,16 @@ class TestSpecInt8KV:
         while cb.active[0]:
             len0 = int(np.asarray(cb.cache.lengths)[0])
             pre = np.asarray(cb.cache.k_scale)
-            out, n_emit = cb._step_spec()
+            chain = cb.slot_blocks[0]
+            out, n_emit = cb._step_spec([0])
             n = int(n_emit[0])
             post = np.asarray(cb.cache.k_scale)
-            chain = cb.slot_blocks[0]
             touched = {chain[p // cb.bs]
                        for p in range(len0, len0 + n)}
             changed = set(np.argwhere(
                 np.any(pre != post, axis=0)).ravel().tolist())
             assert changed <= touched, \
                 "a rejected draft row grew a block scale"
-            cb._emit_spec([0], out, n_emit)
 
 
 class TestTreeSpecConfig:
@@ -446,18 +444,17 @@ class TestTreeSpecParity:
         while cb.active[0]:
             len0 = int(np.asarray(cb.cache.lengths)[0])
             pre = np.asarray(cb.cache.k.astype(jnp.float32))
-            out, n_emit = cb._step_spec()
+            chain = cb.slot_blocks[0]
+            out, n_emit = cb._step_spec([0])
             n = int(n_emit[0])
             assert 1 <= n <= cb._spec_cfg.tree_depth() + 1
             post = np.asarray(cb.cache.k.astype(jnp.float32))
             changed = {tuple(c) for c in np.argwhere(
                 np.any(pre != post, axis=(0, 3, 4)))}
-            chain = cb.slot_blocks[0]
             expect = {(chain[p // cb.bs], p % cb.bs)
                       for p in range(len0, len0 + n)}
             assert changed == expect, \
                 "a sibling/rejected tree row wrote the pool"
-            cb._emit_spec([0], out, n_emit)
 
     def test_tree_int8_kv_scale_cleanliness(self, setup):
         """Tree spec over an int8 pool: per tick, block scales grow
@@ -473,16 +470,15 @@ class TestTreeSpecParity:
         while cb.active[0]:
             len0 = int(np.asarray(cb.cache.lengths)[0])
             pre = np.asarray(cb.cache.k_scale)
-            out, n_emit = cb._step_spec()
+            chain = cb.slot_blocks[0]
+            out, n_emit = cb._step_spec([0])
             n = int(n_emit[0])
             post = np.asarray(cb.cache.k_scale)
-            chain = cb.slot_blocks[0]
             touched = {chain[p // cb.bs]
                        for p in range(len0, len0 + n)}
             changed = set(np.argwhere(
                 np.any(pre != post, axis=0)).ravel().tolist())
             assert changed <= touched
-            cb._emit_spec([0], out, n_emit)
 
     def test_draft_w8_bit_identical(self, setup):
         """draft-from-w8: the truncated draft reads an int8 weight-only

@@ -637,12 +637,14 @@ class TestTraceArtifacts:
                                                 "live": 1}
 
 
-# ---- sampled device-time profiler (PR 13) ------------------------------
+# ---- device-time profiler, fed by the ticks' own stamps ------------------
 class TestStepProfiler:
-    def test_sampling_cadence_honored(self, setup):
-        """profile_sample_every=N fences exactly every Nth device-call
-        tick — the profiler's tick count matches the flight recorder's
-        and samples == ticks // N."""
+    def test_every_synced_tick_recorded(self, setup):
+        """Any profile_sample_every above 0 records every tick that
+        syncs, from the tick's own stamps (no fence): the profiler's
+        tick count matches the flight recorder's, its samples the
+        records closed `synced`, and each sample's device wall is the
+        record's dispatch_s + wait_s."""
         cfg, params = setup
         cb = paged.ContinuousBatcher(
             params, cfg, max_batch=2, block_size=4, max_total_len=32,
@@ -651,19 +653,25 @@ class TestStepProfiler:
         cb.submit(PROMPT2)
         cb.run()
         rep = cb.profiler.report()
+        recs = cb.flight.records()
         assert rep["ticks"] == cb.flight.seq    # one gate per tick
         assert rep["ticks"] >= 4
-        assert rep["samples"] == rep["ticks"] // 3
-        # 0 disables: no fences, no samples
+        synced = [r for r in recs if r["synced"]]
+        assert rep["samples"] == len(synced) >= 4
+        total = sum(r["dispatch_s"] + r["wait_s"] for r in synced)
+        assert sum(row["device_sum_s"] for row in rep["shapes"]) \
+            == pytest.approx(total)
+        # 0 disables: nothing recorded
         cb2 = paged.ContinuousBatcher(
             params, cfg, max_batch=2, block_size=4, max_total_len=32,
             max_new_tokens=8, chunk=2, profile_sample_every=0)
         cb2.submit(PROMPT)
         cb2.run()
         assert cb2.profiler.report()["samples"] == 0
+        assert cb2.profiler.report()["ticks"] == cb2.flight.seq
 
     def test_zero_recompiles_with_sampling_on(self, setup):
-        """Fencing every single step must not touch the compiled-shape
+        """Recording every single step must not touch the compiled-shape
         memo: compile_count stays at its warmup value."""
         cfg, params = setup
         eng = serving.ServingEngine(
@@ -711,10 +719,11 @@ class TestStepProfiler:
         assert all(r["units"] >= 1 for r in by_mode["fused"])
 
     def test_capture_window_lands_device_wall_in_timelines(
-            self, setup, tmp_path):
-        """engine.capture_profile(steps=K) fences K ticks: the report
-        comes back complete, prefill_chunk events carry device_dur next
-        to their host dur, device.* spans land on the device lane of
+            self, setup, tmp_path, monkeypatch):
+        """engine.capture_profile(steps=K) fences K ticks (and only
+        those: block_until_ready is counted): the report comes back
+        complete, prefill_chunk events carry device_dur next to their
+        host dur, device.* spans land on the device lane of
         to_chrome_trace(), and trace_report shows the device columns."""
         cfg, params = setup
         eng = serving.ServingEngine(
@@ -732,7 +741,12 @@ class TestStepProfiler:
 
         t = threading.Thread(target=traffic)
         # arm BEFORE traffic so the first prefill ticks are inside the
-        # window (sampling is off — only the capture fences)
+        # window (recording is off — only the capture window records)
+        fences = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(
+            jax, "block_until_ready",
+            lambda x: (fences.append(1), real(x))[1])
         eng.batcher.profiler.arm_capture(6)
         t.start()
         while eng.batcher.profiler.capture_active() \
@@ -740,6 +754,7 @@ class TestStepProfiler:
             pass
         t.join(300)
         report = eng.batcher.profiler.report()
+        assert len(fences) == 6         # the window's ticks, no other
         assert report["capture"]["complete"], report["capture"]
         assert report["capture"]["steps_captured"] == 6
         step0 = report["capture"]["steps"][0]
@@ -793,7 +808,7 @@ class TestStepProfiler:
         assert rep["capture"]["steps_captured"] == 0
         assert eng.batcher.profiler.capture_active() is False
         # traffic after the timed-out capture pays zero fences
-        # (sampling is off on this engine: any sample = a leak)
+        # (recording is off on this engine: any sample = a leak)
         eng.start()
         eng.generate(PROMPT, timeout=300)
         assert eng.batcher.profiler.report()["samples"] == 0
