@@ -626,84 +626,143 @@ def _forward_spec(params, layers, tokens, cache, positions, base_len,
     return logits, slab_k, slab_v
 
 
-def _attention_paged(x, lp, cfg, cos, sin, pk, pv, table, positions,
-                     valid, is_prefill, attention_impl: str = "xla",
-                     pks=None, pvs=None, mesh=None,
-                     mesh_axis: str = "mp"):
-    """One layer's attention. positions [B, P] per-request absolute
-    positions of x's tokens; valid masks padded slots. Returns
-    (out, pk', pv', pks', pvs') with the new tokens written into the
-    pool — quantized on the commit write when pks/pvs carry this
-    layer's int8 block scales (None = fp pool, the unchanged path)."""
-    B, P, D = x.shape
+class _RowGroup(NamedTuple):
+    """One group of rows of a paged forward: `tokens` [G, P] at absolute
+    `positions` [G, P] through block `table` [G, M]; `valid` [G, P]
+    masks padding (its pool writes drop, its outputs are never read)."""
+    tokens: jax.Array
+    table: jax.Array
+    positions: jax.Array
+    valid: jax.Array
+
+
+def _pack_rows(parts):
+    """Each group's rows [G, P, ...] -> the ONE activation the
+    per-token layers run on. Several groups are flattened and
+    concatenated to [T, ...], T the groups' token counts summed, so
+    that no group is padded to another's width. A single group keeps
+    its [G, P, ...] shape, the shape of `_forward_spec`'s and dense
+    `generate`'s bodies: the one-group paths are held BIT-identical
+    to those two on the CPU (tests/test_speculative.py,
+    tests/test_paged_kv.py), where a flattened [G*P, D] activation
+    rounds its bf16 otherwise and parts from them at near-ties."""
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.concatenate([p.reshape(-1, *p.shape[2:]) for p in parts], 0)
+
+
+def _group_rows(x, groups):
+    """`_pack_rows` undone: the packed x -> each group's [G, P, ...]."""
+    if len(groups) == 1:
+        return [x]
+    out, off = [], 0
+    for g in groups:
+        G, P = g.tokens.shape
+        out.append(lax.slice_in_dim(x, off, off + G * P, axis=0)
+                   .reshape(G, P, *x.shape[1:]))
+        off += G * P
+    return out
+
+
+def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
+                     attention_impl: str = "xla", pks=None, pvs=None,
+                     mesh=None, mesh_axis: str = "mp"):
+    """One layer's attention over x, the packed tokens of `groups`
+    (`_RowGroup`s; `_pack_rows` gives x's shape). The projections are
+    per token: ONE dot each over all of x. RoPE, the pool write and the
+    attention itself are per row group, each at its own [G, P] shape
+    through its own table; every group's KV is written before any group
+    attends, so a row may read blocks that another group's rows write
+    in this very call. Returns (out shaped like x, pk', pv', pks',
+    pvs') with the new tokens written into the pool: quantized on the
+    commit write when pks/pvs carry this layer's int8 block scales
+    (None = fp pool, the unchanged path)."""
     H, KV, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                  cfg.head_dim)
     cd = cfg.dtype
+
+    def heads(y, n):
+        return [r.reshape(*r.shape[:2], n, hd)
+                for r in _group_rows(y, groups)]
+
     with jax.named_scope("attn_qkv"):
-        q = (x @ _wq(lp, "q_proj", cd)).reshape(B, P, H, hd)
-        k = (x @ _wq(lp, "k_proj", cd)).reshape(B, P, KV, hd)
-        v = (x @ _wq(lp, "v_proj", cd)).reshape(B, P, KV, hd)
-        q, k = apply_rope_half(q, k, cos, sin, positions)
+        q = heads(x @ _wq(lp, "q_proj", cd), H)
+        k = heads(x @ _wq(lp, "k_proj", cd), KV)
+        v = heads(x @ _wq(lp, "v_proj", cd), KV)
+        for i, g in enumerate(groups):
+            q[i], k[i] = apply_rope_half(q[i], k[i], cos, sin, g.positions)
     with jax.named_scope("kv_pool_write"):
-        if pks is None:
-            pk = _write_pool(pk, table, positions, k, valid)
-            pv = _write_pool(pv, table, positions, v, valid)
-            kq, vq = k, v
-        else:
-            pk, pks, kq = _write_pool_int8(pk, pks, table, positions, k,
-                                           valid)
-            pv, pvs, vq = _write_pool_int8(pv, pvs, table, positions, v,
-                                           valid)
-            # every consumer sees the quantize→dequantize roundtrip of
-            # this call's own writes — a later cached-prefix read of the
-            # same blocks sees the same KV values (warm == cold by
-            # construction)
-            kq, vq = kq.astype(cd), vq.astype(cd)
+        kq, vq = list(k), list(v)
+        for i, g in enumerate(groups):
+            if pks is None:
+                pk = _write_pool(pk, g.table, g.positions, k[i], g.valid)
+                pv = _write_pool(pv, g.table, g.positions, v[i], g.valid)
+            else:
+                pk, pks, kq[i] = _write_pool_int8(
+                    pk, pks, g.table, g.positions, k[i], g.valid)
+                pv, pvs, vq[i] = _write_pool_int8(
+                    pv, pvs, g.table, g.positions, v[i], g.valid)
+                # every consumer sees the quantize→dequantize roundtrip
+                # of this call's own writes — a later cached-prefix read
+                # of the same blocks sees the same KV values (warm ==
+                # cold by construction)
+                kq[i], vq[i] = kq[i].astype(cd), vq[i].astype(cd)
     with jax.named_scope("attn_kernel"):
         if is_prefill:
             # the prompt attends only to itself: plain causal
-            # self-attention over the right-padded batch (rows past each
-            # request's length produce garbage that is never read — their
-            # pool writes are dropped and their logits never selected)
+            # self-attention over the right-padded batch (rows past
+            # each request's length produce garbage that is never
+            # read — their pool writes are dropped and their logits
+            # never selected)
             from ..kernels import flash_attention as fa
+            assert len(groups) == 1, "a cold prefill is one row group"
             if mesh is not None and fa._pallas_available():
-                # GSPMD cannot partition the Mosaic kernel: each device
-                # runs it on its head shard, like the ragged kernel below
-                o = fa.flash_attention_sharded(
-                    q, kq, vq, mesh,
-                    PartitionSpec(None, None, mesh_axis, None))
+                # GSPMD cannot partition the Mosaic kernel: each
+                # device runs it on its head shard, like the ragged
+                # kernel below
+                outs = [fa.flash_attention_sharded(
+                    q[0], kq[0], vq[0], mesh,
+                    PartitionSpec(None, None, mesh_axis, None))]
             else:
-                o = fa._flash_impl(q, kq, vq, True, None)
+                outs = [fa._flash_impl(q[0], kq[0], vq[0], True, None)]
         else:
-            # decode AND cached-prefix suffix prefill: gather through the
-            # table with per-query causal visibility (j <= position)
-            o = _paged_gqa_attention(q, pk, pv, table, positions, valid,
-                                     impl=attention_impl, k_scale=pks,
-                                     v_scale=pvs, mesh=mesh,
-                                     mesh_axis=mesh_axis)
+            # decode AND cached-prefix suffix prefill: gather through
+            # the table with per-query causal visibility (j <= position)
+            outs = [_paged_gqa_attention(
+                q[i], pk, pv, g.table, g.positions, g.valid,
+                impl=attention_impl, k_scale=pks, v_scale=pvs,
+                mesh=mesh, mesh_axis=mesh_axis)
+                for i, g in enumerate(groups)]
+        outs = [o.reshape(*o.shape[:2], H * hd) for o in outs]
     with jax.named_scope("attn_out"):
-        o = o.reshape(B, P, H * hd) @ _wq(lp, "o_proj", cd)
+        o = _pack_rows(outs) @ _wq(lp, "o_proj", cd)
     return o, pk, pv, pks, pvs
 
 
-def forward_paged(params, tokens, cache: PagedKVCache, positions, valid,
-                  cfg, is_prefill: bool, attention_impl: str = "xla",
-                  mesh=None, mesh_axis: str = "mp"):
-    """tokens [B, P] at per-request absolute `positions` [B, P] →
-    (logits [B, P, V] f32, cache'). visible_len for decode = position+1
-    (the just-written token included). `attention_impl` selects the
-    paged-attention backend ("xla" reference gather | "pallas" ragged
-    kernel) for the non-prefill path; cold prefill keeps flash.
-    `mesh`/`mesh_axis` shard_map-wrap the pallas kernel on the TP mesh
-    (no-op for "xla", which shards under plain GSPMD)."""
+def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
+                    attention_impl: str = "xla", mesh=None,
+                    mesh_axis: str = "mp"):
+    """THE paged layer stack, over the packed tokens of one or more row
+    groups (`_RowGroup`s sharing one pool and one table width). What is
+    per token — embedding, RMSNorm, QKV, o_proj, the MLP — runs on ONE
+    packed activation (`_pack_rows`: [G, P, D] for one group, [T, D]
+    for several, T = sum of G*P: each weight is read once and each
+    projection is one dot per layer, whatever the groups' shapes);
+    what is per row — RoPE positions, the pool write, the attention —
+    runs once per group at the group's own [G, P] shape
+    (`_attention_paged`). `pools` is (k, v, k_scale, v_scale) stacked
+    over layers. Returns (x, the packed hidden states before the final
+    norm, and pools')."""
     cd = cfg.dtype
+    k_all, v_all, ks_all, vs_all = pools
     # rope spans the per-request table width (max reachable position),
     # NOT the whole pool — the pool is ~B x larger by construction
-    T_rope = cache.table.shape[1] * cache.k.shape[2]
+    T_rope = groups[0].table.shape[1] * k_all.shape[2]
     with jax.named_scope("embed"):
-        x = jnp.take(params["embed_tokens"], tokens, axis=0).astype(cd)
+        x = jnp.take(params["embed_tokens"],
+                     _pack_rows([g.tokens for g in groups]),
+                     axis=0).astype(cd)
     cos, sin = rope_freqs(cfg.head_dim, T_rope, cfg.rope_theta, jnp.float32)
-    visible_len = positions[:, -1] + 1
 
     def body(carry, lp):
         # ks_all/vs_all are the [L, N] scale pools in int8-KV mode and
@@ -721,9 +780,8 @@ def forward_paged(params, tokens, cache: PagedKVCache, positions, valid,
         with jax.named_scope("attn_qkv"):
             h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
         a, pk, pv, ks, vs = _attention_paged(
-            h, lp, cfg, cos, sin, pk, pv, cache.table, positions, valid,
-            is_prefill, attention_impl, ks, vs, mesh=mesh,
-            mesh_axis=mesh_axis)
+            h, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
+            attention_impl, ks, vs, mesh=mesh, mesh_axis=mesh_axis)
         with jax.named_scope("kv_pool_write"):
             pk_all = lax.dynamic_update_slice_in_dim(pk_all, pk[None], li,
                                                      0)
@@ -742,11 +800,31 @@ def forward_paged(params, tokens, cache: PagedKVCache, positions, valid,
         return (x, pk_all, pv_all, ks_all, vs_all, li + 1), None
 
     (x, pk, pv, ks, vs, _), _ = lax.scan(
-        body, (x, cache.k, cache.v, cache.k_scale, cache.v_scale,
-               jnp.int32(0)), params["layers"])
+        body, (x, k_all, v_all, ks_all, vs_all, jnp.int32(0)),
+        params["layers"])
+    return x, (pk, pv, ks, vs)
+
+
+def forward_paged(params, tokens, cache: PagedKVCache, positions, valid,
+                  cfg, is_prefill: bool, attention_impl: str = "xla",
+                  mesh=None, mesh_axis: str = "mp"):
+    """tokens [B, P] at per-request absolute `positions` [B, P] →
+    (logits [B, P, V] f32, cache'): the ONE-group call of
+    `_forward_groups` (the plain decode chunk, the standalone prefill,
+    `paged_generate`), with the LM head on every position.
+    visible_len for decode = position+1 (the just-written token
+    included). `attention_impl` selects the paged-attention backend
+    ("xla" reference gather | "pallas" ragged kernel) for the
+    non-prefill path; cold prefill keeps flash. `mesh`/`mesh_axis`
+    shard_map-wrap the pallas kernel on the TP mesh (no-op for "xla",
+    which shards under plain GSPMD)."""
+    x, (pk, pv, ks, vs) = _forward_groups(
+        params, (_RowGroup(tokens, cache.table, positions, valid),),
+        (cache.k, cache.v, cache.k_scale, cache.v_scale), cfg, is_prefill,
+        attention_impl, mesh=mesh, mesh_axis=mesh_axis)
     with jax.named_scope("lm_head"):
         logits = _final_head_cached(params, x, cfg)
-    new_len = jnp.maximum(cache.lengths, visible_len)
+    new_len = jnp.maximum(cache.lengths, positions[:, -1] + 1)
     return logits, PagedKVCache(pk, pv, cache.table, new_len, ks, vs)
 
 
@@ -970,7 +1048,9 @@ class ContinuousBatcher:
     Prefill is FUSED with decode (`fused_prefill=True`): when an
     admission lands while slots are decoding, one compiled call carries
     `max_batch` decode rows PLUS up to one bucket-sized chunk of prefill
-    rows — the Ragged Paged Attention mixed-mode batch — so in-flight
+    rows, as two row groups ([B, 1] and [Gp, Pb]) whose packed
+    B + Gp*Pb tokens share every dense layer (`_forward_groups`: no
+    decode row is padded to the bucket), so in-flight
     decoding advances by its chunk in the same device program that
     prefills the admission, instead of stalling while a standalone
     prefill monopolizes the device. Prepared admissions wait in a
@@ -2610,7 +2690,9 @@ class ContinuousBatcher:
                     decode_rids + [r for u in unit_rids for r in u],
                     (bucket, len(groups)), units=unit_rids,
                     decode_rids=decode_rids, bucket=bucket, group_pad=Gp,
-                    rows=len(groups) * Gp, chunk=self.chunk,
+                    rows=len(groups) * Gp,
+                    gemm_tokens=self.B + len(groups) * Gp * bucket,
+                    chunk=self.chunk,
                     decode_ctx=self._decode_ctx(decoding),
                     prefill_spans=[[start, end] for _, items, _ in groups
                                    for _, start, end in items],
@@ -2844,53 +2926,51 @@ class ContinuousBatcher:
         return exe
 
     def _build_fused(self):
-        """The fused prefill+decode chunk: ONE compiled call over a
-        mixed batch of `max_batch` decode rows plus `Pb` prefill-chunk
-        rows (the Ragged Paged Attention mixed-mode shape). The first
-        decode token and the whole prefill chunk compute in one
-        forward_paged pass — decode rows are [.., Pb]-padded with only
-        column 0 valid, prefill rows mask padding through valid /
-        clamped positions exactly like the standalone path. Every row
-        in the mixed batch takes the per-query-causal paged kernel,
-        COLD prefill rows included (standalone cold prefill uses the
-        flash path): the two compute the same softmax attention and
-        greedy-token parity with the unfused path is asserted in
-        tests/test_fused_step.py, but logits are not bit-for-bit.
-        The remaining chunk-1 decode tokens scan the shared decode
-        step body."""
+        """The fused prefill+decode chunk: ONE compiled call that
+        advances `max_batch` decode rows by their chunk AND prefills
+        `Gp` bucket-wide chunk rows. Its first forward runs TWO row
+        groups through `_forward_groups`: the decode rows as [B, 1]
+        (token, `lengths`, `active`, `table`) and the prefill rows as
+        [Gp, Pb]. The dense layers see the B + Gp*Pb packed tokens and
+        nothing else — no decode row is padded to the bucket — so each
+        weight is read once for both groups; the pool write and the
+        paged attention run once per group, at the shapes the plain
+        decode step and a warm standalone prefill already use. COLD
+        prefill rows take the per-query-causal paged attention too
+        (standalone cold prefill uses the flash path): the two compute
+        the same softmax attention and greedy-token parity with the
+        unfused path is asserted in tests/test_fused_step.py, but
+        logits are not bit-for-bit. The LM head runs on the B + Gp rows
+        whose logits are read: each decode row's token and each prefill
+        row's last valid position. The remaining chunk-1 decode tokens
+        scan the shared decode step body. `fused_units` > 1 is the same
+        program with a larger `Gp`."""
         cfg, chunk, B = self.cfg, self.chunk, self.B
         impl = self.attention_impl
         mesh, max_ = self._mesh, self._mesh_axis()
-        maxpos = self.M * self.bs - 1
 
         def serve_fused_step(params, k, v, ks, vs, table, lengths, tok,
                              active, budget, stop, prows, ppos, pval, ptab,
                              plast):
             Gp, Pb = prows.shape
-            # decode rows ride the prefill chunk's width: token in
-            # column 0 at the slot's current position, the rest padding
-            # (writes drop; per-query attention keeps columns
-            # independent, so column 0 is the P=1 decode computation)
-            dtok = jnp.zeros((B, Pb), jnp.int32).at[:, 0].set(tok)
-            dpos = jnp.minimum(
-                lengths[:, None] + jnp.arange(Pb)[None, :], maxpos)
-            dval = jnp.zeros((B, Pb), jnp.bool_).at[:, 0].set(active)
-            sub = PagedKVCache(
-                k, v, jnp.concatenate([table, ptab], 0),
-                jnp.zeros((B + Gp,), jnp.int32), ks, vs)
-            logits, sub = forward_paged(
-                params, jnp.concatenate([dtok, prows], 0), sub,
-                jnp.concatenate([dpos, ppos], 0),
-                jnp.concatenate([dval, pval], 0), cfg, is_prefill=False,
+            x, (k, v, ks, vs) = _forward_groups(
+                params,
+                (_RowGroup(tok[:, None], table, lengths[:, None],
+                           active[:, None]),
+                 _RowGroup(prows, ptab, ppos, pval)),
+                (k, v, ks, vs), cfg, is_prefill=False,
                 attention_impl=impl, mesh=mesh, mesh_axis=max_)
-            # ragged last-token logits per prefill row → first tokens
+            with jax.named_scope("lm_head"):
+                # x is [B + Gp*Pb, D]: the decode rows' tokens, then
+                # each prefill row's bucket; ragged last-token rows
+                last = B + jnp.arange(Gp) * Pb + plast
+                logits = _final_head_cached(
+                    params, jnp.concatenate([x[:B], x[last]], 0), cfg)
             with jax.named_scope("sample"):
-                pfirst = jnp.argmax(logits[B:][jnp.arange(Gp), plast],
-                                    axis=-1).astype(jnp.int32)
+                pfirst = jnp.argmax(logits[B:], axis=-1).astype(jnp.int32)
             nxt, lengths, budget, active = self._emit_one(
-                logits[:B, 0], tok, active, lengths, budget, stop)
-            cache = PagedKVCache(sub.k, sub.v, table, lengths,
-                                 sub.k_scale, sub.v_scale)
+                logits[:B], tok, active, lengths, budget, stop)
+            cache = PagedKVCache(k, v, table, lengths, ks, vs)
             step = self._decode_step_body(params, stop)
             (cache, tok, lengths, budget, active), toks = jax.lax.scan(
                 step, (cache, nxt, lengths, budget, active), None,

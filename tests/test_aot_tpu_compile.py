@@ -137,7 +137,10 @@ CASES = {
     "ragged-decode": lambda t: _ragged(t, 8, 1),
     "ragged-prefill-bucket-512": lambda t: _ragged(t, 1, 512),
     "ragged-prefill-bucket-8": lambda t: _ragged(t, 8, 8),
-    "ragged-fused-mixed": lambda t: _ragged(t, 9, 128),
+    # the fused step calls the kernel once per row group: the decode
+    # rows as [B, 1], then the prefill rows as [Gp, Pb]
+    "ragged-fused-decode-rows": lambda t: _ragged(t, 16, 1),
+    "ragged-fused-prefill-rows": lambda t: _ragged(t, 2, 128),
     "ragged-int8-decode": lambda t: _ragged(t, 8, 1, jnp.int8),
     "ragged-int8-prefill": lambda t: _ragged(t, 2, 512, jnp.int8),
     "ragged-suffix-slab": lambda t: _ragged(t, 8, 5, slab=5),

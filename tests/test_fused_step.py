@@ -206,16 +206,27 @@ class TestFusedParity:
             outs.append(schedule(cb))
             assert cb.alloc.stats()["blocks_in_use"] == 0
         assert cb.fused_steps > 0            # the fused run really fused
+        self.fused_cb = cb
         return outs
 
-    def test_mid_decode_admissions_match_unfused(self, setup):
+    @pytest.mark.parametrize("kw", [
+        dict(max_batch=2),
+        # int8 KV: the fused step quantizes the decode rows' and the
+        # prefill rows' writes in two passes over disjoint blocks
+        dict(max_batch=2, kv_dtype="int8"),
+        # two units in one call: the same program with twice the rows
+        dict(max_batch=3, fused_units=2, prefill_buckets=(8,)),
+    ], ids=["fp", "kv-int8", "fused-units-2"])
+    def test_mid_decode_admissions_match_unfused(self, setup, kw):
         cfg, params = setup
         a, b, c, d = _prompts(81, (5, 9, 13, 3))
         base, fused = self._both(
             params, cfg,
-            lambda cb: _mid_decode_schedule(cb, a, [b, c, d]),
-            max_batch=2)
+            lambda cb: _mid_decode_schedule(cb, a, [b, c, d]), **kw)
         assert fused == base
+        if kw.get("fused_units", 1) > 1:
+            cb = self.fused_cb
+            assert cb.fused_unit_count > cb.fused_steps
 
     def test_chunked_long_prompt_mid_decode_matches(self, setup):
         """A prompt past the largest bucket streams one FUSED chunk per
@@ -254,6 +265,76 @@ class TestFusedParity:
                                  prefill_buckets=(4,), prefix_cache=True)
         assert fused == base
         assert base[0] == base[2]            # COW really replayed the hit
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+class TestFusedRunsOnlyItsTokens:
+    """The fused step's dense layers run over the packed tokens: B
+    decode tokens plus Gp x Pb prefill positions, never a
+    [B + Gp, Pb] rectangle (counts on the traced program, CPU)."""
+
+    @pytest.mark.parametrize("B,Gp,Pb", [(4, 1, 32), (4, 2, 32)])
+    def test_program_shapes(self, setup, B, Gp, Pb):
+        cfg, params = setup
+        cb = _batcher(params, cfg, max_batch=B, block_size=4,
+                      max_total_len=64, prefill_buckets=(Pb,),
+                      fused_units=Gp)
+        M, T, i32 = cb.M, B + Gp * Pb, np.int32
+        z = lambda shape, dt=i32: jax.ShapeDtypeStruct(shape, dt)  # noqa: E731
+        jaxpr = jax.make_jaxpr(cb._build_fused())(
+            params, cb.cache.k, cb.cache.v, None, None, z((B, M)),
+            z((B,)), z((B,)), z((B,), np.bool_), z((B,)), z((B,)),
+            z((Gp, Pb)), z((Gp, Pb)), z((Gp, Pb), np.bool_), z((Gp, M)),
+            z((Gp,))).jaxpr
+        (call,) = jaxpr.eqns                 # the jitted step itself
+        jaxpr = call.params["jaxpr"].jaxpr
+        # the first layer scan is the mixed forward; the second scans
+        # the rest of the chunk's decode steps
+        scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+        assert scans[0].params["length"] == cfg.num_hidden_layers
+        assert scans[1].params["length"] == cb.chunk - 1
+
+        def weight_dots(eqns):
+            # a projection is a [rows, d] x [d, f] dot (the reference
+            # attention's einsums are rank 5)
+            return [e for e in eqns if e.primitive.name == "dot_general"
+                    and all(len(v.aval.shape) == 2 for v in e.invars)]
+
+        proj = weight_dots(_eqns(scans[0].params["jaxpr"].jaxpr))
+        assert len(proj) == 7      # q, k, v, o, gate, up, down: one each
+        assert {e.invars[0].aval.shape[0] for e in proj} == {T}
+        head = weight_dots(jaxpr.eqns)
+        assert [e.outvars[0].aval.shape for e in head] == \
+            [(B + Gp, cfg.vocab_size)]
+        padded = [v.aval.shape for e in _eqns(jaxpr) for v in e.outvars
+                  if tuple(v.aval.shape[:2]) == (B + Gp, Pb)]
+        assert padded == []
+
+    @pytest.mark.parametrize("units", [1, 2])
+    def test_flight_record_counts_gemm_tokens(self, setup, units):
+        cfg, params = setup
+        a, b, c = _prompts(87, (5, 19, 6))
+        cb = _batcher(params, cfg, max_batch=3, prefill_buckets=(8,),
+                      fused_units=units)
+        cb.submit(a)
+        cb.step()
+        cb.submit(b)
+        cb.submit(c)
+        cb.run()
+        fused = [r for r in cb.flight.records() if r["mode"] == "fused"]
+        assert len(fused) == cb.fused_steps > 0
+        for r in fused:
+            assert r["gemm_tokens"] == cb.B + r["rows"] * r["bucket"]
+        assert {len(r["units"]) for r in fused} >= {units}
+        assert all("gemm_tokens" not in r for r in cb.flight.records()
+                   if r["mode"] != "fused")
 
 
 class TestGroupGrowingAdmission:
