@@ -8,6 +8,8 @@ surrounding matmuls'.
 """
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 
@@ -18,6 +20,43 @@ def rope_freqs(head_dim: int, max_seq: int, base: float = 10000.0,
     t = jnp.arange(max_seq, dtype=jnp.float32)
     freqs = jnp.outer(t, inv)
     return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature m(f, a) = 0.1 a ln f + 1 (1 for f <= 1)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float, original_max: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """YaRN (Peng et al. 2023) inverse frequencies [dim//2], float32: per
+    frequency a blend of the interpolated 1/(factor base^(2i/dim)) and the
+    extrapolated 1/base^(2i/dim), by the linear ramp between the two
+    correction dims (where beta_fast / beta_slow rotations fit into the
+    original context). High frequencies keep extrapolating."""
+    def correction_dim(rotations):
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    extra = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def yarn_freqs(dim: int, max_seq: int, base: float, factor: float,
+               original_max: int, beta_fast: float = 32.0,
+               beta_slow: float = 1.0, mscale: float = 1.0,
+               mscale_all_dim: float = 0.0, dtype=jnp.float32):
+    """cos/sin tables [max_seq, dim//2] at YaRN frequencies, scaled by
+    m(factor, mscale) / m(factor, mscale_all_dim) as the DeepSeek-V2
+    rotary embedding does (1 where the two are equal)."""
+    inv = yarn_inv_freq(dim, base, factor, original_max, beta_fast, beta_slow)
+    m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+    freqs = jnp.outer(jnp.arange(max_seq, dtype=jnp.float32), inv)
+    return (jnp.cos(freqs) * m).astype(dtype), (jnp.sin(freqs) * m).astype(dtype)
 
 
 def apply_rope(q, k, cos, sin, position_ids=None):

@@ -1,6 +1,15 @@
 """Mixture-of-Experts: gating, capacity dispatch, expert parallelism, and
-the flagship MoE transformer (DeepSeekMoE/Qwen2-MoE-style — BASELINE
-config 4).
+the flagship MoE transformer (routed experts + an always-on shared expert —
+BASELINE config 4).
+
+Two routers live here and they are NOT the same function. The TRAINED
+path (`top_k_routing`, `moe_block`, `MoeConfig`) is GShard's: softmax
+scores, a fixed capacity per expert, tokens over capacity DROPPED (they
+fall through the residual). The SERVED path (`sigmoid_top_k`,
+`expert_share_ffn`, used by nlp/paged.py for `mla.MlaMoeConfig`) is what
+published DeepSeek-V3-style routers do: sigmoid scores in float32, plain
+top-k, normalised and scaled gates, and NO capacity: no token is ever
+dropped, whatever the routing.
 
 Reference analog: python/paddle/incubate/distributed/models/moe/
 (moe_layer.py with gshard/switch/naive gates, capacity + all_to_all dispatch
@@ -131,10 +140,123 @@ def top_k_gating(gate_logits: jax.Array, k: int, capacity: int,
     return dispatch, combine, aux
 
 
+def sigmoid_top_k(h: jax.Array, router_w: jax.Array, k: int,
+                  scale: float = 1.0, normalize: bool = True):
+    """The served router, float32 throughout: `s = sigmoid(h W_g)` over
+    ALL routed experts, `I = top-k(s)`, gates `scale * s_i / sum_{j in I}
+    s_j` (the sum over all k chosen, wherever they live). h [T, D],
+    router_w [D, E] -> (idx [T, k] int32, gates [T, k] float32). No
+    group limit, no correction bias, no capacity."""
+    logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    top, idx = jax.lax.top_k(jax.nn.sigmoid(logits), k)
+    if normalize:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), top * scale
+
+
+def expert_share_ffn(h: jax.Array, lp: Dict[str, jax.Array], *, k: int,
+                     first: int, scale: float = 1.0, normalize: bool = True,
+                     valid: Optional[jax.Array] = None,
+                     layer=0, token_block: int = 1024):
+    """One chip's share of a routed-expert layer, DROPLESS: route every
+    token of h [T, D] over all `lp["router"].shape[1]` experts, and
+    compute `sum_{i in top-k, i held here} g_i E_i(h)` for the experts
+    held here: `lp["experts_gate" | "experts_up"]` [Lm, n, D, F] and
+    `lp["experts_down"]` [Lm, n, F, D] hold routed experts `first ..
+    first + n - 1` of ALL Lm expert layers, each a gated SiLU MLP, and
+    this is layer `layer` of them (an int32 scalar, traced in a scan).
+    What absent experts would add is left out; no code stands in for
+    their chips. The shared expert is the caller's
+    (`generation._mlp_cached`).
+
+    The stack goes into the grouped GEMM whole, as Lm*n groups of which
+    only this layer's n have rows. A scan that sliced the layer's experts
+    out instead would copy them (1 GB a layer at A.X-K1's widths) before
+    every GEMM: the grouped GEMM is a custom call and takes no fused
+    slice (my chip runs, PR 26: 19 of a decode step's 43 ms).
+
+    Static shapes, no capacity and no recompile per routing: the T*k
+    (token, choice) pairs are sorted by held expert (pairs of absent
+    experts, and of tokens `valid` [T] masks, sort last and belong to no
+    group) and the experts run as grouped GEMMs (`lax.ragged_dot`) over
+    the sorted rows, so the work follows the pairs routed here, not
+    held experts x tokens. More than `token_block` tokens are processed
+    in blocks of that size, which bounds the sorted buffer at
+    token_block * k rows (every pair local is the worst case, and it
+    must fit).
+
+    Returns (y [T, D] in h's dtype, stats): stats holds int32 scalars
+    `moe_pairs` (pairs computed here), `moe_experts_hit` (held experts
+    that got a token) and `moe_load_max` (most pairs on one expert)."""
+    T, D = h.shape
+    n = lp["experts_gate"].shape[-3]
+    if valid is None:
+        valid = jnp.ones((T,), bool)
+    if T > token_block:
+        nb = -(-T // token_block)
+        pad = nb * token_block - T
+        hb = jnp.pad(h, ((0, pad), (0, 0))).reshape(nb, token_block, D)
+        vb = jnp.pad(valid, (0, pad)).reshape(nb, token_block)
+        yb, sizes = jax.lax.map(
+            lambda a: _share_block(a[0], lp, a[1], k, first, n, scale,
+                                   normalize, layer), (hb, vb))
+        y = yb.reshape(nb * token_block, D)[:T]
+        sizes = jnp.sum(sizes, 0, dtype=jnp.int32)
+    else:
+        y, sizes = _share_block(h, lp, valid, k, first, n, scale, normalize,
+                                layer)
+    # int32 whatever jax_enable_x64 says: they ride a scan's carry
+    stats = {"moe_pairs": jnp.sum(sizes, dtype=jnp.int32),
+             "moe_experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32),
+             "moe_load_max": jnp.max(sizes).astype(jnp.int32)}
+    return y, stats
+
+
+def _share_block(h, lp, valid, k, first, n, scale, normalize, layer):
+    T, D = h.shape
+    with jax.named_scope("moe_router"):
+        idx, gates = sigmoid_top_k(h, lp["router"], k, scale, normalize)
+    with jax.named_scope("moe_dispatch"):
+        local = (idx >= first) & (idx < first + n) & valid[:, None]
+        # held expert of each pair, n for a pair that is not computed here
+        e = jnp.where(local, idx - first, n).reshape(T * k)
+        order = jnp.argsort(e, stable=True)
+        sizes = jnp.zeros((n + 1,), jnp.int32).at[e].add(1)[:n]
+        rows = jnp.take(h, order // k, axis=0)              # [T*k, D]
+    with jax.named_scope("moe_experts"):
+        cd = h.dtype
+        # the stack's Lm*n groups, all empty but this layer's n
+        Lm = lp["experts_gate"].shape[0]
+        gs = jax.lax.dynamic_update_slice(
+            jnp.zeros((Lm * n,), jnp.int32), sizes,
+            (jnp.asarray(layer, jnp.int32) * n,))
+        w = {m: lp["experts_" + m].astype(cd).reshape(
+            Lm * n, *lp["experts_" + m].shape[2:])
+            for m in ("gate", "up", "down")}
+        g = jax.lax.ragged_dot(rows, w["gate"], gs)
+        u = jax.lax.ragged_dot(rows, w["up"], gs)
+        out = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(cd), w["down"],
+                                 gs)
+    with jax.named_scope("moe_combine"):
+        # back to (token, choice) order; a pair outside every group
+        # contributes nothing whatever its row holds
+        inv = jnp.argsort(order)
+        gw = jnp.where(local, gates, 0.0)                   # [T, k]
+        out = jnp.take(out, inv, axis=0).reshape(T, k, D)
+        out = jnp.where(local[..., None], out, jnp.zeros((), cd))
+        y = jnp.einsum("tkd,tk->td", out, gw,
+                       preferred_element_type=jnp.float32).astype(cd)
+    return y, sizes
+
+
 @dataclasses.dataclass
 class MoeConfig:
-    """Flagship MoE transformer (Qwen2-MoE/DeepSeekMoE shape: routed experts
-    + optional always-on shared expert)."""
+    """Flagship TRAINED MoE transformer (routed experts + optional
+    always-on shared expert). Its router is GShard's capacity router: it
+    DROPS the tokens an expert has no capacity slot for. The served,
+    dropless sigmoid router is the other function (`expert_share_ffn`)."""
     vocab_size: int = 32000
     hidden_size: int = 2048
     intermediate_size: int = 5632       # dense (shared) FFN width

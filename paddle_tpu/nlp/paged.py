@@ -64,9 +64,15 @@ class PagedKVCache(NamedTuple):
     ids (-1 = unassigned); lengths: [B] int32 tokens currently cached.
     k_scale/v_scale: [L, N_blocks] f32 per-(layer, block) abs-max
     dequant scales when the pool stores int8 codes (kv_dtype="int8",
-    quantization.kv has the math), None for the fp pool."""
+    quantization.kv has the math), None for the fp pool.
+
+    A LATENT pool (`mla.MlaMoeConfig`) is ONE array: k is
+    [L, N_blocks, block_size, kv_lora_rank + qk_rope_head_dim], a row
+    `[c | k_r]` per token and layer that the attention reads once for
+    keys and values alike, and v is None (None adds no leaves to a step
+    program's signature)."""
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]
     table: jax.Array
     lengths: jax.Array
     k_scale: Optional[jax.Array] = None
@@ -288,6 +294,32 @@ class RefcountingBlockAllocator(BlockAllocator):
         }
 
 
+def _refuse_latent(weight_dtype=None, kv_dtype=None, speculative=False,
+                   mesh=None) -> None:
+    """What the served path cannot do yet for a latent (MLA) mixer with
+    expert layers, refused at construction, each by its mechanism."""
+    if weight_dtype not in (None, "fp"):
+        raise NotImplementedError(
+            f"weight_dtype={weight_dtype!r}: weight-only quantization "
+            f"(generation.quantize_for_serving) knows the dense decoder's "
+            f"projections, not the latent projections or stacked experts")
+    if kvq.resolve_kv_dtype(kv_dtype) != "fp":
+        raise NotImplementedError(
+            f"kv_dtype={kv_dtype!r}: the latent (MLA) pool has no int8 "
+            f"form yet (per-block scales over a normalised latent and a "
+            f"rotated key)")
+    if speculative:
+        raise NotImplementedError(
+            "speculative=True: the draft and verify programs "
+            "(_forward_spec, _spec_gqa_attention) are written for the GQA "
+            "block and its K/V suffix slab, not for the latent pool")
+    if mesh is not None:
+        raise NotImplementedError(
+            "mesh: serving.tp's sharding table splits GQA heads and the "
+            "dense MLP; latent attention under TP and experts over a mesh "
+            "(with their exchange) are not built")
+
+
 def _pow2_ceil(n: int) -> int:
     """Smallest power of two >= n (n >= 1)."""
     return 1 << max(0, (int(n) - 1).bit_length())
@@ -313,13 +345,24 @@ class _Admission(NamedTuple):
     chunks: List[Tuple[int, int, int]]   # (start, end, bucket) per chunk
 
 
-def init_pool(cfg: llama.LlamaConfig, num_blocks: int, block_size: int,
+def _is_latent(cfg) -> bool:
+    """Whether the configuration's mixer is latent attention (MLA): a
+    `mla.MlaMoeConfig`, told by what it declares, not by its class (the
+    nlp -> mla import stays lazy)."""
+    return hasattr(cfg, "kv_lora_rank")
+
+
+def init_pool(cfg, num_blocks: int, block_size: int,
               kv_dtype: str = "fp"):
     """Zeroed K/V pools → (k, v, k_scale, v_scale). The fp pool stores
     the compute dtype with no scales (None); kv_dtype="int8" stores
     int8 codes plus zero-initialized [L, N] per-(layer, block) abs-max
     scales — scale 0 is the never-written sentinel that dequantizes to
     the same exact zeros a fresh fp pool holds."""
+    if _is_latent(cfg):
+        _refuse_latent(kv_dtype=kv_dtype)
+        return jnp.zeros((cfg.num_hidden_layers, num_blocks, block_size,
+                          cfg.kv_row_width), cfg.dtype), None, None, None
     L, KV, hd = (cfg.num_hidden_layers, cfg.num_key_value_heads,
                  cfg.head_dim)
     if kvq.resolve_kv_dtype(kv_dtype) == "int8":
@@ -739,21 +782,120 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
     return o, pk, pv, pks, pvs
 
 
+def _attention_latent(x, lp, cfg, cos, sin, pool, groups, is_prefill,
+                      attention_impl: str = "xla", base=0):
+    """`_attention_paged` for a latent (MLA) mixer: one layer's attention
+    over x, the packed tokens of `groups`, against the layer's latent
+    pool [N, bs, R + rope]. Projections are per token, one dot each over
+    all of x; RoPE, the pool write and the attention are per row group;
+    every group's rows are written before any group attends. A cold
+    prefill attends in the EXPANDED form (per-head keys and values made
+    from its own rows, the flash kernel); everything that reads through
+    the block table in the ABSORBED form, over the pool's rows as they
+    are (nlp/mla.py). `pool` may be the WHOLE stack over layers,
+    flattened to [L*N, bs, R + rope], with `base` = layer * N added to
+    every block id: the layer's blocks are then written and read in
+    place, and no layer slice of the pool is copied out and back.
+    Returns (out shaped like x, pool')."""
+    from . import mla
+    with jax.named_scope("mla_q"):
+        q = _group_rows(mla.project_q(x, lp, cfg), groups)
+    with jax.named_scope("mla_kv_latent"):
+        c, k_r = mla.project_latent(x, lp, cfg)
+        c, k_r = _group_rows(c, groups), _group_rows(k_r, groups)
+        rows = []
+        for i, g in enumerate(groups):
+            q[i], kr = mla.rotate(q[i], k_r[i], cos, sin, g.positions, cfg)
+            rows.append(jnp.concatenate([c[i], kr.astype(c[i].dtype)], -1))
+    tables = [g.table + base for g in groups]
+    with jax.named_scope("kv_pool_write"):
+        for i, g in enumerate(groups):
+            pool = _write_pool(pool, tables[i], g.positions, rows[i],
+                               g.valid)
+    if is_prefill:
+        assert len(groups) == 1, "a cold prefill is one row group"
+        with jax.named_scope("attn_kernel"):
+            outs = [mla.attend_expanded(q[0], rows[0], lp, cfg)]
+    else:
+        with jax.named_scope("mla_absorb"):
+            q = [mla.absorb_q(qi, lp, cfg) for qi in q]
+        with jax.named_scope("attn_kernel"):
+            outs = [mla.latent_paged_attention(
+                q[i], pool, tables[i], g.positions, g.valid, cfg,
+                impl=attention_impl) for i, g in enumerate(groups)]
+        with jax.named_scope("mla_absorb"):
+            outs = [mla.unabsorb_o(o, lp, cfg) for o in outs]
+    with jax.named_scope("attn_out"):
+        o = _pack_rows(outs) @ _wq(lp, "o_proj", cfg.dtype)
+    return o, pool
+
+
+_EXPERT_STACKS = ("experts_gate", "experts_up", "experts_down")
+
+
+def _ffn_experts(x, h, lp, cfg, groups, stats, stacks, layer):
+    """The FFN of a sparse-expert layer on the packed tokens: the share of
+    the routed experts held here (`moe.expert_share_ffn`: dropless, the
+    padding rows masked out of routing) plus the shared expert, onto the
+    residual. `stacks` holds the held experts' matrices for ALL expert
+    layers (`layer` picks this one inside the grouped GEMM: the scan does
+    not slice them). `stats` adds up the layer's routing counters."""
+    from . import moe
+    D = h.shape[-1]
+    tok_valid = _pack_rows([g.valid for g in groups]).reshape(-1)
+    y, st = moe.expert_share_ffn(
+        h.reshape(-1, D), {"router": lp["router"], **stacks},
+        k=cfg.num_experts_per_tok, first=cfg.experts_first,
+        scale=cfg.routed_scaling_factor, normalize=cfg.norm_topk_prob,
+        valid=tok_valid, layer=layer)
+    with jax.named_scope("moe_shared"):
+        x = x + y.reshape(h.shape) + _mlp_cached(h, lp, cfg)
+    return x, _merge_stats(stats, st)
+
+
+def _merge_stats(a, b):
+    """Routing counters of two pieces of work: pairs and hit experts add
+    up, the largest load on one expert is a maximum."""
+    return {k: (jnp.maximum(v, b[k]) if k == "moe_load_max" else v + b[k])
+            for k, v in a.items()}
+
+
+def _layer_groups(params, cfg):
+    """The decoder as the configuration describes it: (stacked layers,
+    FFN kind) in order, each a scan of one body. A dense GQA decoder is
+    one group; an MLA + sparse-expert decoder is its leading dense
+    layers, then its expert layers."""
+    if not _is_latent(cfg):
+        return [(params["layers"], "dense")]
+    out = []
+    if cfg.first_k_dense_replace:
+        out.append((params["dense_layers"], "dense"))
+    if cfg.num_moe_layers:
+        out.append((params["moe_layers"], "moe"))
+    return out
+
+
 def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
                     attention_impl: str = "xla", mesh=None,
                     mesh_axis: str = "mp"):
     """THE paged layer stack, over the packed tokens of one or more row
     groups (`_RowGroup`s sharing one pool and one table width). What is
-    per token — embedding, RMSNorm, QKV, o_proj, the MLP — runs on ONE
-    packed activation (`_pack_rows`: [G, P, D] for one group, [T, D]
+    per token — embedding, RMSNorm, the projections, the FFN — runs on
+    ONE packed activation (`_pack_rows`: [G, P, D] for one group, [T, D]
     for several, T = sum of G*P: each weight is read once and each
     projection is one dot per layer, whatever the groups' shapes);
     what is per row — RoPE positions, the pool write, the attention —
     runs once per group at the group's own [G, P] shape
-    (`_attention_paged`). `pools` is (k, v, k_scale, v_scale) stacked
-    over layers. Returns (x, the packed hidden states before the final
-    norm, and pools')."""
+    (`_attention_paged`, `_attention_latent`). The block is read from
+    the configuration: the mixer (`_is_latent`: GQA over K and V pools,
+    or MLA over one latent pool) and, per group of layers
+    (`_layer_groups`), the FFN (a dense MLP or the held experts'
+    share). `pools` is (k, v, k_scale, v_scale) stacked over layers (a
+    latent pool: (rows, None, None, None)). Returns (x, the packed
+    hidden states before the final norm; pools'; the expert layers'
+    routing counters, None where there are none)."""
     cd = cfg.dtype
+    latent = _is_latent(cfg)
     k_all, v_all, ks_all, vs_all = pools
     # rope spans the per-request table width (max reachable position),
     # NOT the whole pool — the pool is ~B x larger by construction
@@ -762,47 +904,93 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
         x = jnp.take(params["embed_tokens"],
                      _pack_rows([g.tokens for g in groups]),
                      axis=0).astype(cd)
-    cos, sin = rope_freqs(cfg.head_dim, T_rope, cfg.rope_theta, jnp.float32)
+    if latent:
+        cos, sin = cfg.rope_tables(T_rope)
+    else:
+        cos, sin = rope_freqs(cfg.head_dim, T_rope, cfg.rope_theta,
+                              jnp.float32)
 
-    def body(carry, lp):
-        # ks_all/vs_all are the [L, N] scale pools in int8-KV mode and
-        # None for fp — the None branch traces to the exact pre-
-        # quantization jaxpr (None adds no carry leaves), keeping the
-        # fp path byte-identical with quantization off
-        x, pk_all, pv_all, ks_all, vs_all, li = carry
-        with jax.named_scope("kv_pool_read"):
-            pk = lax.dynamic_slice_in_dim(pk_all, li, 1, 0)[0]
-            pv = lax.dynamic_slice_in_dim(pv_all, li, 1, 0)[0]
-            ks = None if ks_all is None else \
-                lax.dynamic_slice_in_dim(ks_all, li, 1, 0)[0]
-            vs = None if vs_all is None else \
-                lax.dynamic_slice_in_dim(vs_all, li, 1, 0)[0]
-        with jax.named_scope("attn_qkv"):
-            h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
-        a, pk, pv, ks, vs = _attention_paged(
-            h, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
-            attention_impl, ks, vs, mesh=mesh, mesh_axis=mesh_axis)
-        with jax.named_scope("kv_pool_write"):
-            pk_all = lax.dynamic_update_slice_in_dim(pk_all, pk[None], li,
-                                                     0)
-            pv_all = lax.dynamic_update_slice_in_dim(pv_all, pv[None], li,
-                                                     0)
-            if ks_all is not None:
-                ks_all = lax.dynamic_update_slice_in_dim(
-                    ks_all, ks[None], li, 0)
-                vs_all = lax.dynamic_update_slice_in_dim(
-                    vs_all, vs[None], li, 0)
-        with jax.named_scope("mlp"):
-            x = x + a
-            h = rms_norm_ref(x, lp["post_attention_layernorm"],
-                             cfg.rms_norm_eps)
-            x = x + _mlp_cached(h, lp, cfg)
-        return (x, pk_all, pv_all, ks_all, vs_all, li + 1), None
+    def make_body(ffn, stacks=None, first_layer=0):
+        def body(carry, lp):
+            # ks_all/vs_all are the [L, N] scale pools in int8-KV mode and
+            # None for fp, as stats is for a decoder without expert
+            # layers — a None traces to the exact jaxpr without it (None
+            # adds no carry leaves), keeping the fp GQA path byte-identical
+            x, pk_all, pv_all, ks_all, vs_all, li, stats = carry
+            if latent:
+                return latent_body(x, pk_all, li, stats, lp)
+            with jax.named_scope("kv_pool_read"):
+                pk = lax.dynamic_slice_in_dim(pk_all, li, 1, 0)[0]
+                pv = lax.dynamic_slice_in_dim(pv_all, li, 1, 0)[0]
+                ks = None if ks_all is None else \
+                    lax.dynamic_slice_in_dim(ks_all, li, 1, 0)[0]
+                vs = None if vs_all is None else \
+                    lax.dynamic_slice_in_dim(vs_all, li, 1, 0)[0]
+            with jax.named_scope("attn_qkv"):
+                h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
+            a, pk, pv, ks, vs = _attention_paged(
+                h, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
+                attention_impl, ks, vs, mesh=mesh, mesh_axis=mesh_axis)
+            with jax.named_scope("kv_pool_write"):
+                pk_all = lax.dynamic_update_slice_in_dim(pk_all, pk[None],
+                                                         li, 0)
+                pv_all = lax.dynamic_update_slice_in_dim(pv_all, pv[None],
+                                                         li, 0)
+                if ks_all is not None:
+                    ks_all = lax.dynamic_update_slice_in_dim(
+                        ks_all, ks[None], li, 0)
+                    vs_all = lax.dynamic_update_slice_in_dim(
+                        vs_all, vs[None], li, 0)
+            with jax.named_scope("mlp"):
+                x = x + a
+                h = rms_norm_ref(x, lp["post_attention_layernorm"],
+                                 cfg.rms_norm_eps)
+                x = x + _mlp_cached(h, lp, cfg)
+            return (x, pk_all, pv_all, ks_all, vs_all, li + 1, stats), None
 
-    (x, pk, pv, ks, vs, _), _ = lax.scan(
-        body, (x, k_all, v_all, ks_all, vs_all, jnp.int32(0)),
-        params["layers"])
-    return x, (pk, pv, ks, vs)
+        def latent_body(x, pool_all, li, stats, lp):
+            # the layer's blocks are written and read IN the stacked pool
+            # (block ids offset by li * N): no layer slice is copied out
+            # and back, and no V pool or scales exist
+            L, N = pool_all.shape[:2]
+            with jax.named_scope("mla_q"):
+                h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
+            a, pool = _attention_latent(
+                h, lp, cfg, cos, sin,
+                pool_all.reshape(L * N, *pool_all.shape[2:]), groups,
+                is_prefill, attention_impl, base=li * N)
+            with jax.named_scope("mlp"):
+                x = x + a
+                h = rms_norm_ref(x, lp["post_attention_layernorm"],
+                                 cfg.rms_norm_eps)
+                if ffn == "dense":
+                    x = x + _mlp_cached(h, lp, cfg)
+            if ffn == "moe":
+                x, stats = _ffn_experts(x, h, lp, cfg, groups, stats,
+                                        stacks, li - first_layer)
+            return (x, pool.reshape(pool_all.shape), None, None, None,
+                    li + 1, stats), None
+
+        return body
+
+    stats = None
+    if latent and cfg.num_moe_layers:
+        z = jnp.zeros((), jnp.int32)
+        stats = {"moe_pairs": z, "moe_experts_hit": z, "moe_load_max": z}
+    carry = (x, k_all, v_all, ks_all, vs_all, jnp.int32(0), stats)
+    first_layer = 0
+    for layers, ffn in _layer_groups(params, cfg):
+        stacks = None
+        if ffn == "moe":
+            # the experts' stacks stay whole, outside the scanned leaves
+            stacks = {k: layers[k] for k in _EXPERT_STACKS}
+            layers = {k: v for k, v in layers.items()
+                      if k not in _EXPERT_STACKS}
+        carry, _ = lax.scan(make_body(ffn, stacks, first_layer), carry,
+                            layers)
+        first_layer += jax.tree_util.tree_leaves(layers)[0].shape[0]
+    x, pk, pv, ks, vs, _, stats = carry
+    return x, (pk, pv, ks, vs), stats
 
 
 def forward_paged(params, tokens, cache: PagedKVCache, positions, valid,
@@ -818,14 +1006,27 @@ def forward_paged(params, tokens, cache: PagedKVCache, positions, valid,
     non-prefill path; cold prefill keeps flash. `mesh`/`mesh_axis`
     shard_map-wrap the pallas kernel on the TP mesh (no-op for "xla",
     which shards under plain GSPMD)."""
-    x, (pk, pv, ks, vs) = _forward_groups(
+    logits, cache, _ = _forward_paged_stats(
+        params, tokens, cache, positions, valid, cfg, is_prefill,
+        attention_impl, mesh=mesh, mesh_axis=mesh_axis)
+    return logits, cache
+
+
+def _forward_paged_stats(params, tokens, cache: PagedKVCache, positions,
+                         valid, cfg, is_prefill: bool,
+                         attention_impl: str = "xla", mesh=None,
+                         mesh_axis: str = "mp"):
+    """`forward_paged` with the expert layers' routing counters as a third
+    result (None for a decoder without expert layers): what the step
+    programs call."""
+    x, (pk, pv, ks, vs), stats = _forward_groups(
         params, (_RowGroup(tokens, cache.table, positions, valid),),
         (cache.k, cache.v, cache.k_scale, cache.v_scale), cfg, is_prefill,
         attention_impl, mesh=mesh, mesh_axis=mesh_axis)
     with jax.named_scope("lm_head"):
         logits = _final_head_cached(params, x, cfg)
     new_len = jnp.maximum(cache.lengths, positions[:, -1] + 1)
-    return logits, PagedKVCache(pk, pv, cache.table, new_len, ks, vs)
+    return logits, PagedKVCache(pk, pv, cache.table, new_len, ks, vs), stats
 
 
 def paged_generate(params, tokens, lengths, cfg: llama.LlamaConfig,
@@ -893,6 +1094,18 @@ def paged_generate(params, tokens, lengths, cfg: llama.LlamaConfig,
     return out, allocator, owned
 
 
+def _sum_steps(stats, first=None):
+    """A chunk's routing counters from its steps' (stacked on a leading
+    axis by the scan; `first`: the fused forward's, outside the scan):
+    summed as `_merge_stats` sums two. None for a decoder without expert
+    layers."""
+    if stats is None:
+        return None
+    out = {k: (jnp.max(v) if k == "moe_load_max"
+               else jnp.sum(v, dtype=jnp.int32)) for k, v in stats.items()}
+    return out if first is None else _merge_stats(out, first)
+
+
 class _Tick:
     """One device-call tick of the batcher, described once.
 
@@ -933,6 +1146,14 @@ class _Tick:
                        "commit": 0.0}
         self.t_dispatch: Optional[float] = None
         self.t_synced: Optional[float] = None
+        self.noted: Dict[str, Any] = {}
+
+    def note(self, fields: Optional[Dict[str, Any]]) -> None:
+        """Host values the call itself produced (read back with its
+        tokens), for the record's close: the expert layers' routing
+        counters. None (a decoder without them) notes nothing."""
+        if fields:
+            self.noted.update({k: int(v) for k, v in fields.items()})
 
     def __enter__(self) -> "_Tick":
         cb = self.cb
@@ -1006,7 +1227,7 @@ class _Tick:
             wait_s=st["wait"], commit_s=st["commit"],
             t_dispatch=self.t_dispatch, t_synced=self.t_synced,
             synced=self.t_synced is not None,
-            live_after=sum(cb.active))
+            live_after=sum(cb.active), **self.noted)
         device_s = self.device_s
         if device_s is not None:
             cb.profiler.record(
@@ -1162,7 +1383,10 @@ class ContinuousBatcher:
                  trace=None, flight_recorder_cap: int = 64,
                  profile_sample_every: int = 64,
                  fault_injector=None, replica_id: str = "r0",
-                 mesh=None):
+                 mesh=None, max_prefill_group: Optional[int] = None):
+        if _is_latent(cfg):
+            _refuse_latent(weight_dtype=weight_dtype, kv_dtype=kv_dtype,
+                           speculative=speculative, mesh=mesh)
         # multi-replica attribution: stamped on every `prepared` trace
         # event so a Router's merged trace artifact (and
         # tools/trace_report.py's per-replica grouping) can tell which
@@ -1312,6 +1536,12 @@ class ContinuousBatcher:
         self._no_spec: set = set()
         self._spec_ok_dev = None
         self.max_total = max_total_len
+        # the most rows one prefill call batches (None = the batch
+        # width): bounds the widest step program's activations and the
+        # warm-up ladder, where admissions never come max_batch at once
+        # ptlint: memo-invariant(fixed at construction)
+        self._group_cap = max_batch if max_prefill_group is None \
+            else max(1, min(int(max_prefill_group), max_batch))
         # ptlint: memo-invariant(pool geometry is fixed at construction)
         self.M = -(-max_total_len // block_size)
         self.max_new = max_new_tokens
@@ -1517,6 +1747,9 @@ class ContinuousBatcher:
         int8 scale-pool overhead included) — quantization.kv's
         kv_block_bytes under this batcher's geometry and kv_dtype."""
         cfg = self.cfg
+        if _is_latent(cfg):
+            from . import mla
+            return mla.kv_block_bytes(cfg, self.bs)
         return kvq.kv_block_bytes(
             cfg.num_hidden_layers, self.bs, cfg.num_key_value_heads,
             cfg.head_dim, self.kv_dtype,
@@ -1704,6 +1937,13 @@ class ContinuousBatcher:
         self.queue[:0] = [(v.rid, v.toks, v.stop, v.mn) for v in victims]
 
     # -- KV transfer (serving/kvtransfer.py holds the container) ----------
+    def _refuse_latent_transfer(self, what: str) -> None:
+        if _is_latent(self.cfg):
+            raise NotImplementedError(
+                f"{what}: serving.kvtransfer.KVSnapshot carries a K and a "
+                f"V pool slice per block; the latent (MLA) pool is one "
+                f"array and has no snapshot form yet")
+
     def kv_fingerprint(self) -> Dict[str, Any]:
         """Model/pool-shape identity a KVSnapshot must match to be
         importable here — kvtransfer.check_compatible compares these
@@ -1712,8 +1952,10 @@ class ContinuousBatcher:
         of scattering misinterpreted codes into the pool."""
         return {
             "num_layers": int(self.cfg.num_hidden_layers),
-            "num_key_value_heads": int(self.cfg.num_key_value_heads),
-            "head_dim": int(self.cfg.head_dim),
+            "num_key_value_heads": int(
+                getattr(self.cfg, "num_key_value_heads", 1)),
+            "head_dim": int(getattr(self.cfg, "kv_row_width", 0)
+                            or self.cfg.head_dim),
             "block_size": self.bs,
             "kv_dtype": self.kv_dtype,
             "pool_dtype": str(self.cache.k.dtype),
@@ -1732,6 +1974,7 @@ class ContinuousBatcher:
         strictly cheaper), and finished ones have released their
         blocks — ValueError for both. Migration boundary, not the
         decode hot path: the device pull below IS the transfer."""
+        self._refuse_latent_transfer("export_kv")
         slot = None
         for s in range(self.B):
             if self.active[s] and self.slot_req[s] == rid:
@@ -1811,6 +2054,7 @@ class ContinuousBatcher:
         Raises ValueError on fingerprint/shape mismatch and
         RuntimeError when no slot or blocks are free — callers gate on
         `free_slots()` / `import_blocks_needed()` first."""
+        self._refuse_latent_transfer("import_kv")
         from ..serving import kvtransfer
         problems = kvtransfer.check_compatible(snap.fingerprint,
                                                self.kv_fingerprint())
@@ -2001,7 +2245,7 @@ class ContinuousBatcher:
     def _group_pad(self, G: int) -> int:
         """Pad an admission group to the next power of two (capped at the
         batch width) so burst sizes draw from a fixed shape ladder."""
-        return min(_pow2_ceil(max(1, G)), self.B)
+        return min(_pow2_ceil(max(1, G)), self._group_cap)
 
     def _mesh_axis(self) -> str:
         """The TP mesh axis name the step builders hand to the
@@ -2045,10 +2289,7 @@ class ContinuousBatcher:
             pstruct = self._pstruct()
             exe = fn.lower(
                 pstruct, sds((G, Pb), i32),
-                sds(self.cache.k.shape, self.cache.k.dtype,
-                    self._shard_pool),
-                sds(self.cache.v.shape, self.cache.v.dtype,
-                    self._shard_pool),
+                self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
                 self._scale_aval(self.cache.k_scale),
                 self._scale_aval(self.cache.v_scale),
                 sds((G, self.M), i32), sds((G, Pb), i32),
@@ -2086,8 +2327,7 @@ class ContinuousBatcher:
         table / lengths / int8 scale pools replicated."""
         c = self.cache
         return PagedKVCache(
-            self._aval(c.k.shape, c.k.dtype, self._shard_pool),
-            self._aval(c.v.shape, c.v.dtype, self._shard_pool),
+            self._pool_aval(c.k), self._pool_aval(c.v),
             self._aval(c.table.shape, c.table.dtype),
             self._aval(c.lengths.shape, c.lengths.dtype),
             self._scale_aval(c.k_scale), self._scale_aval(c.v_scale))
@@ -2106,6 +2346,12 @@ class ContinuousBatcher:
             else put(cache.k_scale, self._shard_repl),
             None if cache.v_scale is None
             else put(cache.v_scale, self._shard_repl))
+
+    def _pool_aval(self, pool):
+        """AOT-lowering aval of a pool array, None for the V pool a
+        latent cache does not have."""
+        return None if pool is None else \
+            self._aval(pool.shape, pool.dtype, self._shard_pool)
 
     def _scale_aval(self, scale):
         """AOT-lowering aval for a scale pool: None (no leaves — the fp
@@ -2134,7 +2380,8 @@ class ContinuousBatcher:
         ladder = self._buckets if buckets is None else tuple(buckets)
         if group_sizes is None:
             # exactly the shapes _group_pad can ever produce
-            group_sizes = {self._group_pad(g) for g in range(1, self.B + 1)}
+            group_sizes = {self._group_pad(g)
+                           for g in range(1, self._group_cap + 1)}
         n0 = self.compile_count
         for Pb in ladder:
             for G in sorted(set(group_sizes)):
@@ -2365,7 +2612,7 @@ class ContinuousBatcher:
             target = None
             after: set = set()
             for i in range(len(units) - 1, -1, -1):
-                if keys[i] == k and len(units[i]) < self.B \
+                if keys[i] == k and len(units[i]) < self._group_cap \
                         and not (deps & after) \
                         and not (rec.cow_src is not None
                                  and rec.cow_src in inserted[i]):
@@ -2394,7 +2641,8 @@ class ContinuousBatcher:
                 self.cache = self.cache._replace(
                     k=self.cache.k.at[:, dst].set(
                         self.cache.k[:, rec.cow_src]),
-                    v=self.cache.v.at[:, dst].set(
+                    v=None if self.cache.v is None
+                    else self.cache.v.at[:, dst].set(
                         self.cache.v[:, rec.cow_src]))
                 if self.cache.k_scale is not None:
                     # int8 pool: the clone's codes are meaningless
@@ -2714,7 +2962,7 @@ class ContinuousBatcher:
                     active, budget, stop = self._dev_state
                 with tick.phase("dispatch"):
                     (k, v, ks, vs, lengths, tok, budget, active, toks,
-                     pfirst) = exe(
+                     pfirst, stats) = exe(
                         self.params, self.cache.k, self.cache.v,
                         self.cache.k_scale, self.cache.v_scale,
                         self.cache.table, self.cache.lengths,
@@ -2728,7 +2976,9 @@ class ContinuousBatcher:
                     # tokens and the prefill rows' first tokens — and,
                     # dispatch being async, surfaces any device-side
                     # failure HERE, before the batcher state commits
-                    toks, pfirst = jax.device_get((toks, pfirst))  # ptlint: disable=SYNC001 — single per-step sync, decode + prefill readbacks coalesced
+                    got = jax.device_get((toks, pfirst, stats))  # ptlint: disable=SYNC001 — single per-step sync, decode + prefill readbacks coalesced
+                    toks, pfirst, stats = got
+                    tick.note(stats)
                 # decode state untouched up to here: a failure rolls
                 # the pending units back (below)
                 committed = True
@@ -2876,7 +3126,7 @@ class ContinuousBatcher:
         def step(carry, _):
             cache, tok, lengths, budget, act = carry
             pos = lengths[:, None]
-            logits, cache = forward_paged(
+            logits, cache, stats = _forward_paged_stats(
                 params, tok[:, None], cache, pos, act[:, None],
                 cfg, is_prefill=False, attention_impl=impl, mesh=mesh,
                 mesh_axis=max_)
@@ -2884,7 +3134,9 @@ class ContinuousBatcher:
                 logits[:, 0], tok, act, lengths, budget, stop)
             # inactive slots must not drift: pin lengths ourselves
             cache = cache._replace(lengths=lengths)
-            return (cache, nxt, lengths, budget, act), nxt
+            # stats: the expert layers' routing counters of this step,
+            # None (no leaves) for a decoder without expert layers
+            return (cache, nxt, lengths, budget, act), (nxt, stats)
 
         return step
 
@@ -2894,12 +3146,13 @@ class ContinuousBatcher:
         def serve_decode_step(params, cache, tok, active, lengths, budget,
                               stop):
             step = self._decode_step_body(params, stop)
-            (cache, tok, lengths, budget, act), toks = jax.lax.scan(
-                step, (cache, tok, lengths, budget, active), None,
-                length=chunk)
+            (cache, tok, lengths, budget, act), (toks, stats) = \
+                jax.lax.scan(step, (cache, tok, lengths, budget, active),
+                             None, length=chunk)
             # act/budget go back to the caller so the next chunk can feed
             # them in again without a host round-trip
-            return cache, tok, lengths, budget, act, toks.T   # [B, chunk]
+            return (cache, tok, lengths, budget, act, toks.T,  # [B, chunk]
+                    _sum_steps(stats))
 
         return jax.jit(serve_decode_step)
 
@@ -2953,7 +3206,7 @@ class ContinuousBatcher:
                              active, budget, stop, prows, ppos, pval, ptab,
                              plast):
             Gp, Pb = prows.shape
-            x, (k, v, ks, vs) = _forward_groups(
+            x, (k, v, ks, vs), stats0 = _forward_groups(
                 params,
                 (_RowGroup(tok[:, None], table, lengths[:, None],
                            active[:, None]),
@@ -2972,13 +3225,14 @@ class ContinuousBatcher:
                 logits[:B], tok, active, lengths, budget, stop)
             cache = PagedKVCache(k, v, table, lengths, ks, vs)
             step = self._decode_step_body(params, stop)
-            (cache, tok, lengths, budget, active), toks = jax.lax.scan(
-                step, (cache, nxt, lengths, budget, active), None,
-                length=chunk - 1)
+            (cache, tok, lengths, budget, active), (toks, stats) = \
+                jax.lax.scan(step, (cache, nxt, lengths, budget, active),
+                             None, length=chunk - 1)
             toks = jnp.concatenate([nxt[None], toks], 0)
             return (cache.k, cache.v, cache.k_scale, cache.v_scale,
                     lengths, tok, budget, active,
-                    toks.T, pfirst)                       # toks [B, chunk]
+                    toks.T, pfirst,                       # toks [B, chunk]
+                    _sum_steps(stats, stats0))
 
         return jax.jit(serve_fused_step)
 
@@ -3001,10 +3255,7 @@ class ContinuousBatcher:
             B = self.B
             exe = self._fused_fn.lower(
                 pstruct,
-                sds(self.cache.k.shape, self.cache.k.dtype,
-                    self._shard_pool),
-                sds(self.cache.v.shape, self.cache.v.dtype,
-                    self._shard_pool),
+                self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
                 self._scale_aval(self.cache.k_scale),
                 self._scale_aval(self.cache.v_scale),
                 sds((B, self.M), i32), sds((B,), i32), sds((B,), i32),
@@ -3566,14 +3817,15 @@ class ContinuousBatcher:
                 active, budget, stop = self._dev_state
             with tick.phase("dispatch"):
                 (self.cache, self.cur_tok, lengths, budget, active,
-                 toks) = self._chunk_exe()(
+                 toks, stats) = self._chunk_exe()(
                     self.params, self.cache, self.cur_tok, active,
                     self.cache.lengths, budget, stop)
             tick.fence((self.cache.k, self.cur_tok, toks))
             with tick.phase("wait"):
                 # one host sync per decode chunk — the per-token loop
                 # of the commit reads this numpy copy, never the device
-                toks = np.asarray(toks)  # ptlint: disable=SYNC001 — single per-chunk sync, hoisted out of the per-token loop
+                toks, stats = jax.device_get((toks, stats))  # ptlint: disable=SYNC001 — single per-chunk sync, hoisted out of the per-token loop
+                tick.note(stats)
             with tick.phase("commit"):
                 self.cache = self.cache._replace(lengths=lengths)
                 # steady state: the chunk's own outputs are next chunk's

@@ -63,7 +63,8 @@ import jax.numpy as jnp
 
 from ..quantization import kv as kvq
 
-__all__ = ["ragged_paged_attention", "resolve_attention_impl"]
+__all__ = ["ragged_paged_attention", "mla_paged_attention",
+           "resolve_attention_impl"]
 
 _NEG_INF = -1e30
 
@@ -459,3 +460,150 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
     in_specs, out_spec = _shard_specs(mesh_axis, quantized, suffix)
     return jax.shard_map(_kernel_call, mesh=mesh, in_specs=in_specs,
                          out_specs=out_spec, check_vma=False)(*args)
+
+
+# ---------------------------------------------------------------------------
+# latent (MLA) mode: one KV head, every query head over it, keys the whole
+# cached row, values its first columns, each row read once
+# ---------------------------------------------------------------------------
+
+def _mla_kernel(*refs, bs: int, nb: int, scale: float, v_width: int):
+    """One (row, query-tile, chunk of `nb` blocks) grid step of the latent
+    kernel. Refs: tab/live (scalar prefetch), pos_ref/val_ref [1, 1, G, 1]
+    int32, q_ref [1, 1, G, W] (G = Pt*H query rows, query-major), `nb`
+    k_refs [1, bs, W] (the chunk's pool blocks, each resolved from the
+    table by its own index map: the SAME pool array `nb` times over),
+    o_ref [1, 1, G, v_width]; scratch acc [G, v_width], m/l [G, 1] f32.
+    Scores contract the full row width W; values are columns
+    [0, v_width) of the same block in VMEM: keys and values alias, so a
+    cached row crosses HBM once. Both dots take the operands in their
+    stored type and accumulate in float32."""
+    import jax.experimental.pallas as pl
+
+    tab_ref, live_ref, pos_ref, val_ref, q_ref = refs[:5]
+    k_refs = refs[5:5 + nb]
+    o_ref, acc_ref, m_ref, l_ref = refs[5 + nb:]
+    r, t, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nlive = (live_ref[r, t] + nb - 1) // nb          # live CHUNKS
+
+    @pl.when(c == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(c < nlive)
+    def _accumulate():
+        G = pos_ref.shape[2]
+        k = jnp.concatenate([kr[0] for kr in k_refs], axis=0)  # [nb*bs, W]
+        # a block slot past the tile's live chain re-reads the last live
+        # block (the index map clamps): its key positions lie past every
+        # visible one, so the causal test hides it
+        kpos = c * (nb * bs) + jax.lax.broadcasted_iota(
+            jnp.int32, (G, nb * bs), 1)
+        vis = (kpos <= pos_ref[0, 0]) & (val_ref[0, 0] != 0)
+        s = jax.lax.dot_general(q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(vis, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(vis, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(k.dtype), k[:, :v_width],
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(c == jnp.maximum(nlive - 1, 0))
+    def _finalize():
+        l = l_ref[...]
+        o_ref[0, 0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
+                       ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "v_width", "q_tile",
+                                             "blocks_per_step", "interpret"))
+def mla_paged_attention(q, pool, table, positions, valid=None, *,
+                        scale: float, v_width: int, q_tile: int = 16,
+                        blocks_per_step=None, interpret=None):
+    """Latent paged attention, the absorbed form of MLA (nlp/mla.py):
+
+      q [R, P, H, W] (each head's query carried into the latent space,
+      its rope columns last); pool [N, bs, W] one row `[c | k_r]` a
+      cached token; table [R, M]; positions / valid [R, P] as in
+      `ragged_paged_attention`. Returns [R, P, H, v_width]: softmax over
+      the visible keys of `scale * q . row`, times the rows' first
+      `v_width` columns. Invalid queries return zeros.
+
+    The grid is (row, query tile, chunk of `blocks_per_step` blocks):
+    the one KV head is shared by all H query heads, so a tile of Pt
+    queries is Pt*H kernel rows against each block (`q_tile` = Pt
+    bounds VMEM: scratch and the q/o blocks grow with Pt*H). The pool
+    goes in `blocks_per_step` times, each operand's index map resolving
+    one block of the chunk from the prefetched table, so a grid step
+    moves that many blocks and the per-step overhead is paid once for
+    them; past a tile's live chain the maps clamp to its last live
+    block and whole dead chunks are skipped. Defaults from the chip
+    (PR 26): a decode call (P = 1) is grid steps, not bytes (64 rows x
+    16 chunks of 8 blocks cost 0.47 ms a call with 5 rows live), so it
+    takes 32 blocks a step; prefill rows take 16 queries x 16 blocks
+    (32 x 16 x H rows of float32 scores do not fit the kernel's VMEM)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    R, P, H, W = q.shape
+    N, bs, _ = pool.shape
+    M = table.shape[1]
+    if blocks_per_step is None:
+        blocks_per_step = 32 if P == 1 else 16
+    nb = max(1, min(int(blocks_per_step), M))
+    if valid is None:
+        valid = jnp.ones((R, P), bool)
+    positions = positions.astype(jnp.int32)
+    table = table.astype(jnp.int32)
+    q_tile = max(1, min(q_tile, P))
+    Pt = max(d for d in range(1, q_tile + 1) if P % d == 0)
+    T, G = P // Pt, Pt * H
+    live_tok = jnp.max(
+        jnp.where(valid, positions + 1, 0).reshape(R, T, Pt), axis=2)
+    live = ((live_tok + bs - 1) // bs).astype(jnp.int32)     # live BLOCKS
+
+    def _rows(x):
+        # [R, P] per query -> [R, T, G, 1] per kernel row (query-major)
+        x = jnp.broadcast_to(x.reshape(R, T, Pt, 1), (R, T, Pt, H))
+        return x.reshape(R, T, G, 1)
+
+    def _tile_map(r, t, c, tab, live):
+        return (r, t, 0, 0)
+
+    def _kv_map(i):
+        def index(r, t, c, tab, live):
+            j = jnp.minimum(c * nb + i, jnp.maximum(live[r, t] - 1, 0))
+            return (jnp.maximum(tab[r, j], 0), 0, 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(R, T, -(-M // nb)),
+        in_specs=[pl.BlockSpec((1, 1, G, 1), _tile_map),
+                  pl.BlockSpec((1, 1, G, 1), _tile_map),
+                  pl.BlockSpec((1, 1, G, W), _tile_map)]
+        + [pl.BlockSpec((1, bs, W), _kv_map(i)) for i in range(nb)],
+        out_specs=pl.BlockSpec((1, 1, G, v_width), _tile_map),
+        scratch_shapes=[pltpu.VMEM((G, v_width), jnp.float32),
+                        pltpu.VMEM((G, 1), jnp.float32),
+                        pltpu.VMEM((G, 1), jnp.float32)])
+    call = pl.pallas_call(
+        functools.partial(_mla_kernel, bs=bs, nb=nb, scale=float(scale),
+                          v_width=v_width),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, T, G, v_width), q.dtype),
+        interpret=interpret, name="mla_paged_attention")
+    with jax.enable_x64(False):
+        o = call(table, live, _rows(positions),
+                 _rows(valid.astype(jnp.int32)), q.reshape(R, T, G, W),
+                 *([pool] * nb))
+    return o.reshape(R, P, H, v_width)

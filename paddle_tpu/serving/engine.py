@@ -174,6 +174,7 @@ class ServingEngine:
                  replica_id: str = "r0",
                  role: str = "both",
                  mesh=None,
+                 max_prefill_group: Optional[int] = None,
                  clock=time.monotonic):
         # multi-replica attribution: every snapshot, health report,
         # flight dump and batcher-side `prepared` trace event carries
@@ -215,7 +216,13 @@ class ServingEngine:
         self.last_flight_dump: Optional[Dict] = None
         self.last_flight_dump_json: Optional[str] = None
         # lazy: keep `import paddle_tpu` from pulling the whole nlp tree
-        from ..nlp.paged import ContinuousBatcher
+        from ..nlp.paged import ContinuousBatcher, _is_latent
+        if role == "prefill" and _is_latent(cfg):
+            # the batcher refuses export_kv / import_kv for a latent pool;
+            # a prefill-role engine exists only to export
+            raise NotImplementedError(
+                "role='prefill': a latent (MLA) pool has no KVSnapshot "
+                "form, so its KV cannot be surrendered to a decode replica")
         self.batcher = ContinuousBatcher(
             params, cfg, max_batch=max_batch, block_size=block_size,
             max_total_len=max_total_len, max_new_tokens=max_new_tokens,
@@ -234,7 +241,7 @@ class ServingEngine:
             profile_sample_every=profile_sample_every,
             fault_injector=fault_injector,
             replica_id=self.replica_id,
-            mesh=mesh)
+            mesh=mesh, max_prefill_group=max_prefill_group)
         # tensor-parallel serving (serving/tp.py): the batcher owns the
         # sharded weights/pool; the engine mirrors the mesh shape into
         # snapshot()/health()/gauges so a Router's merged forensics can

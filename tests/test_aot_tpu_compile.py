@@ -133,7 +133,45 @@ def _adam8(topo):
     return jax.jit(tx.apply_fused).lower(*avals).compile().as_text()
 
 
+def _mla(topo, R, Pq):
+    """The latent (MLA) kernel at A.X-K1's widths: 64 query heads over one
+    cached row of 512 + 64 columns, values the row's first 512."""
+    from paddle_tpu.nlp.ragged_attention import mla_paged_attention
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def fn(q, pool, tab, pos, val):
+        return mla_paged_attention(q, pool, tab, pos, val, scale=0.13086,
+                                   v_width=512, interpret=False)
+
+    return _compile(fn, [one] * 5, ((R, Pq, 64, 576), BF),
+                    ((N, BS, 576), BF), ((R, M), jnp.int32),
+                    ((R, Pq), jnp.int32), ((R, Pq), jnp.bool_))
+
+
+def _expert_share(topo, T):
+    """The dropless expert layer: 12 held experts of A.X-K1's width under
+    a 192-wide router; the grouped GEMMs must be the chip's own."""
+    from paddle_tpu.nlp import moe
+    one = SingleDeviceSharding(topo.devices[0])
+    Dm, Fm, n = 7168, 2048, 12
+
+    def fn(h, router, g, u, d):
+        lp = {"router": router, "experts_gate": g, "experts_up": u,
+              "experts_down": d}
+        return moe.expert_share_ffn(h, lp, k=8, first=0, scale=2.5,
+                                    layer=1)[0]
+
+    txt = _compile(fn, [one] * 5, ((T, Dm), BF), ((Dm, 192), BF),
+                   ((2, n, Dm, Fm), BF), ((2, n, Dm, Fm), BF),
+                   ((2, n, Fm, Dm), BF))
+    assert "ragged-dot" in txt or "ragged_dot" in txt
+    return txt
+
+
 CASES = {
+    "mla-decode-64-rows": lambda t: _mla(t, 64, 1),
+    "mla-prefill-bucket-512": lambda t: _mla(t, 2, 512),
+    "expert-share-fused-576-tokens": lambda t: _expert_share(t, 576),
     "ragged-decode": lambda t: _ragged(t, 8, 1),
     "ragged-prefill-bucket-512": lambda t: _ragged(t, 1, 512),
     "ragged-prefill-bucket-8": lambda t: _ragged(t, 8, 8),

@@ -1,0 +1,398 @@
+"""The MLA + sparse-expert decoder on the served path (nlp/mla.py,
+moe.expert_share_ffn, the latent pool of nlp/paged.py) at a tiny size on
+the CPU, against the benchmark's plain reference
+(benchmark/reference/mla_moe_decoder.py: float32, no cache, expanded
+attention, a loop over the held experts).
+
+Tolerances: everything here runs in float32 on the CPU, program and
+reference alike, so the two differ by the order of float32 sums only
+(absorbed against expanded attention reassociates a 3-matrix product; the
+grouped GEMM sums in another order than the loop over experts): on logits
+of magnitude 0.6 the largest difference read is 1.8e-7 (cold prefill) and
+1.2e-7 (a decode step); 2e-5 leaves a hundredfold room for another backend's
+sums and is a thousandth of what a small fault moves them (the gates'
+factor at 2.4 in place of 2.5: 0.016).
+"""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.models import mla_moe_decoder as family        # noqa: E402
+from benchmark.reference import mla_moe_decoder as reference  # noqa: E402
+from paddle_tpu.kernels import rope                           # noqa: E402
+from paddle_tpu.nlp import mla, moe, paged                    # noqa: E402
+from paddle_tpu.nlp.ragged_attention import mla_paged_attention  # noqa: E402
+
+TOL = 2e-5
+
+MODEL = {
+    "attention_bias": False, "first_k_dense_replace": 1,
+    "hidden_act": "silu", "hidden_size": 64, "intermediate_size": 160,
+    "kv_lora_rank": 32, "max_position_embeddings": 256,
+    "moe_intermediate_size": 32, "moe_layer_freq": 1, "n_routed_experts": 6,
+    "n_shared_experts": 1, "norm_topk_prob": True, "num_attention_heads": 4,
+    "num_experts_per_tok": 4, "num_hidden_layers": 3, "q_lora_rank": 48,
+    "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "rms_norm_eps": 1e-6,
+    "rope_theta": 10000,
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 4,
+                     "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 64, "type": "yarn"},
+    "routed_scaling_factor": 2.5, "scoring_func": "sigmoid",
+    "tie_word_embeddings": False, "topk_method": "none", "v_head_dim": 16,
+    "vocab_size": 128}
+CONFIG = {"family": "mla_moe_decoder", **MODEL, "served_dtype": "float32",
+          "share": {"router_experts": 16, "experts_first": 4}}
+SEED = 3
+
+
+@pytest.fixture(scope="module")
+def model():
+    d = family.dims(CONFIG)
+    cfg = family.program_config(CONFIG)
+    params = family.make_params(SEED, d, jnp.float32)
+    return d, cfg, params
+
+
+def _cache(cfg, rows, max_len, bs=4, nblocks=64):
+    M = -(-max_len // bs)
+    pool, v, _, _ = paged.init_pool(cfg, nblocks, bs)
+    assert v is None
+    table = jnp.asarray(np.arange(1, 1 + rows * M).reshape(rows, M),
+                        jnp.int32)
+    return paged.PagedKVCache(pool, None, table,
+                              jnp.zeros((rows,), jnp.int32))
+
+
+def test_yarn_frequencies_and_scale_hand_worked():
+    # A.X-K1's rope block: theta 10000 on 64 dims, factor 32 over 4096,
+    # beta_fast 32, beta_slow 1. Correction dims by hand:
+    # 64 ln(4096 / (32 * 2 pi)) / (2 ln 10000) = 10.47 -> low 10,
+    # 64 ln(4096 / (1 * 2 pi)) / (2 ln 10000) = 22.51 -> high 23
+    inv = np.asarray(rope.yarn_inv_freq(64, 10000.0, 32.0, 4096, 32, 1))
+    base = 1.0 / 10000.0 ** (np.arange(0, 64, 2) / 64)
+    np.testing.assert_allclose(inv[:11], base[:11], rtol=1e-6)   # kept
+    np.testing.assert_allclose(inv[23:], base[23:] / 32, rtol=1e-6)
+    # halfway up the ramp (i = 16.5 lies between 16 and 17)
+    r16 = (16 - 10) / 13
+    np.testing.assert_allclose(
+        inv[16], base[16] / 32 * r16 + base[16] * (1 - r16), rtol=1e-6)
+    assert abs(rope.yarn_mscale(32, 1) - 1.3465736) < 1e-6
+    cfg = mla.MlaMoeConfig(
+        qk_nope_head_dim=128, qk_rope_head_dim=64,
+        rope_scaling={"factor": 32, "original_max_position_embeddings": 4096,
+                      "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                      "mscale_all_dim": 1})
+    assert abs(cfg.softmax_scale - 0.13086080) < 1e-7
+    cos, sin = cfg.rope_tables(8)            # m(32, 1) / m(32, 1) = 1
+    np.testing.assert_allclose(np.asarray(cos[3]), np.cos(3 * inv), 1e-5)
+    # the reference computes the same frequencies on its own
+    np.testing.assert_allclose(
+        reference.yarn_inv_freq(64, 10000.0, {
+            "factor": 32, "original_max_position_embeddings": 4096,
+            "beta_fast": 32, "beta_slow": 1}), inv, rtol=1e-6)
+
+
+def test_absorbed_equals_expanded_float32(model):
+    d, cfg, params = model
+    lp = jax.tree.map(lambda a: a[0], params["moe_layers"])
+    rng = np.random.default_rng(0)
+    G, P = 2, 12
+    h = jnp.asarray(rng.normal(size=(G, P, cfg.hidden_size)), jnp.float32)
+    pos = jnp.broadcast_to(jnp.arange(P)[None], (G, P))
+    cos, sin = cfg.rope_tables(64)
+    q = mla.project_q(h, lp, cfg)
+    c, k_r = mla.project_latent(h, lp, cfg)
+    q, k_r = mla.rotate(q, k_r, cos, sin, pos, cfg)
+    rows = jnp.concatenate([c, k_r], -1)
+    expanded = mla.attend_expanded(q, rows, lp, cfg)
+    cache = _cache(cfg, G, 16)
+    pool = paged._write_pool(cache.k[0], cache.table, pos, rows,
+                             jnp.ones((G, P), bool))
+    o_lat = mla.latent_paged_attention(mla.absorb_q(q, lp, cfg), pool,
+                                       cache.table, pos, None, cfg, "xla")
+    absorbed = mla.unabsorb_o(o_lat, lp, cfg)
+    np.testing.assert_allclose(np.asarray(absorbed), np.asarray(expanded),
+                               atol=2e-6)
+
+
+@pytest.mark.parametrize("shape", [(3, 1, 1, 8), (2, 8, 4, 2), (2, 6, 3, 3)])
+def test_latent_kernel_matches_gather_reference(shape):
+    R, P, q_tile, nb = shape
+    rng = np.random.default_rng(R * 10 + P)
+    H, W, V, N, bs, M = 4, 40, 32, 40, 4, 8
+    q = jnp.asarray(rng.normal(size=(R, P, H, W)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(N, bs, W)), jnp.float32)
+    table = jnp.asarray(rng.permutation(N)[:R * M].reshape(R, M), jnp.int32)
+    start = rng.integers(0, M * bs - P, R)
+    pos = jnp.asarray(start[:, None] + np.arange(P)[None], jnp.int32)
+    valid = jnp.asarray(rng.random((R, P)) < 0.8).at[:, 0].set(True)
+    out = mla_paged_attention(q, pool, table, pos, valid, scale=0.2,
+                              v_width=V, q_tile=q_tile, blocks_per_step=nb)
+    ref = mla.latent_paged_attention_xla(q, pool, table, pos, 0.2, V)
+    keep = np.asarray(valid)[:, :, None, None]
+    np.testing.assert_allclose(np.where(keep, out, 0), np.where(keep, ref, 0),
+                               atol=2e-6)
+    assert not np.any(np.where(keep, 0, out))    # invalid queries: zeros
+
+
+def _tokens(n_rows, n, vocab, seed=0):
+    return np.random.default_rng(seed).integers(1, vocab, (n_rows, n)
+                                                ).astype(np.int32)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("how", ["cold", "cached_suffix", "fused"])
+def test_paged_forward_matches_reference_logits(model, how, impl):
+    """Prefill, then decode through the latent pool, against the
+    reference's full forward over the same tokens."""
+    d, cfg, params = model
+    B, P, n_dec = 2, 12, 3
+    toks = _tokens(B, P + n_dec, d["V"])
+    ref = np.asarray(reference.logits(SEED, d, jnp.asarray(toks),
+                                      jnp.float32))
+    cache = _cache(cfg, B, 24)
+    pos = jnp.broadcast_to(jnp.arange(P)[None], (B, P))
+    ones = jnp.ones((B, P), bool)
+    t = jnp.asarray(toks)
+    if how == "cold":
+        logits, cache = paged.forward_paged(params, t[:, :P], cache, pos,
+                                            ones, cfg, is_prefill=True)
+    elif how == "cached_suffix":
+        cut = 8         # two blocks cold, the rest through the block table
+        _, cache = paged.forward_paged(params, t[:, :cut], cache,
+                                       pos[:, :cut], ones[:, :cut], cfg,
+                                       is_prefill=True)
+        tail, cache = paged.forward_paged(
+            params, t[:, cut:P], cache, pos[:, cut:], ones[:, cut:], cfg,
+            is_prefill=False, attention_impl=impl)
+        np.testing.assert_allclose(np.asarray(tail), ref[:, cut:P], atol=TOL)
+        logits = None
+    else:
+        # row 0 decodes its token P-1 while row 1's whole prompt prefills,
+        # as serve_fused_step packs them
+        _, c0 = paged.forward_paged(params, t[:1, :P - 1], cache._replace(
+            table=cache.table[:1], lengths=cache.lengths[:1]),
+            pos[:1, :P - 1], ones[:1, :P - 1], cfg, is_prefill=True)
+        groups = (paged._RowGroup(t[:1, P - 1:P], cache.table[:1],
+                                  pos[:1, P - 1:P], ones[:1, :1]),
+                  paged._RowGroup(t[1:, :P], cache.table[1:], pos[1:],
+                                  ones[1:]))
+        x, pools, stats = paged._forward_groups(
+            params, groups, (c0.k, None, None, None), cfg, False, impl)
+        fused = np.asarray(paged._final_head_cached(params, x, cfg))
+        np.testing.assert_allclose(fused[0], ref[0, P - 1], atol=TOL)
+        np.testing.assert_allclose(fused[1:], ref[1, :P], atol=TOL)
+        # P + 1 valid tokens, k choices each, over 2 expert layers
+        assert 0 < int(stats["moe_pairs"]) <= (P + 1) * d["k"] * 2
+        cache = cache._replace(k=pools[0])
+        logits = None
+    if logits is not None:
+        np.testing.assert_allclose(np.asarray(logits), ref[:, :P], atol=TOL)
+    cache = cache._replace(lengths=jnp.full((B,), P, jnp.int32))
+    for j in range(n_dec):
+        at = jnp.full((B, 1), P + j, jnp.int32)
+        step, cache = paged.forward_paged(
+            params, t[:, P + j:P + j + 1], cache, at, jnp.ones((B, 1), bool),
+            cfg, is_prefill=False, attention_impl=impl)
+        np.testing.assert_allclose(np.asarray(step[:, 0]), ref[:, P + j],
+                                   atol=TOL)
+
+
+def test_routing_matches_reference(model):
+    d, cfg, params = model
+    h = jnp.asarray(np.random.default_rng(1).normal(size=(50, d["D"])),
+                    jnp.float32)
+    w = params["moe_layers"]["router"][0]
+    idx, gates = moe.sigmoid_top_k(h, w, d["k"], d["route_scale"], True)
+    ridx, rgates = reference.route(h, w.astype(jnp.float32), d)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
+    np.testing.assert_allclose(np.asarray(gates), np.asarray(rgates),
+                               rtol=1e-6)
+    # k distinct experts a token, gates sum to the scaling factor
+    assert all(len(set(r)) == d["k"] for r in np.asarray(idx))
+    np.testing.assert_allclose(np.asarray(gates).sum(-1), 2.5, rtol=1e-5)
+    assert gates.dtype == jnp.float32
+
+
+def _experts(rng, n, D, F):
+    return {"experts_gate": jnp.asarray(rng.normal(size=(n, D, F)) * 0.2,
+                                        jnp.float32),
+            "experts_up": jnp.asarray(rng.normal(size=(n, D, F)) * 0.2,
+                                      jnp.float32),
+            "experts_down": jnp.asarray(rng.normal(size=(n, F, D)) * 0.2,
+                                        jnp.float32)}
+
+
+def _stacked(lp, layers=3, layer=1):
+    """The layer's experts as layer `layer` of a stack whose other layers
+    hold other weights (the form `expert_share_ffn` takes them in)."""
+    out = {"router": lp["router"]}
+    for m in ("experts_gate", "experts_up", "experts_down"):
+        out[m] = jnp.stack([lp[m] if i == layer else lp[m][::-1] * (i + 2.0)
+                            for i in range(layers)])
+    return out
+
+
+def _loop_over_experts(h, lp, k, first, scale):
+    """sum_{i in top-k, first <= i < first + n} g_i E_i(h), the plain way."""
+    d = {"k": k, "norm_topk": True, "route_scale": scale}
+    idx, gates = reference.route(h, lp["router"], d)
+    y = jnp.zeros_like(h)
+    for j in range(lp["experts_gate"].shape[0]):
+        g = jnp.sum(jnp.where(idx == first + j, gates, 0.0), -1)
+        y = y + g[:, None] * reference._mlp(
+            h, lp["experts_gate"][j], lp["experts_up"][j],
+            lp["experts_down"][j], jnp.matmul)
+    return y
+
+
+def test_dropless_when_every_token_picks_one_expert():
+    """No capacity: a routing that sends all T tokens to one expert
+    computes all T, and the padding rows none."""
+    rng = np.random.default_rng(2)
+    T, D, F, E, n, k = 40, 16, 8, 16, 4, 3
+    h = jnp.asarray(np.abs(rng.normal(size=(T, D))) + 0.1, jnp.float32)
+    router = rng.normal(size=(D, E)) * 0.05
+    router[:, 5] = 2.0              # every token's first choice: expert 5
+    lp = {"router": jnp.asarray(router, jnp.float32),
+          **_experts(rng, n, D, F)}
+    valid = jnp.asarray(np.arange(T) < 33)
+    y, st = moe.expert_share_ffn(h, _stacked(lp), k=k, first=4, scale=2.5,
+                                 valid=valid, layer=jnp.int32(1))
+    want = _loop_over_experts(h, lp, k, 4, 2.5)
+    np.testing.assert_allclose(np.asarray(y)[:33], np.asarray(want)[:33],
+                               atol=1e-5)
+    assert not np.any(np.asarray(y)[33:])
+    assert int(st["moe_load_max"]) == 33          # expert 5 took them all
+    assert 33 <= int(st["moe_pairs"]) <= 33 * k
+    # blocked over tokens (the bound on the sorted buffer): same result
+    yb, stb = moe.expert_share_ffn(h, _stacked(lp), k=k, first=4, scale=2.5,
+                                   valid=valid, layer=1, token_block=16)
+    np.testing.assert_allclose(np.asarray(yb), np.asarray(y), atol=1e-6)
+    assert int(stb["moe_pairs"]) == int(st["moe_pairs"])
+
+
+def test_shares_add_up_to_the_uncut_layer():
+    """A 16-expert layer cut into 4 shares of 4: the routed parts all four
+    chips compute, plus the shared expert ONCE, equal the uncut layer."""
+    rng = np.random.default_rng(4)
+    T, D, F, E, k = 24, 16, 8, 16, 4
+    h = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+    whole = {"router": jnp.asarray(rng.normal(size=(D, E)), jnp.float32),
+             **_experts(rng, E, D, F)}
+    shared = {n: jnp.asarray(rng.normal(size=s) * 0.2, jnp.float32)
+              for n, s in (("gate_proj", (D, F)), ("up_proj", (D, F)),
+                           ("down_proj", (F, D)))}
+    shared_out = reference._mlp(h, shared["gate_proj"], shared["up_proj"],
+                                shared["down_proj"], jnp.matmul)
+    uncut = _loop_over_experts(h, whole, k, 0, 2.5) + shared_out
+    parts, pairs = jnp.zeros_like(h), 0
+    for c in range(4):
+        share = {"router": whole["router"],
+                 **{n: whole[n][4 * c:4 * c + 4] for n in
+                    ("experts_gate", "experts_up", "experts_down")}}
+        y, st = moe.expert_share_ffn(h, _stacked(share, 2, 0), k=k,
+                                     first=4 * c, scale=2.5, layer=0)
+        parts, pairs = parts + y, pairs + int(st["moe_pairs"])
+    np.testing.assert_allclose(np.asarray(parts + shared_out),
+                               np.asarray(uncut), atol=1e-5)
+    assert pairs == T * k           # every pair computed on exactly one chip
+
+
+def test_latent_pool_is_one_array(model):
+    d, cfg, params = model
+    b = paged.ContinuousBatcher(params, cfg, max_batch=2, block_size=4,
+                                max_total_len=32, max_new_tokens=4)
+    W = d["R"] + d["dr"]
+    assert b.cache.v is None and b.cache.k_scale is None
+    assert b.cache.k.shape == (d["L"], 2 * 8, 4, W)
+    assert b.kv_bytes_per_token() == W * 4 * d["L"]        # float32 here
+    assert b.kv_pool_bytes() == b.cache.k.nbytes
+    real = mla.MlaMoeConfig(num_hidden_layers=7, kv_lora_rank=512,
+                            qk_rope_head_dim=64)
+    assert mla.kv_block_bytes(real, 16) / 16 == 1152 * 7
+
+
+def test_engine_serves_mixed_prompts_with_a_prefix_hit(model):
+    from paddle_tpu import serving
+    d, cfg, params = model
+    rng = np.random.default_rng(5)
+    shared = rng.integers(1, d["V"], 16).tolist()
+    prompts = [shared + rng.integers(1, d["V"], n).tolist()
+               for n in (5, 9)] + [rng.integers(1, d["V"], 21).tolist()]
+    eng = serving.ServingEngine(
+        params, cfg, max_batch=4, block_size=4, max_total_len=64,
+        max_new_tokens=6, prefill_buckets=(8, 16), chunk=2,
+        max_prefill_group=2, start=False)
+    try:
+        eng.warmup()
+        eng.start()
+        first = eng.submit(prompts[0], max_new_tokens=6)
+        next(first.stream())            # decoding: the next ones fuse
+        rest = [eng.submit(p, max_new_tokens=6) for p in prompts[1:]]
+        outs = [list(h.result(timeout=300)) for h in [first] + rest]
+        assert eng.drain(60)
+        b = eng.batcher
+        assert b.prefix_stats()["hit_tokens"] >= 16
+        assert b.fused_steps >= 1 and b.alloc.free_blocks == b.alloc.num_blocks
+        recs = [r for r in b.flight.records() if r["mode"] in
+                ("decode", "fused") and r.get("closed")]
+        assert recs and all(r["moe_pairs"] > 0 and 1 <= r["moe_load_max"]
+                            and r["moe_experts_hit"] >= 1 for r in recs)
+    finally:
+        eng.shutdown(drain=False, timeout=60)
+    # the same greedy tokens as the reference's full forward, token by
+    # token (float32 both sides: a near-tie would have to be within 2e-5)
+    for p, out in zip(prompts, outs):
+        seq = np.asarray([p + out], np.int32)
+        ref = np.asarray(reference.logits(SEED, d, jnp.asarray(seq),
+                                          jnp.float32))[0]
+        at = ref[len(p) - 1:len(p) - 1 + len(out)]
+        assert np.all(at.max(-1) - at[np.arange(len(out)), out] < TOL)
+
+
+@pytest.mark.parametrize("option", [
+    {"kv_dtype": "int8"}, {"weight_dtype": "int8"}, {"speculative": True},
+    {"mesh": object()}])
+def test_unsupported_options_are_refused_at_construction(model, option):
+    d, cfg, params = model
+    with pytest.raises(NotImplementedError, match=next(iter(option))):
+        paged.ContinuousBatcher(params, cfg, max_batch=2, block_size=4,
+                                max_total_len=32, max_new_tokens=4, **option)
+
+
+def test_kv_transfer_is_refused():
+    from paddle_tpu import serving
+    cfg = mla.MlaMoeConfig.tiny(experts_first=4, experts_count=8)
+    params = mla.init_params(jax.random.key(0), cfg)
+    assert params["moe_layers"]["experts_up"].shape == (2, 8, 64, 32)
+    b = paged.ContinuousBatcher(params, cfg, max_batch=2, block_size=4,
+                                max_total_len=32, max_new_tokens=4)
+    with pytest.raises(NotImplementedError, match="export_kv"):
+        b.export_kv(0)
+    with pytest.raises(NotImplementedError, match="import_kv"):
+        b.import_kv(None)
+    with pytest.raises(NotImplementedError, match="prefill"):
+        serving.ServingEngine(params, cfg, role="prefill", start=False)
+
+
+def test_prefill_group_cap_bounds_the_ladder():
+    from paddle_tpu.nlp import llama
+    cfg = llama.LlamaConfig.tiny()
+    params = llama.init_params(jax.random.key(0), cfg)
+    kw = dict(max_batch=8, block_size=8, max_total_len=64, max_new_tokens=4,
+              prefill_buckets=(16,))
+    wide = paged.ContinuousBatcher(params, cfg, **kw)
+    capped = paged.ContinuousBatcher(params, cfg, max_prefill_group=2, **kw)
+    assert [wide._group_pad(g) for g in (1, 3, 8)] == [1, 4, 8]
+    assert [capped._group_pad(g) for g in (1, 3, 8)] == [1, 2, 2]
+    recs = [paged._Admission(i, i, [1] * 9, -1, 4, 2, [], 0, None, [], [],
+                             [(0, 9, 16)]) for i in range(5)]
+    assert [len(u) for u in capped._units(recs)] == [2, 2, 1]
+    assert [len(u) for u in wide._units(recs)] == [5]
