@@ -187,9 +187,20 @@ def expert_share_ffn(h: jax.Array, lp: Dict[str, jax.Array], *, k: int,
     token_block * k rows (every pair local is the worst case, and it
     must fit).
 
+    The sorted buffer is SHORT (`_short_rows`): the local pairs lie
+    first after the sort, n / E of the T*k on average (n held of the
+    router's E), so the GEMMs run over S sorted rows at a time, in a
+    loop of as many passes as the local pairs need: one in all but
+    freak routings, none where no pair is local, T*k / S where every
+    pair is. Every local pair is computed in exactly one pass, in the
+    same precision (a token's gated sum is kept in float32 across
+    passes): dropless and exact, whatever the routing.
+
     Returns (y [T, D] in h's dtype, stats): stats holds int32 scalars
     `moe_pairs` (pairs computed here), `moe_experts_hit` (held experts
-    that got a token) and `moe_load_max` (most pairs on one expert)."""
+    that got a token), `moe_load_max` (most pairs on one expert) and
+    `moe_full_passes` (token blocks whose local pairs overflowed one
+    sorted buffer, so that it ran again)."""
     T, D = h.shape
     n = lp["experts_gate"].shape[-3]
     if valid is None:
@@ -199,23 +210,53 @@ def expert_share_ffn(h: jax.Array, lp: Dict[str, jax.Array], *, k: int,
         pad = nb * token_block - T
         hb = jnp.pad(h, ((0, pad), (0, 0))).reshape(nb, token_block, D)
         vb = jnp.pad(valid, (0, pad)).reshape(nb, token_block)
-        yb, sizes = jax.lax.map(
+        yb, sizes, full = jax.lax.map(
             lambda a: _share_block(a[0], lp, a[1], k, first, n, scale,
                                    normalize, layer), (hb, vb))
         y = yb.reshape(nb * token_block, D)[:T]
         sizes = jnp.sum(sizes, 0, dtype=jnp.int32)
     else:
-        y, sizes = _share_block(h, lp, valid, k, first, n, scale, normalize,
-                                layer)
+        y, sizes, full = _share_block(h, lp, valid, k, first, n, scale,
+                                      normalize, layer)
     # int32 whatever jax_enable_x64 says: they ride a scan's carry
     stats = {"moe_pairs": jnp.sum(sizes, dtype=jnp.int32),
              "moe_experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32),
-             "moe_load_max": jnp.max(sizes).astype(jnp.int32)}
+             "moe_load_max": jnp.max(sizes).astype(jnp.int32),
+             "moe_full_passes": jnp.sum(full, dtype=jnp.int32)}
     return y, stats
 
 
+def _short_rows(pairs: int, held: int, routed: int) -> int:
+    """Rows of the sorted buffer a pass of the grouped GEMMs runs over,
+    for `pairs` = T*k (token, choice) pairs on a chip that holds `held`
+    of `routed` experts: the smallest ODD multiple of 128 that is at
+    least twice the pairs expected here, 2 * pairs * held / routed (the
+    caller caps it at `pairs`: a chip that holds half its experts, or a
+    handful of tokens, makes one pass over them all).
+
+    Why odd multiples of 128: the chip's grouped GEMM (v5e) takes its
+    row tile from the buffer's ROW COUNT, 512 rows where that is a
+    multiple of 512, 256 of 256, and 128 where it is an odd multiple of
+    128, and every expert that gets one token pays a whole tile of
+    masked rows. At A.X-K1's widths (7168 x 2048, an expert's three
+    matrices 0.1075 ms of HBM reads) a hit expert costs 0.28 ms in a
+    buffer of 512 rows and 0.146 ms in one of 128; a fused step's 288
+    local rows cost 3.5 ms a layer in 4608 rows, 3.4 in 1024, 2.05 in
+    1152, 896, 640 or 384 (chip runs of PR 26 and PR 28, PERF.md
+    section 6; `tools/micro_moe.py share` measures it again). Twice the
+    expectation, because a second pass costs as much as the first and
+    routing is not uniform: `moe_full_passes` counts how often one is
+    needed."""
+    tiles = max(-(-2 * pairs * held // (routed * 128)), 1)
+    return (tiles + 1 - tiles % 2) * 128
+
+
 def _share_block(h, lp, valid, k, first, n, scale, normalize, layer):
+    """(y [T, D], pairs on each held expert [n], 1 if the local pairs
+    overflowed one sorted buffer else 0) of one block of tokens."""
     T, D = h.shape
+    cd = h.dtype
+    S = min(_short_rows(T * k, n, lp["router"].shape[1]), T * k)
     with jax.named_scope("moe_router"):
         idx, gates = sigmoid_top_k(h, lp["router"], k, scale, normalize)
     with jax.named_scope("moe_dispatch"):
@@ -224,31 +265,61 @@ def _share_block(h, lp, valid, k, first, n, scale, normalize, layer):
         e = jnp.where(local, idx - first, n).reshape(T * k)
         order = jnp.argsort(e, stable=True)
         sizes = jnp.zeros((n + 1,), jnp.int32).at[e].add(1)[:n]
-        rows = jnp.take(h, order // k, axis=0)              # [T*k, D]
+        n_local = jnp.sum(sizes)
+        ends = jnp.cumsum(sizes)        # a group's end among the sorted
     with jax.named_scope("moe_experts"):
-        cd = h.dtype
-        # the stack's Lm*n groups, all empty but this layer's n
         Lm = lp["experts_gate"].shape[0]
-        gs = jax.lax.dynamic_update_slice(
-            jnp.zeros((Lm * n,), jnp.int32), sizes,
-            (jnp.asarray(layer, jnp.int32) * n,))
         w = {m: lp["experts_" + m].astype(cd).reshape(
             Lm * n, *lp["experts_" + m].shape[2:])
             for m in ("gate", "up", "down")}
-        g = jax.lax.ragged_dot(rows, w["gate"], gs)
-        u = jax.lax.ragged_dot(rows, w["up"], gs)
-        out = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(cd), w["down"],
-                                 gs)
     with jax.named_scope("moe_combine"):
-        # back to (token, choice) order; a pair outside every group
-        # contributes nothing whatever its row holds
-        inv = jnp.argsort(order)
+        inv = jnp.argsort(order)        # a pair's place when sorted
         gw = jnp.where(local, gates, 0.0)                   # [T, k]
-        out = jnp.take(out, inv, axis=0).reshape(T, k, D)
-        out = jnp.where(local[..., None], out, jnp.zeros((), cd))
-        y = jnp.einsum("tkd,tk->td", out, gw,
-                       preferred_element_type=jnp.float32).astype(cd)
-    return y, sizes
+    # whole passes: the last one may reach past the T*k pairs
+    padded = jnp.pad(order, (0, -(T * k) % S))
+
+    def one_pass(carry):
+        """Sorted rows lo .. lo + S: their part of every token's sum."""
+        lo, y = carry
+        with jax.named_scope("moe_dispatch"):
+            pairs = jax.lax.dynamic_slice(padded, (lo,), (S,))
+            rows = jnp.take(h, pairs // k, axis=0)              # [S, D]
+        with jax.named_scope("moe_experts"):
+            # what of each group lies in this pass; the stack's Lm*n
+            # groups are all empty but this layer's n
+            part = (jnp.clip(ends - lo, 0, S)
+                    - jnp.clip(ends - sizes - lo, 0, S))
+            gs = jax.lax.dynamic_update_slice(
+                jnp.zeros((Lm * n,), jnp.int32), part,
+                (jnp.asarray(layer, jnp.int32) * n,))
+            out = _grouped_mlp(rows, w, gs)
+        with jax.named_scope("moe_combine"):
+            # back to (token, choice) order; a pair outside this pass, or
+            # outside every group, contributes nothing whatever the row
+            # it reads holds
+            at = inv - lo
+            here = local & ((at >= 0) & (at < S)).reshape(T, k)
+            out = jnp.take(out, jnp.clip(at, 0, S - 1), axis=0)
+            out = jnp.where(here[..., None], out.reshape(T, k, D),
+                            jnp.zeros((), cd))
+            y = y + jnp.einsum("tkd,tk->td", out, gw,
+                               preferred_element_type=jnp.float32)
+        return lo + S, y
+
+    _, y = jax.lax.while_loop(
+        lambda c: c[0] < n_local, one_pass,
+        (jnp.zeros((), jnp.int32), jnp.zeros((T, D), jnp.float32)))
+    return y.astype(cd), sizes, (n_local > S).astype(jnp.int32)
+
+
+def _grouped_mlp(rows, w, group_sizes):
+    """Gated SiLU MLPs as three grouped GEMMs: rows [R, D] sorted by
+    group, w["gate" | "up"] [G, D, F], w["down"] [G, F, D]; a row past
+    the groups' sum belongs to none and its output means nothing."""
+    g = jax.lax.ragged_dot(rows, w["gate"], group_sizes)
+    u = jax.lax.ragged_dot(rows, w["up"], group_sizes)
+    return jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(rows.dtype),
+                              w["down"], group_sizes)
 
 
 @dataclasses.dataclass

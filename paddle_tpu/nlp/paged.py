@@ -854,8 +854,9 @@ def _ffn_experts(x, h, lp, cfg, groups, stats, stacks, layer):
 
 
 def _merge_stats(a, b):
-    """Routing counters of two pieces of work: pairs and hit experts add
-    up, the largest load on one expert is a maximum."""
+    """Routing counters of two pieces of work: pairs, hit experts and
+    overflowed sorted buffers add up, the largest load on one expert is
+    a maximum."""
     return {k: (jnp.maximum(v, b[k]) if k == "moe_load_max" else v + b[k])
             for k, v in a.items()}
 
@@ -976,7 +977,8 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
     stats = None
     if latent and cfg.num_moe_layers:
         z = jnp.zeros((), jnp.int32)
-        stats = {"moe_pairs": z, "moe_experts_hit": z, "moe_load_max": z}
+        stats = {"moe_pairs": z, "moe_experts_hit": z, "moe_load_max": z,
+                 "moe_full_passes": z}
     carry = (x, k_all, v_all, ks_all, vs_all, jnp.int32(0), stats)
     first_layer = 0
     for layers, ffn in _layer_groups(params, cfg):
@@ -1234,7 +1236,8 @@ class _Tick:
                 mode=self.mode, bucket=self.bucket, units=self.units,
                 impl=cb.attention_impl, weight_dtype=cb.weight_dtype,
                 kv_dtype=cb.kv_dtype, device_s=device_s,
-                host_s=st["dispatch"], detail={"rids": self.rids})
+                host_s=st["dispatch"],
+                detail={"rids": self.rids, **self.noted})
             if cb._trace is not None:
                 cb._trace.span(
                     "device." + self.mode, dur=device_s, lane="device",

@@ -11,6 +11,7 @@ table width 128, bf16). A compile that passes is not a chip run; it only
 keeps what `chip_smoke.py` needs from breaking between chip runs.
 """
 import os
+import re
 import subprocess
 import sys
 
@@ -148,9 +149,11 @@ def _mla(topo, R, Pq):
                     ((R, Pq), jnp.int32), ((R, Pq), jnp.bool_))
 
 
-def _expert_share(topo, T):
+def _expert_share(topo, T, short):
     """The dropless expert layer: 12 held experts of A.X-K1's width under
-    a 192-wide router; the grouped GEMMs must be the chip's own."""
+    a 192-wide router; the grouped GEMMs must be the chip's own, and run
+    over the `short` sorted buffer alone (a loop of as many passes as the
+    local pairs need), never over all T x 8 pairs."""
     from paddle_tpu.nlp import moe
     one = SingleDeviceSharding(topo.devices[0])
     Dm, Fm, n = 7168, 2048, 12
@@ -164,14 +167,21 @@ def _expert_share(topo, T):
     txt = _compile(fn, [one] * 5, ((T, Dm), BF), ((Dm, 192), BF),
                    ((2, n, Dm, Fm), BF), ((2, n, Dm, Fm), BF),
                    ((2, n, Fm, Dm), BF))
-    assert "ragged-dot" in txt or "ragged_dot" in txt
+    gemms = re.findall(r"%ragged-dot-none[.\d]* = bf16\[(\d+),(\d+)\]", txt)
+    assert sorted((int(r), int(w)) for r, w in gemms) == [
+        (short, Fm), (short, Fm), (short, Dm)], gemms     # gate, up, down
+    assert " while(" in txt
     return txt
 
 
 CASES = {
     "mla-decode-64-rows": lambda t: _mla(t, 64, 1),
     "mla-prefill-bucket-512": lambda t: _mla(t, 2, 512),
-    "expert-share-fused-576-tokens": lambda t: _expert_share(t, 576),
+    # the sorted buffers of a decode step's 64 slots and of the fused
+    # steps on the 512 and 128 buckets (64 + 512 and 64 + 128 tokens)
+    "expert-share-decode-64-tokens": lambda t: _expert_share(t, 64, 128),
+    "expert-share-fused-576-tokens": lambda t: _expert_share(t, 576, 640),
+    "expert-share-fused-192-tokens": lambda t: _expert_share(t, 192, 384),
     "ragged-decode": lambda t: _ragged(t, 8, 1),
     "ragged-prefill-bucket-512": lambda t: _ragged(t, 1, 512),
     "ragged-prefill-bucket-8": lambda t: _ragged(t, 8, 8),

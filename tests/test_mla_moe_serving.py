@@ -270,12 +270,100 @@ def test_dropless_when_every_token_picks_one_expert():
                                atol=1e-5)
     assert not np.any(np.asarray(y)[33:])
     assert int(st["moe_load_max"]) == 33          # expert 5 took them all
+    assert int(st["moe_full_passes"]) == 0        # 120 pairs: one buffer
     assert 33 <= int(st["moe_pairs"]) <= 33 * k
     # blocked over tokens (the bound on the sorted buffer): same result
     yb, stb = moe.expert_share_ffn(h, _stacked(lp), k=k, first=4, scale=2.5,
                                    valid=valid, layer=1, token_block=16)
     np.testing.assert_allclose(np.asarray(yb), np.asarray(y), atol=1e-6)
     assert int(stb["moe_pairs"]) == int(st["moe_pairs"])
+
+
+def _dictated(T, E, k, first, n, local_choices, rng):
+    """Tokens and a router that route as told: token t's top-k are
+    `local_choices[t]` held experts (first + (t + j) % n) and absent ones
+    for the rest. h is 3 * one-hot(t), so a token's logits are its own row
+    of the router, distinct scores, no ties."""
+    absent = [i for i in range(E) if not first <= i < first + n]
+    h = 3.0 * np.eye(T, dtype=np.float32)
+    router = np.full((T, E), -4.0, np.float32)
+    for t in range(T):
+        m = int(local_choices[t])
+        picks = [first + (t + j) % n for j in range(m)]
+        picks += list(rng.permutation(absent)[:k - m])
+        router[t, picks] = 2.0 - 0.2 * np.arange(k)
+    return jnp.asarray(h), jnp.asarray(router)
+
+
+# (T, held n, router width E, k, local choices of token t, valid tokens,
+#  token_block, token blocks whose local pairs overflow one buffer).
+# With k 4 and T 64 a block sorts 256 pairs; n / E = 1 / 8 makes the
+# sorted buffer 128 rows (`moe._short_rows`).
+SHORT_CASES = {
+    "few-local-pairs": (64, 3, 24, 4, lambda t: t % 2, 64, 1024, 0),
+    "local-pairs-fill-the-short-buffer":           # n_local == S
+        (64, 3, 24, 4, lambda t: 2, 64, 1024, 0),
+    "one-pair-over-the-short-buffer":              # n_local == S + 1
+        (64, 3, 24, 4, lambda t: 3 if t == 0 else 2, 64, 1024, 1),
+    "every-pair-local": (64, 4, 32, 4, lambda t: 4, 64, 1024, 1),
+    "masked-rows-bring-it-under":                  # 129 - 8 x 2 = 113
+        (64, 3, 24, 4, lambda t: 3 if t == 0 else 2, 56, 1024, 0),
+    "wider-block": (96, 3, 24, 4, lambda t: 1, 96, 1024, 0),
+    "half-the-experts-held-no-short-buffer":
+        (64, 8, 16, 4, lambda t: t % 3, 64, 1024, 0),
+    "two-token-blocks-one-overflows":
+        (128, 3, 24, 4, lambda t: 3 if t < 64 else 1, 128, 64, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(SHORT_CASES))
+def test_short_sorted_buffer_is_exact_and_counted(case, monkeypatch):
+    """Passes over a short sorted buffer and one pass over all pairs give
+    the same layer and the same counters, whatever the routing;
+    `moe_full_passes` counts the overflows; and both equal the plain loop
+    over the held experts."""
+    T, n, E, k, choices, n_valid, block, full_passes = SHORT_CASES[case]
+    rng = np.random.default_rng(len(case))
+    first, F = 4, 8
+    h, router = _dictated(T, E, k, first, n,
+                          [choices(t) for t in range(T)], rng)
+    lp = {"router": router, **_experts(rng, n, T, F)}
+    valid = jnp.asarray(np.arange(T) < n_valid)
+    kw = dict(k=k, first=first, scale=2.5, valid=valid, layer=jnp.int32(1),
+              token_block=block)
+    n_local = sum(choices(t) for t in range(n_valid))
+    pairs = min(T, block) * k
+    S = moe._short_rows(pairs, n, E)
+    if n != 8:
+        assert S == 128 < pairs
+    y, st = moe.expert_share_ffn(h, _stacked(lp), **kw)
+    assert int(st["moe_full_passes"]) == full_passes
+    assert int(st["moe_pairs"]) == n_local
+    want = _loop_over_experts(h, lp, k, first, 2.5)
+    np.testing.assert_allclose(np.asarray(y)[:n_valid],
+                               np.asarray(want)[:n_valid], atol=1e-5)
+    assert not np.any(np.asarray(y)[n_valid:])
+    # one buffer of all the pairs: nothing can overflow it
+    monkeypatch.setattr(moe, "_short_rows", lambda pairs, held, routed: pairs)
+    y_full, st_full = moe.expert_share_ffn(h, _stacked(lp), **kw)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_full), atol=1e-6)
+    assert int(st_full["moe_full_passes"]) == 0
+    for name in ("moe_pairs", "moe_experts_hit", "moe_load_max"):
+        assert int(st[name]) == int(st_full[name]), name
+
+
+@pytest.mark.parametrize("pairs, held, routed, rows", [
+    (512, 12, 192, 128),        # a decode step of 64 slots, top-8
+    (1536, 12, 192, 384),       # a fused step on the 128 bucket
+    (4608, 12, 192, 640),       # a fused step on the 512 bucket
+    (8192, 12, 192, 1152),      # a 1024-token block of a cold prefill
+    (16, 6, 16, 128),           # the tiny model: no short buffer (>= 16)
+    (512, 96, 192, 640),        # half the experts held: none (>= 512)
+])
+def test_short_rows_follow_the_pairs_and_the_held_share(pairs, held, routed,
+                                                        rows):
+    assert moe._short_rows(pairs, held, routed) == rows
+    assert rows % 256 == 128 and rows >= 2 * pairs * held / routed
 
 
 def test_shares_add_up_to_the_uncut_layer():
@@ -345,6 +433,9 @@ def test_engine_serves_mixed_prompts_with_a_prefix_hit(model):
                 ("decode", "fused") and r.get("closed")]
         assert recs and all(r["moe_pairs"] > 0 and 1 <= r["moe_load_max"]
                             and r["moe_experts_hit"] >= 1 for r in recs)
+        # 16 pairs a step fit one sorted buffer: nothing overflows
+        assert {r["mode"] for r in recs} == {"decode", "fused"}
+        assert all(r["moe_full_passes"] == 0 for r in recs)
     finally:
         eng.shutdown(drain=False, timeout=60)
     # the same greedy tokens as the reference's full forward, token by

@@ -325,7 +325,10 @@ class TestRouterFailover:
         the pre-failover part a strict prefix (nothing re-emitted or
         lost), zero post-warmup recompiles."""
         injs = [FaultInjector(seed=0), FaultInjector(seed=1)]
-        r = _router(setup, watchdog_s=0.3,
+        # deadlines a loaded host keeps: the survivor's first steps
+        # after the failover compile small eager programs, and at 0.3 s
+        # it tripped too, leaving nothing to fail over to
+        r = _router(setup, watchdog_s=3.0,
                     per_replica=[{"fault_injector": injs[0]},
                                  {"fault_injector": injs[1]}])
         r.warmup()
@@ -345,7 +348,7 @@ class TestRouterFailover:
                     inj = injs[int(reqs[0].replica_id[1:])]
                     c = inj.stats()["calls"]
                     for k in range(1, 6):
-                        inj.hang_on_step(c + k, 1.5)
+                        inj.hang_on_step(c + k, 9.0)
             return on_token
 
         for i, p in enumerate(PROMPTS):
