@@ -1,7 +1,7 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
 Default run (one TPU chip), four phases at the full width of
-`bench.py::flagship_2b_cfg` (d 4096, ffn 9472, 32/8 heads, hd 128,
+`Sizes.flagship()` (d 4096, ffn 9472, 32/8 heads, hd 128,
 11 layers, vocab 32000, bf16; weights random, made from --seed):
 
   device   the platform must be "tpu"; prints what JAX and the chip report
@@ -77,8 +77,14 @@ class Sizes:
 
     @staticmethod
     def flagship() -> "Sizes":
-        import bench
-        return Sizes(cfg=bench.flagship_2b_cfg())
+        """The ~2.1B bf16 Llama the serve and train phases share."""
+        import jax.numpy as jnp
+        from paddle_tpu.nlp import llama
+        return Sizes(cfg=llama.LlamaConfig(
+            vocab_size=32000, hidden_size=4096, intermediate_size=9472,
+            num_hidden_layers=11, num_attention_heads=32,
+            num_key_value_heads=8, max_position_embeddings=2048,
+            param_dtype=jnp.bfloat16))
 
 
 # ---------------------------------------------------------------------------

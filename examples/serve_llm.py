@@ -10,7 +10,8 @@ program — KV cache resident and mp-sharded across the loop
 Run anywhere (sized to the host):
   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python examples/serve_llm.py
-On a real v5e chip this serves the bench.py 2B-class config single-chip;
+On a real v5e chip this serves a 2B-class Llama (d 4096, ffn 9472,
+32/8 heads, 11 layers, vocab 32000, bf16) single-chip;
 with 8 devices it runs mp=2 x dp=2.
 """
 import numpy as np

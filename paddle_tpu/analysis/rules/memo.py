@@ -6,7 +6,7 @@ whole-program: every `self.<attr>` the builder's traced closure bakes
 into the lowered program must be part of the key — a missing element
 means a config change silently serves a STALE executable (wrong math,
 no error), a spurious element means every distinct value recompiles an
-identical program (the recompile storms the zero-recompile bench gates
+identical program (the recompile storms the tests' zero-recompile gates
 only catch per-workload). PR 9 threaded the quantization pair
 (`_qkey`) through all four caches and PR 14 threaded the spec config
 (`_skey`); both needed review fixes for drifted keys. This rule is

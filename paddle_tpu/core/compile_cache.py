@@ -23,8 +23,8 @@ _CHECKOUT_CACHE_DIR = os.path.join(
 
 def enable_compile_cache() -> str:
     """Turn the persistent compile cache on before the first compile.
-    Returns the directory in use. Entry points (chip_smoke.py, bench.py,
-    bench_serving.py, the examples) call this once; the library never
+    Returns the directory in use. Entry points (chip_smoke.py,
+    benchmark/run.py, the examples) call this once; the library never
     does — importing the package configures nothing."""
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:

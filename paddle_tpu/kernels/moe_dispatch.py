@@ -532,175 +532,3 @@ def _combine_bwd(use_pallas, inv_pos, g):
 
 
 combine_gather.defvjp(_combine_fwd, _combine_bwd)
-
-
-# ---------------------------------------------------------------------------
-# Fused dispatch-gather + expert gate/up GEMMs (round 5 — VERDICT r4 next-4:
-# the row gathers sit AT the ~33 ns/row scalar-issue floor, so the next win
-# must come from overlapping them with MXU work rather than polishing the
-# gather itself). One kernel walks the expert slots in MXU-shaped row
-# blocks: each block's source rows stream in through the double-buffered
-# row-DMA pipeline while the PREVIOUS block multiplies against the
-# expert's resident gate/up weights — the dispatch DMA hides under the
-# expert GEMMs instead of serializing before them, and the [E, M, D]
-# expert_in tensor never makes an HBM round trip between gather and GEMM.
-# Expert weights are manually copied into single-buffered VMEM scratch
-# once per expert (automatic block pipelining would double-buffer
-# 2×(D×F) and overflow scoped VMEM).
-# ---------------------------------------------------------------------------
-
-
-def _gather_mlp_kernel(idx_ref, src_ref, wg_ref, wu_ref, g_ref, u_ref,
-                       xin_ref, scratch, sems, swg, swu, wsem, *, bm):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    e = pl.program_id(0)
-    m = pl.program_id(1)
-    nm = pl.num_programs(1)
-    ne = pl.num_programs(0)
-    gb = e * nm + m            # global block counter (m innermost)
-
-    @pl.when(m == 0)
-    def _load_weights():       # once per expert; single-buffered scratch
-        pltpu.make_async_copy(wg_ref.at[e], swg, wsem.at[0]).start()
-        pltpu.make_async_copy(wu_ref.at[e], swu, wsem.at[1]).start()
-
-    _row_dma_pipeline(pl, pltpu, idx_ref, src_ref, scratch, sems,
-                      0, gb, ne * nm, bm, masked=True)
-
-    @pl.when(m == 0)
-    def _wait_weights():
-        pltpu.make_async_copy(wg_ref.at[e], swg, wsem.at[0]).wait()
-        pltpu.make_async_copy(wu_ref.at[e], swu, wsem.at[1]).wait()
-
-    # accumulate per 128-lane tile: dot each [bm, 128] slice of the
-    # gathered rows against its [128, F] weight slab — natural tiles on
-    # both sides, no [bm, D] relayout of the scratch before the MXU
-    # (Mosaic rejects multi-dim contractions; the reshape formulation
-    # re-tiled every block)
-    x4 = scratch[gb % 2]                       # [bm, D/128, 128]
-    nt = x4.shape[1]
-    accg = jnp.zeros((x4.shape[0], swg.shape[-1]), jnp.float32)
-    accu = jnp.zeros((x4.shape[0], swu.shape[-1]), jnp.float32)
-    for t in range(nt):
-        xt = x4[:, t, :]
-        accg = accg + jnp.dot(xt, swg[t],
-                              preferred_element_type=jnp.float32)
-        accu = accu + jnp.dot(xt, swu[t],
-                              preferred_element_type=jnp.float32)
-    g_ref[0] = accg.astype(g_ref.dtype)
-    u_ref[0] = accu.astype(u_ref.dtype)
-    xin_ref[0] = x4.reshape(xin_ref.shape[1:])
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "interpret"))
-def gather_mlp_pallas(src, idx, wg, wu, bm=128, interpret=False):
-    """src [T, D]; idx [E, M] int32 source row per expert slot (-1 =
-    empty → zero row); wg/wu [E, D, F] → (g, u, xin) with g/u [E, M, F]
-    = xin @ wg/wu and xin [E, M, D] the gathered rows (bwd residual)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    T, D = src.shape
-    E, M = idx.shape
-    F = wg.shape[-1]
-    while M % bm:
-        bm //= 2
-    lanes = 128
-    src4 = src.reshape(1, T, D // lanes, lanes)
-    grid = (E, M // bm)
-    with jax.enable_x64(False):  # Mosaic: i64 index arithmetic untileable
-        g, u, xin = pl.pallas_call(
-            functools.partial(_gather_mlp_kernel, bm=bm),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=grid,
-                in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                          pl.BlockSpec(memory_space=pl.ANY),
-                          pl.BlockSpec(memory_space=pl.ANY)],
-                out_specs=[
-                    pl.BlockSpec((1, bm, F), lambda e, m, idx: (e, m, 0)),
-                    pl.BlockSpec((1, bm, F), lambda e, m, idx: (e, m, 0)),
-                    pl.BlockSpec((1, bm, D), lambda e, m, idx: (e, m, 0)),
-                ],
-                scratch_shapes=[pltpu.VMEM((2, bm, D // lanes, lanes),
-                                           src.dtype),
-                                pltpu.SemaphoreType.DMA((2, bm)),
-                                pltpu.VMEM((D // lanes, lanes, F),
-                                           wg.dtype),
-                                pltpu.VMEM((D // lanes, lanes, F),
-                                           wu.dtype),
-                                pltpu.SemaphoreType.DMA((2,))],
-            ),
-            out_shape=[jax.ShapeDtypeStruct((E, M, F), src.dtype),
-                       jax.ShapeDtypeStruct((E, M, F), src.dtype),
-                       jax.ShapeDtypeStruct((E, M, D), src.dtype)],
-            interpret=interpret,
-        )(idx.reshape(1, E * M).astype(jnp.int32), src4,
-          wg.reshape(E, D // lanes, lanes, F),
-          wu.reshape(E, D // lanes, lanes, F))
-    return g, u, xin
-
-
-def _gather_mlp_jnp(src, idx, wg, wu):
-    """jnp reference/fallback: masked gather then batched einsums."""
-    xin = _gather_rows_jnp(src[None], idx.reshape(1, -1))[0].reshape(
-        idx.shape + (src.shape[-1],))
-    g = jnp.einsum("emd,edf->emf", xin, wg)
-    u = jnp.einsum("emd,edf->emf", xin, wu)
-    return g, u, xin
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def gather_mlp(src, idx, inv_flat, w_flat, wg, wu, use_pallas=True):
-    """Fused dispatch + gate/up projection: (g, u) [E, M, F].
-
-    src [T, D] tokens; idx [E, M] source token per slot (-1 empty);
-    inv_flat [T, k] the forward map (slot id per (token, choice), CLIPPED
-    to valid range) and w_flat [T, k] its validity weights (1.0 where the
-    choice is routed, 0.0 where dropped) — consumed by the backward's
-    scatter of d_xin back to tokens (dx[t] = Σ_j d_xin[slot(t, j)]).
-    The gathered rows never surface: they are a backward residual."""
-    if use_pallas and _use_pallas_here(src):
-        from .flash_attention import _interpret
-        g, u, _ = gather_mlp_pallas(src, idx, wg, wu,
-                                    interpret=_interpret())
-        return g, u
-    g, u, _ = _gather_mlp_jnp(src, idx, wg, wu)
-    return g, u
-
-
-def _gather_mlp_fwd(src, idx, inv_flat, w_flat, wg, wu, use_pallas):
-    if use_pallas and _use_pallas_here(src):
-        from .flash_attention import _interpret
-        g, u, xin = gather_mlp_pallas(src, idx, wg, wu,
-                                      interpret=_interpret())
-    else:
-        g, u, xin = _gather_mlp_jnp(src, idx, wg, wu)
-    return (g, u), (xin, idx, inv_flat, w_flat, wg, wu)
-
-
-def _gather_mlp_bwd(use_pallas, res, cots):
-    import numpy as np
-    xin, idx, inv_flat, w_flat, wg, wu = res
-    dg, du = cots
-    dwg = jnp.einsum("emd,emf->edf", xin, dg,
-                     preferred_element_type=jnp.float32).astype(wg.dtype)
-    dwu = jnp.einsum("emd,emf->edf", xin, du,
-                     preferred_element_type=jnp.float32).astype(wu.dtype)
-    dxin = (jnp.einsum("emf,edf->emd", dg, wg) +
-            jnp.einsum("emf,edf->emd", du, wu))
-    E, M, D = dxin.shape
-    T, k = inv_flat.shape
-    # scatter back to tokens through the forward map: the weighted-gather
-    # kernel (w zeroes dropped choices) — rows-at-the-floor like every
-    # other direction, fused k-sum
-    dsrc = gather_wsum(dxin.reshape(1, E * M, D), inv_flat[None],
-                       w_flat[None], use_pallas=use_pallas)[0]
-    z = lambda t: np.zeros(t.shape, jax.dtypes.float0)  # noqa: E731
-    return (dsrc.astype(xin.dtype), z(idx), z(inv_flat), z(w_flat),
-            dwg, dwu)
-
-
-gather_mlp.defvjp(_gather_mlp_fwd, _gather_mlp_bwd)

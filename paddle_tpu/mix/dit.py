@@ -261,24 +261,3 @@ def diffusion_loss(params, key, x0, y, cfg: DiTConfig, n_timesteps=1000):
     pred = forward(params, xt, t, y, cfg).astype(jnp.float32)
     pred_eps = pred[:, :cfg.in_channels]
     return jnp.mean((pred_eps - eps) ** 2)
-
-
-def num_params(cfg: DiTConfig) -> int:
-    flat, _ = jax.tree_util.tree_flatten(
-        jax.eval_shape(lambda k: init_params(k, cfg),
-                       jax.ShapeDtypeStruct((2,), jnp.uint32)))
-    return sum(int(math.prod(x.shape)) for x in flat)
-
-
-def flops_per_image(cfg: DiTConfig) -> float:
-    """Approx. train FLOPs per image (fwd+bwd = 6x fwd MACs): per patch
-    token qkvo + mlp + full attention over n_patches, plus the per-block
-    adaLN modulation MLP (6*D per block from the conditioning vector) and
-    the patch/final projections."""
-    D, T = cfg.hidden_size, cfg.n_patches
-    # qkvo: 4*D^2; mlp: 2*D*(ratio*D); attention: 2*H*hd*T = 2*D*T
-    per_tok = 4 * D * D + 2 * D * int(cfg.mlp_ratio * D) + 2 * D * T
-    per_block = T * per_tok + D * 6 * D
-    pd = cfg.patch_size ** 2 * cfg.in_channels
-    patch_io = T * (pd * D + D * pd * (2 if cfg.learn_sigma else 1))
-    return 6.0 * (cfg.depth * per_block + patch_io)

@@ -280,22 +280,3 @@ def mlm_loss(params, input_ids, mlm_labels, cfg: ErnieConfig,
     safe = jnp.where(mask, mlm_labels, 0)
     nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
     return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
-
-
-def num_params(cfg: ErnieConfig) -> int:
-    D, F, L, V = (cfg.hidden_size, cfg.intermediate_size,
-                  cfg.num_hidden_layers, cfg.vocab_size)
-    per_layer = 3 * D * D + 3 * D + D * D + D + 2 * D * F + F + D + 4 * D
-    emb = V * D + cfg.max_position_embeddings * D + cfg.type_vocab_size * D
-    return emb + L * per_layer + 2 * D + (D * D + D) + \
-        (D * cfg.num_labels + cfg.num_labels) + (D * D + D + 2 * D + V)
-
-
-def flops_per_token(cfg: ErnieConfig, seq_len: int) -> float:
-    """Approx. train FLOPs/token (fwd+bwd = 6x fwd MACs): encoder qkvo +
-    ffn matmuls + BIDIRECTIONAL attention (every token attends all seq_len
-    keys — no causal halving, unlike llama.flops_per_token)."""
-    D, F, H = cfg.hidden_size, cfg.intermediate_size, cfg.num_attention_heads
-    matmul = 4 * D * D + 2 * D * F
-    attn = 2 * H * cfg.head_dim * seq_len
-    return 6.0 * cfg.num_hidden_layers * (matmul + attn)

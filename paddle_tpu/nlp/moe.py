@@ -533,13 +533,9 @@ def moe_block(x: jax.Array, lp: Dict[str, jax.Array], cfg: MoeConfig,
         flat_g, inv_pos, inv_tok, probs = (
             checkpoint_name(t, "moe_routing")
             for t in (flat_g, inv_pos, inv_tok, probs))
-        # NOT the fused gather_mlp kernel (r5 negative result, measured
-        # standalone at flagship shapes: fused dispatch+gate/up 18.6 ms
-        # vs 16.4 ms for gather_rows + XLA einsums — the per-block row
-        # DMA does not hide under the per-step MXU work at bm=128, the
-        # largest block the weight-resident formulation can afford in
-        # scoped VMEM; kernels.moe_dispatch.gather_mlp keeps the kernel
-        # + tests as the documented experiment, VERDICT r4 next-4)
+        # gather, then XLA einsums: fusing the row gather into the
+        # gate/up GEMMs was tried and lost, the row DMA does not hide
+        # under the MXU work at the block VMEM can hold
         expert_in = dispatch_gather(
             x.reshape(1, B * S, D).astype(cd), inv_tok, flat_g, k,
             True).reshape(E, B * C, D)
@@ -842,19 +838,3 @@ def active_params(cfg: MoeConfig) -> int:
     if cfg.num_shared_experts:
         per += 3 * D * Fm * cfg.num_shared_experts
     return V * D + L * per + D + D * V
-
-
-def flops_per_token(cfg: MoeConfig, seq_len: int) -> float:
-    """Approx. train FLOPs/token over ACTIVE params (the MoE convention —
-    only routed + shared experts do work), same 6x fwd+bwd and
-    causal-halved attention accounting as llama.flops_per_token."""
-    D, Fm, L = (cfg.hidden_size, cfg.moe_intermediate_size,
-                cfg.num_hidden_layers)
-    H, KV, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                 cfg.head_dim)
-    matmul = L * (D * (H + 2 * KV) * hd + H * hd * D + D * cfg.num_experts
-                  + 3 * D * Fm * (cfg.num_experts_per_tok
-                                  + cfg.num_shared_experts)) \
-        + cfg.vocab_size * D
-    attn = L * H * hd * seq_len
-    return 6.0 * (matmul + attn)

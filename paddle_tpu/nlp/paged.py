@@ -594,7 +594,7 @@ def _spec_gqa_attention(q, pk, pv, table, base_len, sk, sv, vis,
 
 
 def _forward_spec(params, layers, tokens, cache, positions, base_len,
-                  slab_k, slab_v, row0, cfg, vis=None,
+                  slab_k, slab_v, row0, cfg, vis,
                   impl: str = "xla", mesh=None, mesh_axis: str = "mp"):
     """The speculative score-path forward: tokens [B, P] at per-request
     absolute positions, attending to the committed pool (READ-ONLY,
@@ -609,8 +609,8 @@ def _forward_spec(params, layers, tokens, cache, positions, base_len,
     layers 0..d-1 ARE the d-layer draft's cache — and when the batcher
     built a draft-from-w8 stack, `layers` is that int8 tree while
     `params` stays the target's). `vis` [P, S] gives each query its
-    visible slab rows (None = the chain causal triangle relative to
-    row0 — the pre-tree behavior); `impl` picks the score-path
+    visible slab rows (the config's ancestor mask; a chain's is the
+    causal triangle); `impl` picks the score-path
     attention backend ("xla" concat reference | "pallas" suffix-slab
     kernel), with `mesh`/`mesh_axis` shard_map-wrapping the pallas
     case on the TP mesh. Returns (logits [B, P, V], slab_k', slab_v')."""
@@ -622,12 +622,6 @@ def _forward_spec(params, layers, tokens, cache, positions, base_len,
     B, P = tokens.shape
     H, KV, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                  cfg.head_dim)
-    if vis is None:
-        # chain slab visibility: query p (slab row row0 + p) sees slab
-        # rows <= its own — the causal triangle the tree's ancestor
-        # mask degenerates to at branching [1, 1, ...]
-        vis = jnp.arange(slab_k.shape[2])[None, :] \
-            <= (row0 + jnp.arange(P))[:, None]
 
     def body(carry, lp):
         x, sk_all, sv_all, li = carry
@@ -1303,7 +1297,7 @@ class ContinuousBatcher:
     Quantized serving (`weight_dtype=`, `kv_dtype=`): "int8" weights
     route params through generation.quantize_for_serving (int8 codes +
     per-output-channel scales, dequantized in-register at the consuming
-    dot — the path bench.py's w8 decode numbers measure); "int8" KV
+    dot); "int8" KV
     stores the block pools as int8 codes with per-(layer, block)
     abs-max scales in a sibling scale pool (quantization.kv is the
     single-source math), quantized on every prefill/decode commit
@@ -1396,12 +1390,11 @@ class ContinuousBatcher:
         # replica's batcher admitted each request
         self.replica_id = str(replica_id)
         # quantized serving (ROADMAP direction 4): weight_dtype="int8"
-        # routes params through generation.quantize_for_serving (the
-        # same int8 weight-only path bench.py measures — idempotent on
-        # already-quantized trees, so a caller that pre-quantized for
-        # mesh placement via generation.quantized_specs, the way
-        # inference/llm.py does, passes through); kv_dtype="int8"
-        # stores the K/V
+        # routes params through generation.quantize_for_serving
+        # (idempotent on already-quantized trees, so a caller that
+        # pre-quantized for mesh placement via
+        # generation.quantized_specs, the way inference/llm.py does,
+        # passes through); kv_dtype="int8" stores the K/V
         # pools as int8 codes with per-(layer, block) abs-max scales in
         # a sibling scale pool (quantization.kv holds the single-source
         # math), quantized on every prefill/decode commit write and
@@ -1484,16 +1477,16 @@ class ContinuousBatcher:
         # output is identical to plain decode by construction.
         # serving.speculative holds the config/stat types (lazy import
         # below, like trace/profiling — dependency-free module).
-        # Speculation v2 widens the draft to a token TREE
-        # (spec_tree=[b0, b1, ...]: b0 candidates for the next token,
-        # b1 children each, ... — spec_k is then DERIVED as the node
-        # count), optionally reads the draft sweep's weights from an
-        # int8 quantization of the truncated stack (spec_draft_w8 —
-        # draft bytes halve, verification still runs the target's own
-        # weights so tokens are unchanged), and can route the verify's
-        # score path through the ragged kernel's suffix-slab operand
-        # (spec_attention_impl="pallas"; None inherits the batcher's
-        # resolved backend, so CPU stays on the XLA concat reference).
+        # The draft is a token TREE (spec_tree=[b0, b1, ...]: b0
+        # candidates for the next token, b1 children each, ... —
+        # spec_k is then DERIVED as the node count; spec_k alone is
+        # the chain (1,) * spec_k), optionally reads the draft sweep's
+        # weights from an int8 quantization of the truncated stack
+        # (spec_draft_w8 — draft bytes halve, verification still runs
+        # the target's weights so tokens are unchanged), and can route
+        # the verify's score path through the ragged kernel's
+        # suffix-slab operand (spec_attention_impl="pallas"; None
+        # inherits the batcher's resolved backend: CPU stays on XLA).
         from ..serving.speculative import SpecConfig, SpecStats
         self.speculative = bool(speculative)
         # ptlint: memo-invariant(frozen at construction; its key() rides _skey)
@@ -1502,7 +1495,6 @@ class ContinuousBatcher:
                                     tree=spec_tree,
                                     draft_w8=spec_draft_w8)
         self.spec_k = self._spec_cfg.k
-        self.spec_tree = self._spec_cfg.tree
         self._draft_depth = self._spec_cfg.depth(cfg.num_hidden_layers)
         # ptlint: memo-invariant(resolved once at construction; rides _skey)
         self.spec_attention_impl = self.attention_impl \
@@ -1603,7 +1595,7 @@ class ContinuousBatcher:
         self.prefill_suffix_hist: Dict[int, int] = {}
         # KV-transfer accounting (serving/kvtransfer.py): snapshots
         # exported/imported through this batcher plus a host count of
-        # prefill rows actually computed — the disaggregated bench's
+        # prefill rows actually computed — the disaggregation tests'
         # "decode replica ran ZERO prefill chunks" gate reads these
         self.exported_kv = 0
         self.imported_kv = 0
@@ -1771,8 +1763,9 @@ class ContinuousBatcher:
 
     def kv_bytes_per_token(self) -> float:
         """HBM bytes one cached token costs (and one decode-step gather
-        moves per live token): kv_block_bytes / block_size. The bench's
-        quantized gate asserts int8 <= 0.55x fp on this number."""
+        moves per live token): kv_block_bytes / block_size.
+        tests/test_quantized_serving.py holds int8 <= 0.55x fp on this
+        number."""
         return self.kv_block_bytes() / self.bs
 
     def weight_bytes(self) -> int:
@@ -3275,13 +3268,12 @@ class ContinuousBatcher:
         """Memo key for the spec `phase` ("draft" | "verify")
         executable — spec geometry + backend + quantization config.
         Carries `_skey` like every other compiled-shape memo key, so a
-        batcher whose spec config changes shape (k, draft depth, tree
-        branching, draft-w8) via the full spec tuple can never serve
-        another config's executable; the resolved spec score-path
-        backend rides inside `_skey` next to the geometry for the same
-        reason (KEY001 enforces the convention)."""
-        return (phase, self.spec_k, self._draft_depth,
-                self.attention_impl) \
+        batcher whose spec config changes shape (node count, draft
+        depth, tree branching, draft-w8) via the full spec tuple can
+        never serve another config's executable; the resolved spec
+        score-path backend rides inside `_skey` next to the geometry
+        for the same reason (KEY001 enforces the convention)."""
+        return (phase, self._draft_depth, self.attention_impl) \
             + self._skey + self._qkey + self._mkey
 
     def spec_stats(self) -> Dict[str, Any]:
@@ -3295,59 +3287,23 @@ class ContinuousBatcher:
         d.update(self.spec.as_dict())
         return d
 
-    def _build_spec_draft(self):
-        """The traced chain draft: spec_k autoregressive proposals per
-        slot off the truncated layer stack, reading the committed pool
-        READ-ONLY (layers 0..depth-1 of the target's pool ARE the
-        draft's cache) with its own proposals riding the spec slab.
-        `dlayers` is the draft-from-w8 quantized stack (None drafts
-        from the target's own weights, sliced in-trace so XLA fuses
-        the slice — no copy). Returns drafts [B, spec_k] (proposal
-        j+1 per step j)."""
-        cfg, K, depth, B = self.cfg, self.spec_k, self._draft_depth, \
-            self.B
-        maxpos = self.M * self.bs - 1
-        impl = self.spec_attention_impl
-        mesh, max_ = self._mesh, self._mesh_axis()
-
-        def draft(params, dlayers, k, v, ks, vs, table, lengths, tok,
-                  active):
-            cache = PagedKVCache(k, v, table, lengths, ks, vs)
-            layers = jax.tree_util.tree_map(
-                lambda x: x[:depth], params["layers"]) \
-                if dlayers is None else dlayers
-            KVh, hd = cfg.num_key_value_heads, cfg.head_dim
-            sk = jnp.zeros((depth, B, K, KVh, hd), cfg.dtype)
-            sv = jnp.zeros_like(sk)
-
-            def step(carry, j):
-                tok, sk, sv = carry
-                pos = jnp.minimum(lengths[:, None] + j, maxpos)
-                logits, sk, sv = _forward_spec(
-                    params, layers, tok[:, None], cache, pos, lengths,
-                    sk, sv, j, cfg, impl=impl, mesh=mesh,
-                    mesh_axis=max_)
-                nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-                nxt = jnp.where(active, nxt, tok)
-                return (nxt, sk, sv), nxt
-
-            _, drafts = lax.scan(step, (tok, sk, sv),
-                                 jnp.arange(K, dtype=jnp.int32))
-            return drafts.T                              # [B, K]
-
-        return jax.jit(draft)
-
     def _build_spec_tree_draft(self):
-        """The traced TREE draft: level by level, one truncated-stack
-        forward per level scores ALL of the level's nodes at once
-        (each node's slab visibility is its ancestor path, so its
-        logits equal the sequential prefix's) and lax.top_k proposes
-        tree[j] children per node — child 0 is the node's argmax, so
-        the tree always contains the chain draft's path. Level j's
-        nodes land in slab rows [offs[j], offs[j+1]) — contiguous by
-        the packed-level layout; the LAST level's proposals are never
-        forwarded here (the verify computes their K/V). Returns
-        drafts [B, spec_k] in slab-row order (levels concatenated)."""
+        """The traced draft, a chain (`spec_k`) being the tree
+        (1,) * k: level by level, one truncated-stack forward per
+        level scores ALL of the level's nodes at once (each node's
+        slab visibility is its ancestor path, so its logits equal the
+        sequential prefix's) and lax.top_k proposes tree[j] children
+        per node — child 0 is the node's argmax, so the tree always
+        contains the greedy chain. The committed pool is read, never
+        written (layers 0..depth-1 of the target's pool ARE the
+        draft's cache); the proposals ride the spec slab, level j's
+        nodes in rows [offs[j], offs[j+1]) — contiguous by the
+        packed-level layout; the LAST level's proposals are never
+        forwarded here (the verify computes their K/V). `dlayers` is
+        the draft-from-w8 quantized stack (None drafts from the
+        target's own weights, sliced in-trace so XLA fuses the slice —
+        no copy). Returns drafts [B, spec_k] in slab-row order (levels
+        concatenated)."""
         cfg, B, depth = self.cfg, self.B, self._draft_depth
         sc = self._spec_cfg
         tree = sc.tree
@@ -3385,8 +3341,8 @@ class ContinuousBatcher:
                     mesh=mesh, mesh_axis=max_)
                 # top-b children per node: lax.top_k ties break toward
                 # the lower index, same as argmax — child 0 IS the
-                # greedy continuation, so tree acceptance dominates
-                # the chain's per sweep
+                # greedy continuation, so a wider tree accepts at least
+                # what the chain of its depth does per sweep
                 _, top = lax.top_k(logits, tree[j])  # [B, w, b]
                 nxt = top.reshape(B, w * tree[j]).astype(jnp.int32)
                 nxt = jnp.where(active[:, None], nxt, tok[:, None])
@@ -3406,16 +3362,13 @@ class ContinuousBatcher:
             self._spec_dlayers)
 
     def _spec_draft_exe(self):
-        """Memoized COMPILED draft step (chain or tree per the spec
-        config), AOT-lowered like the prefill shapes so
-        `warmup_prefill` covers it."""
+        """Memoized COMPILED draft step, AOT-lowered like the prefill
+        shapes so `warmup_prefill` covers it."""
         key = self._spec_key("draft")
         exe = self._spec_cache.get(key)
         if exe is None:
             if self._spec_draft_fn is None:
-                self._spec_draft_fn = self._build_spec_tree_draft() \
-                    if self.spec_tree is not None \
-                    else self._build_spec_draft()
+                self._spec_draft_fn = self._build_spec_tree_draft()
             sds, i32 = self._aval, jnp.int32
             pstruct = self._pstruct()
             B = self.B
@@ -3432,105 +3385,24 @@ class ContinuousBatcher:
             self._spec_cache[key] = exe
         return exe
 
-    def _build_spec_verify(self):
-        """The traced verify: score all spec_k+1 positions (cur_tok +
-        the draft's proposals) in ONE full-depth pass over the
-        read-only pool + spec slab, accept the longest prefix of
-        proposals matching the target's own greedy tokens plus one
-        corrected token (truncated by per-slot budget and eos/stop —
-        the `_emit_one` stopping rule, vectorized over rows), then
-        COMMIT: only the accepted rows' slab K/V reach the pool,
-        written one row at a time in order so the int8 pool's
-        grow-only per-block scales evolve exactly as sequential
-        decode's would. Greedy output is identical to plain decode by
-        construction — speculation changes the schedule, not the
-        tokens."""
-        cfg, K, B = self.cfg, self.spec_k, self.B
-        P = K + 1
-        eos = -1 if self.eos is None else int(self.eos)
-        maxpos = self.M * self.bs - 1
-        impl = self.spec_attention_impl
-        mesh, max_ = self._mesh, self._mesh_axis()
-
-        def verify(params, k, v, ks, vs, table, lengths, tok, drafts,
-                   active, budget, stop, spec_ok):
-            cache = PagedKVCache(k, v, table, lengths, ks, vs)
-            toks_in = jnp.concatenate([tok[:, None], drafts], axis=1)
-            pos = jnp.minimum(
-                lengths[:, None] + jnp.arange(P)[None, :], maxpos)
-            KVh, hd = cfg.num_key_value_heads, cfg.head_dim
-            sk = jnp.zeros((cfg.num_hidden_layers, B, P, KVh, hd),
-                           cfg.dtype)
-            sv = jnp.zeros_like(sk)
-            logits, sk, sv = _forward_spec(
-                params, params["layers"], toks_in, cache, pos, lengths,
-                sk, sv, jnp.int32(0), cfg, impl=impl, mesh=mesh,
-                mesh_axis=max_)
-            g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, P]
-            # accept proposal i+1 while it equals the target's greedy
-            # token at the previous position (longest matching prefix)
-            match = (drafts == g[:, :K]).astype(jnp.int32)
-            n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1,
-                            dtype=jnp.int32)
-            n_acc = jnp.where(spec_ok, n_acc, 0)
-            # emit g_0..g_{n_acc}, truncated at the budget and at the
-            # first eos/stop emitted (tokens after an end never emit)
-            idx = jnp.arange(P)[None, :]
-            is_end = (g == eos) | (g == stop[:, None])
-            ends_before = jnp.cumsum(is_end.astype(jnp.int32), axis=1) \
-                - is_end.astype(jnp.int32)
-            emit = (idx <= n_acc[:, None]) & (idx < budget[:, None]) \
-                & (ends_before == 0) & active[:, None]
-            n_emit = jnp.sum(emit, axis=1, dtype=jnp.int32)
-            # verify-then-commit: ONLY accepted rows reach the pool —
-            # row-sequential writes keep int8 scale growth identical
-            # to plain decode's token-by-token commits
-            ks2, vs2 = ks, vs
-            for r in range(P):
-                posr, valr = pos[:, r:r + 1], emit[:, r:r + 1]
-                kr, vr = sk[:, :, r:r + 1], sv[:, :, r:r + 1]
-                if ks is None:
-                    k = jax.vmap(_write_pool,
-                                 in_axes=(0, None, None, 0, None))(
-                        k, table, posr, kr, valr)
-                    v = jax.vmap(_write_pool,
-                                 in_axes=(0, None, None, 0, None))(
-                        v, table, posr, vr, valr)
-                else:
-                    k, ks2, _ = jax.vmap(
-                        _write_pool_int8,
-                        in_axes=(0, 0, None, None, 0, None))(
-                        k, ks2, table, posr, kr, valr)
-                    v, vs2, _ = jax.vmap(
-                        _write_pool_int8,
-                        in_axes=(0, 0, None, None, 0, None))(
-                        v, vs2, table, posr, vr, valr)
-            last = jnp.take_along_axis(
-                g, jnp.maximum(n_emit - 1, 0)[:, None], axis=1)[:, 0]
-            last = jnp.where(active & (n_emit > 0), last, tok)
-            budget2 = budget - n_emit
-            active2 = active & (budget2 > 0) & (last != eos) \
-                & (last != stop)
-            return (k, v, ks2, vs2, lengths + n_emit, last, budget2,
-                    active2, jnp.where(emit, g, 0), n_emit, n_acc)
-
-        return jax.jit(verify)
-
     def _build_spec_tree_verify(self):
-        """The traced TREE verify: score the whole packed token tree —
+        """The traced verify: score the whole packed token tree —
         root + every drafted node, slab visibility = the static
-        ancestor mask — in ONE full-depth pass, then walk the tree
-        level by level following the target's own greedy tokens: at
-        each accepted node, the child whose draft token equals the
-        target's greedy continuation extends the path (top-k children
-        are distinct, so at most one matches — the same longest-
-        matching-prefix rule as the chain, over a wider candidate
-        set). The accepted path's rows — and ONLY those — commit
-        row-sequentially exactly like the chain verify, so greedy
-        output stays bit-identical to plain decode and the int8
-        grow-only scale / prefix-cache invariants hold unchanged.
-        Returns the chain verify's tuple with out/n_emit sized to the
-        path width (tree depth + 1)."""
+        ancestor mask — in ONE full-depth pass over the read-only pool
+        + spec slab, then walk the tree level by level following the
+        target's own greedy tokens: at each accepted node, the child
+        whose draft token equals the target's greedy continuation
+        extends the path (top-k children are distinct, so at most one
+        matches: the longest matching prefix). The accepted path plus
+        one corrected token emit, truncated by per-slot budget and
+        eos/stop — the `_emit_one` stopping rule, vectorized over
+        rows. Then COMMIT: the accepted path's slab K/V — and ONLY
+        those — reach the pool, written one row at a time in order so
+        the int8 pool's grow-only per-block scales evolve exactly as
+        sequential decode's would. Greedy output is identical to plain
+        decode by construction — speculation changes the schedule, not
+        the tokens. out/n_emit are sized to the path width (tree depth
+        + 1)."""
         cfg, B = self.cfg, self.B
         sc = self._spec_cfg
         tree = sc.tree
@@ -3564,7 +3436,7 @@ class ContinuousBatcher:
             g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, S]
             # accept walk: cur = the path head's slab row, ci = its
             # index within its level; a level with no matching child
-            # kills the walk (alive), exactly the chain's cumprod
+            # kills the walk (alive)
             cur = jnp.zeros((B,), jnp.int32)
             ci = jnp.zeros((B,), jnp.int32)
             alive = spec_ok
@@ -3597,10 +3469,10 @@ class ContinuousBatcher:
             emit = (idx <= n_acc[:, None]) & (idx < budget[:, None]) \
                 & (ends_before == 0) & active[:, None]
             n_emit = jnp.sum(emit, axis=1, dtype=jnp.int32)
-            # verify-then-commit, identical to the chain: the accepted
-            # path's positions are sequential (lengths + r), only its
-            # rows' slab K/V reach the pool, one row at a time in
-            # order — int8 scale growth matches sequential decode's
+            # verify-then-commit: the accepted path's positions are
+            # sequential (lengths + r), only its rows' slab K/V reach
+            # the pool, one row at a time in order — int8 scale growth
+            # matches sequential decode's
             pos_path = jnp.minimum(lengths[:, None] + idx, maxpos)
             ks2, vs2 = ks, vs
             for r in range(P_out):
@@ -3642,9 +3514,7 @@ class ContinuousBatcher:
         exe = self._spec_cache.get(key)
         if exe is None:
             if self._spec_verify_fn is None:
-                self._spec_verify_fn = self._build_spec_tree_verify() \
-                    if self.spec_tree is not None \
-                    else self._build_spec_verify()
+                self._spec_verify_fn = self._build_spec_tree_verify()
             sds, i32 = self._aval, jnp.int32
             pstruct = self._pstruct()
             B = self.B

@@ -194,7 +194,7 @@ def load_profiler_result(filename: str):
     """Load a serving trace artifact back in-process.
 
     The serving stack's Chrome-trace JSON (`serving.trace.TraceSink.
-    to_chrome_trace()`, written by `bench_serving.py --trace`) uses the
+    to_chrome_trace()`, dumped with `json.dump`) uses the
     same host clock as the `MetricsRegistry.timer` RecordEvent spans,
     so its timelines correlate with a concurrent jax-profiler capture.
     This loader returns that artifact as the parsed dict (inspect
@@ -214,4 +214,4 @@ def load_profiler_result(filename: str):
     raise NotImplementedError(
         "XPlane traces are read by TensorBoard/xprof, not reloaded in-process"
         " (paddle_tpu/profiler/__init__.py); only serving trace JSON"
-        " (bench_serving.py --trace) loads here")
+        " (TraceSink.to_chrome_trace()) loads here")

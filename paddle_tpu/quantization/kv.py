@@ -97,7 +97,7 @@ def kv_block_bytes(num_layers: int, block_size: int, kv_heads: int,
     pools together, INCLUDING the sibling scale pool's per-block
     overhead in int8 mode (2 pools x num_layers x 4-byte f32 scales).
     The single source for every bytes surface — `kv_pool_bytes` /
-    `kv_bytes_per_token` gauges, the bench gather-bytes gate, and
+    `kv_bytes_per_token` gauges, the tests' gather-bytes gate, and
     `bucket_tuner`'s pad-bytes accounting all derive from it."""
     elems = num_layers * block_size * kv_heads * head_dim * 2
     if resolve_kv_dtype(kv_dtype) == "int8":

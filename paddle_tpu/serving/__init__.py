@@ -32,8 +32,8 @@ refcounted by `RefcountingBlockAllocator` — on by default; pass
 timelines with Chrome-trace/Perfetto export + the step flight
 recorder the engine dumps on a device-step failure), `faults`
 (deterministic fault injection: the chaos harness behind the engine's
-quarantine / retry / watchdog recovery paths and
-`bench_serving.py --chaos`), `router` (N-replica routing: health +
+quarantine / retry / watchdog recovery paths,
+tests/test_fault_tolerance.py), `router` (N-replica routing: health +
 occupancy + prefix-affinity policy, cross-replica failover via
 resume-from-`prompt + tokens`), `supervisor` (self-healing replica
 lifecycle: auto-restart with a readiness gate, exponential backoff

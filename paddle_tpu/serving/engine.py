@@ -248,10 +248,10 @@ class ServingEngine:
         # attribute a multi-chip replica (None = single-device)
         self.mesh = mesh
         # the RESOLVED backend ("auto" already collapsed to the concrete
-        # choice at batcher construction) — bench/snapshot surface.
+        # choice at batcher construction) — the snapshot surface.
         # Same for the resolved quantization config: the batcher owns
         # quantize_for_serving and the int8 KV pool; the engine mirrors
-        # the resolved choice into snapshot()/gauges/bench JSON.
+        # the resolved choice into snapshot()/gauges.
         self.attention_impl = self.batcher.attention_impl
         self.weight_dtype = self.batcher.weight_dtype
         self.kv_dtype = self.batcher.kv_dtype

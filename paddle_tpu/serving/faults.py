@@ -7,7 +7,7 @@ The chaos harness behind the quarantine/retry/watchdog machinery: a
 `ServingEngine(fault_injector=...)`) and decides, per device call,
 whether to raise an `InjectedFault`, sleep (a hung step), or pass.
 Every decision is deterministic given the rule set and the seed, so a
-chaos test or `bench_serving.py --chaos` run replays bit-identically.
+chaos test replays bit-identically.
 
 The batcher calls `check(mode, rids)` once per REAL device-call tick
 (mode "decode" | "fused" | "prefill", rids = every request riding the

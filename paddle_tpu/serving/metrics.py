@@ -156,7 +156,7 @@ class Histogram:
         benchmark emitters read arbitrary quantiles (itl_ms_p99 & co)
         without re-implementing the windowing. `since` drops the first
         `since` lifetime observations (as counted by summary()["count"])
-        from the window first, so a bench can rank only the samples
+        from the window first, so a caller can rank only the samples
         recorded inside its timed region (e.g. skip the warmup request's
         compile-tainted inter-token gaps); observations that already
         fell off the ring are skipped implicitly."""

@@ -767,8 +767,8 @@ class Router:
                     continue
             self._affinity.observe(eff, i)
             # the outer handle advertises its CURRENT serving replica
-            # (updated on failover) — the frontend's SSE events and the
-            # bench read it without reaching into router internals
+            # (updated on failover) — the frontend's SSE events and
+            # callers read it without reaching into router internals
             outer.replica_id = eng.replica_id
             self._c_routed.inc()
             self._per_replica_routed[i].inc()

@@ -1,8 +1,8 @@
 """paddle_tpu.serving.slo — in-process SLO engine for the serving tier.
 
 The serving stack measures everything (PR 7 histograms, PR 11 router
-counters) but until now nothing in-process *watched* the objectives the
-`--load` bench leg reports: a TTFT regression or a goodput collapse was
+counters) but until now nothing in-process *watched* the objectives
+a load test reports: a TTFT regression or a goodput collapse was
 visible only to whoever read the dashboard. The `SloTracker` closes
 that loop — declarative objectives, evaluated continuously over dual
 rolling windows, producing burn rates and OK / WARN / BREACH verdicts

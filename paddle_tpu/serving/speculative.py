@@ -72,16 +72,17 @@ class SpecConfig:
 
     `k` is the chain draft length (tokens proposed per verify sweep;
     the verify scores k+1 positions and emits between 1 and k+1
-    tokens). `draft_layers` is the truncated draft depth — None drafts
-    at full depth (the draft IS the target: acceptance ~100%, useful
-    for parity tests and for benches on random-init models whose
+    tokens): the shorthand for `tree=(1,) * k`, which is what it
+    resolves to here. `draft_layers` is the truncated draft depth —
+    None drafts at full depth (the draft IS the target: acceptance
+    ~100%, useful for parity tests on random-init models whose
     truncated drafts never agree with the target).
 
-    `tree` switches to tree drafts: a branching spec like [3, 2, 1]
-    proposes 3 candidates for the next token, 2 children under each of
-    those, 1 under each of those — `k` is then DERIVED (the total node
-    count, the per-sweep draft budget) and the chain `k` argument is
-    ignored. `draft_w8` makes the draft sweep read an int8 weight-only
+    `tree` is the branching spec: [3, 2, 1] proposes 3 candidates for
+    the next token, 2 children under each of those, 1 under each of
+    those — `k` is then DERIVED (the total node count, the per-sweep
+    draft budget) and the chain `k` argument is ignored. `draft_w8`
+    makes the draft sweep read an int8 weight-only
     quantization of the truncated layer stack (built once at batcher
     construction when the target serves fp weights; a no-op when the
     target already serves weight_dtype="int8") — drafting then costs
@@ -93,20 +94,17 @@ class SpecConfig:
                  tree: Optional[Sequence[int]] = None,
                  draft_w8: bool = False):
         if tree is None:
-            self.tree: Optional[Tuple[int, ...]] = None
-            self.k = int(k)
-            if self.k < 1:
+            if int(k) < 1:
                 raise ValueError(f"spec_k must be >= 1, got {k}")
-        else:
-            self.tree = tuple(int(b) for b in tree)
-            if not self.tree or any(b < 1 for b in self.tree):
-                raise ValueError(
-                    f"spec tree must be a non-empty sequence of "
-                    f"positive branching factors, got {tree!r}")
-            # the per-sweep draft budget: every node of the packed tree
-            # is one proposed token (the equal-k-budget comparison the
-            # bench's tree-vs-chain gate uses)
-            self.k = sum(self.level_sizes()[1:])
+            tree = (1,) * int(k)
+        self.tree: Tuple[int, ...] = tuple(int(b) for b in tree)
+        if not self.tree or any(b < 1 for b in self.tree):
+            raise ValueError(
+                f"spec tree must be a non-empty sequence of "
+                f"positive branching factors, got {tree!r}")
+        # the per-sweep draft budget: every node of the packed tree
+        # is one proposed token
+        self.k = sum(self.level_sizes()[1:])
         self.draft_w8 = bool(draft_w8)
         if draft_layers is None:
             self.draft_layers = None
@@ -120,17 +118,16 @@ class SpecConfig:
                     f"draft_layers {self.draft_layers} exceeds the "
                     f"model's {num_layers} layers")
 
-    # -- tree geometry (all static host math; () / chain answers keep
-    #    the chain path byte-identical to before trees existed) --------
+    # -- tree geometry (all static host math) -------------------------
     def tree_depth(self) -> int:
-        """Levels below the root (0 for a chain config)."""
-        return 0 if self.tree is None else len(self.tree)
+        """Levels below the root."""
+        return len(self.tree)
 
     def level_sizes(self) -> List[int]:
         """Node count per level, level 0 = the root (current token):
         n_0 = 1, n_j = n_{j-1} * tree[j-1]."""
         sizes = [1]
-        for b in (self.tree or ()):
+        for b in self.tree:
             sizes.append(sizes[-1] * b)
         return sizes
 
@@ -145,7 +142,7 @@ class SpecConfig:
 
     def slab_rows(self) -> int:
         """Packed-tree suffix-slab rows: root + every drafted node."""
-        return 1 + self.k if self.tree is not None else self.k + 1
+        return 1 + self.k
 
     def row_levels(self) -> List[int]:
         """Level of each slab row (0 for the root row)."""
@@ -158,8 +155,6 @@ class SpecConfig:
         """Parent slab row of each slab row (the root points at
         itself): child i of level j (0-indexed within the level) hangs
         under node i // tree[j-1] of level j-1."""
-        if self.tree is None:
-            return [0] + list(range(self.k))  # chain: row r-1; root self
         sizes, offs = self.level_sizes(), self.level_offsets()
         parents = [0]
         for j in range(1, len(sizes)):
@@ -193,12 +188,11 @@ class SpecConfig:
         """The spec-config element of every compiled-shape memo key:
         a spec batcher's executables must never be confused with a
         plain one's (zero post-warmup recompiles is gated per config).
-        Chain configs keep the pre-tree 3-tuple byte-identical; a tree
-        spec appends its branching factors and draft_w8 appends a
-        marker, so every shape-bearing knob lands in the key."""
-        base = ("spec", self.k, self.depth(num_layers))
-        if self.tree is not None:
-            base = base + ("tree",) + self.tree
+        The branching factors follow the node count and draft depth,
+        and draft_w8 appends a marker, so every shape-bearing knob
+        lands in the key."""
+        base = ("spec", self.k, self.depth(num_layers), "tree") \
+            + self.tree
         if self.draft_w8:
             base = base + ("w8",)
         return base
@@ -206,7 +200,7 @@ class SpecConfig:
     def as_dict(self, num_layers: Optional[int] = None) -> Dict[str, Any]:
         d: Dict[str, Any] = {"k": self.k,
                              "draft_layers": self.draft_layers}
-        if self.tree is not None:
+        if any(b > 1 for b in self.tree):    # a chain prints as a chain
             d["tree"] = list(self.tree)
         if self.draft_w8:
             d["draft_w8"] = True
@@ -267,8 +261,8 @@ class SpecStats:
 
     def tokens_per_step(self) -> float:
         """Tokens emitted per (sweep, slot) — directly comparable to
-        plain decode's 1.0 per slot per step; the >1 multiplier the
-        bench's --speculative gate asserts."""
+        plain decode's 1.0 per slot per step; > 1 is what speculation
+        is for."""
         return self.emitted / self.slot_sweeps if self.slot_sweeps \
             else 0.0
 

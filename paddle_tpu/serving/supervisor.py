@@ -90,7 +90,7 @@ __all__ = ["ReplicaSupervisor", "SLOT_SERVING", "SLOT_RESTARTING",
            "SLOT_FAILED", "compute_backoff"]
 
 # Slot lifecycle states (strings on purpose: they travel through
-# health() JSON to /health and the bench unchanged).
+# health() JSON to /health unchanged).
 SLOT_SERVING = "SERVING"
 """Slot state: the replica is in rotation and the policy may pick it."""
 SLOT_RESTARTING = "RESTARTING"
@@ -449,7 +449,8 @@ class ReplicaSupervisor:
                 continue
             # readiness gate passed: rejoin rotation. The compile count
             # recorded here is the zero-post-warmup baseline for the
-            # respawned engine (the bench's recompile gate reads it).
+            # respawned engine (tests/test_supervisor.py's recompile gate
+            # reads it).
             warm = fresh.batcher.compile_count
             with r._lock:
                 r.engines[slot.index] = fresh

@@ -41,7 +41,7 @@ unsharded dot — ulp logit drift that flips near-tie argmaxes
 mid-decode. Serving output-splits o/down instead, so every output
 element is one full-contraction dot in the unsharded order and
 greedy decode is BIT-identical to the mesh-off batcher (the gate
-`bench_serving.py --tp` and tests/test_tp_serving.py enforce).
+tests/test_tp_serving.py enforces).
 
 Sharding table (axis `mp`, TP degree t):
 
@@ -66,8 +66,8 @@ align with their kv-head shard under GQA), intermediate_size
 
 CPU development recipe: set `XLA_FLAGS=--xla_force_host_platform_
 device_count=N` BEFORE jax initializes and a single host exposes N
-devices — `tests/test_tp_serving.py` and `bench_serving.py --tp` run
-the whole TP matrix this way, no TPU required.
+devices — `tests/test_tp_serving.py` runs the whole TP matrix this
+way, no TPU required.
 """
 from __future__ import annotations
 

@@ -6,7 +6,7 @@ reshapes Mosaic cannot lay out, kernels GSPMD cannot partition, programs
 that do not fit VMEM. Interpret mode sees none of that.
 
 Every case is one kernel at the flagship widths of
-`bench.py::flagship_2b_cfg` (H 32, KV 8, hd 128, d 4096, block 16,
+`chip_smoke.py::Sizes.flagship` (H 32, KV 8, hd 128, d 4096, block 16,
 table width 128, bf16). A compile that passes is not a chip run; it only
 keeps what `chip_smoke.py` needs from breaking between chip runs.
 """

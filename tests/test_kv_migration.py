@@ -208,11 +208,14 @@ class TestAffinity:
 
 
 class TestDisaggRouter:
-    def test_end_to_end_parity_and_zero_prefill(self, setup):
+    @pytest.mark.parametrize("dtypes", [
+        {}, {"weight_dtype": "int8", "kv_dtype": "int8"}],
+        ids=["fp", "int8"])
+    def test_end_to_end_parity_and_zero_prefill(self, setup, dtypes):
         cfg, params = setup
         kw = dict(max_batch=2, block_size=4, max_total_len=48,
                   max_new_tokens=MAX_NEW, chunk=2,
-                  prefill_buckets=(8,), max_queue_depth=16)
+                  prefill_buckets=(8,), max_queue_depth=16, **dtypes)
         eng = serving.ServingEngine(params, cfg, start=False, **kw)
         eng.warmup()
         eng.start()
@@ -224,11 +227,18 @@ class TestDisaggRouter:
                    start=False, **kw)
         r.warmup()
         r.start()
+        warm = [e.batcher.compile_count for e in r.engines]
         streamed = [[] for _ in PROMPTS]
         reqs = [r.submit(p, on_token=streamed[i].append)
                 for i, p in enumerate(PROMPTS)]
         out = [q.result(timeout=300) for q in reqs]
         pre, dec = r.engines
+        # exports and imports stay on the warmed ladder, and the hop
+        # leaks no pool block on either side
+        assert [e.batcher.compile_count for e in r.engines] == warm
+        assert r.drain(timeout=60)
+        assert [e.batcher.alloc.stats()["blocks_in_use"]
+                for e in r.engines] == [0, 0]
         health = r.health()
         snap = r.snapshot()
         assert out == ref                    # bit-identical across hop

@@ -563,68 +563,3 @@ class TestMeshFusedKernels:
         # without the force flag the CPU lowering has no pallas calls
         txt_cpu = fn.lower(params, toks).as_text()
         assert "tpu_custom_call" not in txt_cpu
-
-
-class TestGatherMlp:
-    """Fused dispatch-gather + gate/up GEMM kernel (r5, VERDICT r4 next-4):
-    interpret-mode parity vs the jnp formulation, values and grads."""
-
-    def _case(self, seed=0, T=32, D=128, E=4, M=16, F=128, k=2):
-        from paddle_tpu.kernels import moe_dispatch as md
-        rng = np.random.RandomState(seed)
-        src = jnp.asarray(rng.randn(T, D), jnp.float32)
-        wg = jnp.asarray(rng.randn(E, D, F) * 0.05, jnp.float32)
-        wu = jnp.asarray(rng.randn(E, D, F) * 0.05, jnp.float32)
-        # a routing-shaped index set: each token's k choices land in
-        # distinct slots; some slots stay empty (-1)
-        perm = rng.permutation(E * M)[: T * k]
-        idx = np.full((E * M,), -1, np.int64)
-        idx[perm] = np.arange(T * k) // k     # choice i sits at slot perm[i]
-        inv_flat = np.zeros((T, k), np.int64)
-        w_flat = np.zeros((T, k), np.float32)
-        for i, s in enumerate(perm):          # forward map (token, choice)→slot
-            inv_flat[i // k, i % k] = s
-            w_flat[i // k, i % k] = 1.0
-        return (md, src, jnp.asarray(idx.reshape(E, M), jnp.int32),
-                jnp.asarray(inv_flat, jnp.int32), jnp.asarray(w_flat),
-                wg, wu)
-
-    def test_pallas_matches_jnp(self):
-        from paddle_tpu.core import flags
-        md, src, idx, inv_flat, w_flat, wg, wu = self._case()
-        g_ref, u_ref, xin_ref = md._gather_mlp_jnp(src, idx, wg, wu)
-        flags.set_flags({"FLAGS_pallas_interpret": True})
-        try:
-            g, u, xin = md.gather_mlp_pallas(src, idx, wg, wu,
-                                             interpret=True)
-        finally:
-            flags.set_flags({"FLAGS_pallas_interpret": False})
-        np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
-                                   rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(u), np.asarray(u_ref),
-                                   rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(xin), np.asarray(xin_ref))
-
-    def test_grads_match_unfused(self):
-        from paddle_tpu.core import flags
-        md, src, idx, inv_flat, w_flat, wg, wu = self._case(seed=3)
-
-        def fused(s, a, b):
-            g, u = md.gather_mlp(s, idx, inv_flat, w_flat, a, b, True)
-            return jnp.sum((jax.nn.silu(g) * u) ** 2)
-
-        def unfused(s, a, b):
-            g, u, _ = md._gather_mlp_jnp(s, idx, a, b)
-            return jnp.sum((jax.nn.silu(g) * u) ** 2)
-
-        for interp in (False, True):
-            flags.set_flags({"FLAGS_pallas_interpret": interp})
-            try:
-                v, gr = jax.value_and_grad(fused, (0, 1, 2))(src, wg, wu)
-            finally:
-                flags.set_flags({"FLAGS_pallas_interpret": False})
-            rv, rgr = jax.value_and_grad(unfused, (0, 1, 2))(src, wg, wu)
-            np.testing.assert_allclose(float(v), float(rv), rtol=1e-5)
-            for a, b in zip(gr, rgr):
-                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                           rtol=1e-4, atol=1e-5)

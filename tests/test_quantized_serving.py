@@ -62,6 +62,28 @@ def _serve_round(cb, prompts):
     return [out[r] for r in rids]
 
 
+# Quantized-vs-fp greedy divergence floor on the tiny random-init model:
+# across the workload, at least this fraction of the fp run's greedy
+# tokens must match the quantized run position-for-position up to each
+# request's first divergence. int8 weight/KV error flips the argmax on a
+# small minority of steps; a collapse below the floor means the
+# quantized math broke, not that rounding moved a borderline logit.
+QUANT_MATCH_FLOOR = 0.60
+
+
+def _prefix_match(base, quant) -> float:
+    """Fraction of baseline greedy tokens the quantized run reproduces
+    up to each request's first divergence (1.0 = bit-identical)."""
+    total = sum(len(b) for b in base)
+    lcp = 0
+    for b, t in zip(base, quant):
+        for x, y in zip(b, t):
+            if x != y:
+                break
+            lcp += 1
+    return lcp / total if total else 1.0
+
+
 # ---- quantization.kv math units ----------------------------------------
 class TestKvMath:
     def test_resolve_kv_dtype(self):
@@ -146,10 +168,9 @@ class TestQuantizedBatcher:
 
     def test_quantized_vs_fp_divergence_bound(self, setup):
         """Greedy outputs under quantization track the fp run within
-        the documented bound (bench_serving.QUANT_MATCH_FLOOR): the
-        matched-prefix fraction across the workload stays above the
-        floor for every quantized configuration."""
-        from bench_serving import QUANT_MATCH_FLOOR, _prefix_match
+        the documented bound (QUANT_MATCH_FLOOR): the matched-prefix
+        fraction across the workload stays above the floor for every
+        quantized configuration."""
         cfg, params = setup
         base = _serve_round(_batcher(params, cfg), PROMPTS)
         for qkw in QUANT_CONFIGS:

@@ -24,8 +24,8 @@ against the mesh-off full-width kernel: elementwise ops are
 shape-sensitive at the last ulp in interpret mode (SIMD lane packing
 over differently-sized buffers), so full-width vs sliced can drift by
 ~1 ulp while serving-level greedy TOKENS stay bit-identical — that
-end-to-end claim is gated by tests/test_tp_serving.py and the bench
-`--tp --speculative --attention-impl pallas` composition leg.
+end-to-end claim is gated by tests/test_tp_serving.py (mesh x pallas
+x tree speculation against mesh-off plain decode).
 """
 import numpy as np
 import pytest

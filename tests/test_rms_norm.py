@@ -214,59 +214,3 @@ class TestNormDoubleGrad:
         ref = hvp_of(layer_norm_ref)
         np.testing.assert_allclose(np.asarray(hvp), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
-
-
-class TestAdaLN:
-    """Fused adaLN (LN + per-sample modulate) kernel — the r5 DiT lever:
-    interpret-mode value/grad parity vs the jnp reference."""
-
-    def _case(self, seed=0, B=2, N=256, D=128):
-        import jax.numpy as jnp
-        rng = np.random.RandomState(seed)
-        x = jnp.asarray(rng.randn(B, N, D), jnp.float32)
-        sh = jnp.asarray(rng.randn(B, D) * 0.1, jnp.float32)
-        sc = jnp.asarray(rng.randn(B, D) * 0.1, jnp.float32)
-        return x, sh, sc
-
-    def test_value_and_grads_match_ref(self):
-        import jax
-        import jax.numpy as jnp
-        from paddle_tpu.core import flags
-        from paddle_tpu.kernels.adaln import adaln_modulate, adaln_ref
-        x, sh, sc = self._case()
-
-        def loss(fn):
-            return jax.value_and_grad(
-                lambda a, b, c: jnp.sum(fn(a, b, c) ** 2), (0, 1, 2))
-
-        rv, rg = loss(lambda a, b, c: adaln_ref(a, b, c))(x, sh, sc)
-        flags.set_flags({"FLAGS_pallas_interpret": True})
-        try:
-            gv, gg = loss(lambda a, b, c: adaln_modulate(a, b, c))(x, sh, sc)
-        finally:
-            flags.set_flags({"FLAGS_pallas_interpret": False})
-        np.testing.assert_allclose(float(gv), float(rv), rtol=1e-5)
-        for a, b in zip(gg, rg):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4)
-
-    def test_double_grad(self):
-        import jax
-        import jax.numpy as jnp
-        from paddle_tpu.core import flags
-        from paddle_tpu.kernels.adaln import adaln_modulate, adaln_ref
-        x, sh, sc = self._case(seed=1, N=128)
-        v = jnp.ones_like(x)
-
-        def hvp_of(fn):
-            g = jax.grad(lambda a: jnp.sum(fn(a, sh, sc) ** 2))
-            return jax.grad(lambda a: jnp.vdot(g(a), v))(x)
-
-        flags.set_flags({"FLAGS_pallas_interpret": True})
-        try:
-            hvp = hvp_of(adaln_modulate)
-        finally:
-            flags.set_flags({"FLAGS_pallas_interpret": False})
-        ref = hvp_of(adaln_ref)
-        np.testing.assert_allclose(np.asarray(hvp), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-4)

@@ -371,6 +371,10 @@ class TestRouterFailover:
         recompiles = sum(e.batcher.compile_count - c0
                          for e, c0 in zip(r.engines, compiles0))
         assert recompiles == 0
+        # cross-replica recovery leaked nothing on the survivor
+        dead = by_rid[reqs[0].request_id]["from_replica"]
+        survivor = next(e for e in r.engines if e.replica_id != dead)
+        assert survivor.batcher.alloc.stats()["blocks_in_use"] == 0
         # failover trace event landed on the new replica's timeline
         merged = r.to_chrome_trace()
         fo = [e for e in merged["traceEvents"]

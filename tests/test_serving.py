@@ -334,8 +334,13 @@ class TestServingPrefixCache:
         assert snap["gauges"]["prefill_compile_count"] >= 1
         assert snap["gauges"]["prefill_pad_tokens"] > 0
 
-    def test_warmup_precompiles_and_refuses_after_start(self, setup):
-        eng = self._engine(setup, start=False)
+    @pytest.mark.parametrize("trace", [True, False],
+                             ids=["traced", "untraced"])
+    def test_warmup_precompiles_and_refuses_after_start(self, setup,
+                                                        trace):
+        """Trace emission touches no compiled-shape memo key: traced or
+        not, nothing compiles past warmup()."""
+        eng = self._engine(setup, start=False, trace=trace)
         warmed = eng.warmup()
         assert warmed == eng.batcher.compile_count > 0
         eng.start()

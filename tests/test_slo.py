@@ -339,6 +339,57 @@ class TestEngineSlo:
         assert "SLO breach windows" in out
         eng.shutdown()
 
+    def test_injected_latency_breaches_itl_and_clears(self, setup):
+        """Step hangs SHORT of the watchdog (latency degraded, replica
+        alive) drive an itl_ms_p99 BREACH — the tracker watches the
+        latency the engine serves — and the verdict clears once the
+        fault heals and the windows forget the spike, with nothing
+        compiled past warmup."""
+        from paddle_tpu.serving.faults import FaultInjector
+        cfg, params = setup
+        inj = FaultInjector(seed=0)
+        eng = serving.ServingEngine(
+            params, cfg, max_batch=2, block_size=8, max_total_len=48,
+            max_new_tokens=8, chunk=2, fault_injector=inj,
+            watchdog_s=30.0,
+            slo_objectives={"itl_ms_p99": 150.0, "error_rate": 0.5},
+            slo_opts={"fast_window_s": 1.0, "slow_window_s": 3.0,
+                      "eval_every_s": 0.0}, start=False)
+        eng.warmup()
+        eng.start()
+        eng.generate([5, 6, 7], timeout=300)
+        warm = eng.batcher.compile_count
+        c = inj.stats()["calls"]
+        for k in range(1, 4):
+            inj.hang_on_step(c + k, 0.6)
+        req = eng.submit([1, 2, 3, 4])
+        seen = None
+        deadline = time.monotonic() + 120
+        while time.monotonic() < deadline and seen is None:
+            slo = eng.health()["slo"]
+            if slo["verdict"] == "BREACH":
+                seen = slo
+            elif req.done:
+                break
+            time.sleep(0.02)
+        req.result(300)
+        assert seen is not None, "the hangs never drove a BREACH"
+        assert seen["objectives"]["itl_ms_p99"]["verdict"] == "BREACH"
+        assert seen["objectives"]["error_rate"]["verdict"] == "OK"
+        assert eng.health()["status"] != "UNHEALTHY"   # degraded, alive
+        inj.heal()
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline:
+            eng.generate([9, 8, 7], max_new_tokens=2, timeout=300)
+            if eng.health()["slo"]["verdict"] == "OK":
+                break
+            time.sleep(0.1)
+        final = eng.health()["slo"]
+        assert final["verdict"] == "OK"
+        assert final["breaches_total"] >= 1
+        assert eng.batcher.compile_count == warm
+        eng.shutdown()
+
     def test_slo_off_is_none(self, setup):
         cfg, params = setup
         eng = serving.ServingEngine(
@@ -384,6 +435,25 @@ class TestRouterRollup:
                     for ln in rows}
         assert by_label['replica="r0"'] >= 1.0
         assert by_label['replica="router"'] >= 1.0
+        # over HTTP: a breach is detail, not outage (/health keeps its
+        # 200), and /metrics serves the same merged exposition
+        import http.client
+        fe = serving.HttpFrontend(r, port=0, shutdown_router=False)
+        host, port = fe.start()
+        got = {}
+        for path in ("/health", "/metrics"):
+            conn = http.client.HTTPConnection(host, port, timeout=60)
+            conn.request("GET", path)
+            resp = conn.getresponse()
+            got[path] = (resp.status, resp.read())
+            conn.close()
+        fe.shutdown(drain=True)
+        assert got["/health"][0] == 200
+        body = json.loads(got["/health"][1])
+        assert body["slo"]["verdict"] == "BREACH"
+        assert "ttft_s_p99" in body["slo"]["objectives"]
+        assert 'paddle_tpu_slo_breaches_total{replica="router"}' \
+            in got["/metrics"][1].decode()
         r.shutdown()
 
 

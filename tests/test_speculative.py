@@ -76,9 +76,21 @@ class TestSpecConfig:
         c = SpecConfig(3, draft_layers=1, num_layers=2)
         assert c.depth(2) == 1
         assert SpecConfig(3).depth(2) == 2          # None = full depth
-        assert c.key(2) == ("spec", 3, 1)
+        assert c.key(2) == ("spec", 3, 1, "tree", 1, 1, 1)
         assert c.as_dict(2) == {"k": 3, "draft_layers": 1,
                                 "draft_depth": 1}
+
+    @pytest.mark.parametrize("k", [1, 3])
+    def test_chain_resolves_to_the_tree_of_ones(self, k):
+        """`k` is the shorthand for `tree=(1,) * k`: one geometry, one
+        memo key, printed as a chain."""
+        sc, tc = SpecConfig(k=k), SpecConfig(tree=[1] * k)
+        assert sc.tree == tc.tree == (1,) * k and sc.k == tc.k == k
+        assert sc.tree_depth() == k and sc.slab_rows() == k + 1
+        assert sc.row_parents() == [0] + list(range(k))
+        assert sc.key(2) == tc.key(2)
+        assert sc.as_dict() == tc.as_dict() \
+            == {"k": k, "draft_layers": None}
 
     def test_stats_math(self):
         s = SpecStats()
@@ -257,7 +269,8 @@ class TestVerifyThenCommit:
         assert keys
         for k in keys:
             assert k[-2:] == ("fp", "int8")
-            assert ("spec", 3, 1, "xla") == tuple(k[-6:-2])
+            assert ("spec", 3, 1, "tree", 1, 1, 1, "xla") \
+                == tuple(k[-10:-2])
         assert {k[0] for k in cb._spec_cache} == {"draft", "verify"}
         # a plain batcher's keys are unchanged (no spec element)
         cb0 = _batcher(params, cfg)
@@ -360,22 +373,22 @@ class TestTreeSpecConfig:
         A = sc.ancestor_mask()
         # node 5 (child 0 of slab row 2): sees exactly root -> 2 -> 5
         assert [s for s in range(7) if A[5][s]] == [0, 2, 5]
-        # the chain's mask is the causal triangle (the pre-tree shape)
+        # the chain's mask is the causal triangle
         Ac = SpecConfig(k=3).ancestor_mask()
         assert all(Ac[p][s] == (s <= p)
                    for p in range(4) for s in range(4))
         assert SpecConfig(k=3).row_parents() == [0, 0, 1, 2]
 
     def test_tree_key_and_dict(self):
-        """Tree / draft_w8 configs extend the memo-key element; chain
-        configs keep the pre-tree 3-tuple byte-identical."""
+        """The branching factors and draft_w8 ride the memo-key
+        element; a chain's are its (1,) * k."""
         sc = SpecConfig(tree=[2, 1], draft_layers=1, num_layers=2)
         assert sc.key(2) == ("spec", 4, 1, "tree", 2, 1)
         d = sc.as_dict(2)
         assert d["tree"] == [2, 1] and d["k"] == 4
-        assert SpecConfig(3).key(2) == ("spec", 3, 2)
+        assert SpecConfig(3).key(2) == ("spec", 3, 2, "tree", 1, 1, 1)
         assert SpecConfig(3, draft_w8=True).key(2) == \
-            ("spec", 3, 2, "w8")
+            ("spec", 3, 2, "tree", 1, 1, 1, "w8")
 
     def test_depth_hist_and_accepted_per_sweep(self):
         s = SpecStats()
@@ -415,22 +428,28 @@ class TestTreeSpecParity:
         st = tree.spec_stats()
         assert st["tree"] == [2, 1, 1] and st["k"] == 6
 
-    def test_degenerate_tree_equals_chain(self, setup):
-        """tree=[1,1,1] IS a chain of k=3: identical tokens AND
-        identical acceptance counters (the tree machinery reduces
-        exactly to the chain when every branching factor is 1)."""
+    def test_chain_and_tree_of_ones_are_one_batcher(self, setup):
+        """spec_k=3 IS spec_tree=[1, 1, 1]: one pair of executables
+        (the same memo keys), the same spec_stats() geometry, printed
+        as a chain; and its tokens are plain greedy decode's."""
         cfg, params = setup
         short = PROMPTS[:3]
+        ref, _ = _run(_batcher(params, cfg), short)
         chain = _batcher(params, cfg, speculative=True, spec_k=3,
                          draft_layers=1)
-        gc, _ = _run(chain, short)
+        gc, rec = _run(chain, short)
         tree = _batcher(params, cfg, speculative=True,
                         spec_tree=[1, 1, 1], draft_layers=1)
-        gt, _ = _run(tree, short)
-        assert gt == gc
-        assert tree.spec.accepted == chain.spec.accepted
-        assert tree.spec.emitted == chain.spec.emitted
-        assert tree.spec.depth_hist == chain.spec.depth_hist
+        assert tree.spec_k == 3 and chain._spec_cfg.tree == (1, 1, 1)
+        tree.warmup_prefill()
+        assert set(tree._spec_cache) == set(chain._spec_cache)
+        assert gc == ref and rec == 0
+        assert chain.spec.steps > 0
+        geometry = ("enabled", "backend", "k", "draft_layers",
+                    "draft_depth")
+        st, tt = chain.spec_stats(), tree.spec_stats()
+        assert "tree" not in st and "tree" not in tt
+        assert [st[g] for g in geometry] == [tt[g] for g in geometry]
 
     def test_tree_truncated_draft_bit_identical(self, setup):
         """A truncated tree draft (real rejections at every level)
@@ -556,7 +575,8 @@ class TestTreeSpecParity:
         sk = [k for k in cb._spec_cache]
         assert {k[0] for k in sk} == {"draft", "verify"}
         for k in sk:
-            assert k[1] == 4 and k[2] == 1       # spec_k, draft depth
+            assert k[1] == 1                     # draft depth
+            assert ("spec", 4, 1, "tree", 2, 1) == k[3:9]
             assert "pallas" in k and "xla" in k  # both resolved impls
             assert "tree" in k
 

@@ -13,6 +13,8 @@ export/import round-trips across mesh shapes (snapshots are
 host-gathered, mesh-agnostic), and a Router fronting one sharded
 replica.
 """
+import time
+
 import numpy as np
 import pytest
 import jax
@@ -249,6 +251,12 @@ class TestTPServing:
         assert cb.compile_count == warm     # warm re-serve: 0 compiles
         st = cb.spec_stats()
         assert st["enabled"] and st["backend"] == "pallas"
+        assert st["steps"] >= 1             # speculation ran on the mesh
+        # the mesh stamp says what ran: a silent fall-back to the XLA
+        # gather under the mesh would keep the tokens and lose the kernel
+        stamp = shard_info(MeshConfig(tp=2), cb)["mesh"]
+        assert stamp["attention_impl"] == cb.attention_impl == "pallas"
+        assert stamp["spec_backend"] == st["backend"]
 
 
 def _export_mid_decode(cb, rid, min_tokens=2):
@@ -293,4 +301,32 @@ class TestRouterShardedReplica:
         r.start()
         assert r.generate(PROMPTS[0], timeout=300) == baselines[0]
         assert r.health()["replicas"]["r0"]["mesh"]["tp"] == 2
+        r.shutdown()
+
+    def test_sharded_slot_respawns_with_its_mesh(self, setup, baselines):
+        """The supervisor replays a slot's per-replica kwargs on
+        respawn: the rebuilt engine re-derives its mesh and shardings,
+        passes the readiness gate, rejoins and serves bit-identically
+        with nothing compiled past that gate."""
+        cfg, params = setup
+        r = Router(params, cfg, replicas=1, auto_restart=True,
+                   per_replica=[{"mesh": MeshConfig(tp=2)}],
+                   start=False, **E_KW)
+        r.warmup()
+        r.start()
+        old = r.engines[0]
+        assert r._supervisor.restart_slot(0)
+        deadline = time.monotonic() + 240
+        while time.monotonic() < deadline:
+            h = r.health()
+            if h["serving_replicas"] == 1 and h["replica_restarts"] >= 1:
+                break
+            time.sleep(0.05)
+        assert h["serving_replicas"] == 1 and h["replica_restarts"] == 1
+        fresh = r.engines[0]
+        assert fresh is not old
+        assert fresh.health()["mesh"]["tp"] == 2
+        assert r.generate(PROMPTS[0], timeout=300) == baselines[0]
+        assert fresh.batcher.compile_count == \
+            r.health()["supervisor"]["r0"]["warm_compile_count"]
         r.shutdown()

@@ -1,9 +1,9 @@
 """Pad-aware prefill bucket-ladder tuner.
 
-`bench_serving.py` emits the accounting a workload-specific ladder is
-fitted from: `prefill_suffix_hist` (real pre-padding chunk length ->
-count), `prefill_buckets` (the ladder that served the run),
-`prefill_pad_tokens` and `prefill_compile_count`. The default
+A served batcher keeps the accounting a workload-specific ladder is
+fitted from: `batcher.prefill_suffix_hist` (real pre-padding chunk
+length -> count) and `batcher.prefill_buckets` (the ladder that served
+the run). The default
 power-of-two ladder is workload-agnostic — chat-like traffic whose
 prompts cluster under 64 tokens pays pad tokens a denser sub-64 ladder
 would not — so this tool fits the ladder that MINIMIZES total pad
@@ -17,14 +17,20 @@ lengths can be lowered to the smaller one without adding pad), so a
 classic O(n^2 * k) interval DP over the (length, count) histogram finds
 the minimum-pad ladder with at most k buckets.
 
-Usage:
-    python bench_serving.py --bucketed > bench.json
-    python tools/bucket_tuner.py bench.json [--max-buckets 4]
-    python tools/bucket_tuner.py bench.json --json   # machine-readable
+Usage: after serving the workload, dump one JSON record from the
+engine's batcher (`b = engine.batcher`):
+    {"prefill_suffix_hist": {str(k): v for k, v in
+                             b.prefill_suffix_hist.items()},
+     "prefill_buckets": list(b.prefill_buckets),
+     "kv_bytes_per_token": b.kv_bytes_per_token(),   # optional
+     "kv_dtype": b.kv_dtype}                         # optional
+then
+    python tools/bucket_tuner.py record.json [--max-buckets 4]
+    python tools/bucket_tuner.py record.json --json   # machine-readable
 
 Prints the recommended ladder as a `prefill_buckets=(...)` /
 `--prefill-buckets` setting plus the projected pad-token saving vs the
-ladder the bench actually ran (re-costed over the same histogram).
+ladder the run actually served (re-costed over the same histogram).
 Standalone stdlib tool — no jax import, safe anywhere ptlint runs.
 """
 from __future__ import annotations
@@ -93,15 +99,15 @@ def fit_ladder(hist: Dict[int, int], k: int) -> Tuple[List[int], int]:
 
 
 def tune(bench: Dict, max_buckets: int = 0) -> Dict:
-    """Fit a ladder from one bench JSON record. max_buckets 0 keeps the
+    """Fit a ladder from one JSON record. max_buckets 0 keeps the
     observed ladder's bucket count (same compile budget, less pad)."""
     raw = bench.get("prefill_suffix_hist") or {}
     hist = {int(k): int(v) for k, v in raw.items()}
     observed = [int(b) for b in bench.get("prefill_buckets", [])]
     if not hist:
         raise SystemExit(
-            "bench record has no prefill_suffix_hist — rerun "
-            "bench_serving.py from this tree")
+            "record has no prefill_suffix_hist — dump "
+            "batcher.prefill_suffix_hist after serving the workload")
     k = max_buckets or (len(observed) or 4)
     ladder, best = fit_ladder(hist, k)
     current = pad_cost(hist, observed) if observed else None
@@ -117,12 +123,12 @@ def tune(bench: Dict, max_buckets: int = 0) -> Dict:
     if current:
         out["pad_reduction"] = round(1.0 - best / current, 4)
     # price the padding in KV-gather bytes under the run's kv_dtype:
-    # kv_bytes_per_token (emitted by bench_serving from quantization.
-    # kv.kv_block_bytes) already includes the int8 scale-pool overhead,
-    # so an int8-KV run's pad bytes are ~half an fp run's for the same
-    # ladder — the tuner's recommendation stays token-driven (the DP is
-    # dtype-invariant), but the byte stakes it reports reflect what the
-    # attention gather actually moves.
+    # kv_bytes_per_token (batcher.kv_bytes_per_token(), from
+    # quantization.kv.kv_block_bytes) already includes the int8
+    # scale-pool overhead, so an int8-KV run's pad bytes are ~half an
+    # fp run's for the same ladder — the tuner's recommendation stays
+    # token-driven (the DP is dtype-invariant), but the byte stakes it
+    # reports reflect what the attention gather actually moves.
     bpt = bench.get("kv_bytes_per_token")
     if bpt:
         out["kv_dtype"] = bench.get("kv_dtype", "fp")
@@ -136,7 +142,9 @@ def tune(bench: Dict, max_buckets: int = 0) -> Dict:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("bench", nargs="?", default="-",
-                    help="bench_serving.py JSON line (file or '-')")
+                    help="JSON record of a served batcher's "
+                         "prefill_suffix_hist / prefill_buckets "
+                         "(file or '-')")
     ap.add_argument("--max-buckets", type=int, default=0,
                     help="bucket-count budget (0 = match the observed "
                          "ladder: same compile cost, less pad)")

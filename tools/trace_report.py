@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Summarize a serving trace artifact (bench_serving.py --trace out.json).
+"""Summarize a serving trace artifact (a dumped `to_chrome_trace()`).
 
 Reads the Chrome-trace JSON exported by `serving.trace.TraceSink.
 to_chrome_trace()` and answers, per request and in aggregate, the
@@ -51,9 +51,8 @@ questions the raw timeline is too granular for:
     windows, each listing the requests whose timelines rode it — the
     request-correlated view of "which users felt the burn".
 
-Standard library only (no jax import): runs anywhere the JSON landed,
-including the CI bench-smoke job where it ships as a non-blocking
-artifact. `--json` prints the summary as one JSON object instead of
+Standard library only (no jax import): runs anywhere the JSON landed.
+`--json` prints the summary as one JSON object instead of
 the text table.
 """
 from __future__ import annotations
@@ -463,8 +462,8 @@ def render(summary: dict, show_slo: bool = False) -> str:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("trace", help="Chrome-trace JSON written by "
-                                  "bench_serving.py --trace")
+    ap.add_argument("trace", help="Chrome-trace JSON dumped from "
+                                  "TraceSink.to_chrome_trace()")
     ap.add_argument("--json", action="store_true",
                     help="print the summary as one JSON object")
     ap.add_argument("--slo", action="store_true",
