@@ -310,16 +310,32 @@ def latent_paged_attention_xla(q, pool, table, positions, scale: float,
     return o.astype(q.dtype)
 
 
+def latent_work_list(positions, valid, table_width: int, block_size: int,
+                     impl: str = "xla"):
+    """What the kernel's grid walks for one row group (`positions`,
+    `valid` [G, P]): its list of live (row, query tile, chunk) items
+    (`ragged_attention.mla_work_list`), the same for every layer of a
+    forward, so built once and handed to each layer's
+    `latent_paged_attention`. None for the gather reference, which walks
+    no grid."""
+    if impl != "pallas":
+        return None
+    from .ragged_attention import mla_work_list
+    return mla_work_list(positions, valid, block_size=block_size,
+                         table_width=table_width)
+
+
 def latent_paged_attention(q, pool, table, positions, valid, cfg,
-                           impl: str = "xla"):
+                           impl: str = "xla", work=None):
     """The absorbed form's attention over the latent pool, by backend:
     "xla" the gather reference above, "pallas" the kernel
-    (`ragged_attention.mla_paged_attention`)."""
+    (`ragged_attention.mla_paged_attention`; `work`: its list from
+    `latent_work_list`, built here if None)."""
     if impl == "pallas":
         from .ragged_attention import mla_paged_attention
         return mla_paged_attention(q, pool, table, positions, valid,
                                    scale=cfg.softmax_scale,
-                                   v_width=cfg.kv_lora_rank)
+                                   v_width=cfg.kv_lora_rank, work=work)
     return latent_paged_attention_xla(q, pool, table, positions,
                                       cfg.softmax_scale, cfg.kv_lora_rank)
 

@@ -777,7 +777,7 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
 
 
 def _attention_latent(x, lp, cfg, cos, sin, pool, groups, is_prefill,
-                      attention_impl: str = "xla", base=0):
+                      attention_impl: str, base, works):
     """`_attention_paged` for a latent (MLA) mixer: one layer's attention
     over x, the packed tokens of `groups`, against the layer's latent
     pool [N, bs, R + rope]. Projections are per token, one dot each over
@@ -790,6 +790,9 @@ def _attention_latent(x, lp, cfg, cos, sin, pool, groups, is_prefill,
     flattened to [L*N, bs, R + rope], with `base` = layer * N added to
     every block id: the layer's blocks are then written and read in
     place, and no layer slice of the pool is copied out and back.
+    `works`: each group's kernel work list (`mla.latent_work_list`; None
+    where the backend walks none), the same for every layer, so made by
+    the caller once a forward.
     Returns (out shaped like x, pool')."""
     from . import mla
     with jax.named_scope("mla_q"):
@@ -816,7 +819,8 @@ def _attention_latent(x, lp, cfg, cos, sin, pool, groups, is_prefill,
         with jax.named_scope("attn_kernel"):
             outs = [mla.latent_paged_attention(
                 q[i], pool, tables[i], g.positions, g.valid, cfg,
-                impl=attention_impl) for i, g in enumerate(groups)]
+                impl=attention_impl, work=works[i])
+                for i, g in enumerate(groups)]
         with jax.named_scope("mla_absorb"):
             outs = [mla.unabsorb_o(o, lp, cfg) for o in outs]
     with jax.named_scope("attn_out"):
@@ -848,9 +852,9 @@ def _ffn_experts(x, h, lp, cfg, groups, stats, stacks, layer):
 
 
 def _merge_stats(a, b):
-    """Routing counters of two pieces of work: pairs, hit experts and
-    overflowed sorted buffers add up, the largest load on one expert is
-    a maximum."""
+    """Counters of two pieces of work: pairs, hit experts, overflowed
+    sorted buffers and the attention kernel's work items add up, the
+    largest load on one expert is a maximum."""
     return {k: (jnp.maximum(v, b[k]) if k == "moe_load_max" else v + b[k])
             for k, v in a.items()}
 
@@ -887,8 +891,10 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
     (`_layer_groups`), the FFN (a dense MLP or the held experts'
     share). `pools` is (k, v, k_scale, v_scale) stacked over layers (a
     latent pool: (rows, None, None, None)). Returns (x, the packed
-    hidden states before the final norm; pools'; the expert layers'
-    routing counters, None where there are none)."""
+    hidden states before the final norm; pools'; the forward's counters:
+    the expert layers' routing and, where the latent kernel runs, the
+    work items one layer's calls walked (`attn_work_steps`); None where
+    there are none)."""
     cd = cfg.dtype
     latent = _is_latent(cfg)
     k_all, v_all, ks_all, vs_all = pools
@@ -904,6 +910,15 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
     else:
         cos, sin = rope_freqs(cfg.head_dim, T_rope, cfg.rope_theta,
                               jnp.float32)
+    works = [None] * len(groups)
+    if latent and not is_prefill:
+        # the latent kernel's grid: one list a row group, from positions
+        # and valid alone, so built here and not once a layer in the scan
+        # (None each where the backend walks no grid)
+        from . import mla
+        works = [mla.latent_work_list(g.positions, g.valid, g.table.shape[1],
+                                      k_all.shape[2], attention_impl)
+                 for g in groups]
 
     def make_body(ffn, stacks=None, first_layer=0):
         def body(carry, lp):
@@ -953,7 +968,7 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
             a, pool = _attention_latent(
                 h, lp, cfg, cos, sin,
                 pool_all.reshape(L * N, *pool_all.shape[2:]), groups,
-                is_prefill, attention_impl, base=li * N)
+                is_prefill, attention_impl, base=li * N, works=works)
             with jax.named_scope("mlp"):
                 x = x + a
                 h = rms_norm_ref(x, lp["post_attention_layernorm"],
@@ -986,6 +1001,11 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
                             layers)
         first_layer += jax.tree_util.tree_leaves(layers)[0].shape[0]
     x, pk, pv, ks, vs, _, stats = carry
+    if works[0] is not None:
+        # the items ONE layer's kernel calls walked (every layer walks
+        # the same lists), beside the routing counters
+        stats = {**(stats or {}), "attn_work_steps": sum(
+            w.count for w in works)}
     return x, (pk, pv, ks, vs), stats
 
 
@@ -1091,10 +1111,10 @@ def paged_generate(params, tokens, lengths, cfg: llama.LlamaConfig,
 
 
 def _sum_steps(stats, first=None):
-    """A chunk's routing counters from its steps' (stacked on a leading
-    axis by the scan; `first`: the fused forward's, outside the scan):
-    summed as `_merge_stats` sums two. None for a decoder without expert
-    layers."""
+    """A chunk's counters from its steps' (stacked on a leading axis by
+    the scan; `first`: the fused forward's, outside the scan): summed as
+    `_merge_stats` sums two. None for a decoder whose forward counts
+    nothing."""
     if stats is None:
         return None
     out = {k: (jnp.max(v) if k == "moe_load_max"
@@ -1147,7 +1167,9 @@ class _Tick:
     def note(self, fields: Optional[Dict[str, Any]]) -> None:
         """Host values the call itself produced (read back with its
         tokens), for the record's close: the expert layers' routing
-        counters. None (a decoder without them) notes nothing."""
+        counters, the latent kernel's `attn_work_steps` (and beside it
+        `attn_grid_steps`, which the host knows: `_note_counters`). None
+        (a decoder without them) notes nothing."""
         if fields:
             self.noted.update({k: int(v) for k, v in fields.items()})
 
@@ -2208,6 +2230,22 @@ class ContinuousBatcher:
         return [len(self.slot_tokens[s]) + len(self.outputs[self.slot_req[s]])
                 for s in slots]
 
+    def _note_counters(self, tick: "_Tick", stats, prefill_rows: int = 0,
+                       bucket: int = 0) -> None:
+        """A decode or fused tick's counters, read back with its tokens,
+        onto its record; and, where the latent kernel counted the items
+        its calls of ONE layer walked (`attn_work_steps`), beside them
+        the full grid, live or not, that those calls span
+        (`attn_grid_steps`: `chunk` decode calls `[B, 1]` and, on a fused
+        tick, the `[prefill_rows, bucket]` call)."""
+        tick.note(stats)
+        if stats and "attn_work_steps" in stats:
+            from .ragged_attention import mla_grid_steps
+            steps = self.chunk * mla_grid_steps(self.B, 1, self.M)
+            if prefill_rows:
+                steps += mla_grid_steps(prefill_rows, bucket, self.M)
+            tick.note({"attn_grid_steps": steps})
+
     def _probe_gate(self, rid: int) -> None:
         """Fault-injection hook of the quarantine probes (a tick's own
         gate is in `_Tick.__enter__`): a no-op without an injector."""
@@ -2974,7 +3012,8 @@ class ContinuousBatcher:
                     # failure HERE, before the batcher state commits
                     got = jax.device_get((toks, pfirst, stats))  # ptlint: disable=SYNC001 — single per-step sync, decode + prefill readbacks coalesced
                     toks, pfirst, stats = got
-                    tick.note(stats)
+                    self._note_counters(tick, stats, len(groups) * Gp,
+                                        bucket)
                 # decode state untouched up to here: a failure rolls
                 # the pending units back (below)
                 committed = True
@@ -3698,7 +3737,7 @@ class ContinuousBatcher:
                 # one host sync per decode chunk — the per-token loop
                 # of the commit reads this numpy copy, never the device
                 toks, stats = jax.device_get((toks, stats))  # ptlint: disable=SYNC001 — single per-chunk sync, hoisted out of the per-token loop
-                tick.note(stats)
+                self._note_counters(tick, stats)
             with tick.phase("commit"):
                 self.cache = self.cache._replace(lengths=lengths)
                 # steady state: the chunk's own outputs are next chunk's

@@ -464,27 +464,92 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
 
 # ---------------------------------------------------------------------------
 # latent (MLA) mode: one KV head, every query head over it, keys the whole
-# cached row, values its first columns, each row read once
+# cached row, values its first columns, each row read once; the grid is a
+# list of the live (row, query tile, chunk) items and nothing else
 # ---------------------------------------------------------------------------
 
+from typing import NamedTuple  # noqa: E402 (here, so no line above moves)
+
+
+def _mla_tiling(P: int, M: int, q_tile: int, blocks_per_step):
+    """How a `[R, P]` call over a table `M` blocks wide is cut: (Pt
+    queries a tile, T tiles a row, nb blocks a chunk, C chunks a table
+    row). One place, so that the work list and the kernel agree."""
+    if blocks_per_step is None:
+        blocks_per_step = 32 if P == 1 else 16
+    nb = max(1, min(int(blocks_per_step), M))
+    q_tile = max(1, min(q_tile, P))
+    Pt = max(d for d in range(1, q_tile + 1) if P % d == 0)
+    return Pt, P // Pt, nb, -(-M // nb)
+
+
+def mla_grid_steps(R: int, P: int, M: int, q_tile: int = 16,
+                   blocks_per_step=None) -> int:
+    """The full grid of a `[R, P]` call: every (row, query tile, chunk),
+    live or not. What the kernel walked before its grid was a work list,
+    and the length the list's arrays have."""
+    _, T, _, C = _mla_tiling(P, M, q_tile, blocks_per_step)
+    return R * T * C
+
+
+class MlaWork(NamedTuple):
+    """The latent kernel's work list (`mla_work_list`)."""
+    live: jax.Array      # [R, T] live BLOCKS of each (row, query tile)
+    row: jax.Array       # [R*T*C] the row of work item i
+    tile: jax.Array      # [R*T*C] its query tile
+    chunk: jax.Array     # [R*T*C] its chunk of `nb` blocks
+    count: jax.Array     # [] the items that are live work: the grid
+
+
+def mla_work_list(positions, valid, *, block_size: int, table_width: int,
+                  q_tile: int = 16, blocks_per_step=None) -> MlaWork:
+    """The work of one `mla_paged_attention` call, from what the call
+    sees: for every (row, query tile) with a valid query, its
+    `ceil(live_blocks / nb)` chunks, row-major, so that one (row, tile)'s
+    chunks are consecutive and in order. The arrays have the static
+    length of the full grid (`mla_grid_steps`); the first `count` entries
+    are the list. It depends on neither the layer nor the pool, so a
+    forward builds it once a row group and every layer's call takes it
+    (`work=`)."""
+    R, P = positions.shape
+    Pt, T, nb, C = _mla_tiling(P, table_width, q_tile, blocks_per_step)
+    live_tok = jnp.max(
+        jnp.where(valid, positions.astype(jnp.int32) + 1, 0
+                  ).reshape(R, T, Pt), axis=2)
+    live = jnp.minimum((live_tok + block_size - 1) // block_size,
+                       table_width).astype(jnp.int32)
+    chunks = ((live + nb - 1) // nb).reshape(R * T)
+    ends = jnp.cumsum(chunks, dtype=jnp.int32)
+    i = jnp.arange(R * T * C, dtype=jnp.int32)
+    done = ends[None, :] <= i[:, None]       # the (row, tile)s before item i
+    item = jnp.minimum(jnp.sum(done, axis=1, dtype=jnp.int32), R * T - 1)
+    # where item i's (row, tile) starts: the largest end not past i
+    first = jnp.max(jnp.where(done, ends[None, :], 0), axis=1)
+    return MlaWork(live, item // T, item % T, i - first, ends[-1])
+
+
 def _mla_kernel(*refs, bs: int, nb: int, scale: float, v_width: int):
-    """One (row, query-tile, chunk of `nb` blocks) grid step of the latent
-    kernel. Refs: tab/live (scalar prefetch), pos_ref/val_ref [1, 1, G, 1]
-    int32, q_ref [1, 1, G, W] (G = Pt*H query rows, query-major), `nb`
-    k_refs [1, bs, W] (the chunk's pool blocks, each resolved from the
-    table by its own index map: the SAME pool array `nb` times over),
-    o_ref [1, 1, G, v_width]; scratch acc [G, v_width], m/l [G, 1] f32.
+    """One work item of the latent kernel: a chunk of `nb` blocks of one
+    (row, query tile). Refs: tab/live/row/tile/chunk (scalar prefetch),
+    pos_ref/val_ref [1, 1, G, 1] int32, q_ref [1, 1, G, W] (G = Pt*H
+    query rows, query-major), `nb` k_refs [1, bs, W] (the chunk's pool
+    blocks, each resolved from the table by its own index map: the SAME
+    pool array `nb` times over), o_ref [1, 1, G, v_width]; scratch acc
+    [G, v_width], m/l [G, 1] f32. A (row, tile)'s items are consecutive:
+    its first starts the softmax state, its last writes the output.
     Scores contract the full row width W; values are columns
     [0, v_width) of the same block in VMEM: keys and values alias, so a
     cached row crosses HBM once. Both dots take the operands in their
     stored type and accumulate in float32."""
     import jax.experimental.pallas as pl
 
-    tab_ref, live_ref, pos_ref, val_ref, q_ref = refs[:5]
-    k_refs = refs[5:5 + nb]
-    o_ref, acc_ref, m_ref, l_ref = refs[5 + nb:]
-    r, t, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nlive = (live_ref[r, t] + nb - 1) // nb          # live CHUNKS
+    tab_ref, live_ref, row_ref, tile_ref, chunk_ref = refs[:5]
+    pos_ref, val_ref, q_ref = refs[5:8]
+    k_refs = refs[8:8 + nb]
+    o_ref, acc_ref, m_ref, l_ref = refs[8 + nb:]
+    i = pl.program_id(0)
+    c = chunk_ref[i]
+    nlive = (live_ref[row_ref[i], tile_ref[i]] + nb - 1) // nb  # live CHUNKS
 
     @pl.when(c == 0)
     def _init():
@@ -492,30 +557,28 @@ def _mla_kernel(*refs, bs: int, nb: int, scale: float, v_width: int):
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(c < nlive)
-    def _accumulate():
-        G = pos_ref.shape[2]
-        k = jnp.concatenate([kr[0] for kr in k_refs], axis=0)  # [nb*bs, W]
-        # a block slot past the tile's live chain re-reads the last live
-        # block (the index map clamps): its key positions lie past every
-        # visible one, so the causal test hides it
-        kpos = c * (nb * bs) + jax.lax.broadcasted_iota(
-            jnp.int32, (G, nb * bs), 1)
-        vis = (kpos <= pos_ref[0, 0]) & (val_ref[0, 0] != 0)
-        s = jax.lax.dot_general(q_ref[0, 0], k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(vis, s, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(vis, jnp.exp(s - m_new), 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p.astype(k.dtype), k[:, :v_width],
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+    G = pos_ref.shape[2]
+    k = jnp.concatenate([kr[0] for kr in k_refs], axis=0)    # [nb*bs, W]
+    # a block slot past the tile's live chain re-reads the last live
+    # block (the index map clamps): its key positions lie past every
+    # visible one, so the causal test hides it
+    kpos = c * (nb * bs) + jax.lax.broadcasted_iota(
+        jnp.int32, (G, nb * bs), 1)
+    vis = (kpos <= pos_ref[0, 0]) & (val_ref[0, 0] != 0)
+    s = jax.lax.dot_general(q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(vis, s, _NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(vis, jnp.exp(s - m_new), 0.0)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+        p.astype(k.dtype), k[:, :v_width],
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
 
-    @pl.when(c == jnp.maximum(nlive - 1, 0))
+    @pl.when(c == nlive - 1)
     def _finalize():
         l = l_ref[...]
         o_ref[0, 0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
@@ -526,7 +589,7 @@ def _mla_kernel(*refs, bs: int, nb: int, scale: float, v_width: int):
                                              "blocks_per_step", "interpret"))
 def mla_paged_attention(q, pool, table, positions, valid=None, *,
                         scale: float, v_width: int, q_tile: int = 16,
-                        blocks_per_step=None, interpret=None):
+                        blocks_per_step=None, interpret=None, work=None):
     """Latent paged attention, the absorbed form of MLA (nlp/mla.py):
 
       q [R, P, H, W] (each head's query carried into the latent space,
@@ -536,19 +599,32 @@ def mla_paged_attention(q, pool, table, positions, valid=None, *,
       the visible keys of `scale * q . row`, times the rows' first
       `v_width` columns. Invalid queries return zeros.
 
-    The grid is (row, query tile, chunk of `blocks_per_step` blocks):
-    the one KV head is shared by all H query heads, so a tile of Pt
-    queries is Pt*H kernel rows against each block (`q_tile` = Pt
-    bounds VMEM: scratch and the q/o blocks grow with Pt*H). The pool
-    goes in `blocks_per_step` times, each operand's index map resolving
-    one block of the chunk from the prefetched table, so a grid step
-    moves that many blocks and the per-step overhead is paid once for
-    them; past a tile's live chain the maps clamp to its last live
-    block and whole dead chunks are skipped. Defaults from the chip
-    (PR 26): a decode call (P = 1) is grid steps, not bytes (64 rows x
-    16 chunks of 8 blocks cost 0.47 ms a call with 5 rows live), so it
-    takes 32 blocks a step; prefill rows take 16 queries x 16 blocks
-    (32 x 16 x H rows of float32 scores do not fit the kernel's VMEM)."""
+    The grid is a WORK LIST (`mla_work_list`, built here unless the
+    caller hands in the one it built for these positions and valid:
+    `work=`): one step for every chunk of `blocks_per_step` blocks of
+    every (row, query tile) that has a valid query, as many steps as the
+    list is long (a grid bound read on the device), every index map
+    resolving row, tile and chunk through the prefetched list. A row or
+    tile with no valid query is never visited: its q block is not moved,
+    its output not written (the select below zeroes it), and a call with
+    nothing live walks no step at all. The one KV head is shared by all
+    H query heads, so a tile of Pt queries is Pt*H kernel rows against
+    each block (`q_tile` = Pt bounds VMEM: scratch and the q/o blocks
+    grow with Pt*H). The pool goes in `blocks_per_step` times, each
+    operand's index map resolving one block of the chunk from the
+    prefetched table, so a step moves that many blocks and the per-step
+    overhead is paid once for them; past a tile's live chain the maps
+    clamp to its last live block. From the chip: a grid step costs its
+    operands, about 50 ns each (PR 30: a `[64, 1]` call walking all 256
+    steps of 35 operands took 0.455 ms with no row live, 1.8 us a step,
+    and half and a quarter of that at 32 and 16 slots; PR 26: 1024
+    steps of 11 operands 0.47 ms), so a decode call (P = 1) takes 32
+    blocks a step and, since PR 30, only its live steps: 4 us with none
+    live, then 3 us an item (a step of 32 blocks), 19 us at the served
+    cell's 3 rows live where it was 455; with every row live and at
+    full context it costs what the full grid did (0.744 against 0.737
+    ms). Prefill rows take 16 queries x 16 blocks (32 x 16 x H rows of
+    float32 scores do not fit the kernel's VMEM)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -557,41 +633,43 @@ def mla_paged_attention(q, pool, table, positions, valid=None, *,
     R, P, H, W = q.shape
     N, bs, _ = pool.shape
     M = table.shape[1]
-    if blocks_per_step is None:
-        blocks_per_step = 32 if P == 1 else 16
-    nb = max(1, min(int(blocks_per_step), M))
+    Pt, T, nb, C = _mla_tiling(P, M, q_tile, blocks_per_step)
+    G = Pt * H
     if valid is None:
         valid = jnp.ones((R, P), bool)
     positions = positions.astype(jnp.int32)
     table = table.astype(jnp.int32)
-    q_tile = max(1, min(q_tile, P))
-    Pt = max(d for d in range(1, q_tile + 1) if P % d == 0)
-    T, G = P // Pt, Pt * H
-    live_tok = jnp.max(
-        jnp.where(valid, positions + 1, 0).reshape(R, T, Pt), axis=2)
-    live = ((live_tok + bs - 1) // bs).astype(jnp.int32)     # live BLOCKS
+    if work is None:
+        work = mla_work_list(positions, valid, block_size=bs, table_width=M,
+                             q_tile=q_tile, blocks_per_step=blocks_per_step)
+    if work.live.shape != (R, T) or work.row.shape != (R * T * C,):
+        raise ValueError(
+            f"work list of {work.live.shape} tiles and {work.row.shape} "
+            f"items does not fit a call of {(R, T)} tiles x {C} chunks")
 
     def _rows(x):
         # [R, P] per query -> [R, T, G, 1] per kernel row (query-major)
         x = jnp.broadcast_to(x.reshape(R, T, Pt, 1), (R, T, Pt, H))
         return x.reshape(R, T, G, 1)
 
-    def _tile_map(r, t, c, tab, live):
-        return (r, t, 0, 0)
+    def _tile_map(i, tab, live, row, tile, chunk):
+        return (row[i], tile[i], 0, 0)
 
-    def _kv_map(i):
-        def index(r, t, c, tab, live):
-            j = jnp.minimum(c * nb + i, jnp.maximum(live[r, t] - 1, 0))
+    def _kv_map(b):
+        def index(i, tab, live, row, tile, chunk):
+            r = row[i]
+            j = jnp.minimum(chunk[i] * nb + b,
+                            jnp.maximum(live[r, tile[i]] - 1, 0))
             return (jnp.maximum(tab[r, j], 0), 0, 0)
         return index
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(R, T, -(-M // nb)),
+        num_scalar_prefetch=5,
+        grid=(work.count,),
         in_specs=[pl.BlockSpec((1, 1, G, 1), _tile_map),
                   pl.BlockSpec((1, 1, G, 1), _tile_map),
                   pl.BlockSpec((1, 1, G, W), _tile_map)]
-        + [pl.BlockSpec((1, bs, W), _kv_map(i)) for i in range(nb)],
+        + [pl.BlockSpec((1, bs, W), _kv_map(b)) for b in range(nb)],
         out_specs=pl.BlockSpec((1, 1, G, v_width), _tile_map),
         scratch_shapes=[pltpu.VMEM((G, v_width), jnp.float32),
                         pltpu.VMEM((G, 1), jnp.float32),
@@ -603,7 +681,9 @@ def mla_paged_attention(q, pool, table, positions, valid=None, *,
         out_shape=jax.ShapeDtypeStruct((R, T, G, v_width), q.dtype),
         interpret=interpret, name="mla_paged_attention")
     with jax.enable_x64(False):
-        o = call(table, live, _rows(positions),
-                 _rows(valid.astype(jnp.int32)), q.reshape(R, T, G, W),
-                 *([pool] * nb))
-    return o.reshape(R, P, H, v_width)
+        o = call(table, work.live, work.row, work.tile, work.chunk,
+                 _rows(positions), _rows(valid.astype(jnp.int32)),
+                 q.reshape(R, T, G, W), *([pool] * nb))
+    # what the list never visited was never written
+    return jnp.where(valid[:, :, None, None], o.reshape(R, P, H, v_width),
+                     jnp.zeros((), q.dtype))

@@ -136,17 +136,29 @@ def _adam8(topo):
 
 def _mla(topo, R, Pq):
     """The latent (MLA) kernel at A.X-K1's widths: 64 query heads over one
-    cached row of 512 + 64 columns, values the row's first 512."""
-    from paddle_tpu.nlp.ragged_attention import mla_paged_attention
+    cached row of 512 + 64 columns, values the row's first 512. Its grid
+    is its work list: the bound is an operand of the call (the scalar
+    that leads them), then the table, the tiles' live blocks and the
+    list's three arrays at the full grid's length."""
+    from paddle_tpu.nlp.ragged_attention import (mla_grid_steps,
+                                                 mla_paged_attention)
     one = SingleDeviceSharding(topo.devices[0])
 
     def fn(q, pool, tab, pos, val):
         return mla_paged_attention(q, pool, tab, pos, val, scale=0.13086,
                                    v_width=512, interpret=False)
 
-    return _compile(fn, [one] * 5, ((R, Pq, 64, 576), BF),
-                    ((N, BS, 576), BF), ((R, M), jnp.int32),
-                    ((R, Pq), jnp.int32), ((R, Pq), jnp.bool_))
+    txt = _compile(fn, [one] * 5, ((R, Pq, 64, 576), BF),
+                   ((N, BS, 576), BF), ((R, M), jnp.int32),
+                   ((R, Pq), jnp.int32), ((R, Pq), jnp.bool_))
+    call = next(line for line in txt.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    n = mla_grid_steps(R, Pq, M)
+    T = Pq // min(Pq, 16)
+    assert (f"operand_layout_constraints={{s32[], s32[{R},{M}]{{1,0}}, "
+            f"s32[{R},{T}]{{1,0}}, s32[{n}]{{0}}, s32[{n}]{{0}}, "
+            f"s32[{n}]{{0}}, ") in call, call[:600]
+    return txt
 
 
 def _expert_share(topo, T, short):
@@ -177,6 +189,8 @@ def _expert_share(topo, T, short):
 CASES = {
     "mla-decode-64-rows": lambda t: _mla(t, 64, 1),
     "mla-prefill-bucket-512": lambda t: _mla(t, 2, 512),
+    # the fused step's prefill rows at `max_prefill_group` 4
+    "mla-fused-prefill-rows": lambda t: _mla(t, 4, 512),
     # the sorted buffers of a decode step's 64 slots and of the fused
     # steps on the 512 and 128 buckets (64 + 512 and 64 + 128 tokens)
     "expert-share-decode-64-tokens": lambda t: _expert_share(t, 64, 128),

@@ -27,6 +27,7 @@ from benchmark.models import mla_moe_decoder as family        # noqa: E402
 from benchmark.reference import mla_moe_decoder as reference  # noqa: E402
 from paddle_tpu.kernels import rope                           # noqa: E402
 from paddle_tpu.nlp import mla, moe, paged                    # noqa: E402
+from paddle_tpu.nlp import ragged_attention                   # noqa: E402
 from paddle_tpu.nlp.ragged_attention import mla_paged_attention  # noqa: E402
 
 TOL = 2e-5
@@ -141,6 +142,172 @@ def test_latent_kernel_matches_gather_reference(shape):
     assert not np.any(np.where(keep, 0, out))    # invalid queries: zeros
 
 
+def _full_grid_kernel(q, pool, table, pos, valid, *, scale, v_width, q_tile,
+                      nb):
+    """The latent kernel as it was before its grid became a work list
+    (PR 29's, kept here as the yardstick): a straight loop over EVERY
+    (row, query tile, chunk), a dead chunk skipped in the body, the same
+    dots in the same order on the live ones."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    R, P, H, W = q.shape
+    bs, M = pool.shape[1], table.shape[1]
+    Pt, T, nb, C = ragged_attention._mla_tiling(P, M, q_tile, nb)
+    G = Pt * H
+    live_tok = jnp.max(jnp.where(valid, pos + 1, 0).reshape(R, T, Pt), axis=2)
+    live = ((live_tok + bs - 1) // bs).astype(jnp.int32)
+
+    def kernel(tab_ref, live_ref, pos_ref, val_ref, q_ref, *rest):
+        k_refs, (o_ref, acc_ref, m_ref, l_ref) = rest[:nb], rest[nb:]
+        r, t, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        nlive = (live_ref[r, t] + nb - 1) // nb
+
+        @pl.when(c == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, ragged_attention._NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+        @pl.when(c < nlive)
+        def _accumulate():
+            k = jnp.concatenate([kr[0] for kr in k_refs], axis=0)
+            kpos = c * (nb * bs) + jax.lax.broadcasted_iota(
+                jnp.int32, (G, nb * bs), 1)
+            vis = (kpos <= pos_ref[0, 0]) & (val_ref[0, 0] != 0)
+            s = jax.lax.dot_general(
+                q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(vis, s, ragged_attention._NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(vis, jnp.exp(s - m_new), 0.0)
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+                p.astype(k.dtype), k[:, :v_width],
+                preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+
+        @pl.when(c == jnp.maximum(nlive - 1, 0))
+        def _finalize():
+            l = l_ref[...]
+            o_ref[0, 0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
+                           ).astype(o_ref.dtype)
+
+    def rows(x):
+        x = jnp.broadcast_to(x.reshape(R, T, Pt, 1), (R, T, Pt, H))
+        return x.reshape(R, T, G, 1)
+
+    def tile_map(r, t, c, tab, live):
+        return (r, t, 0, 0)
+
+    def kv_map(b):
+        def index(r, t, c, tab, live):
+            j = jnp.minimum(c * nb + b, jnp.maximum(live[r, t] - 1, 0))
+            return (jnp.maximum(tab[r, j], 0), 0, 0)
+        return index
+
+    spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(R, T, C),
+        in_specs=[pl.BlockSpec((1, 1, G, 1), tile_map)] * 2
+        + [pl.BlockSpec((1, 1, G, W), tile_map)]
+        + [pl.BlockSpec((1, bs, W), kv_map(b)) for b in range(nb)],
+        out_specs=pl.BlockSpec((1, 1, G, v_width), tile_map),
+        scratch_shapes=[pltpu.VMEM((G, v_width), jnp.float32),
+                        pltpu.VMEM((G, 1), jnp.float32),
+                        pltpu.VMEM((G, 1), jnp.float32)])
+    with jax.enable_x64(False):
+        o = pl.pallas_call(
+            kernel, grid_spec=spec, interpret=True,
+            out_shape=jax.ShapeDtypeStruct((R, T, G, v_width), q.dtype))(
+            table, live, rows(pos), rows(valid.astype(jnp.int32)),
+            q.reshape(R, T, G, W), *([pool] * nb))
+    return o.reshape(R, P, H, v_width)
+
+
+def _decode_rows(live, ctx):
+    """A `[8, 1]` decode call: rows `live` valid, row r at context
+    `ctx[r]` (its query sits at position ctx[r] - 1)."""
+    valid = np.zeros((8, 1), bool)
+    valid[list(live)] = True
+    return valid, np.asarray(ctx, np.int32)[:, None] - 1
+
+
+_CTX = (5, 30, 12, 1, 17, 32, 9, 24)        # of at most M * bs = 32 keys
+# a chunk is nb x bs = 2 x 4 = 8 keys: contexts on its boundaries
+_AT, _SHORT, _PAST = [[8 * k + d for k in (1, 2, 3, 1, 2, 3, 1, 2)]
+                      for d in (0, -1, 1)]
+_PREFILL = np.ones((4, 8), bool)
+_PREFILL[1] = False                         # a wholly dead row
+_PREFILL[2, 6:] = False                     # a half-valid second tile
+_PREFILL[3, 2:] = False                     # a second tile with no query
+WORK_CASES = {
+    # name: (valid [R, P], positions [R, P], q_tile, dtype)
+    "live-none": (*_decode_rows((), _CTX), 1, jnp.float32),
+    "live-one": (*_decode_rows((3,), _CTX), 1, jnp.float32),
+    "live-scattered": (*_decode_rows((0, 2, 5, 6), _CTX), 1, jnp.float32),
+    "live-all": (*_decode_rows(range(8), _CTX), 1, jnp.float32),
+    "context-at-chunk-end": (*_decode_rows(range(8), _AT), 1, jnp.float32),
+    "context-one-short": (*_decode_rows(range(8), _SHORT), 1, jnp.float32),
+    "context-one-past": (*_decode_rows(range(8), _PAST), 1, jnp.float32),
+    "prefill-dead-row-half-tile": (
+        _PREFILL, np.asarray([0, 4, 8, 20])[:, None] + np.arange(8)[None],
+        4, jnp.float32),
+    "scattered-bfloat16": (*_decode_rows((1, 4, 5, 7), _CTX), 1,
+                           jnp.bfloat16),
+    "prefill-bfloat16": (
+        _PREFILL, np.asarray([3, 4, 8, 21])[:, None] + np.arange(8)[None],
+        2, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(WORK_CASES))
+def test_latent_kernel_walks_only_live_work(case):
+    """The grid is the work list: valid queries equal the gather
+    reference, invalid ones are zeros, every live row is bit-equal to the
+    full-grid kernel's (same dots, same order), and the list holds
+    `ceil(live_blocks / nb)` items for each (row, tile) with a valid
+    query and nothing else."""
+    valid, pos, q_tile, dtype = WORK_CASES[case]
+    R, P = valid.shape
+    H, W, V, bs, M, nb = 4, 40, 32, 4, 8, 2
+    rng = np.random.default_rng(len(case))
+    N = R * M + 3
+    q = jnp.asarray(rng.normal(size=(R, P, H, W)), dtype)
+    pool = jnp.asarray(rng.normal(size=(N, bs, W)), dtype)
+    table = jnp.asarray(rng.permutation(N)[:R * M].reshape(R, M), jnp.int32)
+    valid, pos = jnp.asarray(valid), jnp.asarray(pos, jnp.int32)
+    kw = dict(scale=0.2, v_width=V, q_tile=q_tile)
+    out = np.asarray(mla_paged_attention(
+        q, pool, table, pos, valid, blocks_per_step=nb, **kw
+    ).astype(jnp.float32))
+    keep = np.asarray(valid)[:, :, None, None]
+    assert not np.any(np.where(keep, 0, out))    # invalid queries: zeros
+    ref = mla.latent_paged_attention_xla(q, pool, table, pos, 0.2, V)
+    np.testing.assert_allclose(
+        np.where(keep, out, 0), np.where(keep, ref.astype(jnp.float32), 0),
+        atol=2e-6 if dtype == jnp.float32 else 2e-2)
+    full = np.asarray(_full_grid_kernel(q, pool, table, pos, valid, nb=nb,
+                                        **kw).astype(jnp.float32))
+    assert np.array_equal(out, full)
+    # the list: every (row, tile) with a valid query, its chunks in order
+    work = ragged_attention.mla_work_list(
+        pos, valid, block_size=bs, table_width=M, q_tile=q_tile,
+        blocks_per_step=nb)
+    Pt = q_tile
+    top = np.where(np.asarray(valid), np.asarray(pos) + 1, 0
+                   ).reshape(R, P // Pt, Pt).max(-1)
+    want = [(r, t, c) for r in range(R) for t in range(P // Pt)
+            for c in range(-(-(-(-int(top[r, t]) // bs)) // nb))]
+    n = int(work.count)
+    assert n == len(want)
+    assert len(work.row) == ragged_attention.mla_grid_steps(
+        R, P, M, q_tile, nb) == R * (P // Pt) * (M // nb)
+    assert list(zip(*(np.asarray(a)[:n].tolist() for a in
+                      (work.row, work.tile, work.chunk)))) == want
+
+
 def _tokens(n_rows, n, vocab, seed=0):
     return np.random.default_rng(seed).integers(1, vocab, (n_rows, n)
                                                 ).astype(np.int32)
@@ -190,6 +357,12 @@ def test_paged_forward_matches_reference_logits(model, how, impl):
         np.testing.assert_allclose(fused[1:], ref[1, :P], atol=TOL)
         # P + 1 valid tokens, k choices each, over 2 expert layers
         assert 0 < int(stats["moe_pairs"]) <= (P + 1) * d["k"] * 2
+        # one layer's kernel calls: the decode row's one chunk and the
+        # prefill row's one tile of one chunk (24 keys fit one chunk);
+        # the gather reference walks no grid and counts nothing
+        assert ("attn_work_steps" in stats) == (impl == "pallas")
+        if impl == "pallas":
+            assert int(stats["attn_work_steps"]) == 2
         cache = cache._replace(k=pools[0])
         logits = None
     if logits is not None:
@@ -446,6 +619,46 @@ def test_engine_serves_mixed_prompts_with_a_prefix_hit(model):
                                           jnp.float32))[0]
         at = ref[len(p) - 1:len(p) - 1 + len(out)]
         assert np.all(at.max(-1) - at[np.arange(len(out)), out] < TOL)
+
+
+def test_flight_records_carry_the_kernels_work_beside_its_full_grid(model):
+    """Every decode and fused tick of a batcher whose latent attention is
+    the kernel notes `attn_work_steps` (one layer's work items, added up
+    on the device) beside `attn_grid_steps` (the full grid of the same
+    calls, from shapes); the gather reference walks no grid and notes
+    neither."""
+    d, cfg, params = model
+    rng = np.random.default_rng(7)
+    short = rng.integers(1, d["V"], 5).tolist()
+    long = rng.integers(1, d["V"], 20).tolist()
+
+    def serve(impl):
+        b = paged.ContinuousBatcher(
+            params, cfg, max_batch=4, block_size=4, max_total_len=64,
+            max_new_tokens=8, chunk=2, max_prefill_bucket=8,
+            attention_impl=impl)
+        b.submit(short)
+        b.step()
+        b.submit(long)          # joins mid-decode: its chunks ride fused
+        b.run()
+        recs = [r for r in b.flight.records()
+                if r["mode"] in ("decode", "fused")]
+        assert {r["mode"] for r in recs} == {"decode", "fused"}
+        return b, recs
+
+    b, recs = serve("pallas")
+    # a table of 16 blocks is one chunk a row: a `[4, 1]` decode call
+    # spans 4 grid steps, an `[1, 8]` prefill call one
+    for r in recs:
+        grid = b.chunk * b.B + (r["rows"] if r["mode"] == "fused" else 0)
+        assert r["attn_grid_steps"] == grid
+        # a live row is one item a decode step; a prefill row one
+        live = b.chunk * r["active_slots"] + (r["mode"] == "fused")
+        assert 0 < r["attn_work_steps"] <= live
+    assert any(r["attn_work_steps"] < r["attn_grid_steps"] for r in recs)
+    _, recs = serve("xla")
+    assert not any("attn_work_steps" in r or "attn_grid_steps" in r
+                   for r in recs)
 
 
 @pytest.mark.parametrize("option", [

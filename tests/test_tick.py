@@ -98,6 +98,8 @@ def test_every_kind_of_tick_closes_its_record(setup, kind):
             assert r[key] >= 0.0, (key, r)
         assert r["dispatch_s"] > 0.0 and r["t_dispatch"] >= r["t"]
         assert isinstance(r["live_after"], int)
+        # the latent kernel's counters: not a GQA engine's to carry
+        assert "attn_work_steps" not in r and "attn_grid_steps" not in r
         if r["synced"]:
             assert r["wait_s"] > 0.0
             assert r["t_synced"] >= r["t_dispatch"] + r["dispatch_s"]
