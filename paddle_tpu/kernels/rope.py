@@ -49,12 +49,17 @@ def yarn_inv_freq(dim: int, base: float, factor: float, original_max: int,
 def yarn_freqs(dim: int, max_seq: int, base: float, factor: float,
                original_max: int, beta_fast: float = 32.0,
                beta_slow: float = 1.0, mscale: float = 1.0,
-               mscale_all_dim: float = 0.0, dtype=jnp.float32):
+               mscale_all_dim: float = 0.0, dtype=jnp.float32,
+               attention_factor=None):
     """cos/sin tables [max_seq, dim//2] at YaRN frequencies, scaled by
     m(factor, mscale) / m(factor, mscale_all_dim) as the DeepSeek-V2
-    rotary embedding does (1 where the two are equal)."""
+    rotary embedding does (1 where the two are equal), or by
+    `attention_factor` where the configuration gives one (Hugging Face's
+    `_compute_yarn_parameters`: q and k both carry it, so a score carries
+    its square)."""
     inv = yarn_inv_freq(dim, base, factor, original_max, beta_fast, beta_slow)
-    m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+    m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim) \
+        if attention_factor is None else float(attention_factor)
     freqs = jnp.outer(jnp.arange(max_seq, dtype=jnp.float32), inv)
     return (jnp.cos(freqs) * m).astype(dtype), (jnp.sin(freqs) * m).astype(dtype)
 

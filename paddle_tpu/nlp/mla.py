@@ -68,6 +68,7 @@ class MlaMoeConfig:
     n_shared_experts: int = 1
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"        # the router's scores (moe.ROUTERS)
     experts_first: int = 0
     experts_count: Optional[int] = None
     max_position_embeddings: int = 4096

@@ -156,10 +156,29 @@ def sigmoid_top_k(h: jax.Array, router_w: jax.Array, k: int,
     return idx.astype(jnp.int32), top * scale
 
 
+def softmax_top_k(h: jax.Array, router_w: jax.Array, k: int,
+                  scale: float = 1.0, normalize: bool = True):
+    """`sigmoid_top_k` with softmax scores (the Mixtral / Qwen-MoE
+    router), float32 throughout: `p = softmax(h W_g)` over ALL routed
+    experts, `I = top-k(p)`, gates `scale * p_i / sum_{j in I} p_j` when
+    `normalize`, else `scale * p_i`."""
+    logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if normalize:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), top * scale
+
+
+ROUTERS = {"sigmoid": sigmoid_top_k, "softmax": softmax_top_k}
+
+
 def expert_share_ffn(h: jax.Array, lp: Dict[str, jax.Array], *, k: int,
                      first: int, scale: float = 1.0, normalize: bool = True,
                      valid: Optional[jax.Array] = None,
-                     layer=0, token_block: int = 1024):
+                     layer=0, token_block: int = 1024,
+                     score: str = "sigmoid"):
     """One chip's share of a routed-expert layer, DROPLESS: route every
     token of h [T, D] over all `lp["router"].shape[1]` experts, and
     compute `sum_{i in top-k, i held here} g_i E_i(h)` for the experts
@@ -170,6 +189,9 @@ def expert_share_ffn(h: jax.Array, lp: Dict[str, jax.Array], *, k: int,
     What absent experts would add is left out; no code stands in for
     their chips. The shared expert is the caller's
     (`generation._mlp_cached`).
+
+    `score` names the router's scoring function (`ROUTERS`: "sigmoid" |
+    "softmax"), float32 either way.
 
     The stack goes into the grouped GEMM whole, as Lm*n groups of which
     only this layer's n have rows. A scan that sliced the layer's experts
@@ -212,12 +234,12 @@ def expert_share_ffn(h: jax.Array, lp: Dict[str, jax.Array], *, k: int,
         vb = jnp.pad(valid, (0, pad)).reshape(nb, token_block)
         yb, sizes, full = jax.lax.map(
             lambda a: _share_block(a[0], lp, a[1], k, first, n, scale,
-                                   normalize, layer), (hb, vb))
+                                   normalize, layer, score), (hb, vb))
         y = yb.reshape(nb * token_block, D)[:T]
         sizes = jnp.sum(sizes, 0, dtype=jnp.int32)
     else:
         y, sizes, full = _share_block(h, lp, valid, k, first, n, scale,
-                                      normalize, layer)
+                                      normalize, layer, score)
     # int32 whatever jax_enable_x64 says: they ride a scan's carry
     stats = {"moe_pairs": jnp.sum(sizes, dtype=jnp.int32),
              "moe_experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32),
@@ -251,14 +273,15 @@ def _short_rows(pairs: int, held: int, routed: int) -> int:
     return (tiles + 1 - tiles % 2) * 128
 
 
-def _share_block(h, lp, valid, k, first, n, scale, normalize, layer):
+def _share_block(h, lp, valid, k, first, n, scale, normalize, layer,
+                 score="sigmoid"):
     """(y [T, D], pairs on each held expert [n], 1 if the local pairs
     overflowed one sorted buffer else 0) of one block of tokens."""
     T, D = h.shape
     cd = h.dtype
     S = min(_short_rows(T * k, n, lp["router"].shape[1]), T * k)
     with jax.named_scope("moe_router"):
-        idx, gates = sigmoid_top_k(h, lp["router"], k, scale, normalize)
+        idx, gates = ROUTERS[score](h, lp["router"], k, scale, normalize)
     with jax.named_scope("moe_dispatch"):
         local = (idx >= first) & (idx < first + n) & valid[:, None]
         # held expert of each pair, n for a pair that is not computed here

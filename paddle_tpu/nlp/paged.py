@@ -70,7 +70,14 @@ class PagedKVCache(NamedTuple):
     [L, N_blocks, block_size, kv_lora_rank + qk_rope_head_dim], a row
     `[c | k_r]` per token and layer that the attention reads once for
     keys and values alike, and v is None (None adds no leaves to a step
-    program's signature)."""
+    program's signature).
+
+    A KINDED pool (a configuration whose GQA layers are of two kinds,
+    `KVLayout`): k and v are [1, blocks, block_size, KV, hd], every
+    layer's blocks side by side in one array, the full layers' first and
+    the window layers' after them, and a table row is [M + R] wide: the
+    sequence's block chain for the full layers, then its ring of blocks
+    for the window layers."""
     k: jax.Array
     v: Optional[jax.Array]
     table: jax.Array
@@ -320,6 +327,100 @@ def _refuse_latent(weight_dtype=None, kv_dtype=None, speculative=False,
             "(with their exchange) are not built")
 
 
+def _refuse_kinded(weight_dtype=None, kv_dtype=None, speculative=False,
+                   mesh=None, prefix_cache=False) -> None:
+    """What the served path cannot do yet for GQA layers of two kinds
+    (window and full) over a kinded pool, or for experts on the GQA
+    mixer, refused at construction, each by its mechanism."""
+    if weight_dtype not in (None, "fp"):
+        raise NotImplementedError(
+            f"weight_dtype={weight_dtype!r}: weight-only quantization "
+            f"(generation.quantize_for_serving) knows the dense decoder's "
+            f"projections, not the router or the stacked experts")
+    if kvq.resolve_kv_dtype(kv_dtype) != "fp":
+        raise NotImplementedError(
+            f"kv_dtype={kv_dtype!r}: the kinded pool (window rings beside "
+            f"full chains, addressed in place) has no int8 form: a ring "
+            f"block's grow-only scale would outlive the keys it was set by")
+    if speculative:
+        raise NotImplementedError(
+            "speculative=True: the draft and verify programs "
+            "(_forward_spec, _spec_gqa_attention) read one uniform pool "
+            "with no lower bound on visibility; a window layer's suffix "
+            "slab and ring are not built")
+    if mesh is not None:
+        raise NotImplementedError(
+            "mesh: serving.tp's sharding table splits GQA heads of one "
+            "uniform pool and the dense MLP; the kinded pool, the window "
+            "form of the ragged kernel under shard_map and experts over a "
+            "mesh (with their exchange) are not built")
+    if prefix_cache:
+        raise NotImplementedError(
+            "prefix_cache=True: a hit shares a prefix's blocks of the full "
+            "layers, but a window layer's ring holds only the last W keys "
+            "of its own sequence; sharing needs the prefix's last W keys "
+            "copied into the new ring, which is not built")
+
+
+class KVLayout(NamedTuple):
+    """How the ONE pool of a configuration with two kinds of GQA layer is
+    laid out, and how wide each kind's part of a table row is. A FULL
+    layer keeps every key of a sequence: a chain of blocks from the
+    allocator, `width` (M) table entries. A WINDOW layer needs the last
+    W keys and keeps no more: a RING of `ring` (R) blocks a sequence, the
+    block of position p being `ring[(p // block_size) % R]`. Every
+    layer's blocks lie in one array, addressed in place (no layer slice
+    is copied out and back): full layer f's block b is row
+    `f * full_blocks + b`, window layer w's is row `full_layers *
+    full_blocks + w * window_blocks + b`."""
+    full_layers: int
+    window_layers: int
+    full_blocks: int        # per full layer: the allocator's capacity
+    window_blocks: int      # per window layer: the ring allocator's
+    width: int              # M: a row's chain entries
+    ring: int               # R: a row's ring entries
+
+    @property
+    def total_blocks(self) -> int:
+        return (self.full_layers * self.full_blocks
+                + self.window_layers * self.window_blocks)
+
+    def base(self, kind: str, index):
+        """First pool row of the `index`-th layer of its kind (`index`
+        may be traced)."""
+        if kind == "full":
+            return index * self.full_blocks
+        return self.full_layers * self.full_blocks \
+            + index * self.window_blocks
+
+    def table(self, kind: str, table):
+        """A kind's part of a table row [.., M + R]."""
+        return table[..., :self.width] if kind == "full" \
+            else table[..., self.width:]
+
+
+def ring_blocks(window: int, widest_chunk: int, block_size: int) -> int:
+    """Blocks of a window layer's ring: every key the earliest query of a
+    chunk may see (its own and the W - 1 before it) must survive the
+    chunk's own writes, which all land before any row attends: W +
+    chunk - 1 tokens, rounded up to blocks, plus one because neither end
+    need lie on a block boundary."""
+    return -(-(window + widest_chunk - 1) // block_size) + 1
+
+
+def _layer_kinds(cfg) -> Optional[Tuple[str, ...]]:
+    """One period of the configuration's layer kinds ("full" | "window")
+    where it declares them (`window_moe.WindowMoeConfig`), None for a
+    decoder whose layers are all alike and keep every key."""
+    return getattr(cfg, "period_kinds", None)
+
+
+def _has_experts(cfg) -> bool:
+    """Whether the GQA decoder's FFN is the held experts' share (a latent
+    decoder says it by its layer groups, `_layer_groups`)."""
+    return bool(getattr(cfg, "num_experts", 0))
+
+
 def _pow2_ceil(n: int) -> int:
     """Smallest power of two >= n (n >= 1)."""
     return 1 << max(0, (int(n) - 1).bit_length())
@@ -343,6 +444,7 @@ class _Admission(NamedTuple):
     fresh: List[int]
     inserted: List[int]
     chunks: List[Tuple[int, int, int]]   # (start, end, bucket) per chunk
+    ring: Sequence[int] = ()             # window layers' ring blocks
 
 
 def _is_latent(cfg) -> bool:
@@ -353,18 +455,24 @@ def _is_latent(cfg) -> bool:
 
 
 def init_pool(cfg, num_blocks: int, block_size: int,
-              kv_dtype: str = "fp"):
+              kv_dtype: str = "fp", layout: Optional[KVLayout] = None):
     """Zeroed K/V pools → (k, v, k_scale, v_scale). The fp pool stores
     the compute dtype with no scales (None); kv_dtype="int8" stores
     int8 codes plus zero-initialized [L, N] per-(layer, block) abs-max
     scales — scale 0 is the never-written sentinel that dequantizes to
-    the same exact zeros a fresh fp pool holds."""
+    the same exact zeros a fresh fp pool holds. `layout` (a kinded
+    configuration): one [1, layout.total_blocks, ...] array each."""
     if _is_latent(cfg):
         _refuse_latent(kv_dtype=kv_dtype)
         return jnp.zeros((cfg.num_hidden_layers, num_blocks, block_size,
                           cfg.kv_row_width), cfg.dtype), None, None, None
     L, KV, hd = (cfg.num_hidden_layers, cfg.num_key_value_heads,
                  cfg.head_dim)
+    if layout is not None:
+        _refuse_kinded(kv_dtype=kv_dtype)
+        z = jnp.zeros((1, layout.total_blocks, block_size, KV, hd),
+                      cfg.dtype)
+        return z, z, None, None
     if kvq.resolve_kv_dtype(kv_dtype) == "int8":
         z = jnp.zeros((L, num_blocks, block_size, KV, hd), jnp.int8)
         s = jnp.zeros((L, num_blocks), jnp.float32)
@@ -386,13 +494,17 @@ def build_table(allocator: BlockAllocator, lengths, max_len: int,
     return jnp.asarray(rows, jnp.int32), owned
 
 
-def _write_pool(pool, table, positions, new, valid):
+def _write_pool(pool, table, positions, new, valid, ring: bool = False):
     """Scatter new [B, P, KV, hd] rows into pool [N, bs, KV, hd] at
     per-request absolute positions [B, P] through the block table;
-    valid [B, P] masks padded slots (their writes drop)."""
+    valid [B, P] masks padded slots (their writes drop). `ring`: the
+    table is a ring, position p's block is `table[(p // bs) % width]`."""
     N, bs = pool.shape[0], pool.shape[1]
     B, P = positions.shape
-    blk = jnp.take_along_axis(table, positions // bs, axis=1)
+    at = positions // bs
+    if ring:
+        at = at % table.shape[1]
+    blk = jnp.take_along_axis(table, at, axis=1)
     flat = blk * bs + positions % bs
     flat = jnp.where(valid, flat, N * bs)          # dropped by mode="drop"
     poolf = pool.reshape(N * bs, *pool.shape[2:])
@@ -453,7 +565,8 @@ def _write_pool_int8(pool, scale, table, positions, new, valid):
 
 def _paged_gqa_attention(q, k_pool, v_pool, table, positions, valid=None,
                          impl: str = "xla", k_scale=None, v_scale=None,
-                         mesh=None, mesh_axis: str = "mp"):
+                         mesh=None, mesh_axis: str = "mp", window=None,
+                         ring: bool = False):
     """q [B, P, H, hd] against pool blocks gathered through the table.
     positions [B, P]: query p sees pool keys at absolute positions
     j <= positions[b, p] — per-query causal, so this one path serves
@@ -477,17 +590,38 @@ def _paged_gqa_attention(q, k_pool, v_pool, table, positions, valid=None,
 
     `mesh`/`mesh_axis` (pallas only) run the kernel shard_map-wrapped
     over the KV-head-sharded pool — the XLA path never needs them: its
-    einsums partition under plain GSPMD."""
+    einsums partition under plain GSPMD.
+
+    `window` (W, static; None = the program as it was) makes this a
+    sliding-window layer's attention: query p sees keys p - W < j <= p.
+    `ring`: the table is a ring of M blocks, chain block m in
+    `table[:, m % M]` (`KVLayout`); the gather then takes the M chain
+    blocks from the row's first visible one on."""
     if impl == "pallas":
         from .ragged_attention import ragged_paged_attention
         return ragged_paged_attention(q, k_pool, v_pool, table, positions,
                                       valid, k_scale=k_scale,
                                       v_scale=v_scale, mesh=mesh,
-                                      mesh_axis=mesh_axis)
+                                      mesh_axis=mesh_axis, window=window,
+                                      ring=ring)
     B, P, H, hd = q.shape
     N, bs, KV, _ = k_pool.shape
     M = table.shape[1]
     tb = jnp.clip(table, 0)
+    kpos = jnp.arange(M * bs)[None, None, :]            # a key's position
+    if ring:
+        # gathered slot i holds chain block lo + i, lo the block of the
+        # row's first visible key (a slot past the last written block
+        # holds an older block's keys: its positions lie in the future
+        # of every query and the causal bound masks them)
+        from .ragged_attention import first_visible_block
+        lo = first_visible_block(
+            positions, jnp.ones((B, P), bool) if valid is None else valid,
+            window, bs, axis=1)
+        chain = lo[:, None] + jnp.arange(M)[None, :]             # [B, M]
+        tb = jnp.take_along_axis(tb, chain % M, axis=1)
+        kpos = (chain[:, :, None] * bs + jnp.arange(bs)[None, None, :]
+                ).reshape(B, 1, M * bs)
     if k_scale is not None:
         # dequantize after the gather: [B, M] block scales broadcast
         # over each gathered block's [bs, KV, hd] codes (the reference
@@ -506,8 +640,10 @@ def _paged_gqa_attention(q, k_pool, v_pool, table, positions, valid=None,
     s = jnp.einsum("bpkrd,btkd->bkrpt", qg, k,
                    preferred_element_type=jnp.float32) / math.sqrt(hd)
     # [B, P, T] key-visibility per query → broadcast over (KV, rep)
-    vis = (jnp.arange(M * bs)[None, None, :] <= positions[:, :, None]
-           )[:, None, None, :, :]
+    vis = kpos <= positions[:, :, None]
+    if window is not None:
+        vis = vis & (kpos > positions[:, :, None] - window)
+    vis = vis[:, None, None, :, :]
     s = jnp.where(vis, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkrpt,btkd->bpkrd", p, v,
@@ -703,7 +839,8 @@ def _group_rows(x, groups):
 
 def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
                      attention_impl: str = "xla", pks=None, pvs=None,
-                     mesh=None, mesh_axis: str = "mp"):
+                     mesh=None, mesh_axis: str = "mp", tables=None,
+                     window=None, ring: bool = False):
     """One layer's attention over x, the packed tokens of `groups`
     (`_RowGroup`s; `_pack_rows` gives x's shape). The projections are
     per token: ONE dot each over all of x. RoPE, the pool write and the
@@ -713,10 +850,20 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
     in this very call. Returns (out shaped like x, pk', pv', pks',
     pvs') with the new tokens written into the pool: quantized on the
     commit write when pks/pvs carry this layer's int8 block scales
-    (None = fp pool, the unchanged path)."""
+    (None = fp pool, the unchanged path).
+
+    A kinded pool (`KVLayout`): pk and pv are the WHOLE pool, flat, and
+    `tables` each group's table for this layer, its kind's part of the
+    row with the layer's base added, so that the layer's blocks are
+    written and read in place; `window` / `ring` make it a window
+    layer's (`_paged_gqa_attention`). A cold chunk no longer than the
+    window sees all of itself and takes the flash path like any other;
+    a longer one attends through the table like a warm one."""
     H, KV, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                  cfg.head_dim)
     cd = cfg.dtype
+    if tables is None:
+        tables = [g.table for g in groups]
 
     def heads(y, n):
         return [r.reshape(*r.shape[:2], n, hd)
@@ -732,20 +879,22 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
         kq, vq = list(k), list(v)
         for i, g in enumerate(groups):
             if pks is None:
-                pk = _write_pool(pk, g.table, g.positions, k[i], g.valid)
-                pv = _write_pool(pv, g.table, g.positions, v[i], g.valid)
+                pk = _write_pool(pk, tables[i], g.positions, k[i], g.valid,
+                                 ring)
+                pv = _write_pool(pv, tables[i], g.positions, v[i], g.valid,
+                                 ring)
             else:
                 pk, pks, kq[i] = _write_pool_int8(
-                    pk, pks, g.table, g.positions, k[i], g.valid)
+                    pk, pks, tables[i], g.positions, k[i], g.valid)
                 pv, pvs, vq[i] = _write_pool_int8(
-                    pv, pvs, g.table, g.positions, v[i], g.valid)
+                    pv, pvs, tables[i], g.positions, v[i], g.valid)
                 # every consumer sees the quantize→dequantize roundtrip
                 # of this call's own writes — a later cached-prefix read
                 # of the same blocks sees the same KV values (warm ==
                 # cold by construction)
                 kq[i], vq[i] = kq[i].astype(cd), vq[i].astype(cd)
     with jax.named_scope("attn_kernel"):
-        if is_prefill:
+        if is_prefill and (window is None or q[0].shape[1] <= window):
             # the prompt attends only to itself: plain causal
             # self-attention over the right-padded batch (rows past
             # each request's length produce garbage that is never
@@ -766,9 +915,9 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
             # decode AND cached-prefix suffix prefill: gather through
             # the table with per-query causal visibility (j <= position)
             outs = [_paged_gqa_attention(
-                q[i], pk, pv, g.table, g.positions, g.valid,
+                q[i], pk, pv, tables[i], g.positions, g.valid,
                 impl=attention_impl, k_scale=pks, v_scale=pvs,
-                mesh=mesh, mesh_axis=mesh_axis)
+                mesh=mesh, mesh_axis=mesh_axis, window=window, ring=ring)
                 for i, g in enumerate(groups)]
         outs = [o.reshape(*o.shape[:2], H * hd) for o in outs]
     with jax.named_scope("attn_out"):
@@ -834,10 +983,12 @@ _EXPERT_STACKS = ("experts_gate", "experts_up", "experts_down")
 def _ffn_experts(x, h, lp, cfg, groups, stats, stacks, layer):
     """The FFN of a sparse-expert layer on the packed tokens: the share of
     the routed experts held here (`moe.expert_share_ffn`: dropless, the
-    padding rows masked out of routing) plus the shared expert, onto the
-    residual. `stacks` holds the held experts' matrices for ALL expert
-    layers (`layer` picks this one inside the grouped GEMM: the scan does
-    not slice them). `stats` adds up the layer's routing counters."""
+    padding rows masked out of routing, the router's scoring function the
+    configuration's) plus the shared expert where the configuration has
+    one, onto the residual. `stacks` holds the held experts' matrices for
+    ALL expert layers (`layer` picks this one inside the grouped GEMM:
+    the scan does not slice them). `stats` adds up the layer's routing
+    counters."""
     from . import moe
     D = h.shape[-1]
     tok_valid = _pack_rows([g.valid for g in groups]).reshape(-1)
@@ -845,9 +996,11 @@ def _ffn_experts(x, h, lp, cfg, groups, stats, stacks, layer):
         h.reshape(-1, D), {"router": lp["router"], **stacks},
         k=cfg.num_experts_per_tok, first=cfg.experts_first,
         scale=cfg.routed_scaling_factor, normalize=cfg.norm_topk_prob,
-        valid=tok_valid, layer=layer)
+        valid=tok_valid, layer=layer, score=cfg.scoring_func)
     with jax.named_scope("moe_shared"):
-        x = x + y.reshape(h.shape) + _mlp_cached(h, lp, cfg)
+        x = x + y.reshape(h.shape)
+        if cfg.n_shared_experts:
+            x = x + _mlp_cached(h, lp, cfg)
     return x, _merge_stats(stats, st)
 
 
@@ -861,22 +1014,27 @@ def _merge_stats(a, b):
 
 def _layer_groups(params, cfg):
     """The decoder as the configuration describes it: (stacked layers,
-    FFN kind) in order, each a scan of one body. A dense GQA decoder is
-    one group; an MLA + sparse-expert decoder is its leading dense
-    layers, then its expert layers."""
+    FFN kind, one period of layer kinds or None) in order, each a scan
+    of one body. Mixer and FFN are independent: a dense GQA decoder is
+    one group; a GQA decoder with expert layers is one too, and where
+    its layers are of several kinds (window and full) a scan step is one
+    whole PERIOD of them, the stack reshaped to [periods, period, ...];
+    an MLA + sparse-expert decoder is its leading dense layers, then its
+    expert layers."""
     if not _is_latent(cfg):
-        return [(params["layers"], "dense")]
+        return [(params["layers"], "moe" if _has_experts(cfg) else "dense",
+                 _layer_kinds(cfg))]
     out = []
     if cfg.first_k_dense_replace:
-        out.append((params["dense_layers"], "dense"))
+        out.append((params["dense_layers"], "dense", None))
     if cfg.num_moe_layers:
-        out.append((params["moe_layers"], "moe"))
+        out.append((params["moe_layers"], "moe", None))
     return out
 
 
 def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
                     attention_impl: str = "xla", mesh=None,
-                    mesh_axis: str = "mp"):
+                    mesh_axis: str = "mp", layout: Optional[KVLayout] = None):
     """THE paged layer stack, over the packed tokens of one or more row
     groups (`_RowGroup`s sharing one pool and one table width). What is
     per token — embedding, RMSNorm, the projections, the FFN — runs on
@@ -886,27 +1044,34 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
     what is per row — RoPE positions, the pool write, the attention —
     runs once per group at the group's own [G, P] shape
     (`_attention_paged`, `_attention_latent`). The block is read from
-    the configuration: the mixer (`_is_latent`: GQA over K and V pools,
-    or MLA over one latent pool) and, per group of layers
-    (`_layer_groups`), the FFN (a dense MLP or the held experts'
-    share). `pools` is (k, v, k_scale, v_scale) stacked over layers (a
-    latent pool: (rows, None, None, None)). Returns (x, the packed
-    hidden states before the final norm; pools'; the forward's counters:
-    the expert layers' routing and, where the latent kernel runs, the
-    work items one layer's calls walked (`attn_work_steps`); None where
-    there are none)."""
+    the configuration, each choice on its own: the MIXER (`_is_latent`:
+    MLA over one latent pool, or GQA over K and V pools; GQA layers all
+    alike over a pool stacked by layer, or of two kinds, window and
+    full, over the kinded pool that `layout` describes, `_layer_kinds`),
+    and, per group of layers (`_layer_groups`), the FFN (a dense MLP, or
+    the held experts' share with or without a shared expert). `pools` is
+    (k, v, k_scale, v_scale) stacked over layers (a latent pool: (rows,
+    None, None, None); a kinded pool: (k, v, None, None), each [1,
+    blocks, ...]). Returns (x, the packed hidden states before the final
+    norm; pools'; the forward's counters: the expert layers' routing
+    and, where the latent kernel runs, the work items one layer's calls
+    walked (`attn_work_steps`); None where there are none)."""
     cd = cfg.dtype
     latent = _is_latent(cfg)
     k_all, v_all, ks_all, vs_all = pools
     # rope spans the per-request table width (max reachable position),
     # NOT the whole pool — the pool is ~B x larger by construction
-    T_rope = groups[0].table.shape[1] * k_all.shape[2]
+    T_rope = (groups[0].table.shape[1] if layout is None else layout.width) \
+        * k_all.shape[2]
     with jax.named_scope("embed"):
         x = jnp.take(params["embed_tokens"],
                      _pack_rows([g.tokens for g in groups]),
                      axis=0).astype(cd)
     if latent:
         cos, sin = cfg.rope_tables(T_rope)
+    elif layout is not None:
+        # one (cos, sin) pair a layer kind, built once a forward
+        ropes = cfg.rope_tables(T_rope)
     else:
         cos, sin = rope_freqs(cfg.head_dim, T_rope, cfg.rope_theta,
                               jnp.float32)
@@ -920,55 +1085,83 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
                                       k_all.shape[2], attention_impl)
                  for g in groups]
 
-    def make_body(ffn, stacks=None, first_layer=0):
-        def body(carry, lp):
-            # ks_all/vs_all are the [L, N] scale pools in int8-KV mode and
-            # None for fp, as stats is for a decoder without expert
-            # layers — a None traces to the exact jaxpr without it (None
-            # adds no carry leaves), keeping the fp GQA path byte-identical
-            x, pk_all, pv_all, ks_all, vs_all, li, stats = carry
-            if latent:
-                return latent_body(x, pk_all, li, stats, lp)
-            with jax.named_scope("kv_pool_read"):
-                pk = lax.dynamic_slice_in_dim(pk_all, li, 1, 0)[0]
-                pv = lax.dynamic_slice_in_dim(pv_all, li, 1, 0)[0]
-                ks = None if ks_all is None else \
-                    lax.dynamic_slice_in_dim(ks_all, li, 1, 0)[0]
-                vs = None if vs_all is None else \
-                    lax.dynamic_slice_in_dim(vs_all, li, 1, 0)[0]
+    def mix_gqa(x, pools, li, lp):
+        # GQA layers all alike: the layer's pool is sliced out of the
+        # stack over layers and written back (ROADMAP S1).
+        # ks_all/vs_all are the [L, N] scale pools in int8-KV mode and
+        # None for fp, as stats is for a decoder without expert
+        # layers — a None traces to the exact jaxpr without it (None
+        # adds no carry leaves), keeping the fp GQA path byte-identical
+        pk_all, pv_all, ks_all, vs_all = pools
+        with jax.named_scope("kv_pool_read"):
+            pk = lax.dynamic_slice_in_dim(pk_all, li, 1, 0)[0]
+            pv = lax.dynamic_slice_in_dim(pv_all, li, 1, 0)[0]
+            ks = None if ks_all is None else \
+                lax.dynamic_slice_in_dim(ks_all, li, 1, 0)[0]
+            vs = None if vs_all is None else \
+                lax.dynamic_slice_in_dim(vs_all, li, 1, 0)[0]
+        with jax.named_scope("attn_qkv"):
+            h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
+        a, pk, pv, ks, vs = _attention_paged(
+            h, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
+            attention_impl, ks, vs, mesh=mesh, mesh_axis=mesh_axis)
+        with jax.named_scope("kv_pool_write"):
+            pk_all = lax.dynamic_update_slice_in_dim(pk_all, pk[None],
+                                                     li, 0)
+            pv_all = lax.dynamic_update_slice_in_dim(pv_all, pv[None],
+                                                     li, 0)
+            if ks_all is not None:
+                ks_all = lax.dynamic_update_slice_in_dim(
+                    ks_all, ks[None], li, 0)
+                vs_all = lax.dynamic_update_slice_in_dim(
+                    vs_all, vs[None], li, 0)
+        return a, (pk_all, pv_all, ks_all, vs_all)
+
+    def mix_gqa_kinded(x, pools, li, kinds, at, lp):
+        # GQA layers of two kinds over the kinded pool, this one the
+        # `at`-th of its period: the layer's blocks are written and read
+        # IN the one flat pool (block ids offset by the layer's base, the
+        # layer counted among those of its kind); a window layer through
+        # its ring with its own RoPE table and the window's bound
+        kind = kinds[at]
+        pk, pv = pools[0][0], pools[1][0]
+        base = layout.base(kind, (li // len(kinds)) * kinds.count(kind)
+                           + kinds[:at].count(kind))
+        window = cfg.sliding_window if kind == "window" else None
+        with jax.named_scope("attn_" + kind):
             with jax.named_scope("attn_qkv"):
                 h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
-            a, pk, pv, ks, vs = _attention_paged(
-                h, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
-                attention_impl, ks, vs, mesh=mesh, mesh_axis=mesh_axis)
-            with jax.named_scope("kv_pool_write"):
-                pk_all = lax.dynamic_update_slice_in_dim(pk_all, pk[None],
-                                                         li, 0)
-                pv_all = lax.dynamic_update_slice_in_dim(pv_all, pv[None],
-                                                         li, 0)
-                if ks_all is not None:
-                    ks_all = lax.dynamic_update_slice_in_dim(
-                        ks_all, ks[None], li, 0)
-                    vs_all = lax.dynamic_update_slice_in_dim(
-                        vs_all, vs[None], li, 0)
-            with jax.named_scope("mlp"):
-                x = x + a
-                h = rms_norm_ref(x, lp["post_attention_layernorm"],
-                                 cfg.rms_norm_eps)
-                x = x + _mlp_cached(h, lp, cfg)
-            return (x, pk_all, pv_all, ks_all, vs_all, li + 1, stats), None
+            a, pk, pv, _, _ = _attention_paged(
+                h, lp, cfg, *ropes[kind], pk, pv, groups, is_prefill,
+                attention_impl,
+                tables=[layout.table(kind, g.table) + base for g in groups],
+                window=window, ring=window is not None)
+        return a, (pk[None], pv[None], None, None)
 
-        def latent_body(x, pool_all, li, stats, lp):
-            # the layer's blocks are written and read IN the stacked pool
-            # (block ids offset by li * N): no layer slice is copied out
-            # and back, and no V pool or scales exist
-            L, N = pool_all.shape[:2]
-            with jax.named_scope("mla_q"):
-                h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
-            a, pool = _attention_latent(
-                h, lp, cfg, cos, sin,
-                pool_all.reshape(L * N, *pool_all.shape[2:]), groups,
-                is_prefill, attention_impl, base=li * N, works=works)
+    def mix_latent(x, pools, li, lp):
+        # the layer's blocks are written and read IN the stacked pool
+        # (block ids offset by li * N): no layer slice is copied out
+        # and back, and no V pool or scales exist
+        pool_all = pools[0]
+        L, N = pool_all.shape[:2]
+        with jax.named_scope("mla_q"):
+            h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
+        a, pool = _attention_latent(
+            h, lp, cfg, cos, sin,
+            pool_all.reshape(L * N, *pool_all.shape[2:]), groups,
+            is_prefill, attention_impl, base=li * N, works=works)
+        return a, (pool.reshape(pool_all.shape), None, None, None)
+
+    def make_body(ffn, stacks=None, first_layer=0, kinds=None):
+        def layer(x, pools, li, stats, lp, at=None):
+            # one layer: its mixer, then its FFN; `at`: its place in the
+            # period where the layers are of several kinds
+            if latent:
+                a, pools = mix_latent(x, pools, li, lp)
+            elif layout is None:
+                a, pools = mix_gqa(x, pools, li, lp)
+            else:
+                a, pools = mix_gqa_kinded(x, pools, li, kinds, at, lp)
             with jax.named_scope("mlp"):
                 x = x + a
                 h = rms_norm_ref(x, lp["post_attention_layernorm"],
@@ -978,28 +1171,45 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
             if ffn == "moe":
                 x, stats = _ffn_experts(x, h, lp, cfg, groups, stats,
                                         stacks, li - first_layer)
-            return (x, pool.reshape(pool_all.shape), None, None, None,
-                    li + 1, stats), None
+            return x, pools, stats
+
+        def body(carry, lp):
+            x, pk_all, pv_all, ks_all, vs_all, li, stats = carry
+            pools = (pk_all, pv_all, ks_all, vs_all)
+            if kinds is None or len(kinds) == 1:
+                x, pools, stats = layer(x, pools, li, stats, lp, 0)
+                return (x, *pools, li + 1, stats), None
+            # one whole period a scan step, its layers in order
+            for at in range(len(kinds)):
+                x, pools, stats = layer(
+                    x, pools, li + at, stats,
+                    jax.tree_util.tree_map(lambda w: w[at], lp), at)
+            return (x, *pools, li + len(kinds), stats), None
 
         return body
 
     stats = None
-    if latent and cfg.num_moe_layers:
+    if (latent and cfg.num_moe_layers) or (not latent and _has_experts(cfg)):
         z = jnp.zeros((), jnp.int32)
         stats = {"moe_pairs": z, "moe_experts_hit": z, "moe_load_max": z,
                  "moe_full_passes": z}
     carry = (x, k_all, v_all, ks_all, vs_all, jnp.int32(0), stats)
     first_layer = 0
-    for layers, ffn in _layer_groups(params, cfg):
+    for layers, ffn, kinds in _layer_groups(params, cfg):
         stacks = None
         if ffn == "moe":
             # the experts' stacks stay whole, outside the scanned leaves
             stacks = {k: layers[k] for k in _EXPERT_STACKS}
             layers = {k: v for k, v in layers.items()
                       if k not in _EXPERT_STACKS}
-        carry, _ = lax.scan(make_body(ffn, stacks, first_layer), carry,
-                            layers)
-        first_layer += jax.tree_util.tree_leaves(layers)[0].shape[0]
+        n_layers = jax.tree_util.tree_leaves(layers)[0].shape[0]
+        if kinds is not None and len(kinds) > 1:
+            layers = jax.tree_util.tree_map(
+                lambda w: w.reshape(n_layers // len(kinds), len(kinds),
+                                    *w.shape[1:]), layers)
+        carry, _ = lax.scan(make_body(ffn, stacks, first_layer, kinds),
+                            carry, layers)
+        first_layer += n_layers
     x, pk, pv, ks, vs, _, stats = carry
     if works[0] is not None:
         # the items ONE layer's kernel calls walked (every layer walks
@@ -1011,7 +1221,8 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
 
 def forward_paged(params, tokens, cache: PagedKVCache, positions, valid,
                   cfg, is_prefill: bool, attention_impl: str = "xla",
-                  mesh=None, mesh_axis: str = "mp"):
+                  mesh=None, mesh_axis: str = "mp",
+                  layout: Optional[KVLayout] = None):
     """tokens [B, P] at per-request absolute `positions` [B, P] →
     (logits [B, P, V] f32, cache'): the ONE-group call of
     `_forward_groups` (the plain decode chunk, the standalone prefill,
@@ -1024,21 +1235,22 @@ def forward_paged(params, tokens, cache: PagedKVCache, positions, valid,
     which shards under plain GSPMD)."""
     logits, cache, _ = _forward_paged_stats(
         params, tokens, cache, positions, valid, cfg, is_prefill,
-        attention_impl, mesh=mesh, mesh_axis=mesh_axis)
+        attention_impl, mesh=mesh, mesh_axis=mesh_axis, layout=layout)
     return logits, cache
 
 
 def _forward_paged_stats(params, tokens, cache: PagedKVCache, positions,
                          valid, cfg, is_prefill: bool,
                          attention_impl: str = "xla", mesh=None,
-                         mesh_axis: str = "mp"):
+                         mesh_axis: str = "mp",
+                         layout: Optional[KVLayout] = None):
     """`forward_paged` with the expert layers' routing counters as a third
     result (None for a decoder without expert layers): what the step
     programs call."""
     x, (pk, pv, ks, vs), stats = _forward_groups(
         params, (_RowGroup(tokens, cache.table, positions, valid),),
         (cache.k, cache.v, cache.k_scale, cache.v_scale), cfg, is_prefill,
-        attention_impl, mesh=mesh, mesh_axis=mesh_axis)
+        attention_impl, mesh=mesh, mesh_axis=mesh_axis, layout=layout)
     with jax.named_scope("lm_head"):
         logits = _final_head_cached(params, x, cfg)
     new_len = jnp.maximum(cache.lengths, positions[:, -1] + 1)
@@ -1245,7 +1457,8 @@ class _Tick:
             wait_s=st["wait"], commit_s=st["commit"],
             t_dispatch=self.t_dispatch, t_synced=self.t_synced,
             synced=self.t_synced is not None,
-            live_after=sum(cb.active), **self.noted)
+            live_after=sum(cb.active), **cb._kv_blocks_in_use(),
+            **self.noted)
         device_s = self.device_s
         if device_s is not None:
             cb.profiler.record(
@@ -1403,9 +1616,15 @@ class ContinuousBatcher:
                  profile_sample_every: int = 64,
                  fault_injector=None, replica_id: str = "r0",
                  mesh=None, max_prefill_group: Optional[int] = None):
+        kinds = _layer_kinds(cfg)       # None for a latent decoder too
         if _is_latent(cfg):
             _refuse_latent(weight_dtype=weight_dtype, kv_dtype=kv_dtype,
                            speculative=speculative, mesh=mesh)
+        elif kinds is not None or _has_experts(cfg):
+            _refuse_kinded(weight_dtype=weight_dtype, kv_dtype=kv_dtype,
+                           speculative=speculative, mesh=mesh,
+                           prefix_cache=prefix_cache and kinds is not None
+                           and "window" in kinds)
         # multi-replica attribution: stamped on every `prepared` trace
         # event so a Router's merged trace artifact (and
         # tools/trace_report.py's per-replica grouping) can tell which
@@ -1652,6 +1871,32 @@ class ContinuousBatcher:
         self._trace = trace
         self.flight = FlightRecorder(cap=flight_recorder_cap)
         nb = num_blocks or (max_batch * self.M)
+        # two kinds of GQA layer (window and full) share ONE pool and ONE
+        # table row a slot (`KVLayout`): the full layers' chains come
+        # from `alloc` as ever, the window layers' rings from `walloc`,
+        # whose capacity gives every slot a whole ring, so that only the
+        # full kind can ever defer an admission
+        layout = None
+        if kinds is not None:
+            if not self._buckets:
+                raise ValueError(
+                    "layers of several kinds need a prefill bucket ladder: "
+                    "the widest chunk sizes the window layers' ring")
+            L = cfg.num_hidden_layers
+            n_win = L // len(kinds) * kinds.count("window")
+            ring = ring_blocks(cfg.sliding_window, self._buckets[-1],
+                               block_size) if n_win else 0
+            ring = min(ring, self.M)    # no sequence outgrows its table
+            layout = KVLayout(
+                full_layers=L - n_win, window_layers=n_win, full_blocks=nb,
+                window_blocks=max_batch * ring,
+                width=self.M, ring=ring)
+        # ptlint: memo-invariant(pool geometry is fixed at construction)
+        self._layout = layout
+        self.walloc = BlockAllocator(layout.window_blocks) \
+            if layout is not None and layout.window_layers else None
+        # ptlint: memo-invariant(pool geometry is fixed at construction)
+        self._table_width = self.M + (layout.ring if layout else 0)
         if prefix_cache:
             # vLLM-style automatic prefix caching: a trie over full-block
             # token contents + a refcounted pool, so admissions sharing a
@@ -1670,15 +1915,17 @@ class ContinuousBatcher:
             self._pcache = None
             self.alloc = BlockAllocator(nb)
         kp, vp, ksc, vsc = init_pool(cfg, nb, block_size,
-                                     kv_dtype=self.kv_dtype)
+                                     kv_dtype=self.kv_dtype,
+                                     layout=self._layout)
         self.cache = PagedKVCache(
-            kp, vp, jnp.zeros((max_batch, self.M), jnp.int32),
+            kp, vp, jnp.zeros((max_batch, self._table_width), jnp.int32),
             jnp.zeros((max_batch,), jnp.int32), ksc, vsc)
         if self._mesh is not None:
             self.cache = self._pin_cache_shardings(self.cache)
         self.active = [False] * max_batch
         self.slot_req: List[Optional[int]] = [None] * max_batch
         self.slot_blocks: List[Optional[List[int]]] = [None] * max_batch
+        self.slot_ring: List[Optional[List[int]]] = [None] * max_batch
         self.slot_tokens: List[Optional[List[int]]] = [None] * max_batch
         self.budget = [0] * max_batch
         self.stop = [-1] * max_batch          # per-slot stop id (-1 = none)
@@ -1747,6 +1994,10 @@ class ContinuousBatcher:
         comparison stays exact either way."""
         mn = self.max_new if max_new_tokens is None else int(max_new_tokens)
         need = -(-(prompt_len + mn) // self.bs)
+        # a kinded pool: this is the FULL layers' chain, the kind whose
+        # pool can run out; the window layers' ring is
+        # `ring_blocks_needed`, bounded a slot and provisioned for every
+        # slot, and `_drain_queue` holds an admission to both
         if tokens is not None and self._pcache is not None:
             matched, _, _ = self._match_cached(list(tokens))
             need -= sum(1 for b in matched if self.alloc.refcount(b) > 0)
@@ -1758,23 +2009,74 @@ class ContinuousBatcher:
         # below is the single source for that, scale overhead included.
         return need
 
+    def ring_blocks_needed(self, prompt_len: int,
+                           max_new_tokens: Optional[int] = None) -> int:
+        """Blocks of the window layers' ring a request of this shape
+        holds while in flight: its whole length in blocks, at most the
+        ring (`KVLayout.ring`), however long it grows; 0 where no layer
+        has a window."""
+        if self.walloc is None:
+            return 0
+        mn = self.max_new if max_new_tokens is None else int(max_new_tokens)
+        return min(-(-(prompt_len + mn) // self.bs), self._layout.ring)
+
+    def alloc_stats(self) -> Dict[str, int]:
+        """The allocator's `stats()`, of both kinds of block where the
+        pool is kinded: the full layers' chains under the usual names,
+        the window layers' rings under `window_*`."""
+        out = dict(self.alloc.stats())
+        if self.walloc is not None:
+            out.update({"window_" + k: v
+                        for k, v in self.walloc.stats().items()})
+        return out
+
+    def _kv_blocks_in_use(self) -> Dict[str, int]:
+        """Blocks in use of each kind, for a tick's flight record; empty
+        where the pool is of one kind."""
+        if self._layout is None:
+            return {}
+        return {"kv_full_blocks": self.alloc.stats()["blocks_in_use"],
+                "kv_window_blocks": 0 if self.walloc is None
+                else self.walloc.stats()["blocks_in_use"]}
+
     # -- quantized-serving byte accounting --------------------------------
+    def _kv_row_bytes(self) -> int:
+        """K and V of one token in one GQA layer, as the pool holds them."""
+        cfg = self.cfg
+        return (2 * cfg.num_key_value_heads * cfg.head_dim
+                * jnp.dtype(cfg.dtype).itemsize)
+
     def kv_block_bytes(self) -> int:
         """HBM bytes ONE pool block occupies (all layers, K+V pools,
         int8 scale-pool overhead included) — quantization.kv's
-        kv_block_bytes under this batcher's geometry and kv_dtype."""
+        kv_block_bytes under this batcher's geometry and kv_dtype. In a
+        kinded pool: one block of a sequence's CHAIN, over the full
+        layers that keep one (a ring's bytes: `kv_ring_bytes`)."""
         cfg = self.cfg
         if _is_latent(cfg):
             from . import mla
             return mla.kv_block_bytes(cfg, self.bs)
+        if self._layout is not None:
+            return self._layout.full_layers * self.bs * self._kv_row_bytes()
         return kvq.kv_block_bytes(
             cfg.num_hidden_layers, self.bs, cfg.num_key_value_heads,
             cfg.head_dim, self.kv_dtype,
             fp_itemsize=jnp.dtype(cfg.dtype).itemsize)
 
+    def kv_ring_bytes(self) -> int:
+        """HBM bytes the window layers hold for ONE sequence at most, its
+        whole ring over those layers; 0 where no layer has a window."""
+        if self.walloc is None:
+            return 0
+        lay = self._layout
+        return lay.window_layers * lay.ring * self.bs * self._kv_row_bytes()
+
     def kv_pool_bytes(self) -> int:
         """Total KV pool footprint: capacity blocks x kv_block_bytes()
-        — equals the device arrays' nbytes sum (asserted in tests)."""
+        (a kinded pool: both kinds' blocks) — equals the device arrays'
+        nbytes sum (asserted in tests)."""
+        if self._layout is not None:
+            return self._layout.total_blocks * self.bs * self._kv_row_bytes()
         return self.alloc.num_blocks * self.kv_block_bytes()
 
     def kv_cached_bytes(self) -> int:
@@ -1787,7 +2089,8 @@ class ContinuousBatcher:
         """HBM bytes one cached token costs (and one decode-step gather
         moves per live token): kv_block_bytes / block_size.
         tests/test_quantized_serving.py holds int8 <= 0.55x fp on this
-        number."""
+        number. In a kinded pool: what a token costs once the sequence
+        is longer than the ring, the full layers' rows alone."""
         return self.kv_block_bytes() / self.bs
 
     def weight_bytes(self) -> int:
@@ -1961,6 +2264,11 @@ class ContinuousBatcher:
                 f"{what}: serving.kvtransfer.KVSnapshot carries a K and a "
                 f"V pool slice per block; the latent (MLA) pool is one "
                 f"array and has no snapshot form yet")
+        if self._layout is not None:
+            raise NotImplementedError(
+                f"{what}: serving.kvtransfer.KVSnapshot carries one chain "
+                f"of blocks over every layer; a kinded pool's window "
+                f"rings have no snapshot form yet")
 
     def kv_fingerprint(self) -> Dict[str, Any]:
         """Model/pool-shape identity a KVSnapshot must match to be
@@ -2292,7 +2600,7 @@ class ContinuousBatcher:
         positions against the shared pool. Pure — compile bookkeeping
         lives host-side in `_prefill_exe` (TRACE001)."""
         cfg, impl = self.cfg, self.attention_impl
-        mesh, max_ = self._mesh, self._mesh_axis()
+        mesh, max_, layout = self._mesh, self._mesh_axis(), self._layout
 
         def serve_prefill_step(params, rows, k, v, ks, vs, table, positions,
                                valid, lengths):
@@ -2300,7 +2608,7 @@ class ContinuousBatcher:
             logits, sub = forward_paged(params, rows, sub, positions,
                                         valid, cfg, is_prefill=cold,
                                         attention_impl=impl, mesh=mesh,
-                                        mesh_axis=max_)
+                                        mesh_axis=max_, layout=layout)
             return logits, sub.k, sub.v, sub.k_scale, sub.v_scale
 
         return jax.jit(serve_prefill_step)
@@ -2326,7 +2634,7 @@ class ContinuousBatcher:
                 self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
                 self._scale_aval(self.cache.k_scale),
                 self._scale_aval(self.cache.v_scale),
-                sds((G, self.M), i32), sds((G, Pb), i32),
+                sds((G, self._table_width), i32), sds((G, Pb), i32),
                 sds((G, Pb), jnp.bool_), sds((G,), i32)).compile()
             self._prefill_cache[key] = exe
         return exe
@@ -2491,6 +2799,15 @@ class ContinuousBatcher:
             if pinned:
                 self.alloc.release(pinned)
             raise
+        # the window layers' ring: blocks of its own kind, never shared
+        # and never more than the ring however long the sequence
+        ring: Sequence[int] = ()
+        if self.walloc is not None:
+            try:
+                ring = self.walloc.allocate(self.ring_blocks_needed(P, mn))
+            except Exception:
+                self.alloc.release(fresh + pinned)
+                raise
         if self.kv_dtype == "int8" and fresh:
             # a recycled block keeps its previous tenant's scale (free
             # is host-side bookkeeping); writing under that inflated
@@ -2542,7 +2859,7 @@ class ContinuousBatcher:
                              mesh_tp=(1 if self._mesh_cfg is None
                                       else int(self._mesh_cfg.tp)))
         return _Admission(slot, rid, list(toks), stop, mn, need, matched,
-                          cached_len, cow_src, fresh, inserted, chunks)
+                          cached_len, cow_src, fresh, inserted, chunks, ring)
 
     def _rollback(self, recs: Sequence[_Admission]) -> None:
         """Undo prepared-but-uncommitted admissions after a failed
@@ -2554,6 +2871,8 @@ class ContinuousBatcher:
                 for b in rec.inserted:
                     self._pcache.unlink(b)
             self.alloc.release(rec.fresh)
+            if rec.ring:
+                self.walloc.release(list(rec.ring))
             pinned = rec.matched + ([rec.cow_src]
                                     if rec.cow_src is not None else [])
             if pinned:
@@ -2571,7 +2890,7 @@ class ContinuousBatcher:
         rows = np.zeros((Gp, Pb), np.int32)
         pos = np.zeros((Gp, Pb), np.int32)
         val = np.zeros((Gp, Pb), np.bool_)
-        tab = np.zeros((Gp, self.M), np.int32)
+        tab = np.zeros((Gp, self._table_width), np.int32)
         li = np.zeros((Gp,), np.int32)
         real = 0
         maxpos = self.M * self.bs - 1
@@ -2582,6 +2901,7 @@ class ContinuousBatcher:
             pos[g] = np.minimum(np.arange(start, start + Pb), maxpos)
             val[g, :S] = True
             tab[g, :rec.need] = rec.matched + rec.fresh
+            tab[g, self.M:self.M + len(rec.ring)] = rec.ring
             li[g] = S - 1
         self.prefill_pad_tokens += Gp * Pb - real
         return rows, pos, val, tab, li
@@ -2704,7 +3024,8 @@ class ContinuousBatcher:
             if rec.inserted:
                 self.alloc.mark_cached(rec.inserted)
         owned = rec.matched + rec.fresh
-        blocks = owned + [0] * (self.M - rec.need)
+        blocks = owned + [0] * (self.M - rec.need) + list(rec.ring)
+        blocks += [0] * (self._table_width - len(blocks))
         self.cache = self.cache._replace(
             table=self.cache.table.at[rec.slot].set(
                 jnp.asarray(blocks, jnp.int32)),
@@ -2713,6 +3034,7 @@ class ContinuousBatcher:
         self.active[rec.slot] = True
         self.slot_req[rec.slot] = rec.rid
         self.slot_blocks[rec.slot] = owned
+        self.slot_ring[rec.slot] = list(rec.ring)
         self.slot_tokens[rec.slot] = list(rec.toks)
         self.budget[rec.slot] = rec.mn - 1
         self.stop[rec.slot] = rec.stop
@@ -3068,10 +3390,13 @@ class ContinuousBatcher:
             self.alloc.release(list(reversed(blocks)))
         else:
             self.alloc.free(blocks)
+        if self.slot_ring[slot]:
+            self.walloc.free(self.slot_ring[slot])
         self._just_finished.append(rid)
         self.active[slot] = False
         self.slot_req[slot] = None
         self.slot_blocks[slot] = None
+        self.slot_ring[slot] = None
         self.slot_tokens[slot] = None
         self.stop[slot] = -1
         self._dev_state = None        # host slot state diverged from device
@@ -3098,7 +3423,10 @@ class ContinuousBatcher:
                 # (and registered their prompts), so the head-of-line
                 # check and the trie walk both see them.
                 need = self.blocks_needed(len(toks0), mn0, tokens=toks0)
-                if need > self.alloc.free_blocks:
+                if need > self.alloc.free_blocks or (
+                        self.walloc is not None
+                        and self.ring_blocks_needed(len(toks0), mn0)
+                        > self.walloc.free_blocks):
                     if (not any(self.active) and not recs
                             and not self._pending):
                         # nothing in flight will ever free blocks
@@ -3156,7 +3484,7 @@ class ContinuousBatcher:
         """The one traced single-token decode step, shared by the plain
         decode chunk AND the fused chunk's post-first-token scan."""
         cfg, impl = self.cfg, self.attention_impl
-        mesh, max_ = self._mesh, self._mesh_axis()
+        mesh, max_, layout = self._mesh, self._mesh_axis(), self._layout
 
         def step(carry, _):
             cache, tok, lengths, budget, act = carry
@@ -3164,7 +3492,7 @@ class ContinuousBatcher:
             logits, cache, stats = _forward_paged_stats(
                 params, tok[:, None], cache, pos, act[:, None],
                 cfg, is_prefill=False, attention_impl=impl, mesh=mesh,
-                mesh_axis=max_)
+                mesh_axis=max_, layout=layout)
             nxt, lengths, budget, act = self._emit_one(
                 logits[:, 0], tok, act, lengths, budget, stop)
             # inactive slots must not drift: pin lengths ourselves
@@ -3235,7 +3563,7 @@ class ContinuousBatcher:
         program with a larger `Gp`."""
         cfg, chunk, B = self.cfg, self.chunk, self.B
         impl = self.attention_impl
-        mesh, max_ = self._mesh, self._mesh_axis()
+        mesh, max_, layout = self._mesh, self._mesh_axis(), self._layout
 
         def serve_fused_step(params, k, v, ks, vs, table, lengths, tok,
                              active, budget, stop, prows, ppos, pval, ptab,
@@ -3247,7 +3575,8 @@ class ContinuousBatcher:
                            active[:, None]),
                  _RowGroup(prows, ptab, ppos, pval)),
                 (k, v, ks, vs), cfg, is_prefill=False,
-                attention_impl=impl, mesh=mesh, mesh_axis=max_)
+                attention_impl=impl, mesh=mesh, mesh_axis=max_,
+                layout=layout)
             with jax.named_scope("lm_head"):
                 # x is [B + Gp*Pb, D]: the decode rows' tokens, then
                 # each prefill row's bucket; ragged last-token rows
@@ -3293,10 +3622,11 @@ class ContinuousBatcher:
                 self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
                 self._scale_aval(self.cache.k_scale),
                 self._scale_aval(self.cache.v_scale),
-                sds((B, self.M), i32), sds((B,), i32), sds((B,), i32),
+                sds((B, self._table_width), i32), sds((B,), i32),
+                sds((B,), i32),
                 sds((B,), jnp.bool_), sds((B,), i32), sds((B,), i32),
                 sds((Gp, Pb), i32), sds((Gp, Pb), i32),
-                sds((Gp, Pb), jnp.bool_), sds((Gp, self.M), i32),
+                sds((Gp, Pb), jnp.bool_), sds((Gp, self._table_width), i32),
                 sds((Gp,), i32)).compile()
             self._fused_cache[key] = exe
         return exe

@@ -87,8 +87,20 @@ def resolve_attention_impl(impl: str) -> str:
     return impl
 
 
+def first_visible_block(positions, valid, window: int, bs: int, axis: int):
+    """The chain block that holds the first key any valid query along
+    `axis` may see under a window of `window` keys (its smallest valid
+    position's window start); 0 where no query is valid. The kernel's
+    walk starts there (per row and tile), the XLA twin's gather too (per
+    row): one definition, so the two agree."""
+    lo = jnp.min(jnp.where(valid, positions - (int(window) - 1),
+                           jnp.iinfo(jnp.int32).max), axis=axis)
+    return jnp.where(jnp.any(valid, axis=axis),
+                     jnp.maximum(lo, 0) // bs, 0).astype(jnp.int32)
+
+
 def _rpa_kernel(*refs, bs: int, scale: float, quantized: bool,
-                suffix: bool = False, nchunks: int = 0):
+                suffix: bool = False, nchunks: int = 0, window=None):
     """One (row, query-tile, block-chunk) grid step of the ragged kernel.
 
     Every in-kernel value is 2-D with hd as its lane dim — the shapes
@@ -119,9 +131,19 @@ def _rpa_kernel(*refs, bs: int, scale: float, quantized: bool,
     same online softmax and finalizes there — every row finalizes at
     the slab chunk, since slab visibility is independent of the pool
     chain length.
+
+    `window` (W, a static int) bounds visibility from below as well: query
+    p sees keys p - W < j <= p. The scalar prefetch then carries
+    `first_ref` [R, T] after `live_ref`: the chain block that holds the
+    tile's first visible key, where its walk starts; `live_ref` counts the
+    blocks from there on, and chunk c is chain block first + c.
     """
     import jax.experimental.pallas as pl
 
+    first_ref = None
+    if window is not None:
+        tab_ref, live_ref, first_ref, *refs = refs
+        refs = (tab_ref, live_ref, *refs)
     if quantized:
         (tab_ref, live_ref, ks_ref, vs_ref, pos_ref, val_ref, q_ref,
          k_ref, v_ref, *rest) = refs
@@ -175,7 +197,11 @@ def _rpa_kernel(*refs, bs: int, scale: float, quantized: bool,
         # query validity so padded rows accumulate nothing
         G = pos_ref.shape[2]
         kpos = c * bs + jax.lax.broadcasted_iota(jnp.int32, (G, bs), 1)
+        if window is not None:
+            kpos = kpos + first_ref[r, t] * bs
         vis = (kpos <= pos_ref[0, 0]) & (val_ref[0, 0] != 0)   # [G, bs]
+        if window is not None:
+            vis = vis & (kpos > pos_ref[0, 0] - window)
         if quantized:
             # chain chunk c of row r is pool block tab[r, c] — live,
             # since c < nlive here
@@ -243,12 +269,13 @@ def _shard_specs(mesh_axis: str, quantized: bool, suffix: bool):
 # call (kernels/naming.py), and one jitted object lets every step program
 # that calls it at the same shapes share one trace of the kernel
 @functools.partial(jax.jit, static_argnames=("q_tile", "interpret", "mesh",
-                                             "mesh_axis"))
+                                             "mesh_axis", "window", "ring"))
 def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
                            *, k_scale=None, v_scale=None,
                            suffix_k=None, suffix_v=None, suffix_vis=None,
                            q_tile: int = 128, interpret=None,
-                           mesh=None, mesh_axis: str = "mp"):
+                           mesh=None, mesh_axis: str = "mp",
+                           window=None, ring: bool = False):
     """Paged GQA attention walking only each request's live block chain.
 
     Drop-in twin of the XLA `_paged_gqa_attention` gather path
@@ -308,6 +335,18 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
     result BIT-identical to the mesh-off kernel. Requires H and KV
     divisible by the mesh axis size.
 
+    `window` (W, static; None = the program as it was, instruction for
+    instruction) makes a layer a SLIDING-WINDOW layer: query p sees keys
+    p - W < j <= p, W keys with its own. A (row, tile)'s chain walk then
+    starts at the block that holds its first visible key, not at block
+    0, and spans the blocks from there to its last one, both carried by
+    scalar prefetch. `ring` says that the row's table is a RING of M
+    blocks: chain block m lives in `table[r, m % M]`, so a sequence
+    holds M blocks however long it grows (the caller keeps M * bs at
+    least the window plus the widest chunk it writes before attending).
+    Not built with an int8 pool, a suffix slab or a mesh. In a device
+    trace the window form's events read `%ragged_window_attention.N`.
+
     `interpret=None` auto-selects Pallas interpret mode off-TPU — the
     CPU CI parity path. Tolerance vs XLA is tight-but-not-bitwise: the
     online softmax reassociates the reduction.
@@ -342,6 +381,19 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
 
     quantized = k_scale is not None
     suffix = suffix_k is not None
+    windowed = window is not None
+    if (windowed or ring) and (quantized or suffix or mesh is not None):
+        raise NotImplementedError(
+            "ragged_paged_attention: a window or a ring table with an int8 "
+            "pool, a suffix slab or a mesh is not built")
+    if ring and not windowed:
+        raise ValueError("a ring table holds a window's keys: give `window`")
+    if windowed:
+        # the walk spans the blocks from the tile's first visible key's
+        # to `live`
+        first = first_visible_block(positions.reshape(R, T, Pt),
+                                    valid.reshape(R, T, Pt), window, bs, 2)
+        live = jnp.minimum(live - first, M)
 
     def _tile_map(r, t, c, tab, live, *scales):
         return (r, t, 0, 0)
@@ -355,14 +407,18 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
         # an unchanged index, so the pipeline skips the fetch (the
         # suffix grid's extra slab chunk clamps here too)
         j = jnp.minimum(c, jnp.maximum(live[r, t] - 1, 0))
+        if windowed:
+            j = j + scales[0][r, t]         # `first`, after `live`
+            if ring:
+                j = j % M
         return (jnp.maximum(tab[r, j], 0), 0, 0, 0)
 
     def _suffix_map(r, t, c, tab, live, *scales):
         # the row's whole slab, fetched once per (row, tile)
         return (r, 0, 0, 0)
 
-    nscal = 4 if quantized else 2
-    args = [table, live]
+    nscal = 4 if quantized else 3 if windowed else 2
+    args = [table, live] + ([first] if windowed else [])
     if quantized:
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     args += [positions, val, q, k_pool, v_pool]
@@ -434,11 +490,15 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
             functools.partial(_rpa_kernel, bs=bs,
                               scale=1.0 / math.sqrt(hd),
                               quantized=quantized, suffix=suffix,
-                              nchunks=M),
+                              nchunks=M, window=window),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((R, T, KVl, G, hd), q.dtype),
             interpret=interpret,
-            name="ragged_paged_attention",
+            # the window form under a name of its own, which its events
+            # in a device trace carry: the two kinds of layer of one
+            # decoder are told apart at a glance
+            name="ragged_window_attention" if windowed
+            else "ragged_paged_attention",
         )
         # the package enables jax_enable_x64 globally; traced with it on,
         # the weak-typed float constants and index math lower as 64-bit,

@@ -216,13 +216,16 @@ class ServingEngine:
         self.last_flight_dump: Optional[Dict] = None
         self.last_flight_dump_json: Optional[str] = None
         # lazy: keep `import paddle_tpu` from pulling the whole nlp tree
-        from ..nlp.paged import ContinuousBatcher, _is_latent
-        if role == "prefill" and _is_latent(cfg):
-            # the batcher refuses export_kv / import_kv for a latent pool;
-            # a prefill-role engine exists only to export
+        from ..nlp.paged import ContinuousBatcher, _is_latent, _layer_kinds
+        if role == "prefill" and (_is_latent(cfg)
+                                  or _layer_kinds(cfg) is not None):
+            # the batcher refuses export_kv / import_kv for a latent pool
+            # and for a kinded one (window rings beside full chains); a
+            # prefill-role engine exists only to export
             raise NotImplementedError(
-                "role='prefill': a latent (MLA) pool has no KVSnapshot "
-                "form, so its KV cannot be surrendered to a decode replica")
+                "role='prefill': a latent (MLA) pool, or one whose window "
+                "layers keep a ring, has no KVSnapshot form, so its KV "
+                "cannot be surrendered to a decode replica")
         self.batcher = ContinuousBatcher(
             params, cfg, max_batch=max_batch, block_size=block_size,
             max_total_len=max_total_len, max_new_tokens=max_new_tokens,
@@ -269,7 +272,7 @@ class ServingEngine:
         self._accepting = True
         self._stop = False
         self._thread: Optional[threading.Thread] = None
-        self._alloc_stats = self.batcher.alloc.stats()
+        self._alloc_stats = self.batcher.alloc_stats()
         self._prefix_stats = self.batcher.prefix_stats()
         # fault tolerance: quarantine-by-bisection on step failures,
         # transient-culprit retries with exponential backoff, hung-step
@@ -981,7 +984,7 @@ class ServingEngine:
                 "error": None if error is None else repr(error),
                 "failing_record": records[-1] if records else None,
                 "records": records,
-                "allocator": dict(b.alloc.stats()),
+                "allocator": b.alloc_stats(),
                 "queue_depth": len(self.queue),
                 "running_rids": sorted(self._running),
                 "pending_rids": [e[0].rid for e in b._pending],
@@ -1793,7 +1796,7 @@ class ServingEngine:
 
     def _update_gauges_locked(self) -> None:
         self._slo_eval()
-        stats = self.batcher.alloc.stats()
+        stats = self.batcher.alloc_stats()
         self._alloc_stats = stats          # snapshot() reads this cache
         pc = self.batcher.prefix_stats()
         self._prefix_stats = pc

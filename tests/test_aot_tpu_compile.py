@@ -94,6 +94,39 @@ def _ragged(topo, R, Pq, kv_dtype=BF, slab=0, mesh=False):
     return _compile(fn, sh, *shapes)
 
 
+def _ragged_window(topo, R, Pq, width, ring):
+    """The window form of the ragged kernel at the widths of the window +
+    full GQA decoder in the benchmark (H 32, KV 4 of 128: a group of 8):
+    a window of 1024 over a ring table 97 wide, and, window None, the
+    full layers' call over their table of 800. The walk's first block
+    rides the scalar prefetch after the live counts."""
+    from paddle_tpu.nlp.ragged_attention import ragged_paged_attention
+    one = SingleDeviceSharding(topo.devices[0])
+    KVw, Nw = 4, 4 * width
+    window = 1024 if ring else None
+
+    def fn(q, kp, vp, tab, pos, val):
+        return ragged_paged_attention(q, kp, vp, tab, pos, val,
+                                      interpret=False, window=window,
+                                      ring=ring)
+
+    txt = _compile(fn, [one] * 6, ((R, Pq, H, HD), BF),
+                   ((Nw, BS, KVw, HD), BF), ((Nw, BS, KVw, HD), BF),
+                   ((R, width), jnp.int32), ((R, Pq), jnp.int32),
+                   ((R, Pq), jnp.bool_))
+    call = next(line for line in txt.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    T = -(-Pq // 128)
+    lead = f"s32[{R},{width}]{{1,0}}, s32[{R},{T}]{{1,0}}, "
+    assert ("operand_layout_constraints={" + lead
+            + (f"s32[{R},{T}]{{1,0}}, s32[" if ring else "s32[")) in call, \
+        call[:600]
+    # the window form's instruction carries its own name: a trace's
+    # readers tell the two kinds of layer apart by it
+    assert ("%ragged_window_attention" in call) == ring, call[:200]
+    return txt
+
+
 def _flash(topo, mesh=False):
     from paddle_tpu.kernels import flash_attention as fa
     S = 2048
@@ -210,6 +243,17 @@ CASES = {
     "ragged-shard_map-4dev": lambda t: _ragged(t, 8, 1, mesh=True),
     "ragged-shard_map-4dev-prefill": lambda t: _ragged(t, 1, 512,
                                                        mesh=True),
+    # window + full GQA layers (H 32, KV 4): the window layers' ring of
+    # 97 blocks and the full layers' table of 800, a decode step's 32
+    # rows and a prefill row of the 512 bucket
+    "ragged-window-ring-97-decode": lambda t: _ragged_window(
+        t, 32, 1, 97, True),
+    "ragged-window-ring-97-prefill-512": lambda t: _ragged_window(
+        t, 1, 512, 97, True),
+    "ragged-full-table-800-decode": lambda t: _ragged_window(
+        t, 32, 1, 800, False),
+    "ragged-full-table-800-prefill-512": lambda t: _ragged_window(
+        t, 1, 512, 800, False),
     "flash-fwd-bwd-2048": _flash,
     "flash-shard_map-4dev": lambda t: _flash(t, mesh=True),
     "rms_norm-fwd-bwd-4096": _rms_norm,
