@@ -315,15 +315,15 @@ def latent_work_list(positions, valid, table_width: int, block_size: int,
                      impl: str = "xla"):
     """What the kernel's grid walks for one row group (`positions`,
     `valid` [G, P]): its list of live (row, query tile, chunk) items
-    (`ragged_attention.mla_work_list`), the same for every layer of a
+    (`ragged_attention.attn_work_list`), the same for every layer of a
     forward, so built once and handed to each layer's
     `latent_paged_attention`. None for the gather reference, which walks
     no grid."""
     if impl != "pallas":
         return None
-    from .ragged_attention import mla_work_list
-    return mla_work_list(positions, valid, block_size=block_size,
-                         table_width=table_width)
+    from .ragged_attention import attn_work_list
+    return attn_work_list(positions, valid, block_size=block_size,
+                          table_width=table_width)
 
 
 def latent_paged_attention(q, pool, table, positions, valid, cfg,

@@ -566,7 +566,7 @@ def _write_pool_int8(pool, scale, table, positions, new, valid):
 def _paged_gqa_attention(q, k_pool, v_pool, table, positions, valid=None,
                          impl: str = "xla", k_scale=None, v_scale=None,
                          mesh=None, mesh_axis: str = "mp", window=None,
-                         ring: bool = False):
+                         ring: bool = False, work=None):
     """q [B, P, H, hd] against pool blocks gathered through the table.
     positions [B, P]: query p sees pool keys at absolute positions
     j <= positions[b, p] — per-query causal, so this one path serves
@@ -596,14 +596,19 @@ def _paged_gqa_attention(q, k_pool, v_pool, table, positions, valid=None,
     sliding-window layer's attention: query p sees keys p - W < j <= p.
     `ring`: the table is a ring of M blocks, chain block m in
     `table[:, m % M]` (`KVLayout`); the gather then takes the M chain
-    blocks from the row's first visible one on."""
+    blocks from the row's first visible one on.
+
+    `work` (pallas only): the kernel's work list for these positions,
+    valid and window (`ragged_attention.gqa_work_list`), the same for
+    every layer of its kind, so built by the caller once a forward; the
+    kernel builds its own where None."""
     if impl == "pallas":
         from .ragged_attention import ragged_paged_attention
         return ragged_paged_attention(q, k_pool, v_pool, table, positions,
                                       valid, k_scale=k_scale,
                                       v_scale=v_scale, mesh=mesh,
                                       mesh_axis=mesh_axis, window=window,
-                                      ring=ring)
+                                      ring=ring, work=work)
     B, P, H, hd = q.shape
     N, bs, KV, _ = k_pool.shape
     M = table.shape[1]
@@ -651,9 +656,32 @@ def _paged_gqa_attention(q, k_pool, v_pool, table, positions, valid=None,
     return o.reshape(B, P, H, hd).astype(q.dtype)
 
 
+def _spec_queries(base_len, P: int):
+    """(positions, valid) [B, P] of the speculative score path's kernel
+    call: pool visibility j < base_len == positions j <= base_len - 1,
+    every query valid (inactive slots score garbage the caller discards
+    — same as the XLA formulation)."""
+    B = base_len.shape[0]
+    return (jnp.broadcast_to((base_len - 1)[:, None], (B, P)),
+            jnp.ones((B, P), bool))
+
+
+def _spec_work_list(base_len, P: int, table_width: int, pool_shape,
+                    pool_dtype, impl: str):
+    """The suffix-slab kernel's work list for a `[B, P]` score call over
+    a layer's pool `pool_shape` [N, bs, KV, hd]: one list a forward,
+    every layer walks it. None for the XLA formulation, which walks no
+    grid."""
+    if impl != "pallas":
+        return None
+    from .ragged_attention import gqa_work_list
+    return gqa_work_list(*_spec_queries(base_len, P), table_width,
+                         pool_shape, pool_dtype, slab=True)
+
+
 def _spec_gqa_attention(q, pk, pv, table, base_len, sk, sv, vis,
                         k_scale=None, v_scale=None, impl: str = "xla",
-                        mesh=None, mesh_axis: str = "mp"):
+                        mesh=None, mesh_axis: str = "mp", work=None):
     """The speculative score path's attention: q [B, P, H, hd] over the
     committed pool history PLUS an in-register draft/verify suffix
     slab. The pool is READ-ONLY here — visibility for pool keys is
@@ -678,27 +706,25 @@ def _spec_gqa_attention(q, pk, pv, table, base_len, sk, sv, vis,
     impl="pallas" routes the whole thing through the ragged Pallas
     kernel's suffix-slab operand (nlp/ragged_attention.py): the pool
     sweep stays the int8-gathered block-chunk loop and the slab folds
-    into the same online softmax at the grid's extra chunk — instead
+    into the same online softmax at each row's last work item — instead
     of this XLA concat formulation, which stays the bit-stable parity
     reference (and the CPU default). `mesh`/`mesh_axis` (pallas only)
     shard that kernel call on heads — the slab and its accept walk
-    shard naturally, since slab rows carry whole KV heads."""
+    shard naturally, since slab rows carry whole KV heads. `work`
+    (pallas only): the kernel's list (`_spec_work_list`), the same for
+    every layer."""
     B, P, H, hd = q.shape
     N, bs, KV, _ = pk.shape
     M = table.shape[1]
     S = sk.shape[1]
     if impl == "pallas":
         from .ragged_attention import ragged_paged_attention
-        # pool visibility j < base_len == positions j <= base_len - 1,
-        # every query valid (inactive slots score garbage the caller
-        # discards — same as the XLA formulation below)
         return ragged_paged_attention(
-            q, pk, pv, table,
-            jnp.broadcast_to((base_len - 1)[:, None], (B, P)),
-            jnp.ones((B, P), bool), k_scale=k_scale, v_scale=v_scale,
+            q, pk, pv, table, *_spec_queries(base_len, P),
+            k_scale=k_scale, v_scale=v_scale,
             suffix_k=sk, suffix_v=sv,
             suffix_vis=jnp.broadcast_to(vis[None], (B, P, S)),
-            mesh=mesh, mesh_axis=mesh_axis)
+            mesh=mesh, mesh_axis=mesh_axis, work=work)
     tb = jnp.clip(table, 0)
     if k_scale is not None:
         k = kvq.dequantize(pk[tb],
@@ -758,6 +784,8 @@ def _forward_spec(params, layers, tokens, cache, positions, base_len,
     B, P = tokens.shape
     H, KV, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                  cfg.head_dim)
+    work = _spec_work_list(base_len, P, cache.table.shape[1],
+                           cache.k.shape[1:], cache.k.dtype, impl)
 
     def body(carry, lp):
         x, sk_all, sv_all, li = carry
@@ -783,7 +811,7 @@ def _forward_spec(params, layers, tokens, cache, positions, base_len,
                                              row0, axis=1)
         a = _spec_gqa_attention(q, pk, pv, cache.table, base_len,
                                 sk, sv, vis, ks, vs, impl=impl,
-                                mesh=mesh, mesh_axis=mesh_axis)
+                                mesh=mesh, mesh_axis=mesh_axis, work=work)
         a = a.reshape(B, P, H * hd) @ _wq(lp, "o_proj", cd)
         sk_all = lax.dynamic_update_slice_in_dim(sk_all, sk[None], li, 0)
         sv_all = lax.dynamic_update_slice_in_dim(sv_all, sv[None], li, 0)
@@ -840,7 +868,7 @@ def _group_rows(x, groups):
 def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
                      attention_impl: str = "xla", pks=None, pvs=None,
                      mesh=None, mesh_axis: str = "mp", tables=None,
-                     window=None, ring: bool = False):
+                     window=None, ring: bool = False, works=None):
     """One layer's attention over x, the packed tokens of `groups`
     (`_RowGroup`s; `_pack_rows` gives x's shape). The projections are
     per token: ONE dot each over all of x. RoPE, the pool write and the
@@ -858,12 +886,17 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
     written and read in place; `window` / `ring` make it a window
     layer's (`_paged_gqa_attention`). A cold chunk no longer than the
     window sees all of itself and takes the flash path like any other;
-    a longer one attends through the table like a warm one."""
+    a longer one attends through the table like a warm one.
+    `works`: each group's kernel work list for this kind of layer
+    (`_gqa_work_lists`), made by the caller once a forward; the kernel
+    builds its own where there is none."""
     H, KV, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                  cfg.head_dim)
     cd = cfg.dtype
     if tables is None:
         tables = [g.table for g in groups]
+    if works is None:
+        works = [None] * len(groups)
 
     def heads(y, n):
         return [r.reshape(*r.shape[:2], n, hd)
@@ -917,7 +950,8 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
             outs = [_paged_gqa_attention(
                 q[i], pk, pv, tables[i], g.positions, g.valid,
                 impl=attention_impl, k_scale=pks, v_scale=pvs,
-                mesh=mesh, mesh_axis=mesh_axis, window=window, ring=ring)
+                mesh=mesh, mesh_axis=mesh_axis, window=window, ring=ring,
+                work=works[i])
                 for i, g in enumerate(groups)]
         outs = [o.reshape(*o.shape[:2], H * hd) for o in outs]
     with jax.named_scope("attn_out"):
@@ -940,8 +974,8 @@ def _attention_latent(x, lp, cfg, cos, sin, pool, groups, is_prefill,
     every block id: the layer's blocks are then written and read in
     place, and no layer slice of the pool is copied out and back.
     `works`: each group's kernel work list (`mla.latent_work_list`; None
-    where the backend walks none), the same for every layer, so made by
-    the caller once a forward.
+    where the backend walks none or the kernel is to build its own), the
+    same for every layer, so made by the caller once a forward.
     Returns (out shaped like x, pool')."""
     from . import mla
     with jax.named_scope("mla_q"):
@@ -954,6 +988,8 @@ def _attention_latent(x, lp, cfg, cos, sin, pool, groups, is_prefill,
             q[i], kr = mla.rotate(q[i], k_r[i], cos, sin, g.positions, cfg)
             rows.append(jnp.concatenate([c[i], kr.astype(c[i].dtype)], -1))
     tables = [g.table + base for g in groups]
+    if works is None:
+        works = [None] * len(groups)
     with jax.named_scope("kv_pool_write"):
         for i, g in enumerate(groups):
             pool = _write_pool(pool, tables[i], g.positions, rows[i],
@@ -1012,6 +1048,32 @@ def _merge_stats(a, b):
             for k, v in a.items()}
 
 
+def _gqa_work_lists(groups, cfg, pool_shape, pool_dtype, is_prefill: bool,
+                    attention_impl: str, layout):
+    """The ragged kernel's work lists of one forward: {layer kind: one
+    list a row group} (`ragged_attention.gqa_work_list`). A list depends
+    on positions, valid, the table's width and the window, not on the
+    layer or the pool's contents, so every layer of a kind walks the
+    same one: the key is None where the layers are all alike, "full" and
+    "window" over a kinded pool (`layout`). A kind whose layers call no
+    kernel in this forward has no entry: the gather reference walks no
+    grid, and a cold prefill attends by the flash kernel unless its
+    chunk is longer than the window (`_attention_paged`)."""
+    if attention_impl != "pallas":
+        return {}
+    from .ragged_attention import gqa_work_list
+    kinds = {None: (None, None)} if layout is None else {
+        "full": (None, layout.width),
+        "window": (cfg.sliding_window, layout.ring)}
+    P = groups[0].tokens.shape[1]
+    return {
+        kind: [gqa_work_list(g.positions, g.valid,
+                             width or g.table.shape[1], pool_shape,
+                             pool_dtype, window=window) for g in groups]
+        for kind, (window, width) in kinds.items()
+        if not (is_prefill and (window is None or P <= window))}
+
+
 def _layer_groups(params, cfg):
     """The decoder as the configuration describes it: (stacked layers,
     FFN kind, one period of layer kinds or None) in order, each a scan
@@ -1054,8 +1116,9 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
     None, None, None); a kinded pool: (k, v, None, None), each [1,
     blocks, ...]). Returns (x, the packed hidden states before the final
     norm; pools'; the forward's counters: the expert layers' routing
-    and, where the latent kernel runs, the work items one layer's calls
-    walked (`attn_work_steps`); None where there are none)."""
+    and, where an attention kernel walks a work list, the items ONE
+    layer's calls walked (`attn_work_steps`; over a kinded pool one
+    layer of each kind, summed); None where there are none)."""
     cd = cfg.dtype
     latent = _is_latent(cfg)
     k_all, v_all, ks_all, vs_all = pools
@@ -1075,15 +1138,19 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
     else:
         cos, sin = rope_freqs(cfg.head_dim, T_rope, cfg.rope_theta,
                               jnp.float32)
-    works = [None] * len(groups)
-    if latent and not is_prefill:
-        # the latent kernel's grid: one list a row group, from positions
-        # and valid alone, so built here and not once a layer in the scan
-        # (None each where the backend walks no grid)
+    # the kernels' grids: one list a row group (and kind of GQA layer),
+    # from positions and valid alone, so built here and not once a layer
+    # in the scan (none where the backend walks no grid)
+    works = {}
+    if latent and not is_prefill and attention_impl == "pallas":
         from . import mla
-        works = [mla.latent_work_list(g.positions, g.valid, g.table.shape[1],
-                                      k_all.shape[2], attention_impl)
-                 for g in groups]
+        works = {None: [
+            mla.latent_work_list(g.positions, g.valid, g.table.shape[1],
+                                 k_all.shape[2], attention_impl)
+            for g in groups]}
+    elif not latent:
+        works = _gqa_work_lists(groups, cfg, k_all.shape[1:], k_all.dtype,
+                                is_prefill, attention_impl, layout)
 
     def mix_gqa(x, pools, li, lp):
         # GQA layers all alike: the layer's pool is sliced out of the
@@ -1104,7 +1171,8 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
             h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
         a, pk, pv, ks, vs = _attention_paged(
             h, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
-            attention_impl, ks, vs, mesh=mesh, mesh_axis=mesh_axis)
+            attention_impl, ks, vs, mesh=mesh, mesh_axis=mesh_axis,
+            works=works.get(None))
         with jax.named_scope("kv_pool_write"):
             pk_all = lax.dynamic_update_slice_in_dim(pk_all, pk[None],
                                                      li, 0)
@@ -1135,7 +1203,8 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
                 h, lp, cfg, *ropes[kind], pk, pv, groups, is_prefill,
                 attention_impl,
                 tables=[layout.table(kind, g.table) + base for g in groups],
-                window=window, ring=window is not None)
+                window=window, ring=window is not None,
+                works=works.get(kind))
         return a, (pk[None], pv[None], None, None)
 
     def mix_latent(x, pools, li, lp):
@@ -1149,7 +1218,7 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
         a, pool = _attention_latent(
             h, lp, cfg, cos, sin,
             pool_all.reshape(L * N, *pool_all.shape[2:]), groups,
-            is_prefill, attention_impl, base=li * N, works=works)
+            is_prefill, attention_impl, base=li * N, works=works.get(None))
         return a, (pool.reshape(pool_all.shape), None, None, None)
 
     def make_body(ffn, stacks=None, first_layer=0, kinds=None):
@@ -1211,11 +1280,11 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
                             carry, layers)
         first_layer += n_layers
     x, pk, pv, ks, vs, _, stats = carry
-    if works[0] is not None:
-        # the items ONE layer's kernel calls walked (every layer walks
-        # the same lists), beside the routing counters
+    if works:
+        # the items ONE layer's kernel calls walked (every layer of a
+        # kind walks the same lists), beside the routing counters
         stats = {**(stats or {}), "attn_work_steps": sum(
-            w.count for w in works)}
+            w.count for ws in works.values() for w in ws)}
     return x, (pk, pv, ks, vs), stats
 
 
@@ -2548,11 +2617,23 @@ class ContinuousBatcher:
         tick, the `[prefill_rows, bucket]` call)."""
         tick.note(stats)
         if stats and "attn_work_steps" in stats:
-            from .ragged_attention import mla_grid_steps
-            steps = self.chunk * mla_grid_steps(self.B, 1, self.M)
+            steps = self.chunk * self._attn_grid_steps(self.B, 1)
             if prefill_rows:
-                steps += mla_grid_steps(prefill_rows, bucket, self.M)
+                steps += self._attn_grid_steps(prefill_rows, bucket)
             tick.note({"attn_grid_steps": steps})
+
+    def _attn_grid_steps(self, R: int, P: int) -> int:
+        """The full grid of ONE layer's `[R, P]` kernel call over this
+        batcher's table (over a kinded pool one layer of each kind,
+        summed), in the steps its work list counts (chunks of `nb`
+        blocks)."""
+        from .ragged_attention import attn_grid_steps, gqa_tiling_args
+        if _is_latent(self.cfg):
+            return attn_grid_steps(R, P, self.M)
+        widths = (self.M,) if self._layout is None \
+            else (self._layout.width, self._layout.ring)
+        tiling = gqa_tiling_args(self.cache.k.shape[1:], self.cache.k.dtype)
+        return sum(attn_grid_steps(R, P, M, **tiling) for M in widths)
 
     def _probe_gate(self, rid: int) -> None:
         """Fault-injection hook of the quarantine probes (a tick's own

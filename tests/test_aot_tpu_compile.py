@@ -63,6 +63,24 @@ def _compile(fn, shardings, *shapes):
     return jax.jit(fn).lower(*avals).compile().as_text()
 
 
+def _list_grid(txt, R, Pq, width, pool, windowed=False):
+    """The kernel's call in a compiled program, its grid a work list: the
+    bound is an operand (the scalar that leads them), then the table,
+    the tiles' live blocks, the list's three arrays at the full grid's
+    length and, for a window layer, the tiles' first blocks."""
+    from paddle_tpu.nlp.ragged_attention import (attn_grid_steps,
+                                                 gqa_tiling_args)
+    call = next(line for line in txt.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    n = attn_grid_steps(R, Pq, width, **gqa_tiling_args(*pool))
+    T = -(-Pq // 128)
+    tiles = f"s32[{R},{T}]{{1,0}}, "
+    assert ("operand_layout_constraints={s32[], "
+            f"s32[{R},{width}]{{1,0}}, " + tiles + f"s32[{n}]{{0}}, " * 3
+            + (tiles if windowed else "")) in call, call[:600]
+    return call
+
+
 def _ragged(topo, R, Pq, kv_dtype=BF, slab=0, mesh=False):
     from paddle_tpu.nlp.ragged_attention import ragged_paged_attention
     if mesh:
@@ -91,7 +109,10 @@ def _ragged(topo, R, Pq, kv_dtype=BF, slab=0, mesh=False):
                                       interpret=False, mesh=m,
                                       **dict(zip(names, rest)))
 
-    return _compile(fn, sh, *shapes)
+    txt = _compile(fn, sh, *shapes)
+    # under a mesh every shard walks the list of the GLOBAL pool's call
+    _list_grid(txt, R, Pq, M, ((N, BS, KV, HD), kv_dtype))
+    return txt
 
 
 def _ragged_window(topo, R, Pq, width, ring):
@@ -99,7 +120,7 @@ def _ragged_window(topo, R, Pq, width, ring):
     full GQA decoder in the benchmark (H 32, KV 4 of 128: a group of 8):
     a window of 1024 over a ring table 97 wide, and, window None, the
     full layers' call over their table of 800. The walk's first block
-    rides the scalar prefetch after the live counts."""
+    rides the scalar prefetch after the work list."""
     from paddle_tpu.nlp.ragged_attention import ragged_paged_attention
     one = SingleDeviceSharding(topo.devices[0])
     KVw, Nw = 4, 4 * width
@@ -114,13 +135,7 @@ def _ragged_window(topo, R, Pq, width, ring):
                    ((Nw, BS, KVw, HD), BF), ((Nw, BS, KVw, HD), BF),
                    ((R, width), jnp.int32), ((R, Pq), jnp.int32),
                    ((R, Pq), jnp.bool_))
-    call = next(line for line in txt.splitlines()
-                if 'custom_call_target="tpu_custom_call"' in line)
-    T = -(-Pq // 128)
-    lead = f"s32[{R},{width}]{{1,0}}, s32[{R},{T}]{{1,0}}, "
-    assert ("operand_layout_constraints={" + lead
-            + (f"s32[{R},{T}]{{1,0}}, s32[" if ring else "s32[")) in call, \
-        call[:600]
+    call = _list_grid(txt, R, Pq, width, ((Nw, BS, KVw, HD), BF), ring)
     # the window form's instruction carries its own name: a trace's
     # readers tell the two kinds of layer apart by it
     assert ("%ragged_window_attention" in call) == ring, call[:200]
@@ -173,7 +188,7 @@ def _mla(topo, R, Pq):
     is its work list: the bound is an operand of the call (the scalar
     that leads them), then the table, the tiles' live blocks and the
     list's three arrays at the full grid's length."""
-    from paddle_tpu.nlp.ragged_attention import (mla_grid_steps,
+    from paddle_tpu.nlp.ragged_attention import (attn_grid_steps,
                                                  mla_paged_attention)
     one = SingleDeviceSharding(topo.devices[0])
 
@@ -186,7 +201,7 @@ def _mla(topo, R, Pq):
                    ((R, Pq), jnp.int32), ((R, Pq), jnp.bool_))
     call = next(line for line in txt.splitlines()
                 if 'custom_call_target="tpu_custom_call"' in line)
-    n = mla_grid_steps(R, Pq, M)
+    n = attn_grid_steps(R, Pq, M)
     T = Pq // min(Pq, 16)
     assert (f"operand_layout_constraints={{s32[], s32[{R},{M}]{{1,0}}, "
             f"s32[{R},{T}]{{1,0}}, s32[{n}]{{0}}, s32[{n}]{{0}}, "
@@ -236,6 +251,7 @@ CASES = {
     # rows as [B, 1], then the prefill rows as [Gp, Pb]
     "ragged-fused-decode-rows": lambda t: _ragged(t, 16, 1),
     "ragged-fused-prefill-rows": lambda t: _ragged(t, 2, 128),
+    "ragged-fused-prefill-rows-4x128": lambda t: _ragged(t, 4, 128),
     "ragged-int8-decode": lambda t: _ragged(t, 8, 1, jnp.int8),
     "ragged-int8-prefill": lambda t: _ragged(t, 2, 512, jnp.int8),
     "ragged-suffix-slab": lambda t: _ragged(t, 8, 5, slab=5),
@@ -254,6 +270,10 @@ CASES = {
         t, 32, 1, 800, False),
     "ragged-full-table-800-prefill-512": lambda t: _ragged_window(
         t, 1, 512, 800, False),
+    "ragged-window-ring-97-prefill-4x128": lambda t: _ragged_window(
+        t, 4, 128, 97, True),
+    "ragged-full-table-800-prefill-4x128": lambda t: _ragged_window(
+        t, 4, 128, 800, False),
     "flash-fwd-bwd-2048": _flash,
     "flash-shard_map-4dev": lambda t: _flash(t, mesh=True),
     "rms_norm-fwd-bwd-4096": _rms_norm,

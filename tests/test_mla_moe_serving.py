@@ -152,7 +152,7 @@ def _full_grid_kernel(q, pool, table, pos, valid, *, scale, v_width, q_tile,
     from jax.experimental.pallas import tpu as pltpu
     R, P, H, W = q.shape
     bs, M = pool.shape[1], table.shape[1]
-    Pt, T, nb, C = ragged_attention._mla_tiling(P, M, q_tile, nb)
+    Pt, T, nb, C = ragged_attention._attn_tiling(P, M, q_tile, nb)
     G = Pt * H
     live_tok = jnp.max(jnp.where(valid, pos + 1, 0).reshape(R, T, Pt), axis=2)
     live = ((live_tok + bs - 1) // bs).astype(jnp.int32)
@@ -292,7 +292,7 @@ def test_latent_kernel_walks_only_live_work(case):
                                         **kw).astype(jnp.float32))
     assert np.array_equal(out, full)
     # the list: every (row, tile) with a valid query, its chunks in order
-    work = ragged_attention.mla_work_list(
+    work = ragged_attention.attn_work_list(
         pos, valid, block_size=bs, table_width=M, q_tile=q_tile,
         blocks_per_step=nb)
     Pt = q_tile
@@ -302,7 +302,7 @@ def test_latent_kernel_walks_only_live_work(case):
             for c in range(-(-(-(-int(top[r, t]) // bs)) // nb))]
     n = int(work.count)
     assert n == len(want)
-    assert len(work.row) == ragged_attention.mla_grid_steps(
+    assert len(work.row) == ragged_attention.attn_grid_steps(
         R, P, M, q_tile, nb) == R * (P // Pt) * (M // nb)
     assert list(zip(*(np.asarray(a)[:n].tolist() for a in
                       (work.row, work.tile, work.chunk)))) == want

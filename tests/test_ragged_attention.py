@@ -331,7 +331,7 @@ def _slab(rng, B, S, KV, hd):
 class TestSuffixSlabParity:
     """The spec verify's suffix-slab operand: the Pallas kernel folds
     the in-register draft slab into the SAME online softmax as the
-    pool sweep at the grid's extra chunk (`c == nchunks`), pinned in
+    pool sweep at each row's last work item, pinned in
     interpret mode against the XLA concat formulation
     (`paged._spec_gqa_attention(impl="xla")` — the bit-stable
     reference the verify path keeps). Chain triangles and packed-tree
@@ -575,3 +575,194 @@ class TestBatcherParity:
         # aliases an fp executable either
         assert keys and all("pallas" in k and k[-2:] == ("fp", "fp")
                             for k in keys)
+
+
+def test_flight_records_carry_the_gqa_kernels_work(setup):
+    """Every decode and fused tick of a GQA batcher whose attention is the
+    kernel notes `attn_work_steps` (the items ONE layer's calls walked,
+    added up on the device) beside `attn_grid_steps` (the full grid of
+    the same calls, from shapes), and both follow the schedule; the
+    gather reference walks no grid and notes neither."""
+    from attn_work_expect import work_steps
+    cfg, params = setup
+    long, short = _prompts(15, (70, 5))
+
+    def serve(impl):
+        cb = _batcher(params, cfg, attention_impl=impl, max_total_len=160,
+                      max_batch=3, prefill_buckets=(8,), prefix_cache=False)
+        cb.submit(long)
+        while not any(cb.active):
+            cb.step()
+        cb.submit(short)        # joins mid-decode: its chunk rides fused
+        cb.run()
+        recs = [r for r in cb.flight.records()
+                if r["mode"] in ("decode", "fused")]
+        assert {r["mode"] for r in recs} == {"decode", "fused"}
+        return cb, recs
+
+    cb, recs = serve("pallas")
+    # a table of 40 blocks, 8 a decode step: 5 chunks a row, the long
+    # row's 18 blocks and more 3 items; 16 a prefill tile's step
+    kinds = [(cb.M, None, 8, 16)]
+    assert cb.M == 40
+    exact = 0
+    for r in recs:
+        work, grid = work_steps(r, cb.bs, cb.B, kinds)
+        assert r["attn_grid_steps"] == grid
+        assert 0 < r["attn_work_steps"] <= work < grid
+        if r["live_after"] == r["active_slots"]:    # no row retired in it
+            assert r["attn_work_steps"] == work
+            exact += 1
+    assert exact >= 2
+    _, recs = serve("xla")
+    assert not any("attn_work_steps" in r or "attn_grid_steps" in r
+                   for r in recs)
+
+
+# ---- the work list (PR 34): the grid is the call's live items ------------
+from attn_work_expect import enumerate_work, work_items  # noqa: E402
+
+
+class TestWorkList:
+    """The kernel's grid is a list of the live (row, query tile, chunk)
+    items of the call, a chunk several blocks: every form against its XLA
+    twin where the list is empty, short, the whole grid, and ragged over
+    a row's tiles; the list itself against a plain enumeration; a list
+    handed in against the one built inside, bit for bit."""
+
+    bs, KV, hd, H, M = 4, 2, 8, 4, 40       # 8 blocks a decode step (5
+    N = 4 * M + 1                           # chunks a row), 16 a tile's
+
+    # case -> (P, q_tile, each row's keys; 0 = the row is not live)
+    CASES = {
+        "none-live": (1, 128, [0, 0, 0, 0]),
+        "one-row": (1, 128, [0, 0, 70, 0]),
+        "all-rows": (1, 128, [3, 64, 65, 130]),
+        "full-width": (1, 128, [160, 0, 17, 0]),
+        # 16-query suffixes cut into tiles of 4: a row's early tiles end
+        # before its late ones, and row 1's first two tiles are padding
+        "ragged-tiles": (16, 4, [134, 7, 0, 77]),
+    }
+
+    def _inputs(self, case, seed):
+        from paddle_tpu.nlp.ragged_attention import _attn_tiling
+        P, q_tile, lengths = self.CASES[case]
+        rng, kp, vp = _pools(seed, self.N, self.bs, self.KV, self.hd)
+        table = _chains(rng, lengths, self.M, self.bs, self.N)
+        pos, val = _suffix_qpv(rng, lengths, P, self.M, self.bs)
+        q = jnp.asarray(rng.randn(len(lengths), P, self.H, self.hd),
+                        jnp.float32)
+        Pt, _, nb, _ = _attn_tiling(P, self.M, q_tile, pools=2)
+        assert nb == (8 if P == 1 else 16)
+        return rng, q, kp, vp, table, pos, val, q_tile, Pt, nb
+
+    @pytest.mark.parametrize("case", list(CASES))
+    @pytest.mark.parametrize("form", ["plain", "int8"])
+    def test_list_form_matches_its_xla_twin(self, form, case):
+        from paddle_tpu.nlp.ragged_attention import gqa_work_list
+        rng, q, kp, vp, table, pos, val, q_tile, Pt, nb = self._inputs(
+            case, 40 + len(case))
+        scales = {}
+        if form == "int8":
+            kp, vp, ks, vs = _quantize_pools(kp, vp)
+            scales = {"k_scale": ks, "v_scale": vs}
+        ref = paged._paged_gqa_attention(q, kp, vp, table, pos, val,
+                                         impl="xla", **scales)
+        keep = np.asarray(val)[:, :, None, None]
+        out = np.asarray(ragged_paged_attention(
+            q, kp, vp, table, pos, val, q_tile=q_tile, **scales))
+        np.testing.assert_allclose(out, np.where(keep, np.asarray(ref), 0.0),
+                                   atol=2e-5, rtol=2e-5)
+        # the list, against the enumeration by hand
+        work = gqa_work_list(pos, val, self.M, kp.shape, kp.dtype,
+                             q_tile=q_tile)
+        want, _ = enumerate_work(pos, val, self.bs, self.M, Pt, nb)
+        assert work_items(work) == want
+        assert work.first is None
+        if case == "none-live":
+            assert int(work.count) == 0 and not out.any()
+        if case == "full-width":
+            assert want[:5] == [(0, 0, c) for c in range(5)]
+        if case == "ragged-tiles":
+            per_tile = [sum(1 for r, t, _ in want if (r, t) == (0, tt))
+                        for tt in range(4)]
+            assert per_tile == sorted(per_tile) and per_tile[0] < per_tile[-1]
+            assert not any(r == 1 and t < 2 for r, t, _ in want)
+        # handed in or built inside: the same program on the same list
+        again = np.asarray(ragged_paged_attention(
+            q, kp, vp, table, pos, val, q_tile=q_tile, work=work, **scales))
+        assert np.array_equal(out, again)
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["fp", "int8"])
+    @pytest.mark.parametrize("case", ["none-live", "one-row", "all-rows",
+                                      "full-width", "tiles"])
+    def test_suffix_list_form_matches_its_xla_twin(self, case, int8):
+        """The suffix slab folds into each row's last item; a row whose
+        pool chain is still empty gets one item all the same."""
+        P, q_tile, base = {
+            "none-live": (3, 128, [5, 70, 0, 33]),
+            "one-row": (3, 128, [0, 70, 0, 0]),
+            "all-rows": (3, 128, [3, 64, 65, 130]),
+            "full-width": (3, 128, [160, 0, 17, 1]),
+            "tiles": (6, 3, [150, 0, 9, 77]),
+        }[case]
+        rng, kp, vp = _pools(60 + len(case), self.N, self.bs, self.KV,
+                             self.hd)
+        R, S = len(base), P + 2
+        table = _chains(rng, base, self.M, self.bs, self.N)
+        sk, sv = _slab(rng, R, S, self.KV, self.hd)
+        vis = jnp.asarray(np.tril(np.ones((P, S), bool), k=S - P))
+        q = jnp.asarray(rng.randn(R, P, self.H, self.hd), jnp.float32)
+        base_len = jnp.asarray(base, jnp.int32)
+        scales = {}
+        if int8:
+            kp, vp, ks, vs = _quantize_pools(kp, vp)
+            scales = {"k_scale": ks, "v_scale": vs}
+        ref = np.asarray(paged._spec_gqa_attention(
+            q, kp, vp, table, base_len, sk, sv, vis, impl="xla", **scales))
+        pos, ones = paged._spec_queries(base_len, P)
+        live = np.asarray(base) > 0 if case == "one-row" else np.ones(R, bool)
+        if case == "none-live":
+            live[:] = False
+        val = jnp.asarray(np.broadcast_to(live[:, None], (R, P)))
+        kw = dict(suffix_k=sk, suffix_v=sv, q_tile=q_tile,
+                  suffix_vis=jnp.broadcast_to(vis[None], (R, P, S)), **scales)
+        out = np.asarray(ragged_paged_attention(q, kp, vp, table, pos, val,
+                                                **kw))
+        np.testing.assert_allclose(
+            out, np.where(live[:, None, None, None], ref, 0.0),
+            atol=2e-5, rtol=2e-5)
+        from paddle_tpu.nlp.ragged_attention import (_attn_tiling,
+                                                     gqa_work_list)
+        work = gqa_work_list(pos, val, self.M, kp.shape, kp.dtype, slab=True,
+                             q_tile=q_tile)
+        Pt, _, nb, _ = _attn_tiling(P, self.M, q_tile, pools=2)
+        # an empty chain (position -1) still has its one item
+        want, _ = enumerate_work(np.maximum(np.asarray(pos), 0), val,
+                                 self.bs, self.M, Pt, nb)
+        assert work_items(work) == want
+        if case == "none-live":
+            assert int(work.count) == 0 and not out.any()
+        if case == "full-width":
+            assert (1, 0, 0) in want          # the row with no pool key
+        again = np.asarray(ragged_paged_attention(
+            q, kp, vp, table, pos, val, work=work, **kw))
+        assert np.array_equal(out, again)
+        if case == "all-rows":
+            # the served call: `_spec_gqa_attention` builds the same list
+            served = np.asarray(paged._spec_gqa_attention(
+                q, kp, vp, table, base_len, sk, sv, vis, impl="pallas",
+                **scales))
+            assert np.array_equal(served, out)
+
+    def test_a_list_of_another_call_is_refused(self):
+        from paddle_tpu.nlp.ragged_attention import gqa_work_list
+        rng, q, kp, vp, table, pos, val, q_tile, Pt, nb = self._inputs(
+            "all-rows", 0)
+        work = gqa_work_list(pos, val, self.M, kp.shape, kp.dtype,
+                             window=8)
+        with pytest.raises(ValueError, match="work list"):
+            ragged_paged_attention(q, kp, vp, table, pos, val, work=work)
+        work = gqa_work_list(pos[:2], val[:2], self.M, kp.shape, kp.dtype)
+        with pytest.raises(ValueError, match="work list"):
+            ragged_paged_attention(q, kp, vp, table, pos, val, work=work)

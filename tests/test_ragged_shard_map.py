@@ -216,19 +216,19 @@ class TestShardMapParity:
 class TestShardSpecs:
     def test_operand_specs(self):
         """_shard_specs mirrors the kernel's operand order exactly:
-        scalar-prefetch operands (table, live, scales) and positions/
-        validity replicate; q, the pools and the slab shard on their
-        head axis; the visibility mask replicates."""
+        scalar-prefetch operands (table, the work list's four arrays,
+        scales), the list's count and positions/validity replicate; q,
+        the pools and the slab shard on their head axis; the visibility
+        mask replicates."""
         head = P(None, None, "mp", None)
         repl = P()
-        specs, out = _shard_specs("mp", False, False)
-        assert specs == (repl, repl, repl, repl, head, head, head)
+        specs, out = _shard_specs("mp", 4, False, False)
+        assert specs == (repl,) * 8 + (head, head, head)
         assert out == head
-        specs, _ = _shard_specs("mp", True, False)
-        assert specs == (repl, repl, repl, repl, repl, repl,
-                         head, head, head)
-        specs, _ = _shard_specs("mp", True, True)
-        assert len(specs) == 12 and specs[-3:] == (head, head, repl)
+        specs, _ = _shard_specs("mp", 4, True, False)
+        assert specs == (repl,) * 10 + (head, head, head)
+        specs, _ = _shard_specs("mp", 4, True, True)
+        assert len(specs) == 16 and specs[-3:] == (head, head, repl)
 
     def test_indivisible_heads_rejected(self):
         """H=8/KV=4 on a 3-wide axis: the kernel refuses loudly at

@@ -171,6 +171,54 @@ def test_batcher_tokens_are_the_references(model, impl, fused):
     assert st["blocks_in_use"] == st["window_blocks_in_use"] == 0
 
 
+def test_flight_records_carry_both_kinds_of_kernel_work(model):
+    """A kinded batcher's decode and fused ticks note `attn_work_steps`,
+    the items ONE layer of EACH kind walked (a full layer from block 0, a
+    window layer from its window's first block, at most a ring), beside
+    `attn_grid_steps`, the full grid of the same calls; both follow the
+    schedule. The gather reference notes neither."""
+    from attn_work_expect import work_steps
+    from paddle_tpu.nlp.ragged_attention import _attn_tiling
+    d, cfg, params = model
+
+    def serve(impl):
+        cb = paged.ContinuousBatcher(
+            params, cfg, max_batch=3, block_size=BS, max_total_len=96,
+            max_new_tokens=8, prefill_buckets=(CHUNK,), chunk=4,
+            attention_impl=impl, max_prefill_group=2)
+        cb.submit(_tokens(4 * W, seed=1).tolist())
+        while not any(cb.active):
+            cb.step()
+        cb.submit(_tokens(30, seed=2).tolist())     # two chunks, fused
+        cb.run()
+        return cb, [r for r in cb.flight.records()
+                    if r["mode"] in ("decode", "fused")]
+
+    cb, recs = serve("pallas")
+    assert {r["mode"] for r in recs} == {"decode", "fused"}
+    lay = cb._layout
+    kinds = [(width, window, *(_attn_tiling(P, width, 128, pools=2)[2]
+                               for P in (1, CHUNK)))
+             for width, window in ((lay.width, None), (lay.ring, W))]
+    assert kinds == [(24, None, 8, 16), (9, W, 8, 9)]
+    exact = 0
+    for r in recs:
+        work, grid = work_steps(r, BS, cb.B, kinds)
+        assert r["attn_grid_steps"] == grid
+        assert 0 < r["attn_work_steps"] <= work < grid
+        if r["live_after"] == r["active_slots"]:    # no row retired in it
+            assert r["attn_work_steps"] == work
+            exact += 1
+    assert exact >= 2
+    # the long row's window layers walk fewer blocks than its full layers
+    full_only = [work_steps(r, BS, cb.B, kinds[:1])[0] for r in recs]
+    both = [work_steps(r, BS, cb.B, kinds)[0] for r in recs]
+    assert any(b < 2 * f for f, b in zip(full_only, both))
+    _, recs = serve("xla")
+    assert recs and not any("attn_work_steps" in r or "attn_grid_steps" in r
+                            for r in recs)
+
+
 # ---- (d) what must fail it -----------------------------------------------
 def test_dropping_the_window_or_the_precision_fails_the_comparison(model):
     d, cfg, params = model
@@ -282,6 +330,85 @@ def test_window_kernel_agrees_with_its_xla_twin(ring, P):
         # the window is a bound: without it the result differs
         loose = paged._paged_gqa_attention(q, kp, vp, table, pos, valid)
         assert np.abs(np.asarray(loose) - np.asarray(want))[ok].max() > 1e-3
+
+
+# the window form's work list (PR 34): case -> (P, q_tile, ring, each
+# row's last position; -1 = the row is not live). Window 20 over blocks of
+# 4: a ring of 10 blocks (`ring_blocks(20, 16, 4)`), 8 blocks a decode
+# step, 16 (the whole ring) a prefill tile's
+WINDOW_LIST_CASES = {
+    "none-live": (1, 128, True, [-1, -1, -1]),
+    "one-row": (1, 128, True, [-1, 30, -1]),
+    "all-rows": (1, 128, True, [2, 30, 19]),
+    # a chain table 40 wide, a row at its last position: the walk starts
+    # at the window's first block, 35, not at block 0
+    "full-width": (1, 128, False, [159, 40, -1]),
+    # the ring has gone round more than twice: chain block m in m % 10
+    "ring-wrapped": (1, 128, True, [97, 36, 5]),
+    # 16-query chunks in tiles of 4: row 0's early tiles start and end
+    # before its late ones, row 2's first three tiles are padding
+    "ragged-tiles": (16, 4, True, [61, 23, 2]),
+}
+
+
+@pytest.mark.parametrize("case", list(WINDOW_LIST_CASES))
+def test_window_list_form_matches_its_xla_twin(case):
+    """The window + ring form of the kernel over its work list (each
+    (row, tile)'s walk starts at its first visible block) against the XLA
+    twin; the list against a plain enumeration; handed in or built inside,
+    the same output bit for bit."""
+    from attn_work_expect import enumerate_work, work_items
+    from paddle_tpu.nlp.ragged_attention import _attn_tiling, gqa_work_list
+    P, q_tile, ring, last = WINDOW_LIST_CASES[case]
+    rng = np.random.default_rng(len(case))
+    Rr, H, KV, hd, win = len(last), 4, 2, 16, 20
+    M = paged.ring_blocks(win, 16, BS) if ring else 40
+    N = Rr * M + 1
+    kp = jnp.asarray(rng.normal(size=(N, BS, KV, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(N, BS, KV, hd)), jnp.float32)
+    table = jnp.asarray(rng.permutation(N - 1)[:Rr * M].reshape(Rr, M) + 1,
+                        jnp.int32)
+    last = np.asarray(last)
+    pos = last[:, None] - (P - 1) + np.arange(P)[None]
+    valid = jnp.asarray((pos >= 0) & (last >= 0)[:, None])
+    pos = jnp.asarray(np.maximum(pos, 0), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(Rr, P, H, hd)), jnp.float32)
+    want = paged._paged_gqa_attention(q, kp, vp, table, pos, valid,
+                                      impl="xla", window=win, ring=ring)
+    kw = dict(window=win, ring=ring, q_tile=q_tile, interpret=True)
+    got = np.asarray(ragged_paged_attention(q, kp, vp, table, pos, valid,
+                                            **kw))
+    ok = np.asarray(valid)
+    np.testing.assert_allclose(got[ok], np.asarray(want)[ok], atol=2e-6,
+                               rtol=0)
+    assert not got[~ok].any()
+    work = gqa_work_list(pos, valid, M, kp.shape, kp.dtype, window=win,
+                         q_tile=q_tile)
+    Pt, T, nb, _ = _attn_tiling(P, M, q_tile, pools=2)
+    assert nb == (8 if P == 1 else min(16, M))
+    items, firsts = enumerate_work(pos, valid, BS, M, Pt, nb, window=win)
+    n = int(work.count)
+    assert work_items(work) == items
+    assert np.array_equal(np.asarray(work.first), firsts)
+    if case == "none-live":
+        assert n == 0 and not got.any()
+    if case == "full-width":
+        assert firsts[0, 0] == 35 and items == [(0, 0, 0), (1, 0, 0)]
+    if case == "ring-wrapped":
+        # the walk's blocks lie past the ring's width: they wrap
+        assert firsts[0, 0] * BS > M * BS
+    if case == "ragged-tiles":
+        assert firsts[0, 0] < firsts[0, -1]
+        assert not any(r == 2 and t < 3 for r, t, _ in items)
+    again = np.asarray(ragged_paged_attention(q, kp, vp, table, pos, valid,
+                                              work=work, **kw))
+    assert np.array_equal(got, again)
+    # a plain call's list has no starts: refused here by shape
+    with pytest.raises(ValueError, match="work list"):
+        ragged_paged_attention(
+            q, kp, vp, table, pos, valid, **kw,
+            work=gqa_work_list(pos, valid, M, kp.shape, kp.dtype,
+                               q_tile=q_tile))
 
 
 # ---- (c) the router ------------------------------------------------------
