@@ -92,8 +92,10 @@ def rms_norm(x, weight=None, epsilon: float = 1e-6):
 
 
 def _blk_rows(d: int) -> int:
-    # ~5 f32 row-temps of [blk, d] must fit scoped VMEM (16MB)
-    return 128 if d >= 4096 else 256
+    # ~5 f32 row-temps of [blk, d] must fit scoped VMEM (16MB): the
+    # backward's stack is 21.5 bytes an element (19.71M at 256 x 3584, the
+    # chip's compiler, PR 36), so 256 rows hold up to d = 2600
+    return 128 if d > 2600 else 256
 
 
 def _rms_fwd_kernel(x_ref, w_ref, o_ref, r_ref, *, eps):

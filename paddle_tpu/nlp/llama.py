@@ -19,6 +19,7 @@ softmax/loss in f32.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -418,8 +419,8 @@ def _mb_loss(logits, tokens):
 _CE_CHUNKS = 8
 
 
-@jax.custom_vjp
-def fused_head_ce(x, head, tokens):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def fused_head_ce(x, head, tokens, shift=1):
     """LM head + next-token CE WITHOUT materializing [B, S, V] f32 logits.
 
     The straightforward `_final_head + _mb_loss` makes autodiff save the
@@ -433,12 +434,15 @@ def fused_head_ce(x, head, tokens):
     bench (r3 notes).
 
     x: post-RMSNorm activations [B, S, D] (compute dtype); head [D, V];
-    tokens [B, S] int32. Returns the scalar mean loss."""
-    loss, _ = _fused_head_ce_fwd(x, head, tokens)
+    tokens [B, S] int32. Returns the scalar mean loss. `shift`: position
+    i is scored against token i + shift, over the S - shift positions
+    that have one (1: the next token; 2: a multi-token-prediction
+    module's)."""
+    loss, _ = _fused_head_ce_fwd(x, head, tokens, shift)
     return loss
 
 
-def _ce_scan_chunks(x, tokens):
+def _ce_scan_chunks(x, tokens, shift=1):
     B, S, D = x.shape
     # largest chunk count <= _CE_CHUNKS dividing S — never silently fall
     # back to one chunk (nc=1 would materialize the full [B, S, V] f32
@@ -446,13 +450,13 @@ def _ce_scan_chunks(x, tokens):
     nc = next(n for n in range(_CE_CHUNKS, 0, -1) if S % n == 0)
     c = S // nc
     xs = x.reshape(B, nc, c, D).swapaxes(0, 1)           # [nc, B, c, D]
-    tg = jnp.roll(tokens, -1, axis=1).reshape(B, nc, c).swapaxes(0, 1)
+    tg = jnp.roll(tokens, -shift, axis=1).reshape(B, nc, c).swapaxes(0, 1)
     return xs, tg, nc, c
 
 
-def _fused_head_ce_fwd(x, head, tokens):
+def _fused_head_ce_fwd(x, head, tokens, shift):
     B, S, D = x.shape
-    xs, tg, nc, c = _ce_scan_chunks(x, tokens)
+    xs, tg, nc, c = _ce_scan_chunks(x, tokens, shift)
 
     def chunk(_, xt):
         xc, tc = xt
@@ -464,19 +468,19 @@ def _fused_head_ce_fwd(x, head, tokens):
     _, (logz, gold) = lax.scan(chunk, None, (xs, tg))
     logz = logz.swapaxes(0, 1).reshape(B, S)
     gold = gold.swapaxes(0, 1).reshape(B, S)
-    valid = (jnp.arange(S) < S - 1).astype(jnp.float32)
-    loss = jnp.sum((logz - gold) * valid[None]) / (B * (S - 1))
+    valid = (jnp.arange(S) < S - shift).astype(jnp.float32)
+    loss = jnp.sum((logz - gold) * valid[None]) / (B * (S - shift))
     return loss, (x, head, tokens, logz)
 
 
-def _fused_head_ce_bwd(res, g):
+def _fused_head_ce_bwd(shift, res, g):
     x, head, tokens, logz = res
     B, S, D = x.shape
     V = head.shape[1]
-    xs, tg, nc, c = _ce_scan_chunks(x, tokens)
+    xs, tg, nc, c = _ce_scan_chunks(x, tokens, shift)
     lz = logz.reshape(B, nc, c).swapaxes(0, 1)
-    valid = (jnp.arange(S) < S - 1).astype(jnp.float32).reshape(nc, 1, c)
-    scale = g / (B * (S - 1))
+    valid = (jnp.arange(S) < S - shift).astype(jnp.float32).reshape(nc, 1, c)
+    scale = g / (B * (S - shift))
 
     def chunk(dhead, args):
         xc, tc, lzc, vc = args

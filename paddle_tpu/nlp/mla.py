@@ -27,8 +27,11 @@ softmax scale carries YaRN's temperature squared.
 
 The decoder: `first_k_dense` leading layers with a dense gated-SiLU MLP,
 then layers whose FFN is `moe.expert_share_ffn` (sigmoid top-k router over
-ALL routed experts, the experts held here, one shared expert). Only the
-served path lives here; nothing of this is imported by `nlp.train`.
+ALL routed experts, the experts held here, one shared expert). The served
+path (nlp/paged.py) and the trained one (nlp/mla_train.py, which adds the
+multi-stream residual path of nlp/hyper.py and a multi-token-prediction
+module) run the SAME projections, rotation, YaRN tables and expanded
+attention from here; `attention` is the sublayer as `jax.grad` takes it.
 """
 from __future__ import annotations
 
@@ -71,6 +74,9 @@ class MlaMoeConfig:
     scoring_func: str = "sigmoid"        # the router's scores (moe.ROUTERS)
     experts_first: int = 0
     experts_count: Optional[int] = None
+    # streams of the residual path (nlp/hyper.py); the served decoder runs
+    # one and refuses more (paged._refuse_latent)
+    hc_mult: int = 1
     max_position_embeddings: int = 4096
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
@@ -264,13 +270,34 @@ def attend_expanded(q, row, lp, cfg: MlaMoeConfig):
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_r[:, :, None, :],
                                   (G, P, H, cfg.qk_rope_head_dim))], -1)
-    # the flash kernel takes one head size: v rides zero-padded to q's
+    # the flash kernels, forward and backward, take one head size: v rides
+    # zero-padded to q's (a third of the P.V, dP and dV work at 192 / 128
+    # is padding; the rooflines count the useful work only)
     pad = cfg.qk_head_dim - cfg.v_head_dim
     if pad > 0:
         v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
-    o = fa._flash_impl(q, k.astype(q.dtype), v.astype(q.dtype), True,
-                       cfg.softmax_scale)
+    o = fa.flash_attention_fwd(q, k.astype(q.dtype), v.astype(q.dtype),
+                               True, cfg.softmax_scale)
     return o[..., :cfg.v_head_dim].reshape(G, P, H * cfg.v_head_dim)
+
+
+def attention(h, lp, cfg: MlaMoeConfig, cos, sin):
+    """The attention sublayer as a training step runs it: h [B, S, D]
+    (normalised) -> [B, S, D], every row a whole sequence from position 0,
+    the EXPANDED form (no cache), on the projections, rotation and flash
+    call the server's cold prefill makes; differentiable throughout."""
+    B, S, _ = h.shape
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    with jax.named_scope("mla_q"):
+        q = project_q(h, lp, cfg)
+    with jax.named_scope("mla_kv_latent"):
+        c, k_r = project_latent(h, lp, cfg)
+        q, k_r = rotate(q, k_r, cos, sin, positions, cfg)
+        row = jnp.concatenate([c, k_r.astype(c.dtype)], -1)
+    with jax.named_scope("attn_kernel"):
+        o = attend_expanded(q, row, lp, cfg)
+    with jax.named_scope("attn_out"):
+        return o @ _wq(lp, "o_proj", cfg.dtype)
 
 
 def absorb_q(q, lp, cfg: MlaMoeConfig):

@@ -6,7 +6,8 @@ Two routers live here and they are NOT the same function. The TRAINED
 path (`top_k_routing`, `moe_block`, `MoeConfig`) is GShard's: softmax
 scores, a fixed capacity per expert, tokens over capacity DROPPED (they
 fall through the residual). The SERVED path (`sigmoid_top_k`,
-`expert_share_ffn`, used by nlp/paged.py for `mla.MlaMoeConfig`) is what
+`expert_share_ffn`, used by nlp/paged.py for `mla.MlaMoeConfig`; under
+`jax.grad` `expert_share_train`, used by nlp/mla_train.py) is what
 published DeepSeek-V3-style routers do: sigmoid scores in float32, plain
 top-k, normalised and scaled gates, and NO capacity: no token is ever
 dropped, whatever the routing.
@@ -31,6 +32,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.rms_norm import rms_norm_ref, rms_norm_train
@@ -171,7 +173,35 @@ def softmax_top_k(h: jax.Array, router_w: jax.Array, k: int,
     return idx.astype(jnp.int32), top * scale
 
 
-ROUTERS = {"sigmoid": sigmoid_top_k, "softmax": softmax_top_k}
+def sigmoid_bias_top_k(h: jax.Array, router_w: jax.Array, k: int,
+                       scale: float, normalize: bool, bias: jax.Array):
+    """`sigmoid_top_k` with a per-expert SELECTION bias (DeepSeek-V3's
+    auxiliary-loss-free balancing, `topk_method: "noaux_tc"`; with
+    `n_group` 1 and `topk_group` 1 there is no group limit): `I =
+    top-k(s + b)`, gates from `s` alone, `scale * s_i / sum_{j in I}
+    s_j`. The bias [E] moves which experts are chosen and nothing else:
+    the gates do not see it, and no gradient reaches it (the selection
+    carries none; the loss does not train the bias)."""
+    logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    top = jnp.take_along_axis(s, idx, axis=-1)
+    if normalize:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), top * scale
+
+
+ROUTERS = {"sigmoid": sigmoid_top_k, "softmax": softmax_top_k,
+           "sigmoid_bias": sigmoid_bias_top_k}
+
+
+def _route(h, lp, k, scale, normalize, score):
+    """(idx [T, k], gates [T, k]) by the router `score` names; the one
+    that selects by a bias reads the layer's `e_bias` [E]."""
+    bias = (lp["e_bias"],) if score == "sigmoid_bias" else ()
+    return ROUTERS[score](h, lp["router"], k, scale, normalize, *bias)
 
 
 def expert_share_ffn(h: jax.Array, lp: Dict[str, jax.Array], *, k: int,
@@ -190,8 +220,12 @@ def expert_share_ffn(h: jax.Array, lp: Dict[str, jax.Array], *, k: int,
     their chips. The shared expert is the caller's
     (`generation._mlp_cached`).
 
-    `score` names the router's scoring function (`ROUTERS`: "sigmoid" |
-    "softmax"), float32 either way.
+    `score` names the router (`ROUTERS`: "sigmoid" | "softmax" |
+    "sigmoid_bias", which reads the layer's selection bias
+    `lp["e_bias"]` [E]), float32 either way.
+
+    This is the SERVED form: a `while_loop` of passes has no reverse
+    mode. Under `jax.grad` the same layer is `expert_share_train`.
 
     The stack goes into the grouped GEMM whole, as Lm*n groups of which
     only this layer's n have rows. A scan that sliced the layer's experts
@@ -240,12 +274,35 @@ def expert_share_ffn(h: jax.Array, lp: Dict[str, jax.Array], *, k: int,
     else:
         y, sizes, full = _share_block(h, lp, valid, k, first, n, scale,
                                       normalize, layer, score)
-    # int32 whatever jax_enable_x64 says: they ride a scan's carry
-    stats = {"moe_pairs": jnp.sum(sizes, dtype=jnp.int32),
-             "moe_experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32),
-             "moe_load_max": jnp.max(sizes).astype(jnp.int32),
-             "moe_full_passes": jnp.sum(full, dtype=jnp.int32)}
-    return y, stats
+    return y, _share_stats(sizes, full)
+
+
+def _share_stats(sizes, full):
+    """The expert layer's counters from the pairs on each held expert [n]
+    and the blocks that overflowed; int32 whatever jax_enable_x64 says:
+    they ride a scan's carry."""
+    return {"moe_pairs": jnp.sum(sizes, dtype=jnp.int32),
+            "moe_experts_hit": jnp.sum(sizes > 0, dtype=jnp.int32),
+            "moe_load_max": jnp.max(sizes).astype(jnp.int32),
+            "moe_full_passes": jnp.sum(full, dtype=jnp.int32)}
+
+
+def _sorted_pairs(h, lp, valid, k, first, n, scale, normalize, score):
+    """What both forms of the share start from, for the tokens h [T, D]:
+    (`local` [T, k]: the pairs computed here; `gates` [T, k]: every
+    pair's gate; `order` [T k]: the pairs sorted by held expert, those of
+    absent experts and masked tokens last; `sizes` [n]: pairs on each
+    held expert)."""
+    T = h.shape[0]
+    with jax.named_scope("moe_router"):
+        idx, gates = _route(h, lp, k, scale, normalize, score)
+    with jax.named_scope("moe_dispatch"):
+        local = (idx >= first) & (idx < first + n) & valid[:, None]
+        # held expert of each pair, n for a pair that is not computed here
+        e = jnp.where(local, idx - first, n).reshape(T * k)
+        order = jnp.argsort(e, stable=True)
+        sizes = jnp.zeros((n + 1,), jnp.int32).at[e].add(1)[:n]
+    return local, gates, order, sizes
 
 
 def _short_rows(pairs: int, held: int, routed: int) -> int:
@@ -280,14 +337,9 @@ def _share_block(h, lp, valid, k, first, n, scale, normalize, layer,
     T, D = h.shape
     cd = h.dtype
     S = min(_short_rows(T * k, n, lp["router"].shape[1]), T * k)
-    with jax.named_scope("moe_router"):
-        idx, gates = ROUTERS[score](h, lp["router"], k, scale, normalize)
+    local, gates, order, sizes = _sorted_pairs(h, lp, valid, k, first, n,
+                                               scale, normalize, score)
     with jax.named_scope("moe_dispatch"):
-        local = (idx >= first) & (idx < first + n) & valid[:, None]
-        # held expert of each pair, n for a pair that is not computed here
-        e = jnp.where(local, idx - first, n).reshape(T * k)
-        order = jnp.argsort(e, stable=True)
-        sizes = jnp.zeros((n + 1,), jnp.int32).at[e].add(1)[:n]
         n_local = jnp.sum(sizes)
         ends = jnp.cumsum(sizes)        # a group's end among the sorted
     with jax.named_scope("moe_experts"):
@@ -333,6 +385,125 @@ def _share_block(h, lp, valid, k, first, n, scale, normalize, layer,
         lambda c: c[0] < n_local, one_pass,
         (jnp.zeros((), jnp.int32), jnp.zeros((T, D), jnp.float32)))
     return y.astype(cd), sizes, (n_local > S).astype(jnp.int32)
+
+
+@jax.custom_vjp
+def _gather_pairs(h, tok, at, here):
+    """rows[s] = h[tok[s]]: the sorted buffer's rows. `at` [T, k] is each
+    pair's place in it and `here` whether it is computed here. The
+    transpose is written as a gather too (each token sums the rows of its
+    own pairs): XLA's own would be a scatter-add of S rows into T, and it
+    would read what the grouped GEMM leaves in rows that belong to no
+    expert."""
+    return jnp.take(h, tok, axis=0)
+
+
+def _gather_pairs_fwd(h, tok, at, here):
+    return jnp.take(h, tok, axis=0), (tok, at, here)
+
+
+def _gather_pairs_bwd(res, d_rows):
+    tok, at, here = res
+    dh = sum(g.astype(jnp.float32) for g in _pairs_out(d_rows, at, here))
+    return (dh.astype(d_rows.dtype), _float0(tok), _float0(at),
+            _float0(here))
+
+
+_gather_pairs.defvjp(_gather_pairs_fwd, _gather_pairs_bwd)
+
+
+@jax.custom_vjp
+def _sum_pairs(out, gw, g_row, tok, at, here):
+    """y[t] = sum_j gw[t, j] out[at[t, j]] over the pairs `here`, float32:
+    the gated sum, back in token order. Its transpose gathers too: a
+    sorted row takes its token's cotangent times its pair's gate (`g_row`
+    [S]: `gw` in sorted order, 0 for a row of no expert)."""
+    return _sum_pairs_fwd(out, gw, g_row, tok, at, here)[0]
+
+
+def _pairs_out(out, at, here):
+    """Per choice j, the sorted rows of every token's j-th pair [T, D]
+    (zeros where that pair is not computed here): k gathers of T rows,
+    each consumed by the elementwise pass that follows it, where one
+    gather of T k rows would stand whole in memory, k on the chip's 8-row
+    tile."""
+    return [jnp.where(here[:, j, None], jnp.take(out, at[:, j], axis=0),
+                      jnp.zeros((), out.dtype)) for j in range(at.shape[1])]
+
+
+def _sum_pairs_fwd(out, gw, g_row, tok, at, here):
+    y = sum(gw[:, j, None] * g.astype(jnp.float32)
+            for j, g in enumerate(_pairs_out(out, at, here)))
+    return y, (out, g_row, tok, at, here)
+
+
+def _sum_pairs_bwd(res, dy):
+    out, g_row, tok, at, here = res
+    # dy rounded to the rows' type BEFORE the gather: S float32 rows of D
+    # (0.45 GB in a 16k-token step) would stand whole in memory otherwise
+    d_out = (jnp.take(dy.astype(out.dtype), tok, axis=0)
+             * g_row[:, None].astype(out.dtype))
+    d_gw = jnp.stack([jnp.sum(g.astype(jnp.float32) * dy, -1)
+                      for g in _pairs_out(out, at, here)], axis=1)
+    return (d_out, d_gw, jnp.zeros_like(g_row), _float0(tok), _float0(at),
+            _float0(here))
+
+
+_sum_pairs.defvjp(_sum_pairs_fwd, _sum_pairs_bwd)
+
+
+def _float0(x):
+    return np.zeros(x.shape, jax.dtypes.float0)
+
+
+def expert_share_train(h: jax.Array, lp: Dict[str, jax.Array], *, k: int,
+                       first: int, scale: float = 1.0,
+                       normalize: bool = True,
+                       valid: Optional[jax.Array] = None,
+                       score: str = "sigmoid"):
+    """`expert_share_ffn` under `jax.grad`, for ONE layer's experts
+    (`lp["experts_*"]` [n, D, F], what a scan over layers hands its body:
+    a gradient with respect to a whole stack would be Lm times the
+    layer's): the same routing, sort by held expert, grouped GEMMs and
+    float32 gated sum (`_sorted_pairs`, `_grouped_mlp`), the same value
+    and `stats`. What differs follows from the reverse mode. All T tokens
+    go at once, so that a held expert sees its T k / E rows in one
+    grouped GEMM and reads its weights once, forward and in each backward
+    form. ONE pass over a sorted buffer of all T k pairs (a `while_loop`
+    of passes has no reverse mode), so no pair is ever dropped, whatever
+    the routing: the buffer bounds the worst case, and the grouped GEMMs
+    walk only the row tiles of the local pairs that lead it (n / E of
+    them on average), so the step does not pay for the rows behind them.
+    A short buffer with further passes under `lax.cond` was tried first
+    and takes MORE memory: the chip's compiler holds every pass's buffers
+    apart (one layer's forward and backward at 16k tokens, D 3584: 4.57
+    GiB of temporaries in this form, 6.0 with two passes of half the
+    rows, 7.7 with four of 5/16; compiled for a described v5e, PR 36).
+    The gates carry gradient; the selection (and a selection bias) none.
+    `moe_full_passes` is 0 by construction."""
+    T, D = h.shape
+    cd = h.dtype
+    n = lp["experts_gate"].shape[0]
+    if valid is None:
+        valid = jnp.ones((T,), bool)
+    local, gates, order, sizes = _sorted_pairs(h, lp, valid, k, first, n,
+                                               scale, normalize, score)
+    with jax.named_scope("moe_dispatch"):
+        order = order.astype(jnp.int32)
+        at = jnp.argsort(order).reshape(T, k).astype(jnp.int32)
+        tok = order // k
+        gw = jnp.where(local, gates, 0.0)                   # [T, k]
+        rows = _gather_pairs(h, tok, at, local)                 # [S, D]
+        # each sorted row's gate, for the combine's transpose: 0 behind
+        # the local pairs, as gw is for a pair that is not computed here
+        g_row = jax.lax.stop_gradient(jnp.take(gw.reshape(T * k), order))
+    with jax.named_scope("moe_experts"):
+        w = {m: lp["experts_" + m].astype(cd)
+             for m in ("gate", "up", "down")}
+        out = _grouped_mlp(rows, w, sizes)
+    with jax.named_scope("moe_combine"):
+        y = _sum_pairs(out, gw, g_row, tok, at, local)
+    return y.astype(cd), _share_stats(sizes, jnp.zeros((), jnp.int32))
 
 
 def _grouped_mlp(rows, w, group_sizes):

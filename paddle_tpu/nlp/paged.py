@@ -302,9 +302,15 @@ class RefcountingBlockAllocator(BlockAllocator):
 
 
 def _refuse_latent(weight_dtype=None, kv_dtype=None, speculative=False,
-                   mesh=None) -> None:
+                   mesh=None, hc_mult: int = 1) -> None:
     """What the served path cannot do yet for a latent (MLA) mixer with
     expert layers, refused at construction, each by its mechanism."""
+    if hc_mult > 1:
+        raise NotImplementedError(
+            f"hc_mult={hc_mult}: the served layer stack carries ONE "
+            f"residual stream; the multi-stream (hyper-connection) path "
+            f"of nlp/hyper.py runs under nlp/mla_train.py's training step "
+            f"only")
     if weight_dtype not in (None, "fp"):
         raise NotImplementedError(
             f"weight_dtype={weight_dtype!r}: weight-only quantization "
@@ -1688,7 +1694,8 @@ class ContinuousBatcher:
         kinds = _layer_kinds(cfg)       # None for a latent decoder too
         if _is_latent(cfg):
             _refuse_latent(weight_dtype=weight_dtype, kv_dtype=kv_dtype,
-                           speculative=speculative, mesh=mesh)
+                           speculative=speculative, mesh=mesh,
+                           hc_mult=getattr(cfg, "hc_mult", 1))
         elif kinds is not None or _has_experts(cfg):
             _refuse_kinded(weight_dtype=weight_dtype, kv_dtype=kv_dtype,
                            speculative=speculative, mesh=mesh,

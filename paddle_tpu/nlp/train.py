@@ -11,6 +11,7 @@ has no runtime equivalent here by design.
 from __future__ import annotations
 
 import functools
+import sys
 from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
@@ -65,11 +66,20 @@ def make_optimizer(learning_rate=3e-4, weight_decay=0.1, b1=0.9, b2=0.95,
     return tx
 
 
-def state_specs(cfg, tx, pp: bool = False, model=llama) -> TrainState:
+def model_of(cfg):
+    """The model module of a configuration object: the module that defines
+    its class (`llama.LlamaConfig` -> llama, `moe.MoeConfig` -> moe,
+    `mla_train.MlaTrainConfig` -> mla_train). Each exposes `init_params`,
+    `param_specs` and `loss_fn` with the same signatures, and optionally
+    `loss_and_metrics` (a loss with counters beside it)."""
+    return sys.modules[type(cfg).__module__]
+
+
+def state_specs(cfg, tx, pp: bool = False, model=None) -> TrainState:
     """PartitionSpec tree for the full TrainState: optimizer moments inherit
     each param's spec (= ZeRO: opt state sharded exactly like params).
-    `model` is the model module (llama or moe) — both expose init_params/
-    param_specs/loss_fn with the same signatures."""
+    `model` is the model module; it follows from `cfg` (`model_of`)."""
+    model = model or model_of(cfg)
     pspecs = model.param_specs(cfg, pp=pp)
     params_shape = jax.eval_shape(
         functools.partial(model.init_params, cfg=cfg), jax.random.key(0))
@@ -110,9 +120,11 @@ def _use_pp(mesh: Optional[Mesh]) -> bool:
             and mesh.shape["pp"] > 1)
 
 
-def init_state(key, cfg, tx, mesh: Optional[Mesh] = None, model=llama):
+def init_state(key, cfg, tx, mesh: Optional[Mesh] = None, model=None):
     """Initialize params + opt state, jitted with out_shardings so big models
     materialize directly sharded (never replicated on one chip)."""
+    model = model or model_of(cfg)
+
     def init():
         params = model.init_params(key, cfg)
         return TrainState(step=jnp.zeros((), jnp.int32), params=params,
@@ -133,9 +145,10 @@ def make_train_step(cfg, tx, mesh: Optional[Mesh] = None,
                     grad_accum_steps: int = 1,
                     pp_schedule: str = "1f1b",
                     virtual_pp_degree: int = 2,
-                    model=llama) -> Callable:
-    """Build the jitted train step. With a mesh: full GSPMD shardings on
-    state and batch; without: plain jit (single device). A mesh with pp > 1
+                    model=None) -> Callable:
+    """Build the jitted train step for `cfg`'s model (`model_of`: the
+    module follows from the configuration object). With a mesh: full GSPMD
+    shardings on state and batch; without: plain jit (single device). A mesh with pp > 1
     runs the decoder through a compiled pipeline schedule —
     `num_microbatches` (default 2·pp) microbatches per step (llama AND moe
     both pipeline via their forward_pp). pp_schedule picks the compiled
@@ -156,7 +169,14 @@ def make_train_step(cfg, tx, mesh: Optional[Mesh] = None,
     memory drops by the accumulation factor; numerics match the full batch
     up to bf16 forward rounding (chunked reductions associate differently).
     Chunks interleave rows (strided) so each chunk stays spread across the
-    dp/sharding batch shards."""
+    dp/sharding batch shards.
+
+    A model whose module has `loss_and_metrics` (a loss with its counters
+    beside it) gets them into the step's `metrics`."""
+    model = model or model_of(cfg)
+    with_aux = hasattr(model, "loss_and_metrics")
+    if with_aux and (_use_pp(mesh) or grad_accum_steps > 1):
+        raise ValueError("loss_and_metrics: one whole batch a step, no pp")
     pp = _use_pp(mesh) and hasattr(model, "forward_pp")
     mb = (num_microbatches or 2 * mesh.shape["pp"]) if pp else None
     if pp_schedule not in ("1f1b", "gpipe", "interleaved"):
@@ -180,8 +200,11 @@ def make_train_step(cfg, tx, mesh: Optional[Mesh] = None,
                     p, t, cfg, mesh, mb, pp_virtual)
             else:
                 lfn = lambda p, t: model.loss_fn(p, t, cfg, mesh, mb)  # noqa: E731
+        elif with_aux:
+            lfn = lambda p, t: model.loss_and_metrics(p, t, cfg, mesh)  # noqa: E731
         else:
             lfn = lambda p, t: model.loss_fn(p, t, cfg, mesh)  # noqa: E731
+        aux = {}
         if grad_accum_steps > 1:
             b = tokens.shape[0]
             if b % grad_accum_steps:
@@ -208,6 +231,9 @@ def make_train_step(cfg, tx, mesh: Optional[Mesh] = None,
         elif use_1f1b:
             loss, grads = model.loss_and_grad_pp(
                 state.params, tokens, cfg, mesh, mb, pp_virtual)
+        elif with_aux:
+            (loss, aux), grads = jax.value_and_grad(lfn, has_aux=True)(
+                state.params, tokens)
         else:
             loss, grads = jax.value_and_grad(lfn)(state.params, tokens)
         if mesh is None and hasattr(tx, "apply_fused"):
@@ -223,7 +249,7 @@ def make_train_step(cfg, tx, mesh: Optional[Mesh] = None,
             updates, new_opt = tx.update(grads, state.opt_state,
                                          state.params)
             new_params = optax.apply_updates(state.params, updates)
-        metrics = {"loss": loss,
+        metrics = {**aux, "loss": loss,
                    "grad_norm": optax.global_norm(grads),
                    "step": state.step}
         return TrainState(state.step + 1, new_params, new_opt), metrics
@@ -236,10 +262,8 @@ def make_train_step(cfg, tx, mesh: Optional[Mesh] = None,
                             is_leaf=lambda x: isinstance(x, P))
     batch_sh = NamedSharding(
         mesh, getattr(model, "batch_spec", llama.batch_spec)())
-    metric_sh = {"loss": NamedSharding(mesh, P()),
-                 "grad_norm": NamedSharding(mesh, P()),
-                 "step": NamedSharding(mesh, P())}
+    # every metric a replicated scalar, whatever the model adds to them
     return jax.jit(train_step,
                    in_shardings=(state_sh, batch_sh),
-                   out_shardings=(state_sh, metric_sh),
+                   out_shardings=(state_sh, NamedSharding(mesh, P())),
                    donate_argnums=(0,) if donate else ())

@@ -31,6 +31,11 @@ import optax
 from ..kernels.naming import named_jit
 
 BLOCK = 256
+# leaves under this many elements keep float32 moments (bitsandbytes'
+# min_8bit_size): a gain of three scalars or a bias of 24 shares ONE block
+# scale, so a float8 code's 6% step lands whole on what the optimizer
+# applies, and the bytes saved are nothing
+MIN_QUANT = 4096
 
 # blocks per lax.map chunk: 65536 * 256 = 16M params; each chunk holds
 # ~4 f32 transients of that size before XLA fusion (g, dequant m, dequant
@@ -81,7 +86,9 @@ def _quantize(x: jax.Array, sqrt_space: bool) -> _QTensor:
     return _q_blocks(flat.reshape(-1, BLOCK), sqrt_space)
 
 
-def _dequantize(q: _QTensor, shape, sqrt_space: bool) -> jax.Array:
+def _dequantize(q, shape, sqrt_space: bool) -> jax.Array:
+    if not isinstance(q, _QTensor):     # a small leaf's float32 moment
+        return q.reshape(shape)
     blocks = _dq_blocks(q, sqrt_space)
     n = 1
     for s in shape:
@@ -106,6 +113,15 @@ class ScaleByAdamQState(NamedTuple):
     v: Any   # pytree of _QTensor
 
 
+def _plain_moments(g, m, v, gscale, bc1, bc2, b1, b2, eps):
+    """Adam's moments and bias-corrected update of one small leaf whose
+    moments are float32 arrays (v in linear space): (update, m', v')."""
+    g = g.astype(jnp.float32) * gscale
+    m = b1 * m + (1 - b1) * g
+    v = b2 * v + (1 - b2) * g * g
+    return (m / bc1) / (jnp.sqrt(v / bc2) + eps), m, v
+
+
 def scale_by_adam_q(b1: float = 0.9, b2: float = 0.999,
                     eps: float = 1e-8, clip_norm: Optional[float] = None
                     ) -> optax.GradientTransformation:
@@ -128,6 +144,8 @@ def scale_by_adam_q(b1: float = 0.9, b2: float = 0.999,
         # cost ~2 full-leaf f32 transients per moment, the very peak the
         # chunked update path exists to avoid)
         def zero_q(p):
+            if p.size < MIN_QUANT:
+                return jnp.zeros(p.shape, jnp.float32)
             nb = (p.size + BLOCK - 1) // BLOCK
             return _QTensor(jnp.zeros((nb, BLOCK), F8),
                             jnp.full((nb, 1), 1e-30 / F8_MAX, jnp.float32))
@@ -158,6 +176,10 @@ def scale_by_adam_q(b1: float = 0.9, b2: float = 0.999,
             return upd.astype(out_dt), _q_blocks(m, False), _q_blocks(v, True)
 
         def leaf(g, mq, vq):
+            if not isinstance(mq, _QTensor):        # a small leaf: float32
+                upd, m, v = _plain_moments(g, mq, vq, gscale, bc1, bc2,
+                                           b1, b2, eps)
+                return upd.astype(g.dtype), m, v
             nb = mq.codes.shape[0]
             gf = jnp.pad(g.reshape(-1),
                          (0, _pad_len(g.size))).reshape(nb, BLOCK)
@@ -371,9 +393,18 @@ def adamw_q_fused(learning_rate, b1: float = 0.9, b2: float = 0.999,
         flat_p = treedef.flatten_up_to(params)
         flat_m = treedef.flatten_up_to(state.m)
         flat_v = treedef.flatten_up_to(state.v)
+
+        def small(g, p, m, v):
+            upd, m, v = _plain_moments(g, m, v, gscale, bc1, bc2, b1, b2,
+                                       eps)
+            p32 = p.astype(jnp.float32)
+            return ((p32 * (1.0 - lr * weight_decay) - lr * upd
+                     ).astype(p.dtype), m, v)
+
         out = [_fused_leaf_update(scalars, g, p, mq, vq, b1=b1, b2=b2,
                                   eps=eps, wd=weight_decay,
                                   interpret=interpret)
+               if isinstance(mq, _QTensor) else small(g, p, mq, vq)
                for g, p, mq, vq in zip(flat_g, flat_p, flat_m, flat_v)]
         new_params = treedef.unflatten([o[0] for o in out])
         new_m = treedef.unflatten([o[1] for o in out])

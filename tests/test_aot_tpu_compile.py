@@ -161,6 +161,65 @@ def _flash(topo, mesh=False):
                     ((B, S, KV, HD), BF))
 
 
+def _flash_mla(topo):
+    """Flash forward and backward at the latent attention's expanded
+    widths, as `mla.attend_expanded` calls them: q and k 192 wide, v
+    zero-padded from 128 to 192, 4 x 4096 tokens of 32 heads (the streamed
+    backward: 4096 is past the resident kernels' 2048)."""
+    from paddle_tpu.nlp import mla
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = mla.MlaMoeConfig(
+        hidden_size=3584, num_attention_heads=32, q_lora_rank=768,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, dtype=BF, param_dtype=BF)
+
+    def loss(q, row, w):
+        o = mla.attend_expanded(q, row, {"kv_b_proj": w}, cfg)
+        return jnp.sum(o.astype(jnp.float32))
+
+    txt = _compile(jax.value_and_grad(loss, (0, 1, 2)), [one] * 3,
+                   ((4, 4096, 32, 192), BF), ((4, 4096, 576), BF),
+                   ((512, 32 * 256), BF))
+    calls = [line for line in txt.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert any("bf16[128,4096,192]" in c for c in calls), calls[:1]
+    assert len(calls) >= 2                          # forward and backward
+    return txt
+
+
+def _mhc_train_step(topo):
+    """The whole training step of the benchmark's `xing4-ep4-train`
+    configuration at its own sizes (4 x 4096 tokens, bf16 parameters, 8-bit
+    Adam, per-layer remat), for ONE described v5e: the chip's compiler
+    refuses a program that does not fit its 15.75 GiB."""
+    from benchmark.harness import manifest
+    from paddle_tpu.nlp import train
+    one = SingleDeviceSharding(topo.devices[0])
+    config = manifest.config(manifest.ROOT, "xing4-ep4-train")
+    fam = manifest.plugin("models", config["family"])
+    d, pcfg = fam.dims(config), fam.program_config(config)
+    t = config["trainer"]
+    tx = train.make_optimizer(
+        t["learning_rate"], weight_decay=t["weight_decay"], b1=t["b1"],
+        b2=t["b2"], grad_clip=t["grad_clip"], state_quant=t["state_quant"])
+    params = fam.params_shape(d, pcfg.param_dtype)
+    state = train.TrainState(jax.ShapeDtypeStruct((), jnp.int32), params,
+                             jax.eval_shape(tx.init, params))
+    on = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)  # noqa: E731
+    exe = train.make_train_step(pcfg, tx, mesh=None).lower(
+        jax.tree.map(on, state),
+        on(jax.ShapeDtypeStruct((4, 4096), jnp.int32))).compile()
+    mem = exe.memory_analysis()
+    # the state is donated: all of it but the batch is written in place
+    assert mem.argument_size_in_bytes - mem.alias_size_in_bytes <= 4 * 16384
+    assert 4.5 * 2**30 < mem.argument_size_in_bytes < 6 * 2**30
+    txt = exe.as_text()
+    # the experts' grouped GEMMs are the chip's own, over ONE sorted
+    # buffer of all 16384 x 4 pairs, in no loop of passes
+    assert re.search(r"ragged-dot[-\w.]* = bf16\[65536,1024\]", txt)
+    return txt
+
+
 def _rms_norm(topo):
     from paddle_tpu.kernels.rms_norm import rms_norm_train
     one = SingleDeviceSharding(topo.devices[0])
@@ -275,6 +334,8 @@ CASES = {
     "ragged-full-table-800-prefill-4x128": lambda t: _ragged_window(
         t, 4, 128, 800, False),
     "flash-fwd-bwd-2048": _flash,
+    "flash-fwd-bwd-4096-q192-v128": _flash_mla,
+    "mhc-mla-moe-train-step-4x4096": _mhc_train_step,
     "flash-shard_map-4dev": lambda t: _flash(t, mesh=True),
     "rms_norm-fwd-bwd-4096": _rms_norm,
     "adam8-fused-update": _adam8,
