@@ -28,6 +28,7 @@ MoE block stays differentiable jnp — no host-side routing, no ragged shapes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -35,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..kernels.grouped_gemm import gemm_work_list, grouped_gemm
 from ..kernels.rms_norm import rms_norm_ref, rms_norm_train
 from ..kernels.rope import rope_freqs
 from . import llama as _llama
@@ -227,54 +229,80 @@ def expert_share_ffn(h: jax.Array, lp: Dict[str, jax.Array], *, k: int,
     This is the SERVED form: a `while_loop` of passes has no reverse
     mode. Under `jax.grad` the same layer is `expert_share_train`.
 
-    The stack goes into the grouped GEMM whole, as Lm*n groups of which
-    only this layer's n have rows. A scan that sliced the layer's experts
-    out instead would copy them (1 GB a layer at A.X-K1's widths) before
-    every GEMM: the grouped GEMM is a custom call and takes no fused
-    slice (my chip runs, PR 26: 19 of a decode step's 43 ms).
+    The grouped GEMMs are the repo's own kernel
+    (`kernels/grouped_gemm.py`): its grid is a list, built on the device
+    from the rows on each held expert, of the live (row tile, hit expert)
+    items of a pass, and an item's weight block is read IN the stack,
+    `stack[layer * n + expert]`, so a layer-step reads the matrices of
+    the experts it hit, once each, and nothing of the rest. The stack
+    goes in whole (reshaped to Lm*n groups: a bitcast): a scan that
+    sliced the layer's experts out instead would copy them (1 GB a layer
+    at A.X-K1's widths) before every GEMM, a custom call taking no fused
+    slice (my chip runs, PR 26: 19 of a decode step's 43 ms). Until PR
+    37 the GEMMs were `lax.ragged_dot` over all Lm*n groups: the groups
+    cost it nothing, but every HIT expert a tile of its own choosing,
+    0.025 ms for a Mellum2 matrix of 0.005 ms of bytes (PERF.md section
+    6, PR 37).
 
     Static shapes, no capacity and no recompile per routing: the T*k
     (token, choice) pairs are sorted by held expert (pairs of absent
     experts, and of tokens `valid` [T] masks, sort last and belong to no
-    group) and the experts run as grouped GEMMs (`lax.ragged_dot`) over
-    the sorted rows, so the work follows the pairs routed here, not
-    held experts x tokens. More than `token_block` tokens are processed
-    in blocks of that size, which bounds the sorted buffer at
-    token_block * k rows (every pair local is the worst case, and it
-    must fit).
+    expert) and the experts run as grouped GEMMs over the sorted rows, so
+    the work follows the pairs routed here, not held experts x tokens.
+    More than `token_block` tokens are processed in blocks of that size,
+    which bounds the sorted buffer at token_block * k rows (every pair
+    local is the worst case, and it must fit).
 
     The sorted buffer is SHORT (`_short_rows`): the local pairs lie
     first after the sort, n / E of the T*k on average (n held of the
-    router's E), so the GEMMs run over S sorted rows at a time, in a
-    loop of as many passes as the local pairs need: one in all but
-    freak routings, none where no pair is local, T*k / S where every
-    pair is. Every local pair is computed in exactly one pass, in the
-    same precision (a token's gated sum is kept in float32 across
-    passes): dropless and exact, whatever the routing.
+    router's E), so the gathers, the GEMMs' list and the gated SiLU run
+    over S sorted rows at a time, in a loop of as many passes as the
+    local pairs need: one in all but freak routings, none where no pair
+    is local, T*k / S where every pair is. Every local pair is computed
+    in exactly one pass, in the same precision (a token's gated sum is
+    kept in float32 across passes): dropless and exact, whatever the
+    routing.
 
     Returns (y [T, D] in h's dtype, stats): stats holds int32 scalars
     `moe_pairs` (pairs computed here), `moe_experts_hit` (held experts
-    that got a token), `moe_load_max` (most pairs on one expert) and
+    that got a token), `moe_load_max` (most pairs on one expert),
     `moe_full_passes` (token blocks whose local pairs overflowed one
-    sorted buffer, so that it ran again)."""
+    sorted buffer, so that it ran again) and `moe_gemm_items` (the items
+    one grouped GEMM's list held, summed over passes and blocks: over
+    `moe_experts_hit` it is 1.0 where every hit expert's rows lie in one
+    row tile, and says how many masked tiles a prefill pass pays)."""
     T, D = h.shape
     n = lp["experts_gate"].shape[-3]
     if valid is None:
         valid = jnp.ones((T,), bool)
+    # what one trace of `_share_block` may not decide for the next caller
+    # at its shapes is its static argument, read here: the buffer's rows
+    # and the kernel's mode
+    block = functools.partial(
+        _share_block, k=k, first=first, n=n, scale=scale,
+        normalize=normalize, score=score,
+        interpret=jax.default_backend() != "tpu")
+
+    def buffer_rows(tokens):
+        return min(_short_rows(tokens * k, n, lp["router"].shape[1]),
+                   tokens * k)
+
     if T > token_block:
         nb = -(-T // token_block)
         pad = nb * token_block - T
         hb = jnp.pad(h, ((0, pad), (0, 0))).reshape(nb, token_block, D)
         vb = jnp.pad(valid, (0, pad)).reshape(nb, token_block)
-        yb, sizes, full = jax.lax.map(
-            lambda a: _share_block(a[0], lp, a[1], k, first, n, scale,
-                                   normalize, layer, score), (hb, vb))
+        yb, sizes, full, items = jax.lax.map(
+            lambda a: block(a[0], lp, a[1], layer,
+                            buffer_rows=buffer_rows(token_block)),
+            (hb, vb))
         y = yb.reshape(nb * token_block, D)[:T]
         sizes = jnp.sum(sizes, 0, dtype=jnp.int32)
     else:
-        y, sizes, full = _share_block(h, lp, valid, k, first, n, scale,
-                                      normalize, layer, score)
-    return y, _share_stats(sizes, full)
+        y, sizes, full, items = block(h, lp, valid, layer,
+                                      buffer_rows=buffer_rows(T))
+    return y, {**_share_stats(sizes, full),
+               "moe_gemm_items": jnp.sum(items, dtype=jnp.int32)}
 
 
 def _share_stats(sizes, full):
@@ -313,30 +341,49 @@ def _short_rows(pairs: int, held: int, routed: int) -> int:
     caller caps it at `pairs`: a chip that holds half its experts, or a
     handful of tokens, makes one pass over them all).
 
-    Why odd multiples of 128: the chip's grouped GEMM (v5e) takes its
-    row tile from the buffer's ROW COUNT, 512 rows where that is a
-    multiple of 512, 256 of 256, and 128 where it is an odd multiple of
-    128, and every expert that gets one token pays a whole tile of
-    masked rows. At A.X-K1's widths (7168 x 2048, an expert's three
-    matrices 0.1075 ms of HBM reads) a hit expert costs 0.28 ms in a
-    buffer of 512 rows and 0.146 ms in one of 128; a fused step's 288
-    local rows cost 3.5 ms a layer in 4608 rows, 3.4 in 1024, 2.05 in
-    1152, 896, 640 or 384 (chip runs of PR 26 and PR 28, PERF.md
-    section 6; `tools/micro_moe.py share` measures it again). Twice the
-    expectation, because a second pass costs as much as the first and
-    routing is not uniform: `moe_full_passes` counts how often one is
-    needed."""
+    What the rule is for since PR 37: the buffer bounds the rows the
+    gathers, the gated SiLU and the combine touch a pass (the layer at
+    A.X-K1's widths, a fused step's 576 tokens: 2.03 ms over 640 rows,
+    2.32 over all 4608 pairs); the grouped GEMMs no longer care, their
+    kernel walks the row tiles that hold a hit expert's rows and no
+    other (0.0405-0.0408 ms a hit expert a GEMM in buffers of 128 to 640
+    rows, `tools/micro_moe.py share`, PERF.md section 6, PR 37). Twice
+    the expectation, because a second pass costs as much as the first
+    and routing is not uniform: `moe_full_passes` counts how often one
+    is needed. A multiple of 128, the kernel's row tile.
+
+    Why ODD multiples: history. `lax.ragged_dot` took its row tile from
+    the buffer's ROW COUNT on the v5e, 512 rows where that is a multiple
+    of 512, 256 of 256, and 128 where it is an odd multiple of 128, and
+    every hit expert paid a whole tile of masked rows (0.094 ms a hit
+    expert a GEMM in 512 rows, 0.060 in 256, 0.049 in 128, 384 or 640).
+    The kernel's tile is its own, so an even multiple would do as well;
+    the sizes are kept (the compiled programs and the tests know them)
+    and cost at most one tile of rows that no item visits."""
     tiles = max(-(-2 * pairs * held // (routed * 128)), 1)
     return (tiles + 1 - tiles % 2) * 128
 
 
-def _share_block(h, lp, valid, k, first, n, scale, normalize, layer,
-                 score="sigmoid"):
+# one jitted object: the layers of a scan step's period, the forwards of
+# a fused step and every step program that runs a block of the same
+# shapes share one trace, and a program lowers the block once however
+# many of its layers call it. A `mellum2-l8` forward has four call
+# sites; traced and lowered apart they took 155 k + 150 k Python calls a
+# forward, 21 k + 89 k as one (19 programs' warm set-up: PERF.md section
+# 6, PR 37). XLA inlines the call before it fuses.
+@functools.partial(jax.jit, static_argnames=(
+    "k", "first", "n", "scale", "normalize", "score", "buffer_rows",
+    "interpret"))
+def _share_block(h, lp, valid, layer, *, k, first, n, scale, normalize,
+                 score, buffer_rows, interpret):
     """(y [T, D], pairs on each held expert [n], 1 if the local pairs
-    overflowed one sorted buffer else 0) of one block of tokens."""
+    overflowed one sorted buffer else 0, the items a grouped GEMM's list
+    held summed over the passes) of one block of tokens, over a sorted
+    buffer of `buffer_rows` rows a pass (`_short_rows`, the caller's to
+    read); `interpret`: the grouped GEMM's Pallas mode."""
     T, D = h.shape
     cd = h.dtype
-    S = min(_short_rows(T * k, n, lp["router"].shape[1]), T * k)
+    S = buffer_rows
     local, gates, order, sizes = _sorted_pairs(h, lp, valid, k, first, n,
                                                scale, normalize, score)
     with jax.named_scope("moe_dispatch"):
@@ -344,9 +391,11 @@ def _share_block(h, lp, valid, k, first, n, scale, normalize, layer,
         ends = jnp.cumsum(sizes)        # a group's end among the sorted
     with jax.named_scope("moe_experts"):
         Lm = lp["experts_gate"].shape[0]
+        # the stack's Lm*n experts as the kernel's groups: a bitcast
         w = {m: lp["experts_" + m].astype(cd).reshape(
             Lm * n, *lp["experts_" + m].shape[2:])
             for m in ("gate", "up", "down")}
+        base = jnp.asarray(layer, jnp.int32) * n
     with jax.named_scope("moe_combine"):
         inv = jnp.argsort(order)        # a pair's place when sorted
         gw = jnp.where(local, gates, 0.0)                   # [T, k]
@@ -355,19 +404,17 @@ def _share_block(h, lp, valid, k, first, n, scale, normalize, layer,
 
     def one_pass(carry):
         """Sorted rows lo .. lo + S: their part of every token's sum."""
-        lo, y = carry
+        lo, y, items = carry
         with jax.named_scope("moe_dispatch"):
             pairs = jax.lax.dynamic_slice(padded, (lo,), (S,))
             rows = jnp.take(h, pairs // k, axis=0)              # [S, D]
         with jax.named_scope("moe_experts"):
-            # what of each group lies in this pass; the stack's Lm*n
-            # groups are all empty but this layer's n
+            # what of each expert's rows lies in this pass, and the list
+            # of (row tile, hit expert) items its three GEMMs walk
             part = (jnp.clip(ends - lo, 0, S)
                     - jnp.clip(ends - sizes - lo, 0, S))
-            gs = jax.lax.dynamic_update_slice(
-                jnp.zeros((Lm * n,), jnp.int32), part,
-                (jnp.asarray(layer, jnp.int32) * n,))
-            out = _grouped_mlp(rows, w, gs)
+            work = gemm_work_list(part, base, rows=S)
+            out = _served_mlp(rows, w, part, base, work, interpret)
         with jax.named_scope("moe_combine"):
             # back to (token, choice) order; a pair outside this pass, or
             # outside every group, contributes nothing whatever the row
@@ -379,12 +426,27 @@ def _share_block(h, lp, valid, k, first, n, scale, normalize, layer,
                             jnp.zeros((), cd))
             y = y + jnp.einsum("tkd,tk->td", out, gw,
                                preferred_element_type=jnp.float32)
-        return lo + S, y
+        return lo + S, y, items + work.count
 
-    _, y = jax.lax.while_loop(
+    z = jnp.zeros((), jnp.int32)
+    _, y, items = jax.lax.while_loop(
         lambda c: c[0] < n_local, one_pass,
-        (jnp.zeros((), jnp.int32), jnp.zeros((T, D), jnp.float32)))
-    return y.astype(cd), sizes, (n_local > S).astype(jnp.int32)
+        (z, jnp.zeros((T, D), jnp.float32), z))
+    return y.astype(cd), sizes, (n_local > S).astype(jnp.int32), items
+
+
+def _served_mlp(rows, w, sizes, base, work, interpret=None):
+    """Gated SiLU MLPs of ONE layer's experts inside the stack, as three
+    grouped GEMMs of the repo's own kernel (`kernels/grouped_gemm.py`):
+    rows [S, D] sorted by expert, `sizes` [n] rows on each, w["gate" |
+    "up"] [Lm n, D, F], w["down"] [Lm n, F, D] the whole stacks, `base`
+    the layer's first expert in them, `work` the items all three walk. A
+    row past the experts' sum belongs to none and its output means
+    nothing. The served form's alone: `_grouped_mlp` is the other."""
+    gemm = functools.partial(grouped_gemm, sizes=sizes, base=base, work=work,
+                             interpret=interpret)
+    g, u = gemm(rows, w["gate"]), gemm(rows, w["up"])
+    return gemm((jax.nn.silu(g) * u).astype(rows.dtype), w["down"])
 
 
 @jax.custom_vjp

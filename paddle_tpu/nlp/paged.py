@@ -1048,8 +1048,8 @@ def _ffn_experts(x, h, lp, cfg, groups, stats, stacks, layer):
 
 def _merge_stats(a, b):
     """Counters of two pieces of work: pairs, hit experts, overflowed
-    sorted buffers and the attention kernel's work items add up, the
-    largest load on one expert is a maximum."""
+    sorted buffers, the grouped GEMM's and the attention kernel's work
+    items add up, the largest load on one expert is a maximum."""
     return {k: (jnp.maximum(v, b[k]) if k == "moe_load_max" else v + b[k])
             for k, v in a.items()}
 
@@ -1267,7 +1267,7 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
     if (latent and cfg.num_moe_layers) or (not latent and _has_experts(cfg)):
         z = jnp.zeros((), jnp.int32)
         stats = {"moe_pairs": z, "moe_experts_hit": z, "moe_load_max": z,
-                 "moe_full_passes": z}
+                 "moe_full_passes": z, "moe_gemm_items": z}
     carry = (x, k_all, v_all, ks_all, vs_all, jnp.int32(0), stats)
     first_layer = 0
     for layers, ffn, kinds in _layer_groups(params, cfg):

@@ -268,14 +268,24 @@ def _mla(topo, R, Pq):
     return txt
 
 
-def _expert_share(topo, T, short):
-    """The dropless expert layer: 12 held experts of A.X-K1's width under
-    a 192-wide router; the grouped GEMMs must be the chip's own, and run
-    over the `short` sorted buffer alone (a loop of as many passes as the
-    local pairs need), never over all T x 8 pairs."""
+# held experts n of E routed, Lm expert layers in the stack, D x F
+EXPERT_SHAPES = {"axk1-ep16": (12, 192, 2, 7168, 2048),
+                 "mellum2-l8": (64, 64, 8, 2304, 896)}
+
+
+def _expert_share(topo, T, short, shape="axk1-ep16"):
+    """The dropless expert layer at a served configuration's widths
+    (`axk1-ep16`: 12 held experts under a 192-wide router; `mellum2-l8`:
+    all 64 of 64, 8 layers in the stack): the three grouped GEMMs must be
+    the repo's own kernel, each handed the WHOLE stack of every layer's
+    experts (a bitcast of the argument: no slice or copy of it anywhere
+    in the module) and its list of items, over the `short` sorted buffer
+    alone (a loop of as many passes as the local pairs need), never over
+    all T x 8 pairs; `lax.ragged_dot` is not in the served form."""
+    from paddle_tpu.kernels.grouped_gemm import gemm_items
     from paddle_tpu.nlp import moe
     one = SingleDeviceSharding(topo.devices[0])
-    Dm, Fm, n = 7168, 2048, 12
+    n, E, Lm, Dm, Fm = EXPERT_SHAPES[shape]
 
     def fn(h, router, g, u, d):
         lp = {"router": router, "experts_gate": g, "experts_up": u,
@@ -283,13 +293,39 @@ def _expert_share(topo, T, short):
         return moe.expert_share_ffn(h, lp, k=8, first=0, scale=2.5,
                                     layer=1)[0]
 
-    txt = _compile(fn, [one] * 5, ((T, Dm), BF), ((Dm, 192), BF),
-                   ((2, n, Dm, Fm), BF), ((2, n, Dm, Fm), BF),
-                   ((2, n, Fm, Dm), BF))
-    gemms = re.findall(r"%ragged-dot-none[.\d]* = bf16\[(\d+),(\d+)\]", txt)
-    assert sorted((int(r), int(w)) for r, w in gemms) == [
-        (short, Fm), (short, Fm), (short, Dm)], gemms     # gate, up, down
-    assert " while(" in txt
+    # the kernel's gate asks the backend, which is the CPU's here: steer
+    # it from the test (the program has no option for it)
+    default_backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:
+        txt = _compile(fn, [one] * 5, ((T, Dm), BF), ((Dm, E), BF),
+                       ((Lm, n, Dm, Fm), BF), ((Lm, n, Dm, Fm), BF),
+                       ((Lm, n, Fm, Dm), BF))
+    finally:
+        jax.default_backend = default_backend
+    calls = re.findall(
+        r"%grouped_gemm[.\d]* = bf16\[(\d+),(\d+)\]\S* custom-call\(.*"
+        r'custom_call_target="tpu_custom_call", '
+        r"operand_layout_constraints=\{(.*?)\}, frontend_attributes", txt)
+    items = f"s32[], s32[4,{gemm_items(short, n)}]{{1,0}}, "
+    assert sorted((int(r), int(w), ops) for r, w, ops in calls) == sorted([
+        (short, Fm, items + f"bf16[{short},{Dm}]{{1,0}}, "
+         f"bf16[{Lm * n},{Dm},{Fm}]{{2,1,0}}")] * 2 + [
+        (short, Dm, items + f"bf16[{short},{Fm}]{{1,0}}, "
+         f"bf16[{Lm * n},{Fm},{Dm}]{{2,1,0}}")]), calls  # gate, up, down
+    assert "ragged-dot" not in txt and " while(" in txt
+    # the stacks reach the kernel as they came in: nothing but the entry's
+    # parameters, bitcasts and tuple plumbing has a stack's (or one
+    # layer's) shape
+    for line in txt.splitlines():
+        made = re.match(r"\s*(?:ROOT )?%\S+ = bf16\[([\d,]+)\]\S* (\S+?)\(",
+                        line)
+        if made and made[1] in (
+                f"{Lm},{n},{Dm},{Fm}", f"{Lm},{n},{Fm},{Dm}",
+                f"{Lm * n},{Dm},{Fm}", f"{Lm * n},{Fm},{Dm}",
+                f"{n},{Dm},{Fm}", f"{n},{Fm},{Dm}",
+                f"1,{n},{Dm},{Fm}", f"1,{n},{Fm},{Dm}"):
+            assert made[2] in ("parameter", "bitcast",
+                               "get-tuple-element"), line[:300]
     return txt
 
 
@@ -303,6 +339,12 @@ CASES = {
     "expert-share-decode-64-tokens": lambda t: _expert_share(t, 64, 128),
     "expert-share-fused-576-tokens": lambda t: _expert_share(t, 576, 640),
     "expert-share-fused-192-tokens": lambda t: _expert_share(t, 192, 384),
+    # every expert held (the sorted buffer is all T x 8 pairs): a decode
+    # step's 32 slots, and a fused step's 32 + 512 tokens
+    "expert-share-mellum2-decode-32-tokens": lambda t: _expert_share(
+        t, 32, 256, "mellum2-l8"),
+    "expert-share-mellum2-fused-544-tokens": lambda t: _expert_share(
+        t, 544, 4352, "mellum2-l8"),
     "ragged-decode": lambda t: _ragged(t, 8, 1),
     "ragged-prefill-bucket-512": lambda t: _ragged(t, 1, 512),
     "ragged-prefill-bucket-8": lambda t: _ragged(t, 8, 8),
