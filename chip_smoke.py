@@ -722,35 +722,15 @@ def phase_sharded_train(sizes: Sizes, seed: int) -> None:
 # driver
 # ---------------------------------------------------------------------------
 
-_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
-                 "/jax/compilation_cache/cache_misses": "misses"}
-_cache_seen: Dict[str, int] = {}
-
-
-def _count_cache_events() -> Dict[str, int]:
-    """Persistent-compile-cache hits and misses, counted by JAX itself
-    (one listener per process, registered on first use)."""
-    if not _cache_seen:
-        from jax import monitoring
-        _cache_seen.update(hits=0, misses=0)
-
-        def on_event(event: str, **_) -> None:
-            if event in _CACHE_EVENTS:
-                _cache_seen[_CACHE_EVENTS[event]] += 1
-
-        monitoring.register_event_listener(on_event)
-    return _cache_seen
-
-
 def run_phases(phases: Sequence[Tuple[str, Callable[[], None]]]) -> bool:
     """Run every phase (a failure does not stop the later ones — one
     chip call should show everything that is broken); True iff all
     passed."""
+    from paddle_tpu.core.compile_cache import compile_log
     failed = []
-    cache = _count_cache_events()
     for name, fn in phases:
         print(f"[{name}]", flush=True)
-        t0, before = time.perf_counter(), dict(cache)
+        t0 = time.perf_counter()
         try:
             fn()
             verdict = "ok"
@@ -759,9 +739,11 @@ def run_phases(phases: Sequence[Tuple[str, Callable[[], None]]]) -> bool:
             failed.append(name)
             verdict = "FAILED"
         gc.collect()
+        # the programs of the phase, by the compile log (its clock is t0's)
+        cache = compile_log.summary(since=t0)
         print(f"[{name}] {verdict} in {time.perf_counter() - t0:.1f} s "
-              f"(compile cache: {cache['hits'] - before['hits']} hits, "
-              f"{cache['misses'] - before['misses']} misses)", flush=True)
+              f"(compile cache: {cache['hits']} hits, "
+              f"{cache['misses']} misses)", flush=True)
     if failed:
         print("failed phases: " + ", ".join(failed))
     return not failed

@@ -49,6 +49,7 @@ from jax.sharding import PartitionSpec
 if TYPE_CHECKING:  # annotation-only: the nlp -> serving edge stays lazy
     from ..serving.cache import PrefixCacheIndex
 
+from ..core.compile_cache import compile_log, program_name
 from ..kernels.rms_norm import rms_norm_ref
 from ..kernels.rope import rope_freqs, apply_rope_half
 from ..profiler import RecordEvent
@@ -2223,11 +2224,10 @@ class ContinuousBatcher:
     def compile_count(self) -> int:
         """EVERY compiled device-step shape: the prefill/fused ladder
         plus the plain decode chunk executable plus the speculative
-        draft/verify pair. The zero-post-warmup-recompiles gate reads
-        this one — a decode-only stretch after a fused stretch must
-        not compile either (the chunk fn used to slip through
-        `prefill_compile_count`, compiling lazily on the first
-        standalone-decode step)."""
+        draft/verify pair: the memos `_aot` fills, a `compile_log`
+        record each. The zero-post-warmup-recompiles gate reads this
+        one (a decode-only stretch after a fused stretch must not
+        compile either)."""
         return (self.prefill_compile_count + len(self._chunk_cache)
                 + len(self._spec_cache))
 
@@ -2702,10 +2702,9 @@ class ContinuousBatcher:
         return jax.jit(serve_prefill_step)
 
     def _prefill_exe(self, G: int, Pb: int, cold: bool):
-        """Memoized COMPILED prefill per (group, bucket, phase) shape.
-        AOT-lowered from abstract avals, so `warmup_prefill` can populate
-        the whole ladder without running a single FLOP; steady-state
-        admission dispatches straight to a compiled executable and never
+        """Memoized COMPILED prefill per (group, bucket, phase) shape,
+        from abstract avals (`_aot`): `warmup_prefill` populates the
+        whole ladder without a FLOP and steady-state admission never
         retraces."""
         key = (G, Pb, cold, self.attention_impl) + self._skey \
             + self._qkey + self._mkey
@@ -2716,14 +2715,13 @@ class ContinuousBatcher:
                 fn = self._build_prefill(cold)
                 self._prefill_fns[cold] = fn
             sds, i32 = self._aval, jnp.int32
-            pstruct = self._pstruct()
-            exe = fn.lower(
-                pstruct, sds((G, Pb), i32),
+            exe = self._aot(
+                key, fn, sds((G, Pb), i32),
                 self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
                 self._scale_aval(self.cache.k_scale),
                 self._scale_aval(self.cache.v_scale),
                 sds((G, self._table_width), i32), sds((G, Pb), i32),
-                sds((G, Pb), jnp.bool_), sds((G,), i32)).compile()
+                sds((G, Pb), jnp.bool_), sds((G,), i32))
             self._prefill_cache[key] = exe
         return exe
 
@@ -2739,6 +2737,26 @@ class ContinuousBatcher:
         return jax.ShapeDtypeStruct(
             shape, dtype,
             sharding=self._shard_repl if sharding is None else sharding)
+
+    def _aot(self, key: Tuple, fn, *avals):
+        """The executable of step program `fn` at the parameters' avals
+        and `avals`, for the memo under `key`: ahead-of-time compilation's
+        three public stages run apart in ONE `serve.compile` span and
+        timed into the program's `compile_log` record (its `key`: what
+        precedes the backend in the memo's, shapes and phase)."""
+        name, clock = program_name(fn.__name__), compile_log.clock
+        short = "/".join(map(str, key[:key.index(self.attention_impl)]))
+        with RecordEvent("serve.compile", program=name, key=short), \
+                compile_log.program(name, key=short) as rec:
+            t0 = clock()
+            traced = fn.trace(self._pstruct(), *avals)
+            t1 = clock()
+            lowered = traced.lower()
+            t2 = clock()
+            exe = lowered.compile()
+            t3 = rec["t"] = clock()
+            rec.update(trace_s=t1 - t0, lower_s=t2 - t1, executable_s=t3 - t2)
+        return exe
 
     def _pstruct(self):
         """Param aval tree for lowering — per-leaf TP shardings when
@@ -2799,14 +2817,11 @@ class ContinuousBatcher:
         ladder bucket x each power-of-two group size x {cold, cached},
         plus (with fusion on) the fused decode+prefill variant per
         reachable prefill-row count (units x group pad, units up to
-        `fused_units`), plus EVERY reachable decode chunk executable
-        (today: the one configured standalone-decode chunk) — via AOT
-        lowering (no device compute). After this, steady state never
-        compiles: not admission, not a fused stretch, and not the first
-        decode-only step after one. Returns the number of newly
-        compiled shapes. With bucketing disabled only the decode chunk
-        warms (exact prefill shapes are unbounded; there is nothing
-        finite to ladder)."""
+        `fused_units`), plus the decode chunk's executable — ahead of
+        time (`_aot`: no device compute, a `compile_log` record each).
+        After this, steady state never compiles. Returns the number of
+        newly compiled shapes. With bucketing disabled only the decode
+        chunk warms (exact prefill shapes are unbounded)."""
         ladder = self._buckets if buckets is None else tuple(buckets)
         if group_sizes is None:
             # exactly the shapes _group_pad can ever produce
@@ -3608,11 +3623,9 @@ class ContinuousBatcher:
         return jax.jit(serve_decode_step)
 
     def _chunk_exe(self):
-        """Memoized COMPILED plain decode chunk, AOT-lowered like the
-        prefill shapes so `warmup_prefill` covers it — before this, the
-        chunk fn compiled lazily on the first standalone-decode step,
-        and a decode-only stretch AFTER a fused stretch (whose steps
-        all ran `_fused_exe`) paid a post-warmup compile."""
+        """Memoized COMPILED plain decode chunk, warmup-covered like
+        the prefill shapes: a decode-only stretch AFTER a fused stretch
+        (whose steps all ran `_fused_exe`) pays no compile."""
         key = (self.chunk, self.attention_impl) + self._skey \
             + self._qkey + self._mkey
         exe = self._chunk_cache.get(key)
@@ -3620,12 +3633,11 @@ class ContinuousBatcher:
             if self._chunk_fn is None:
                 self._chunk_fn = self._build_chunk()
             sds, i32 = self._aval, jnp.int32
-            pstruct = self._pstruct()
-            cstruct = self._cstruct()
             B = self.B
-            exe = self._chunk_fn.lower(
-                pstruct, cstruct, sds((B,), i32), sds((B,), jnp.bool_),
-                sds((B,), i32), sds((B,), i32), sds((B,), i32)).compile()
+            exe = self._aot(
+                key, self._chunk_fn, self._cstruct(), sds((B,), i32),
+                sds((B,), jnp.bool_), sds((B,), i32), sds((B,), i32),
+                sds((B,), i32))
             self._chunk_cache[key] = exe
         return exe
 
@@ -3690,10 +3702,8 @@ class ContinuousBatcher:
 
     def _fused_exe(self, Gp: int, Pb: int):
         """Memoized COMPILED fused chunk per (prefill rows, bucket)
-        shape, AOT-lowered from abstract avals like `_prefill_exe` —
-        warmup covers the whole fused ladder so steady-state
-        piggybacked admission never retraces. `Gp` is the TOTAL prefill
-        row count of the call: units x per-unit group pad for a
+        shape, warmup-covered like `_prefill_exe`. `Gp` is the TOTAL
+        prefill row count of the call: units x per-unit group pad for a
         multi-unit step, so (units, group) pairs with the same product
         share one executable."""
         key = (Gp, Pb, self.attention_impl) + self._skey + self._qkey \
@@ -3703,10 +3713,9 @@ class ContinuousBatcher:
             if self._fused_fn is None:
                 self._fused_fn = self._build_fused()
             sds, i32 = self._aval, jnp.int32
-            pstruct = self._pstruct()
             B = self.B
-            exe = self._fused_fn.lower(
-                pstruct,
+            exe = self._aot(
+                key, self._fused_fn,
                 self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
                 self._scale_aval(self.cache.k_scale),
                 self._scale_aval(self.cache.v_scale),
@@ -3715,7 +3724,7 @@ class ContinuousBatcher:
                 sds((B,), jnp.bool_), sds((B,), i32), sds((B,), i32),
                 sds((Gp, Pb), i32), sds((Gp, Pb), i32),
                 sds((Gp, Pb), jnp.bool_), sds((Gp, self._table_width), i32),
-                sds((Gp,), i32)).compile()
+                sds((Gp,), i32))
             self._fused_cache[key] = exe
         return exe
 
@@ -3777,8 +3786,8 @@ class ContinuousBatcher:
                                A[offs[j]:offs[j + 1]]])
                   for j in range(D)]
 
-        def draft(params, dlayers, k, v, ks, vs, table, lengths, tok,
-                  active):
+        def serve_spec_draft(params, dlayers, k, v, ks, vs, table,
+                             lengths, tok, active):
             cache = PagedKVCache(k, v, table, lengths, ks, vs)
             layers = jax.tree_util.tree_map(
                 lambda x: x[:depth], params["layers"]) \
@@ -3807,7 +3816,7 @@ class ContinuousBatcher:
                 toks = nxt
             return jnp.concatenate(out_levels, axis=1)   # [B, spec_k]
 
-        return jax.jit(draft)
+        return jax.jit(serve_spec_draft)
 
     def _spec_dlayers_aval(self):
         """AOT-lowering aval tree for the draft-from-w8 stack (None —
@@ -3819,26 +3828,21 @@ class ContinuousBatcher:
             self._spec_dlayers)
 
     def _spec_draft_exe(self):
-        """Memoized COMPILED draft step, AOT-lowered like the prefill
-        shapes so `warmup_prefill` covers it."""
+        """Memoized COMPILED draft step (warmup-covered)."""
         key = self._spec_key("draft")
         exe = self._spec_cache.get(key)
         if exe is None:
             if self._spec_draft_fn is None:
                 self._spec_draft_fn = self._build_spec_tree_draft()
             sds, i32 = self._aval, jnp.int32
-            pstruct = self._pstruct()
             B = self.B
-            exe = self._spec_draft_fn.lower(
-                pstruct, self._spec_dlayers_aval(),
-                sds(self.cache.k.shape, self.cache.k.dtype,
-                    self._shard_pool),
-                sds(self.cache.v.shape, self.cache.v.dtype,
-                    self._shard_pool),
+            exe = self._aot(
+                key, self._spec_draft_fn, self._spec_dlayers_aval(),
+                self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
                 self._scale_aval(self.cache.k_scale),
                 self._scale_aval(self.cache.v_scale),
                 sds((B, self.M), i32), sds((B,), i32), sds((B,), i32),
-                sds((B,), jnp.bool_)).compile()
+                sds((B,), jnp.bool_))
             self._spec_cache[key] = exe
         return exe
 
@@ -3874,8 +3878,8 @@ class ContinuousBatcher:
         A = jnp.asarray(sc.ancestor_mask())                   # [S, S]
         lv = jnp.asarray(sc.row_levels(), jnp.int32)          # [S]
 
-        def verify(params, k, v, ks, vs, table, lengths, tok, drafts,
-                   active, budget, stop, spec_ok):
+        def serve_spec_verify(params, k, v, ks, vs, table, lengths, tok,
+                              drafts, active, budget, stop, spec_ok):
             cache = PagedKVCache(k, v, table, lengths, ks, vs)
             toks_in = jnp.concatenate([tok[:, None], drafts], axis=1)
             # every node sits at committed position lengths + level —
@@ -3963,7 +3967,7 @@ class ContinuousBatcher:
             return (k, v, ks2, vs2, lengths + n_emit, last, budget2,
                     active2, jnp.where(emit, out_g, 0), n_emit, n_acc)
 
-        return jax.jit(verify)
+        return jax.jit(serve_spec_verify)
 
     def _spec_verify_exe(self):
         """Memoized COMPILED verify step (AOT-lowered, warmup-covered)."""
@@ -3973,20 +3977,16 @@ class ContinuousBatcher:
             if self._spec_verify_fn is None:
                 self._spec_verify_fn = self._build_spec_tree_verify()
             sds, i32 = self._aval, jnp.int32
-            pstruct = self._pstruct()
             B = self.B
-            exe = self._spec_verify_fn.lower(
-                pstruct,
-                sds(self.cache.k.shape, self.cache.k.dtype,
-                    self._shard_pool),
-                sds(self.cache.v.shape, self.cache.v.dtype,
-                    self._shard_pool),
+            exe = self._aot(
+                key, self._spec_verify_fn,
+                self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
                 self._scale_aval(self.cache.k_scale),
                 self._scale_aval(self.cache.v_scale),
                 sds((B, self.M), i32), sds((B,), i32), sds((B,), i32),
                 sds((B, self.spec_k), i32), sds((B,), jnp.bool_),
                 sds((B,), i32), sds((B,), i32),
-                sds((B,), jnp.bool_)).compile()
+                sds((B,), jnp.bool_))
             self._spec_cache[key] = exe
         return exe
 

@@ -75,12 +75,15 @@ a whole window on demand so that ticks which read nothing back are
 measured too.
 
 Phases on the profiler's clock: the loop opens `engine.housekeeping`
-(reap, admission, gauges: the section under the lock) and
-`engine.deliver` (`_dispatch`: the token bridge and `on_token`) as
+(reap, admission, gauges: the section under the lock),
+`engine.deliver` (`_dispatch`: the token bridge and `on_token`) and
+`engine.idle` (its sleep when nothing is queued or in flight) as
 `RecordEvent` spans beside `serving.step_s`, and the batcher opens
-`serve.tick` and its phases inside the step (nlp/paged.py `_Tick`), so
-a jax profiler trace names what the host did in every gap between
-device programs.
+`serve.tick` and its phases inside the step (nlp/paged.py `_Tick`) and
+`serve.compile` round a step program's compile (`_aot`), so a jax
+profiler trace names what the host did in every gap between device
+programs. `snapshot()["compiles"]` is the compile log's summary of the
+step programs (core/compile_cache.py).
 """
 from __future__ import annotations
 
@@ -90,6 +93,7 @@ import time
 from typing import Dict, Iterator, List, Optional, Tuple
 
 from .kvtransfer import KVSnapshot, check_compatible
+from ..core.compile_cache import compile_log
 from ..profiler import RecordEvent
 from .metrics import LATENCY_BUCKETS, MetricsRegistry
 from .request import GenerationRequest, RequestState
@@ -98,6 +102,10 @@ from .slo import SloTracker
 from .trace import TraceSink
 
 __all__ = ["ServingEngine", "EngineStopped", "HungStepError"]
+
+# the batcher's step programs by name (nlp/paged.py: serve_decode_step,
+# serve_fused_step, serve_prefill_step, serve_spec_draft, serve_spec_verify)
+_STEP_PROGRAMS = ("^jit_serve_",)
 
 
 class EngineStopped(RuntimeError):
@@ -779,9 +787,14 @@ class ServingEngine:
         """Metrics snapshot with pool stats folded in (plain dict).
         Reads the engine thread's cached allocator view — never the
         live allocator, which only the engine thread may touch."""
+        # what the step programs cost to trace, lower and compile or read
+        # back, from the process's compile log (a process with several
+        # engines sees them all); the log has its own lock
+        compiles = compile_log.summary(_STEP_PROGRAMS)
         with self._lock:
             snap = self.metrics.snapshot()
             snap["replica_id"] = self.replica_id
+            snap["compiles"] = compiles
             snap["allocator"] = dict(self._alloc_stats)
             snap["prefix_cache"] = dict(self._prefix_stats)
             snap["attention_impl"] = self.attention_impl
@@ -1047,16 +1060,20 @@ class ServingEngine:
                         delay = min(e[0] for e in self._parked) \
                             - self._clock()
                         if delay > 0:
-                            self._work.wait(min(self._idle_poll_s,
-                                                delay))
+                            with RecordEvent("engine.idle"):
+                                self._work.wait(min(self._idle_poll_s,
+                                                    delay))
                         continue
                     if not self._accepting:
                         return            # graceful drain complete
                     self._work.notify_all()      # wake drain() waiters
                     # idle: nothing queued or in flight means no
                     # deadline can expire either, and every waker
-                    # (submit/cancel/shutdown) notifies — block outright
-                    self._work.wait()
+                    # (submit/cancel/shutdown) notifies — block outright,
+                    # under a span of its own: a device gap here is "no
+                    # request was there", not the last step's tail
+                    with RecordEvent("engine.idle"):
+                        self._work.wait()
                     continue
             # the decode chunk runs OUTSIDE the lock: the batcher is only
             # ever touched from this thread, so submit()/cancel() stay

@@ -4,6 +4,7 @@ its `RecordEvent` spans, its profiler sample and its device names. No
 profiler runs here: `RecordEvent` is patched, and the names are read from
 the lowered program."""
 import re
+import time
 
 import numpy as np
 import pytest
@@ -171,8 +172,13 @@ def test_span_names_of_one_fused_and_one_plain_tick(setup, monkeypatch):
     cb.submit(LONG)
     spans = _Spans()
     monkeypatch.setattr(paged, "RecordEvent", spans)
-    cb.step()                       # a fused tick
+    cb.step()                       # a fused tick, its program's first
     names = [n for n, _ in spans.opened]
+    # the compile shows under its own name, inside the tick that paid it
+    assert names.index("serve.tick") < names.index("serve.compile")
+    assert dict(spans.opened)["serve.compile"]["program"] == \
+        "jit_serve_fused_step"
+    names.remove("serve.compile")
     assert names == ["serve.admit", "serve.tick", "serve.pack",
                      "serve.dispatch", "serve.wait", "serve.commit",
                      "serve.admit"]
@@ -218,13 +224,31 @@ def test_engine_loop_opens_deliver_and_housekeeping(setup, monkeypatch):
     eng = serving.ServingEngine(
         params, cfg, max_batch=2, block_size=4, max_total_len=32,
         max_new_tokens=4, chunk=2)
+
+    def asleep():               # the loop's last span is its sleep
+        t_end = time.monotonic() + 30
+        while time.monotonic() < t_end:
+            if spans.opened and spans.opened[-1][0] == "engine.idle":
+                return True
+            time.sleep(0.005)
+        return False
+
+    assert asleep()
     eng.generate(SHORT, timeout=300)
+    assert asleep()
     eng.shutdown()
     names = [n for n, _ in spans.opened]
-    assert set(names) == {"engine.housekeeping", "engine.deliver"}
+    assert set(names) == {"engine.housekeeping", "engine.deliver",
+                          "engine.idle"}
     # every step the loop made was delivered, after its housekeeping
     assert names[0] == "engine.housekeeping"
     assert names.count("engine.deliver") >= 2
+    # the loop slept before the request came and after it left, and
+    # between its first step and its last delivery not once
+    busy = names[names.index("engine.deliver"):
+                 len(names) - names[::-1].index("engine.deliver")]
+    assert names.index("engine.idle") < names.index("engine.deliver")
+    assert "engine.idle" not in busy
 
 
 # ---- no fence, no compile -----------------------------------------------

@@ -1,15 +1,15 @@
 """A serve cell's set-up by phase, on the chip: imports and device,
-weights, engine, warm-up (lowering, the compile or the compile cache's
-read, Pallas's lowerings apart), start and pre-roll. What `setup_s`
-adds up, from the checkout in the working directory (so a parent
-checkout reads its own program: run it from there).
+weights, engine, warm-up, start and pre-roll on the wall clock (what
+`setup_s` adds up), and under them the program's compile log
+(`paddle_tpu/core/compile_cache.py`): one line a program with its trace,
+lowering and executable seconds and whether the compile cache held it.
+From the checkout in the working directory: it builds the engine as
+`benchmark/run.py` does, from the same files, so in one checkout and one
+cache directory its step programs are `run.py`'s and read back what a
+run left (PERF.md section 6, PR 38).
 
 Usage: python tools/setup_phases.py --workload axk1-chat --seed 1
        [--profile chiprun_out/setup/warmup.txt]   (cProfile of the warm-up)
-Run it twice in one call: its programs did not find the cache entries
-that `benchmark/run.py` left for the same tree (seen in two calls, PR
-37), so the first run compiles (3-6 min) and the second reads the
-cache, which is what a warm `setup_s` is (PERF.md section 6, PR 37).
 """
 import argparse
 import os
@@ -18,18 +18,6 @@ import time
 
 T0 = time.time()
 sys.path.insert(0, os.getcwd())
-
-
-def _timed(acc, name, fn):
-    def wrapped(*a, **k):
-        t = time.perf_counter()
-        try:
-            return fn(*a, **k)
-        finally:
-            c = acc.setdefault(name, [0, 0.0])
-            c[0] += 1
-            c[1] += time.perf_counter() - t
-    return wrapped
 
 
 def main(argv=None):
@@ -48,21 +36,9 @@ def main(argv=None):
     cell = manifest.cell(root, args.workload)
     config = manifest.config(root, cell["config"])
     mix = manifest.traffic(root, cell["traffic"])
-    dev = device.start(int(cell["chips"]))
+    dev = device.start(int(cell["chips"]))      # the log's listeners too
     print("device", dev, flush=True)
-
-    # JAX's own stages, timed where they are looked up at call time
-    from jax._src import compilation_cache, compiler, stages
-    from jax._src.pallas.mosaic import pallas_call_registration as reg
-    acc = {}
-    stages.Traced.lower = _timed(acc, "lower", stages.Traced.lower)
-    stages.Lowered.compile = _timed(acc, "compile", stages.Lowered.compile)
-    compiler.compile_or_get_cached = _timed(
-        acc, "compile or cache", compiler.compile_or_get_cached)
-    compilation_cache.get_executable_and_time = _timed(
-        acc, "cache read", compilation_cache.get_executable_and_time)
-    reg.pallas_call_tpu_lowering_rule = _timed(
-        acc, "pallas lowering", reg.pallas_call_tpu_lowering_rule)
+    from paddle_tpu.core.compile_cache import compile_log
 
     t = [time.time()]
     ctx = brun.Context(root, args.workload, cell, config, mix, args.seed,
@@ -90,7 +66,6 @@ def main(argv=None):
             pstats.Stats(prof, stream=f).sort_stats(
                 "cumulative").print_stats(70)
     t.append(time.time())
-    warm = {k: tuple(v) for k, v in acc.items()}
     try:
         eng.start()
         serve.preroll(eng, config, d["V"], args.seed)
@@ -101,8 +76,19 @@ def main(argv=None):
           f"{t[1] - t[0]:.2f}  engine {t[2] - t[1]:.2f}  warm-up "
           f"{t[3] - t[2]:.2f} ({warmed} programs)  start and pre-roll "
           f"{t[4] - t[3]:.2f}  total {t[4] - T0:.2f} s")
-    for k, (n, s) in sorted(warm.items()):
-        print(f"  in the warm-up: {k}: {n} calls, {s:.2f} s")
+    steps = ["^jit_serve_"]         # the batcher's step programs, by name
+    print("  program, key: trace + lowering + executable s (of it the "
+          "cache's read), cache")
+    for r in compile_log.records(steps):
+        print(f"  {r['name']} {r['key']}: {r['trace_s']:.2f} + "
+              f"{r['lower_s']:.2f} + {r['executable_s']:.2f} "
+              f"({r.get('cache_read_s', 0.0):.2f}) {r['cache']}")
+    for what, s in (("the step programs", compile_log.summary(steps)),
+                    ("every program", compile_log.summary())):
+        print(f"  {what}: {s['count']}, trace {s['trace_s']:.2f} lowering "
+              f"{s['lower_s']:.2f} executable {s['executable_s']:.2f} (the "
+              f"cache's read {s['cache_read_s']:.2f}) s, {s['hits']} hits, "
+              f"{s['misses']} misses")
     return 0
 
 
