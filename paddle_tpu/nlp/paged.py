@@ -523,8 +523,8 @@ def _write_pool(pool, table, positions, new, valid, ring: bool = False):
 def _write_pool_int8(pool, scale, table, positions, new, valid):
     """int8 twin of `_write_pool`: quantize new [B, P, KV, hd] rows into
     the int8 pool [N, bs, KV, hd] through the block table, maintaining
-    ONE per-block abs-max scale [N] (this layer's slice of the sibling
-    scale pool; quantization.kv holds the math). Grow-only scale
+    ONE per-block abs-max scale [N] (the sibling scale pool, over the
+    same blocks; quantization.kv holds the math). Grow-only scale
     discipline: when this call's writes raise a block's abs-max, the
     block's EXISTING codes rescale once under the new scale — only the
     TOUCHED blocks gather/rescale/scatter (a full-pool pass would cost
@@ -589,7 +589,7 @@ def _paged_gqa_attention(q, k_pool, v_pool, table, positions, valid=None,
     only each request's live block chain and zeroes invalid rows;
     parity is tight-tolerance, not bitwise (online softmax).
 
-    k_scale/v_scale [N] f32 (this layer's per-block scales) mark an
+    k_scale/v_scale [N] f32 (the pool's per-block scales) mark an
     int8 pool: the XLA path dequantizes AFTER the gather (the bit-
     stable reference formulation), the Pallas kernel dequantizes inside
     its block-chunk loop with the scales riding scalar prefetch — so
@@ -884,14 +884,14 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
     attends, so a row may read blocks that another group's rows write
     in this very call. Returns (out shaped like x, pk', pv', pks',
     pvs') with the new tokens written into the pool: quantized on the
-    commit write when pks/pvs carry this layer's int8 block scales
+    commit write when pks/pvs carry the pool's int8 block scales
     (None = fp pool, the unchanged path).
 
-    A kinded pool (`KVLayout`): pk and pv are the WHOLE pool, flat, and
-    `tables` each group's table for this layer, its kind's part of the
-    row with the layer's base added, so that the layer's blocks are
-    written and read in place; `window` / `ring` make it a window
-    layer's (`_paged_gqa_attention`). A cold chunk no longer than the
+    pk and pv (and pks, pvs) are the WHOLE pool of every layer, flat, and
+    `tables` each group's table for this layer (of a kinded pool its
+    kind's part of the row) with the layer's base added: the layer's
+    blocks are written and read in place; `window` / `ring` make it a
+    window layer's (`_paged_gqa_attention`). A cold chunk no longer than the
     window sees all of itself and takes the flash path like any other;
     a longer one attends through the table like a warm one.
     `works`: each group's kernel work list for this kind of layer
@@ -1121,7 +1121,11 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
     the held experts' share with or without a shared expert). `pools` is
     (k, v, k_scale, v_scale) stacked over layers (a latent pool: (rows,
     None, None, None); a kinded pool: (k, v, None, None), each [1,
-    blocks, ...]). Returns (x, the packed hidden states before the final
+    blocks, ...]). Whatever the mixer, a layer's blocks are written and
+    read IN the pool the scan carries, through block ids offset by the
+    layer's base (`li * N` into the stacked pool viewed flat, or
+    `KVLayout.base`): no layer's pool is sliced out of the carry or
+    written back. Returns (x, the packed hidden states before the final
     norm; pools'; the forward's counters: the expert layers' routing
     and, where an attention kernel walks a work list, the items ONE
     layer's calls walked (`attn_work_steps`; over a kinded pool one
@@ -1160,37 +1164,32 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
                                 is_prefill, attention_impl, layout)
 
     def mix_gqa(x, pools, li, lp):
-        # GQA layers all alike: the layer's pool is sliced out of the
-        # stack over layers and written back (ROADMAP S1).
+        # GQA layers all alike: the layer's blocks are written and read
+        # IN the pool stacked over layers, viewed flat (block ids offset
+        # by li * N, the scales' [L, N] likewise), as `mix_latent` does:
+        # no layer slice is copied out and back. An unassigned column
+        # (-1) becomes li * N - 1, which no valid row reaches: writes
+        # are masked by `valid`, reads by position.
         # ks_all/vs_all are the [L, N] scale pools in int8-KV mode and
         # None for fp, as stats is for a decoder without expert
         # layers — a None traces to the exact jaxpr without it (None
         # adds no carry leaves), keeping the fp GQA path byte-identical
         pk_all, pv_all, ks_all, vs_all = pools
-        with jax.named_scope("kv_pool_read"):
-            pk = lax.dynamic_slice_in_dim(pk_all, li, 1, 0)[0]
-            pv = lax.dynamic_slice_in_dim(pv_all, li, 1, 0)[0]
-            ks = None if ks_all is None else \
-                lax.dynamic_slice_in_dim(ks_all, li, 1, 0)[0]
-            vs = None if vs_all is None else \
-                lax.dynamic_slice_in_dim(vs_all, li, 1, 0)[0]
+        L, N = pk_all.shape[:2]
+
+        def flat(p):
+            return None if p is None else p.reshape(L * N, *p.shape[2:])
+
         with jax.named_scope("attn_qkv"):
             h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
-        a, pk, pv, ks, vs = _attention_paged(
-            h, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
-            attention_impl, ks, vs, mesh=mesh, mesh_axis=mesh_axis,
+        a, *flats = _attention_paged(
+            h, lp, cfg, cos, sin, flat(pk_all), flat(pv_all), groups,
+            is_prefill, attention_impl, flat(ks_all), flat(vs_all),
+            mesh=mesh, mesh_axis=mesh_axis,
+            tables=[g.table + li * N for g in groups],
             works=works.get(None))
-        with jax.named_scope("kv_pool_write"):
-            pk_all = lax.dynamic_update_slice_in_dim(pk_all, pk[None],
-                                                     li, 0)
-            pv_all = lax.dynamic_update_slice_in_dim(pv_all, pv[None],
-                                                     li, 0)
-            if ks_all is not None:
-                ks_all = lax.dynamic_update_slice_in_dim(
-                    ks_all, ks[None], li, 0)
-                vs_all = lax.dynamic_update_slice_in_dim(
-                    vs_all, vs[None], li, 0)
-        return a, (pk_all, pv_all, ks_all, vs_all)
+        return a, tuple(None if p is None else p.reshape(was.shape)
+                        for p, was in zip(flats, pools))
 
     def mix_gqa_kinded(x, pools, li, kinds, at, lp):
         # GQA layers of two kinds over the kinded pool, this one the

@@ -10,6 +10,7 @@ Every case is one kernel at the flagship widths of
 table width 128, bf16). A compile that passes is not a chip run; it only
 keeps what `chip_smoke.py` needs from breaking between chip runs.
 """
+import contextlib
 import os
 import re
 import subprocess
@@ -61,6 +62,17 @@ def _compile(fn, shardings, *shapes):
     avals = [jax.ShapeDtypeStruct(s, d, sharding=sh)
              for (s, d), sh in zip(shapes, shardings)]
     return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+@contextlib.contextmanager
+def _tpu_backend():
+    """A kernel's gate that asks the backend hears "tpu" from this CPU
+    host: steered from the test (the program has no option for it)."""
+    default_backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:
+        yield
+    finally:
+        jax.default_backend = default_backend
 
 
 def _list_grid(txt, R, Pq, width, pool, windowed=False):
@@ -268,6 +280,66 @@ def _mla(topo, R, Pq):
     return txt
 
 
+def _gqa_uniform_decode(topo, kv_dtype="fp"):
+    """The decode forward of a uniform GQA decoder at Mistral-7B's widths
+    (`paged.forward_paged`, 16 rows of one token, the Pallas kernel) over
+    a pool stacked by layer: a layer's blocks are written and read IN the
+    stack (`_forward_groups`, `mix_gqa`). Nothing in the module has one
+    layer's shape, and outside the entry (the scan's body and what it
+    calls) the stack, viewed [L, N, ...] or flat, is made by parameters,
+    bitcasts, tuple plumbing and the scatters alone: no copy, no slice
+    out, no write back. The stack is 128 MiB a pool (4 layers of bf16, 8
+    of int8): one of 64 MiB the compiler keeps in its fast memory space
+    and copies whole a layer, which no served pool is small enough for."""
+    from paddle_tpu.nlp import llama, paged
+    one = SingleDeviceSharding(topo.devices[0])
+    L, R = (8 if kv_dtype == "int8" else 4), 16
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, hidden_size=D, intermediate_size=14336,
+        num_hidden_layers=L, num_attention_heads=H, num_key_value_heads=KV,
+        dtype=BF, param_dtype=BF, use_flash=False)
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: paged.init_pool(cfg, N, BS, kv_dtype))
+
+    def fn(params, pool, table, tokens, positions, valid):
+        cache = paged.PagedKVCache(pool[0], pool[1], table,
+                                   jnp.zeros((R,), jnp.int32), *pool[2:])
+        logits, cache = paged.forward_paged(
+            params, tokens, cache, positions, valid, cfg, False, "pallas")
+        return logits, cache.k, cache.v, cache.k_scale, cache.v_scale
+
+    on = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)  # noqa: E731
+    i32 = jax.ShapeDtypeStruct((R, 1), jnp.int32, sharding=one)
+    with _tpu_backend():
+        txt = jax.jit(fn).lower(
+            jax.tree.map(on, params), jax.tree.map(on, pool),
+            jax.ShapeDtypeStruct((R, M), jnp.int32, sharding=one), i32, i32,
+            jax.ShapeDtypeStruct((R, 1), jnp.bool_, sharding=one)
+        ).compile().as_text()
+    assert " while(" in txt
+    row = f"{BS},{KV},{HD}"
+    layer = (f"{N},{row}", f"1,{N},{row}", f"{N * BS},{KV},{HD}")
+    stack = (f"{L},{N},{row}", f"{L * N},{row}", f"{L * N * BS},{KV},{HD}")
+    entry = False
+    for line in txt.splitlines():
+        entry = entry or line.startswith("ENTRY ")
+        made = re.match(r"\s*(?:ROOT )?%\S+ = \(*\w+\[([\d,]+)\]\S* (\S+?)\(",
+                        line)
+        if not made:
+            continue
+        assert made[1] not in layer, line[:300]
+        if made[1] in stack and not entry:
+            assert made[2] in ("parameter", "bitcast", "get-tuple-element",
+                               "tuple", "conditional", "scatter",
+                               "fusion"), line[:300]
+            # a fusion of the stack's shape is a scatter in place: the new
+            # rows' or, in a branch, the rescaled blocks' of an int8 pool
+            assert made[2] != "fusion" or re.search(
+                r'op_name="[^"]*kv_pool_write/[^"]*scatter', line), line[:600]
+    return txt
+
+
 # held experts n of E routed, Lm expert layers in the stack, D x F
 EXPERT_SHAPES = {"axk1-ep16": (12, 192, 2, 7168, 2048),
                  "mellum2-l8": (64, 64, 8, 2304, 896)}
@@ -293,15 +365,10 @@ def _expert_share(topo, T, short, shape="axk1-ep16"):
         return moe.expert_share_ffn(h, lp, k=8, first=0, scale=2.5,
                                     layer=1)[0]
 
-    # the kernel's gate asks the backend, which is the CPU's here: steer
-    # it from the test (the program has no option for it)
-    default_backend, jax.default_backend = jax.default_backend, lambda: "tpu"
-    try:
+    with _tpu_backend():
         txt = _compile(fn, [one] * 5, ((T, Dm), BF), ((Dm, E), BF),
                        ((Lm, n, Dm, Fm), BF), ((Lm, n, Dm, Fm), BF),
                        ((Lm, n, Fm, Dm), BF))
-    finally:
-        jax.default_backend = default_backend
     calls = re.findall(
         r"%grouped_gemm[.\d]* = bf16\[(\d+),(\d+)\]\S* custom-call\(.*"
         r'custom_call_target="tpu_custom_call", '
@@ -375,6 +442,11 @@ CASES = {
         t, 4, 128, 97, True),
     "ragged-full-table-800-prefill-4x128": lambda t: _ragged_window(
         t, 4, 128, 800, False),
+    # a uniform GQA decoder's decode forward: a layer's blocks addressed
+    # in place in the pool stacked by layer, bf16 and int8
+    "gqa-uniform-decode-step-in-place": _gqa_uniform_decode,
+    "gqa-uniform-decode-step-in-place-int8": lambda t: _gqa_uniform_decode(
+        t, "int8"),
     "flash-fwd-bwd-2048": _flash,
     "flash-fwd-bwd-4096-q192-v128": _flash_mla,
     "mhc-mla-moe-train-step-4x4096": _mhc_train_step,

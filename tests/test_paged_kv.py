@@ -241,3 +241,148 @@ class TestContinuousBatching:
         big.submit(list(rng.randint(1, 200, 8)))
         with pytest.raises(RuntimeError):
             big.run()
+
+
+# ---- a layer's blocks are addressed IN the stacked pool ------------------
+_L, _N, _BS, _M = 3, 12, 4, 4                      # block 11 is never assigned
+
+
+def _sliced_forward(params, groups, pools, cfg, is_prefill, mesh=None):
+    """The reference of `_forward_groups` for a dense decoder whose GQA
+    layers are all alike, as the program ran it before a layer's blocks
+    were addressed in place: layer `li`'s K and V pools (and int8
+    scales) are sliced out of the stack, `_attention_paged` runs on that
+    one layer's pool through the rows' own tables, and the result is
+    written back into the stack."""
+    from paddle_tpu.kernels.rms_norm import rms_norm_ref
+    from paddle_tpu.kernels.rope import rope_freqs
+    from paddle_tpu.nlp.generation import _mlp_cached
+    x = jnp.take(params["embed_tokens"],
+                 paged._pack_rows([g.tokens for g in groups]),
+                 axis=0).astype(cfg.dtype)
+    cos, sin = rope_freqs(cfg.head_dim, _M * _BS, cfg.rope_theta, jnp.float32)
+
+    def body(carry, lp):
+        x, stacks, li = carry
+        one = [None if p is None else p[li] for p in stacks]
+        h = rms_norm_ref(x, lp["input_layernorm"], cfg.rms_norm_eps)
+        a, *one = paged._attention_paged(
+            h, lp, cfg, cos, sin, one[0], one[1], groups, is_prefill, "xla",
+            one[2], one[3], mesh=mesh)
+        stacks = tuple(None if p is None else p.at[li].set(new)
+                       for p, new in zip(stacks, one))
+        x = x + a
+        h = rms_norm_ref(x, lp["post_attention_layernorm"], cfg.rms_norm_eps)
+        return (x + _mlp_cached(h, lp, cfg), stacks, li + 1), None
+
+    (x, stacks, _), _ = jax.lax.scan(
+        body, (x, tuple(pools), jnp.int32(0)), params["layers"])
+    return x, stacks
+
+
+def _rows(rng, tables, starts, widths, P):
+    """A row group of len(tables) rows of P tokens: row r holds `widths[r]`
+    valid tokens from position `starts[r]` on (0: an invalid row, whose
+    table is unassigned), the rest padding."""
+    G = len(tables)
+    pos = np.asarray(starts)[:, None] + np.arange(P)[None, :]
+    valid = np.arange(P)[None, :] < np.asarray(widths)[:, None]
+    table = np.full((G, _M), -1, np.int32)
+    for r, blocks in enumerate(tables):
+        table[r, :len(blocks)] = blocks
+    return paged._RowGroup(
+        jnp.asarray(rng.randint(1, 200, (G, P)), jnp.int32),
+        jnp.asarray(table), jnp.asarray(np.where(valid, pos, 0), jnp.int32),
+        jnp.asarray(valid))
+
+
+def _in_place_case(mode, rng):
+    decode = ([[0, 1], [2, 3, 4], []], [5, 9, 0], [1, 1, 0], 1)
+    suffix = ([[5, 6, 7], [8, 9], []], [4, 2, 0], [4, 3, 0], 4)
+    if mode == "decode":
+        return (_rows(rng, *decode),), False
+    if mode == "warm-suffix":
+        return (_rows(rng, *suffix),), False
+    if mode == "fused":
+        return (_rows(rng, *decode), _rows(rng, *suffix)), False
+    return (_rows(rng, [[0, 1], [2, 3], []], [0, 0, 0], [8, 5, 0], 8),), True
+
+
+@pytest.fixture(scope="module")
+def in_place_model():
+    # 4 KV heads: the pool's head axis splits over the 4-device mesh
+    cfg = llama.LlamaConfig.tiny(use_flash=False, num_hidden_layers=_L,
+                                 num_key_value_heads=4)
+    return cfg, llama.init_params(jax.random.PRNGKey(3), cfg)
+
+
+@pytest.mark.parametrize("tp", [1, 4])
+@pytest.mark.parametrize("mode", ["decode", "warm-suffix", "fused", "cold"])
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_layer_blocks_in_place_match_sliced_reference(in_place_model,
+                                                      kv_dtype, mode, tp):
+    """`_forward_groups` writes and reads layer li's blocks in the pool
+    stacked over layers (block ids offset by li * N): the valid tokens'
+    logits and the WHOLE pool it returns equal, bit for bit on the `xla`
+    backend, those of the reference that slices the layer out and writes it back; a
+    table's unassigned columns (-1, which the offset turns into the last
+    block of layer li - 1) and an invalid row touch nothing."""
+    from paddle_tpu.nlp.generation import _final_head_cached
+    from paddle_tpu.serving.tp import MeshConfig, build_shardings
+    cfg, params = in_place_model
+    rng = np.random.RandomState(5)
+    shape = (_L, _N, _BS, cfg.num_key_value_heads, cfg.head_dim)
+    if kv_dtype == "int8":
+        pools = tuple(jnp.asarray(rng.randint(-127, 128, shape), jnp.int8)
+                      for _ in range(2)) + tuple(
+            jnp.asarray(rng.uniform(1e-3, 3e-3, (_L, _N)), jnp.float32)
+            for _ in range(2))
+    else:
+        pools = tuple(jnp.asarray(rng.randn(*shape), cfg.dtype)
+                      for _ in range(2)) + (None, None)
+    groups, is_prefill = _in_place_case(mode, rng)
+    mesh = None
+    if tp > 1:
+        mesh, on_params, on_pool, repl = build_shardings(
+            MeshConfig(tp=tp), cfg, params)
+        params = jax.device_put(params, on_params)
+        pools = tuple(jax.device_put(p, on_pool) for p in pools[:2]) + tuple(
+            None if p is None else jax.device_put(p, repl) for p in pools[2:])
+        groups = jax.device_put(groups, repl)
+
+    def run(forward):
+        def fn(params, groups, pools):
+            x, new = forward(params, groups, pools)[:2]
+            return _final_head_cached(params, x, cfg), new
+        return jax.jit(fn)(params, groups, pools)
+
+    logits, new = run(lambda p, g, s: paged._forward_groups(
+        p, g, s, cfg, is_prefill, "xla", mesh=mesh))
+    want_logits, want = run(lambda p, g, s: _sliced_forward(
+        p, g, s, cfg, is_prefill, mesh=mesh))
+    # an invalid token's logits are never read, and part: its attention
+    # reads whatever block its unassigned column names
+    live = np.asarray(paged._pack_rows([g.valid for g in groups]))
+    assert live.any() and not live.all()
+    np.testing.assert_array_equal(np.asarray(logits, np.float32)[live],
+                                  np.asarray(want_logits, np.float32)[live])
+    written = set()
+    for g in groups:
+        t, pos, val = (np.asarray(a) for a in (g.table, g.positions, g.valid))
+        written |= {int(t[r, pos[r, p] // _BS])
+                    for r, p in zip(*np.nonzero(val))}
+    assert written and -1 not in written and _N - 1 not in written
+    rest = sorted(set(range(_N)) - written)
+    for was, got, ref in zip(pools, new, want):
+        if was is None:
+            assert got is None and ref is None
+            continue
+        assert got.shape == was.shape and got.dtype == was.dtype
+        got, was = np.asarray(got, np.float32), np.asarray(was, np.float32)
+        np.testing.assert_array_equal(got, np.asarray(ref, np.float32))
+        # every layer: nothing but the valid rows' blocks changed, the
+        # block before layer li's first (layer li - 1's last) least of all
+        np.testing.assert_array_equal(got[:, rest], was[:, rest])
+        if got.ndim > 2:                     # K and V: every layer wrote
+            assert all((got[li, b] != was[li, b]).any()
+                       for li in range(_L) for b in written)

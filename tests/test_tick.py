@@ -17,8 +17,8 @@ from paddle_tpu.serving import FlightRecorder
 from paddle_tpu.serving.faults import FaultInjector, InjectedFault
 
 PHASES = ("pack_s", "dispatch_s", "wait_s", "commit_s")
-SCOPES = ("embed", "attn_qkv", "kv_pool_read", "attn_kernel",
-          "kv_pool_write", "attn_out", "mlp", "lm_head", "sample")
+SCOPES = ("embed", "attn_qkv", "attn_kernel", "kv_pool_write", "attn_out",
+          "mlp", "lm_head", "sample")
 _RNG = np.random.RandomState(11)
 SHORT = list(map(int, _RNG.randint(1, 200, 5)))
 LONG = list(map(int, _RNG.randint(1, 200, 20)))     # 3 chunks at bucket 8
@@ -314,11 +314,14 @@ def test_lowered_decode_step_names_scopes_and_kernel_once(setup):
     assert calls and all(
         p[p.index("jit(ragged_paged_attention)") - 1] == "attn_kernel"
         for p in calls)
-    assert ("kv_pool_read", "dynamic_slice") in paths
-    assert any(p[0] == "kv_pool_write" and p[-1] == "dynamic_update_slice"
-               for p in paths)
+    # a layer's blocks are addressed in place in the stacked pool: no
+    # scope reads a layer out, and the write scope is the scatter alone
+    assert not any("kv_pool_read" in p for p in paths)
     assert any(p[0] == "kv_pool_write" and "scatter" in p[-1]
                for p in paths)
+    assert not any("dynamic_update_slice" in p[-1] or
+                   "dynamic_slice" in p[-1] for p in paths
+                   if "kv_pool_write" in p)
 
 
 def test_step_programs_and_kernels_have_their_stable_names(setup):
