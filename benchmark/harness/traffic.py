@@ -53,6 +53,26 @@ def _seeded(seed: int) -> np.random.Generator:
     return np.random.default_rng(np.random.SeedSequence(abs(int(seed))))
 
 
+def _order(rng: np.random.Generator, values: np.ndarray,
+           block: int) -> np.ndarray:
+    """The seed's order of n ascending strata. Without `block` any order:
+    one permutation. With it (the mix's `order_block`) the window is
+    n // block hands of consecutive requests, and every hand is itself an
+    even sample of the distribution: of each run of as many consecutive
+    strata as there are hands, every hand gets one (which: drawn), and
+    each hand is shuffled. So no seed puts the long prompts, or the short
+    gaps, all into one part of the window; inside a hand's dozen or two of
+    requests any order can fall."""
+    n = len(values)
+    hands = n // block if block else 0
+    if hands < 2:
+        return rng.permutation(values)
+    to = np.concatenate([rng.permutation(hands)
+                         for _ in range(-(-n // hands))])[:n]
+    return np.concatenate([rng.permutation(values[to == h])
+                           for h in range(hands)])
+
+
 def generate(mix: Dict[str, Any], seed: int, n: int, vocab: int,
              span_s: float = 0.0) -> List[Request]:
     """n requests of the mix, in the seed's order. With span_s > 0 (open
@@ -60,10 +80,11 @@ def generate(mix: Dict[str, Any], seed: int, n: int, vocab: int,
     gaps (the exponential distribution's n strata, scaled to the span),
     the first request half its gap in. A closed loop ignores due times."""
     rng = _seeded(seed)
-    plen = rng.permutation(_strata(mix["prompt"], n))
-    olen = rng.permutation(_strata(mix["output"], n))
+    block = int(mix.get("order_block", 0))
+    plen = _order(rng, _strata(mix["prompt"], n), block)
+    olen = _order(rng, _strata(mix["output"], n), block)
     if span_s:
-        gaps = rng.permutation(-np.log1p(-(np.arange(n) + 0.5) / n))
+        gaps = _order(rng, -np.log1p(-(np.arange(n) + 0.5) / n), block)
         gaps *= span_s / gaps.sum()
         due = np.cumsum(gaps) - 0.5 * gaps[0]
     else:

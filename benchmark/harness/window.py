@@ -10,9 +10,12 @@ from typing import Any, Dict
 
 
 def compile_listener() -> Dict[str, int]:
-    """{"n": XLA backend compilations in this process so far}, counted by
-    JAX's own event. A program read back from the persistent cache is not
-    compiled and not counted."""
+    """{"n": XLA programs this process has got an executable for so far},
+    counted by JAX's own event. On this JAX (0.9.0) the event wraps
+    `compile_or_get_cached`, so a program read back from the persistent
+    cache fires it too and is counted like one that compiled; the count's
+    use, a difference over the window, is sound either way (the program's
+    own log, `compile_log`, tells a hit from a miss: PERF.md section 3)."""
     from jax import monitoring
     seen = {"n": 0}
 

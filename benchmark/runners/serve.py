@@ -155,7 +155,8 @@ def setup(ctx):
 def measure(ctx, sv, seconds: float, seed: int) -> Dict[str, Any]:
     """One window of the cell's traffic on the live engine."""
     eng, d, compiles = sv["eng"], sv["d"], sv["compiles"]
-    b, mix, cell = eng.batcher, ctx.mix, ctx.cell
+    b, cell = eng.batcher, ctx.cell
+    mix = {**ctx.mix, **cell.get("mix", {})}    # the cell's own keys on top
     open_loop = mix["kind"] == "serve_open"
     if open_loop:
         n = int(np.ceil(float(cell["rate_per_s"]) * seconds))

@@ -13,11 +13,42 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-# Mistral-7B-v0.3's published widths: no configuration may differ
-WIDTHS = {"vocab_size": 32768, "hidden_size": 4096,
-          "intermediate_size": 14336, "num_attention_heads": 32,
-          "num_key_value_heads": 8, "head_dim": 128,
-          "rope_theta": 1000000.0, "rms_norm_eps": 1e-05}
+_HF = "https://huggingface.co/"
+# each source's published widths (hidden, intermediate, latent and head
+# sizes, the heads, the experts a token): no configuration of that source
+# may differ from them, whatever else it cuts
+WIDTHS = {
+    _HF + "mistralai/Mistral-7B-v0.3/blob/main/config.json": {
+        "vocab_size": 32768, "hidden_size": 4096,
+        "intermediate_size": 14336, "num_attention_heads": 32,
+        "num_key_value_heads": 8, "head_dim": 128,
+        "rope_theta": 1000000.0, "rms_norm_eps": 1e-05},
+    _HF + "skt/A.X-K1/blob/main/config.json": {
+        "hidden_size": 7168, "intermediate_size": 18432,
+        "moe_intermediate_size": 2048, "q_lora_rank": 1536,
+        "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64, "v_head_dim": 128,
+        "num_attention_heads": 64, "num_experts_per_tok": 8},
+    _HF + "JetBrains/Mellum2-12B-A2.5B-Instruct/blob/main/config.json": {
+        "vocab_size": 98304, "hidden_size": 2304, "head_dim": 128,
+        "num_attention_heads": 32, "num_key_value_heads": 4,
+        "moe_intermediate_size": 896, "num_experts": 64,
+        "num_experts_per_tok": 8, "sliding_window": 1024},
+    _HF + "XingChen-AGI/Xing4.0-29B-A4B/blob/main/config.json": {
+        "hidden_size": 3584, "intermediate_size": 9216,
+        "moe_intermediate_size": 1024, "q_lora_rank": 768,
+        "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64, "v_head_dim": 128,
+        "num_attention_heads": 32, "num_experts_per_tok": 4, "hc_mult": 4},
+}
+
+
+def _is_width(key):
+    """What `reduced` may never name (the contract): a hidden,
+    intermediate, latent, state or projection size, a `_dim` or `_rank`,
+    a head size, the experts a token. The vocabulary may be sliced."""
+    return key.endswith(("_dim", "_rank")) or key == "num_experts_per_tok" \
+        or (key.endswith("_size") and key != "vocab_size")
 
 
 @pytest.fixture(scope="module")
@@ -63,12 +94,16 @@ def test_configs(man):
         # which are not keys of the source
         assert {k for k in spec["reduced"] if "." not in k} == \
             set(c["reduced"])
-        for k, v in WIDTHS.items():
-            assert spec["model"][k] == v, (c["name"], k)
+        # a catalogued configuration's published keys lie at the file's
+        # top level, where the driver's check reads them; the benchmark's
+        # own (in no catalog) keep them in a `model` group
+        published = spec.get("model", spec)
+        for k, v in WIDTHS[c["source"]].items():
+            assert published[k] == v, (c["name"], k)
             assert k not in c["reduced"]
-        assert spec["assumed"]["keys"] and spec["deployment"]
+        assert spec["assumed"] and spec["deployment"]
         for k in c["reduced"]:
-            assert not k.endswith(("_dim", "_rank", "_size"))
+            assert not _is_width(k), (c["name"], k)
 
 
 def test_workloads(man):
@@ -89,7 +124,11 @@ def test_workloads(man):
             # the fixed rate is a number in the cell's file, under the knee
             assert isinstance(cell["rate_per_s"], (int, float))
             assert isinstance(cell["knee_per_s"], (int, float))
-            assert cell["rate_per_s"] <= 0.85 * cell["knee_per_s"]
+            # four fifths of the knee the last sweep read, to rounding
+            # (README.md, "How the knee is read"): a PR that moves one
+            # without the other has not re-founded the cell
+            assert cell["rate_per_s"] == pytest.approx(
+                0.8 * cell["knee_per_s"], rel=0.01), w["name"]
         elif mix["kind"] == "serve_closed":
             assert isinstance(cell["clients"], int)
         for name, limit in cell["correct"]["limits"].items():

@@ -60,6 +60,29 @@ def test_each_kind_of_run(tiny_root, cpu_device, capsys, workload, metrics):
     assert out.count("(limit ") >= 2 and "FAILED" not in out
 
 
+def test_a_cell_lays_its_own_keys_over_its_mix(tiny_root, cpu_device, capsys,
+                                               monkeypatch):
+    """`mix` in a cell's file: keys of the traffic mix that belong to this
+    cell alone (its `order_block`), so that a mix two cells share stays as
+    it is for the other."""
+    path = os.path.join(tiny_root, "benchmark/cells/tiny-chat.json")
+    with open(path) as f:
+        cell = json.load(f)
+    tiny._dump(tiny_root, "benchmark/cells/tiny-chat.json",
+               {**cell, "mix": {"order_block": 4}})
+    seen = []
+    generate = serve.traffic.generate
+
+    def spy(mix, *a, **k):
+        seen.append(mix)
+        return generate(mix, *a, **k)
+    monkeypatch.setattr(serve.traffic, "generate", spy)
+    assert _run(tiny_root, "tiny-chat") == 0
+    assert _last(capsys)[0]["correct"] is True
+    assert seen[0]["order_block"] == 4 and seen[0]["kind"] == "serve_open"
+    assert "order_block" not in manifest.traffic(tiny_root, "chat")
+
+
 def test_traced_run_reports_the_per_layer_metrics(tiny_root, cpu_device,
                                                   capsys, monkeypatch):
     _fake_trace(monkeypatch)

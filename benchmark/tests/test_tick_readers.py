@@ -202,7 +202,8 @@ def test_host_gap_by_hand():
 def test_new_entry_names_a_reader_and_its_cell(name):
     man = manifest.manifest(manifest.ROOT)
     entry, = [m for m in man["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == ["mistral7b-chat"]
+    # later PRs append their cells: the list CONTAINS this one
+    assert "mistral7b-chat" in entry["workloads"]
     assert entry["moves"] == "tpot_p90_ms" and entry["unit"] == "%"
     spec = _spec(name)
     reader = manifest.plugin("readers", spec["reader"])
@@ -248,6 +249,10 @@ def _within(table, t0, t1):
 def _read(name, obs):
     spec, = [m for m in manifest.per_layer(manifest.ROOT, "mistral7b-chat")
              if m["name"] == name]
+    if name == "kv_pool_copy_pct":
+        # the cut was recorded from PR 24's program, whose layers still
+        # sliced their blocks out of the pool under a scope of its own
+        spec = {**spec, "scopes": spec["scopes"] + ["kv_pool_read"]}
     return manifest.plugin("readers", spec["reader"]).read(spec, obs)
 
 

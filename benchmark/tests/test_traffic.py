@@ -41,6 +41,39 @@ def test_every_seed_meets_the_same_set_of_sizes_and_gaps():
     assert [len(r.prompt) for r in a] != sorted(len(r.prompt) for r in a)
 
 
+def _sixths(reqs):
+    """Prompt tokens in each sixth of the window's requests, largest over
+    smallest."""
+    plen = np.array([len(r.prompt) for r in reqs])
+    sums = [part.sum() for part in np.array_split(plen, 6)]
+    return max(sums) / min(sums)
+
+
+def test_an_order_block_deals_every_part_of_the_window_an_even_sample():
+    """`order_block`: the same set of sizes and gaps as the plain order,
+    dealt so that every hand of consecutive requests holds its share of
+    the long prompts and of the short gaps; inside a hand any order."""
+    n, span = 102, 51.0
+    seeds = (11, 2**31 + 99, 3000000004, 5, 6, 7)
+    plain = [traffic.generate(MIX, s, n, 1000, span) for s in seeds]
+    even = [traffic.generate({**MIX, "order_block": 8}, s, n, 1000, span)
+            for s in seeds]
+    for a, b in zip(plain, even):
+        assert sorted(len(r.prompt) for r in b) == \
+            sorted(len(r.prompt) for r in a)
+        assert sorted(r.n_out for r in b) == sorted(r.n_out for r in a)
+        due = [r.due_s for r in b]
+        assert due == sorted(due) and 0 < due[0] and due[-1] < span
+        assert _shape(a) != _shape(b)
+        # a sixth of the requests carries about a sixth of the prompt tokens
+        assert _sixths(b) < 1.5
+    assert _shape(even[0]) != _shape(even[1])   # another seed, another deal
+    assert max(_sixths(a) for a in plain) > 1.8     # the plain order: any
+    # a block that leaves fewer than two hands is the plain order
+    assert _shape(traffic.generate({**MIX, "order_block": 60}, 11, n, 1000,
+                                   span)) == _shape(plain[0])
+
+
 def test_lengths_and_due_times_inside_their_limits():
     reqs = traffic.generate(MIX, 7, 200, 500, 50.0)
     assert all(32 <= len(r.prompt) <= 1536 and 8 <= r.n_out <= 384

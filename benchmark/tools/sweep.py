@@ -4,9 +4,9 @@ window at each rate (or, for a closed loop, each number of clients).
     python3 benchmark/tools/sweep.py --workload <cell> --values 2,3,4,5 \
         --seconds 25 [--seed 1] [--engine '{"kv_dtype": "int8"}']
 
-Prints one line per value. The knee is the highest rate at which the
-window's tails stay flat and the requests finished keep up with those
-sent; the cell's `rate_per_s` is four fifths of it (benchmark/README.md).
+Prints the runner's lines per value, then one table of the windows and
+the knee by the one rule of benchmark/README.md (`knee`, below); the
+cell's `rate_per_s` is four fifths of it.
 """
 from __future__ import annotations
 
@@ -24,7 +24,48 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, ROOT)
 
 from benchmark import run as bench_run                   # noqa: E402
-from benchmark.harness import device, manifest            # noqa: E402
+from benchmark.harness import device, manifest, stats     # noqa: E402
+from benchmark.readers import flight_mean                 # noqa: E402
+
+# the rule's two numbers (benchmark/README.md, "How the knee is read")
+KEEP_UP = 0.9           # finished / sent, inside the window
+TTFT_STEP = 3.0         # first-token p90 against the rate one step below
+# rows decoding a tick, as the metric `decode_batch_mean` reads them
+ROWS_LIVE = {"field": "active_slots", "modes": ["decode", "fused"]}
+
+
+def window_row(value, m):
+    """What the rule reads of one window, and what PERF.md tabulates."""
+    recs, failed = m["recs"], m["failed"]
+    t_end = m["obs"]["window"][1]
+    # as the runner prints it: from when a request was DUE; one still
+    # waiting enters with its wait so far
+    ttft = [((r.t_first if r.t_first is not None else t_end) - r.due) * 1e3
+            for r in recs if r not in failed]
+    return {"value": value, "sent": len(recs),
+            "finished": sum(r.done for r in recs), "failed": len(failed),
+            "ttft_p50_ms": stats.percentile(ttft, 50),
+            "ttft_p90_ms": stats.percentile(ttft, 90),
+            "tpot_p90_ms": m["values"]["tpot_p90_ms"],
+            "tok_s": m["values"]["serve_tok_s"],
+            "rows_live": flight_mean.read(ROWS_LIVE, m["obs"])}
+
+
+def knee(rows):
+    """The highest swept value up to which every window kept up (at least
+    KEEP_UP of its requests finished inside it), none failed or was
+    refused, and the first-token p90 stayed under TTFT_STEP times the
+    window one step below; None where the lowest value already fails. A
+    window that gave no row (the generator ran late, a compile) fails."""
+    best, below = None, None
+    for row in sorted(rows, key=lambda r: r["value"]):
+        if row.get("sent") is None or row["failed"] \
+                or row["finished"] < KEEP_UP * row["sent"] \
+                or (below is not None
+                    and row["ttft_p90_ms"] >= TTFT_STEP * below):
+            break
+        best, below = row["value"], row["ttft_p90_ms"]
+    return best
 
 
 def main(argv=None) -> int:
@@ -50,17 +91,27 @@ def main(argv=None) -> int:
     sv = runner.setup(ctx)
     print(f"set-up took {time.time() - T_START:.1f} s on {dev}", flush=True)
     key = "rate_per_s" if mix["kind"] == "serve_open" else "clients"
+    rows = []
     try:
         for i, v in enumerate(args.values.split(",")):
             cell[key] = float(v) if key == "rate_per_s" else int(v)
             print(f"--- {key} {v}", flush=True)
+            row = {"value": cell[key]}
             try:
-                runner.measure(ctx, sv, args.seconds, args.seed + i)
+                m = runner.measure(ctx, sv, args.seconds, args.seed + i)
+                row = window_row(cell[key], m)
             except RuntimeError as e:
                 print(f"window failed: {e}")
             sv["eng"].drain(120)
-            print(f"peak bytes "
-                  f"{device.memory_peak_bytes(int(cell['chips']))}")
+            row["seed"] = args.seed + i
+            row["peak_bytes"] = device.memory_peak_bytes(int(cell["chips"]))
+            print(f"peak bytes {row['peak_bytes']}")
+            rows.append(row)
+        for row in rows:
+            print("sweep: " + json.dumps(row), flush=True)
+        print(f"sweep: knee by the rule {knee(rows)} ({key}; kept up "
+              f">= {KEEP_UP}, first-token p90 < {TTFT_STEP} x the step "
+              f"below, none failed)", flush=True)
     finally:
         sv["eng"].shutdown(drain=False, timeout=60)
     return 0
