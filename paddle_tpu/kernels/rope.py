@@ -92,10 +92,17 @@ def apply_rope(q, k, cos, sin, position_ids=None):
     return rot(q), rot(k)
 
 
-def apply_rope_half(q, k, cos, sin, position_ids=None):
-    """NeoX/Llama 'rotate_half' convention: split head dim in halves."""
+def apply_rope_half(q, k, cos, sin, position_ids=None, rotary_dim=None):
+    """NeoX/Llama 'rotate_half' convention: split head dim in halves.
+    `rotary_dim` (r, even, at most the head dim; None = all of it)
+    rotates the FIRST r dims of a head, rotate-half inside them, from
+    cos/sin tables [S_max, >= r/2], and passes dims r.. through (a
+    partial rotary factor r / head_dim)."""
     def rot(x):
-        d = x.shape[-1]
+        d = x.shape[-1] if rotary_dim is None else int(rotary_dim)
+        rest = None
+        if d != x.shape[-1]:
+            x, rest = x[..., :d], x[..., d:]
         if position_ids is None:
             c = jnp.concatenate([cos[: x.shape[1], : d // 2]] * 2, axis=-1)
             s = jnp.concatenate([sin[: x.shape[1], : d // 2]] * 2, axis=-1)
@@ -107,9 +114,10 @@ def apply_rope_half(q, k, cos, sin, position_ids=None):
         while c.ndim < x.ndim:
             c = c[:, :, None, :] if c.ndim == 3 else c[None]
             s = s[:, :, None, :] if s.ndim == 3 else s[None]
-        half = x.shape[-1] // 2
+        half = d // 2
         rot_x = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-        return (x * c + rot_x * s).astype(x.dtype)
+        out = (x * c + rot_x * s).astype(x.dtype)
+        return out if rest is None else jnp.concatenate([out, rest], axis=-1)
 
     return rot(q), rot(k)
 

@@ -875,7 +875,8 @@ def _group_rows(x, groups):
 def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
                      attention_impl: str = "xla", pks=None, pvs=None,
                      mesh=None, mesh_axis: str = "mp", tables=None,
-                     window=None, ring: bool = False, works=None):
+                     window=None, ring: bool = False, works=None,
+                     n_heads=None, rotary_dim=None):
     """One layer's attention over x, the packed tokens of `groups`
     (`_RowGroup`s; `_pack_rows` gives x's shape). The projections are
     per token: ONE dot each over all of x. RoPE, the pool write and the
@@ -896,8 +897,13 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
     a longer one attends through the table like a warm one.
     `works`: each group's kernel work list for this kind of layer
     (`_gqa_work_lists`), made by the caller once a forward; the kernel
-    builds its own where there is none."""
-    H, KV, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+    builds its own where there is none. `n_heads`: this layer's query
+    heads where the configuration's kinds differ in them (its q_proj and
+    o_proj are that wide); `rotary_dim`: the leading dims of a head that
+    this layer rotates (cos and sin that wide; None = all). A `g_proj`
+    among the layer's weights is a per-head output gate: sigmoid(x Wg),
+    one a head, times the head's attention output before o_proj."""
+    H, KV, hd = (n_heads or cfg.num_attention_heads, cfg.num_key_value_heads,
                  cfg.head_dim)
     cd = cfg.dtype
     if tables is None:
@@ -914,7 +920,8 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
         k = heads(x @ _wq(lp, "k_proj", cd), KV)
         v = heads(x @ _wq(lp, "v_proj", cd), KV)
         for i, g in enumerate(groups):
-            q[i], k[i] = apply_rope_half(q[i], k[i], cos, sin, g.positions)
+            q[i], k[i] = apply_rope_half(q[i], k[i], cos, sin, g.positions,
+                                         rotary_dim)
     with jax.named_scope("kv_pool_write"):
         kq, vq = list(k), list(v)
         for i, g in enumerate(groups):
@@ -961,6 +968,13 @@ def _attention_paged(x, lp, cfg, cos, sin, pk, pv, groups, is_prefill,
                 work=works[i])
                 for i, g in enumerate(groups)]
         outs = [o.reshape(*o.shape[:2], H * hd) for o in outs]
+    if "g_proj" in lp:
+        with jax.named_scope("attn_gate"):
+            gates = _group_rows(jax.nn.sigmoid(
+                (x @ _wq(lp, "g_proj", cd)).astype(jnp.float32)), groups)
+            outs = [(o.reshape(*o.shape[:2], H, hd) * g[..., None])
+                    .astype(cd).reshape(o.shape)
+                    for o, g in zip(outs, gates)]
     with jax.named_scope("attn_out"):
         o = _pack_rows(outs) @ _wq(lp, "o_proj", cd)
     return o, pk, pv, pks, pvs
@@ -1062,22 +1076,25 @@ def _gqa_work_lists(groups, cfg, pool_shape, pool_dtype, is_prefill: bool,
     on positions, valid, the table's width and the window, not on the
     layer or the pool's contents, so every layer of a kind walks the
     same one: the key is None where the layers are all alike, "full" and
-    "window" over a kinded pool (`layout`). A kind whose layers call no
+    "window" over a kinded pool (`layout`), each cut for its own kind's
+    query heads. A kind whose layers call no
     kernel in this forward has no entry: the gather reference walks no
     grid, and a cold prefill attends by the flash kernel unless its
     chunk is longer than the window (`_attention_paged`)."""
     if attention_impl != "pallas":
         return {}
     from .ragged_attention import gqa_work_list
-    kinds = {None: (None, None)} if layout is None else {
-        "full": (None, layout.width),
-        "window": (cfg.sliding_window, layout.ring)}
+    kinds = {None: (None, None, cfg.num_attention_heads)} \
+        if layout is None else {
+            "full": (None, layout.width, cfg.heads("full")),
+            "window": (cfg.sliding_window, layout.ring, cfg.heads("window"))}
     P = groups[0].tokens.shape[1]
     return {
         kind: [gqa_work_list(g.positions, g.valid,
                              width or g.table.shape[1], pool_shape,
-                             pool_dtype, window=window) for g in groups]
-        for kind, (window, width) in kinds.items()
+                             pool_dtype, window=window, heads=heads)
+               for g in groups]
+        for kind, (window, width, heads) in kinds.items()
         if not (is_prefill and (window is None or P <= window))}
 
 
@@ -1087,18 +1104,54 @@ def _layer_groups(params, cfg):
     of one body. Mixer and FFN are independent: a dense GQA decoder is
     one group; a GQA decoder with expert layers is one too, and where
     its layers are of several kinds (window and full) a scan step is one
-    whole PERIOD of them, the stack reshaped to [periods, period, ...];
-    an MLA + sparse-expert decoder is its leading dense layers, then its
-    expert layers."""
+    whole PERIOD of them, the stack reshaped to [periods, period, ...],
+    after its leading dense layers where it declares some (a group of
+    their own, one "period" of their kinds); an MLA + sparse-expert
+    decoder is its leading dense layers, then its expert layers."""
     if not _is_latent(cfg):
-        return [(params["layers"], "moe" if _has_experts(cfg) else "dense",
-                 _layer_kinds(cfg))]
+        out = [(params["layers"], "moe" if _has_experts(cfg) else "dense",
+                _layer_kinds(cfg))]
+        if getattr(cfg, "mlp_only_layers", ()):
+            out.insert(0, (params["lead_layers"], "dense", cfg.lead_kinds))
+        return out
     out = []
     if cfg.first_k_dense_replace:
         out.append((params["dense_layers"], "dense", None))
     if cfg.num_moe_layers:
         out.append((params["moe_layers"], "moe", None))
     return out
+
+
+# A kinded decoder whose kinds differ in head count holds the attention
+# matrices that follow the count stacked BY KIND, each at its own shape,
+# under these keys of a layer group (`window_moe.init_params`).
+_BY_KIND = {"attn_full": "full", "attn_window": "window"}
+
+
+def _fold_periods(layers, kinds, periods: int):
+    """A group's stacked layers [L, ...] -> [periods, period, ...], one
+    scan step a period; a subtree stacked by kind [n, ...] ->
+    [periods, that kind's layers a period, ...]."""
+    def fold(tree, n):
+        return jax.tree_util.tree_map(
+            lambda w: w.reshape(periods, n, *w.shape[1:]), tree)
+    return {k: fold(v, kinds.count(_BY_KIND[k]) if k in _BY_KIND
+                    else len(kinds)) for k, v in layers.items()}
+
+
+def _period_layer(lp, kinds, at: int):
+    """The `at`-th layer's leaves of one period's (`_fold_periods`)."""
+    return {k: jax.tree_util.tree_map(
+        lambda w: w[kinds[:at].count(_BY_KIND[k]) if k in _BY_KIND else at],
+        v) for k, v in lp.items()}
+
+
+def _kind_leaves(lp, kind: str):
+    """One layer's leaves with its kind's attention matrices among them."""
+    own = lp.get("attn_" + kind)
+    if own is None:
+        return lp
+    return {**{k: v for k, v in lp.items() if k not in _BY_KIND}, **own}
 
 
 def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
@@ -1191,15 +1244,18 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
         return a, tuple(None if p is None else p.reshape(was.shape)
                         for p, was in zip(flats, pools))
 
-    def mix_gqa_kinded(x, pools, li, kinds, at, lp):
+    def mix_gqa_kinded(x, pools, li, kinds, at, lp, before):
         # GQA layers of two kinds over the kinded pool, this one the
-        # `at`-th of its period: the layer's blocks are written and read
-        # IN the one flat pool (block ids offset by the layer's base, the
-        # layer counted among those of its kind); a window layer through
-        # its ring with its own RoPE table and the window's bound
+        # `at`-th of its period and `li`-th of its group: the layer's
+        # blocks are written and read IN the one flat pool (block ids
+        # offset by the layer's base, the layer counted among those of
+        # its kind, `before[kind]` of them in the groups before); a
+        # window layer through its ring with its own RoPE table and the
+        # window's bound, each kind at its own head count and rotary share
         kind = kinds[at]
         pk, pv = pools[0][0], pools[1][0]
-        base = layout.base(kind, (li // len(kinds)) * kinds.count(kind)
+        base = layout.base(kind, before[kind]
+                           + (li // len(kinds)) * kinds.count(kind)
                            + kinds[:at].count(kind))
         window = cfg.sliding_window if kind == "window" else None
         with jax.named_scope("attn_" + kind):
@@ -1210,7 +1266,8 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
                 attention_impl,
                 tables=[layout.table(kind, g.table) + base for g in groups],
                 window=window, ring=window is not None,
-                works=works.get(kind))
+                works=works.get(kind), n_heads=cfg.heads(kind),
+                rotary_dim=cfg.rotary_dim(kind))
         return a, (pk[None], pv[None], None, None)
 
     def mix_latent(x, pools, li, lp):
@@ -1227,7 +1284,7 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
             is_prefill, attention_impl, base=li * N, works=works.get(None))
         return a, (pool.reshape(pool_all.shape), None, None, None)
 
-    def make_body(ffn, stacks=None, first_layer=0, kinds=None):
+    def make_body(ffn, stacks=None, first_layer=0, kinds=None, before=None):
         def layer(x, pools, li, stats, lp, at=None):
             # one layer: its mixer, then its FFN; `at`: its place in the
             # period where the layers are of several kinds
@@ -1236,12 +1293,18 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
             elif layout is None:
                 a, pools = mix_gqa(x, pools, li, lp)
             else:
-                a, pools = mix_gqa_kinded(x, pools, li, kinds, at, lp)
+                a, pools = mix_gqa_kinded(x, pools, li - first_layer, kinds,
+                                          at, _kind_leaves(lp, kinds[at]),
+                                          before)
             with jax.named_scope("mlp"):
                 x = x + a
                 h = rms_norm_ref(x, lp["post_attention_layernorm"],
                                  cfg.rms_norm_eps)
-                if ffn == "dense":
+                if ffn == "dense" and layout is not None:
+                    # a kinded decoder's leading dense layers
+                    with jax.named_scope("mlp_lead"):
+                        x = x + _mlp_cached(h, lp, cfg)
+                elif ffn == "dense":
                     x = x + _mlp_cached(h, lp, cfg)
             if ffn == "moe":
                 x, stats = _ffn_experts(x, h, lp, cfg, groups, stats,
@@ -1258,7 +1321,7 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
             for at in range(len(kinds)):
                 x, pools, stats = layer(
                     x, pools, li + at, stats,
-                    jax.tree_util.tree_map(lambda w: w[at], lp), at)
+                    _period_layer(lp, kinds, at), at)
             return (x, *pools, li + len(kinds), stats), None
 
         return body
@@ -1270,6 +1333,7 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
                  "moe_full_passes": z, "moe_gemm_items": z}
     carry = (x, k_all, v_all, ks_all, vs_all, jnp.int32(0), stats)
     first_layer = 0
+    before = {"full": 0, "window": 0}   # layers of each kind so far
     for layers, ffn, kinds in _layer_groups(params, cfg):
         stacks = None
         if ffn == "moe":
@@ -1277,14 +1341,15 @@ def _forward_groups(params, groups, pools, cfg, is_prefill: bool,
             stacks = {k: layers[k] for k in _EXPERT_STACKS}
             layers = {k: v for k, v in layers.items()
                       if k not in _EXPERT_STACKS}
-        n_layers = jax.tree_util.tree_leaves(layers)[0].shape[0]
+        n_layers = layers["input_layernorm"].shape[0]
         if kinds is not None and len(kinds) > 1:
-            layers = jax.tree_util.tree_map(
-                lambda w: w.reshape(n_layers // len(kinds), len(kinds),
-                                    *w.shape[1:]), layers)
-        carry, _ = lax.scan(make_body(ffn, stacks, first_layer, kinds),
-                            carry, layers)
+            layers = _fold_periods(layers, kinds, n_layers // len(kinds))
+        carry, _ = lax.scan(
+            make_body(ffn, stacks, first_layer, kinds, dict(before)),
+            carry, layers)
         first_layer += n_layers
+        for kind in kinds or ():
+            before[kind] += n_layers // len(kinds) * kinds.count(kind)
     x, pk, pv, ks, vs, _, stats = carry
     if works:
         # the items ONE layer's kernel calls walked (every layer of a
@@ -1959,7 +2024,7 @@ class ContinuousBatcher:
                     "layers of several kinds need a prefill bucket ladder: "
                     "the widest chunk sizes the window layers' ring")
             L = cfg.num_hidden_layers
-            n_win = L // len(kinds) * kinds.count("window")
+            n_win = cfg.layer_kinds.count("window")
             ring = ring_blocks(cfg.sliding_window, self._buckets[-1],
                                block_size) if n_win else 0
             ring = min(ring, self.M)    # no sequence outgrows its table
@@ -2613,6 +2678,18 @@ class ContinuousBatcher:
         return [len(self.slot_tokens[s]) + len(self.outputs[self.slot_req[s]])
                 for s in slots]
 
+    def _decode_fields(self, slots) -> Dict[str, Any]:
+        """A decode or fused tick's record of its decoding rows: their
+        contexts (`decode_ctx`) and, where window layers keep rings, how
+        many of them no longer fit theirs, which has wrapped under them
+        (`ring_wrapped_rows`)."""
+        ctx = self._decode_ctx(slots)
+        if self.walloc is None:
+            return {"decode_ctx": ctx}
+        cap = self._layout.ring * self.bs
+        return {"decode_ctx": ctx,
+                "ring_wrapped_rows": sum(c > cap for c in ctx)}
+
     def _note_counters(self, tick: "_Tick", stats, prefill_rows: int = 0,
                        bucket: int = 0) -> None:
         """A decode or fused tick's counters, read back with its tokens,
@@ -2636,10 +2713,13 @@ class ContinuousBatcher:
         from .ragged_attention import attn_grid_steps, gqa_tiling_args
         if _is_latent(self.cfg):
             return attn_grid_steps(R, P, self.M)
-        widths = (self.M,) if self._layout is None \
-            else (self._layout.width, self._layout.ring)
-        tiling = gqa_tiling_args(self.cache.k.shape[1:], self.cache.k.dtype)
-        return sum(attn_grid_steps(R, P, M, **tiling) for M in widths)
+        cfg, pool = self.cfg, self.cache.k
+        calls = ((self.M, cfg.num_attention_heads),) \
+            if self._layout is None else (
+                (self._layout.width, cfg.heads("full")),
+                (self._layout.ring, cfg.heads("window")))
+        return sum(attn_grid_steps(R, P, M, **gqa_tiling_args(
+            pool.shape[1:], pool.dtype, heads=H)) for M, H in calls)
 
     def _probe_gate(self, rid: int) -> None:
         """Fault-injection hook of the quarantine probes (a tick's own
@@ -3399,7 +3479,7 @@ class ContinuousBatcher:
                     rows=len(groups) * Gp,
                     gemm_tokens=self.B + len(groups) * Gp * bucket,
                     chunk=self.chunk,
-                    decode_ctx=self._decode_ctx(decoding),
+                    **self._decode_fields(decoding),
                     prefill_spans=[[start, end] for _, items, _ in groups
                                    for _, start, end in items],
                     compile_hit=(len(groups) * Gp, bucket,
@@ -4136,7 +4216,7 @@ class ContinuousBatcher:
         with _Tick(
                 self, "decode", decode_rids, (self.chunk, 0),
                 rids=decode_rids, chunk=self.chunk,
-                decode_ctx=self._decode_ctx(decoding),
+                **self._decode_fields(decoding),
                 compile_hit=(self.chunk, self.attention_impl)
                 + self._skey + self._qkey + self._mkey
                 in self._chunk_cache) as tick:

@@ -170,11 +170,27 @@ def attn_grid_steps(R: int, P: int, M: int, q_tile: int = 16,
     return R * T * C
 
 
-def gqa_tiling_args(pool_shape, pool_dtype, q_tile: int = 128) -> dict:
+# Query-head rows (H * Pt) of one tile. A row of 128 lanes costs 20 bytes
+# an element in VMEM: the q and o blocks in two slots each (bf16), the
+# float32 accumulator, and the running max and sum, whose one column is
+# padded to the 128 lanes. 4,608 rows are 11.25 MiB beside a chunk's 2 MiB
+# of blocks and the scores, under a core's 16 MiB of scoped VMEM, which a
+# step program also gives the call's small operands (6,144 rows, 128
+# queries of 48 heads, compiled alone and not inside a prefill step: 17.71
+# MiB of 16.75). 128 queries of up to 36 heads fit; of 48 or 72, 64 do.
+_TILE_HEAD_ROWS = 4608
+
+
+def gqa_tiling_args(pool_shape, pool_dtype, q_tile: int = 128,
+                    heads=None) -> dict:
     """What `_attn_tiling` / `attn_grid_steps` / `attn_work_list` take
     for the GQA kernel over K and V pools `[N, bs, KV, hd]`: its query
-    tile, its two pools, and a block's bytes in both."""
+    tile, its two pools, and a block's bytes in both. `heads` (the
+    call's query heads, H) holds the tile to `_TILE_HEAD_ROWS` rows of
+    query heads: the list and the kernel call must be given the same."""
     _, bs, KV, hd = pool_shape
+    if heads:
+        q_tile = max(1, min(q_tile, _TILE_HEAD_ROWS // int(heads)))
     return dict(q_tile=q_tile, pools=2,
                 block_bytes=2 * bs * KV * hd * jnp.dtype(pool_dtype).itemsize)
 
@@ -239,18 +255,20 @@ def attn_work_list(positions, valid, *, block_size: int, table_width: int,
 
 def gqa_work_list(positions, valid, table_width: int, pool_shape, pool_dtype,
                   *, window=None, slab: bool = False,
-                  q_tile: int = 128) -> AttnWork:
+                  q_tile: int = 128, heads=None) -> AttnWork:
     """The list a `ragged_paged_attention` call over a K pool of
     `pool_shape` / `pool_dtype` (the GLOBAL pool's, under a mesh) and a
     table `table_width` wide walks for `positions` and `valid` [R, P]:
     what the call builds itself without `work=`. `slab`: the call scores
     a suffix slab too, so a tile whose valid queries see no pool key yet
-    (position -1: an empty chain) still gets its one item."""
+    (position -1: an empty chain) still gets its one item. `heads`: the
+    call's query heads (`gqa_tiling_args`)."""
     if slab:
         positions = jnp.maximum(positions, 0)
     return attn_work_list(positions, valid, block_size=pool_shape[1],
                           table_width=table_width, window=window,
-                          **gqa_tiling_args(pool_shape, pool_dtype, q_tile))
+                          **gqa_tiling_args(pool_shape, pool_dtype, q_tile,
+                                            heads))
 
 
 def _check_work(work: AttnWork, R: int, T: int, C: int, windowed: bool):
@@ -536,8 +554,9 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
 
     The query dimension tiles at the largest divisor of P that is
     <= `q_tile` rows per grid step (q_tile itself for the serving
-    path's power-of-two buckets; worst case 1 for a prime P, which
-    trades grid overhead for the VMEM bound), bounding VMEM residency
+    path's power-of-two buckets, held to `_TILE_HEAD_ROWS` rows of query
+    heads: 64 queries a tile at 48 or 72 heads; worst case 1 for a prime P,
+    which trades grid overhead for the VMEM bound), bounding VMEM residency
     — scratch + q/o blocks scale with the TILE, not the full prefill
     bucket width, so a 512-wide bucket at production head counts still
     fits a core's VMEM. Per (row, tile) live chain lengths —
@@ -611,10 +630,11 @@ def ragged_paged_attention(q, k_pool, v_pool, table, positions, valid=None,
     if ring and not windowed:
         raise ValueError("a ring table holds a window's keys: give `window`")
     Pt, T, nb, C = _attn_tiling(
-        P, M, **gqa_tiling_args(k_pool.shape, k_pool.dtype, q_tile))
+        P, M, **gqa_tiling_args(k_pool.shape, k_pool.dtype, q_tile, H))
     if work is None:
         work = gqa_work_list(positions, valid, M, k_pool.shape, k_pool.dtype,
-                             window=window, slab=suffix, q_tile=q_tile)
+                             window=window, slab=suffix, q_tile=q_tile,
+                             heads=H)
     _check_work(work, R, T, C, windowed)
 
     def _tile_map(i, tab, live, row, tile, chunk, *rest):

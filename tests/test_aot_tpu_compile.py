@@ -154,6 +154,86 @@ def _ragged_window(topo, R, Pq, width, ring):
     return txt
 
 
+def _ragged_gated(topo, R, Pq, kind):
+    """The ragged kernel at the widths of the gated window + full decoder
+    in the benchmark, whose kinds differ in query heads over 8 KV heads of
+    128: a window layer's 72 (a group of 9: 9 rows a KV head at decode,
+    no multiple of the 8 sublanes) over a ring table 65 wide, a full
+    layer's 48 (a group of 6) over its table of 304; 64 queries a
+    prefill tile in both (`_TILE_HEAD_ROWS`)."""
+    from paddle_tpu.nlp.ragged_attention import (attn_grid_steps,
+                                                 gqa_tiling_args,
+                                                 ragged_paged_attention)
+    one = SingleDeviceSharding(topo.devices[0])
+    Hk, width, window = (72, 65, 512) if kind == "window" else (48, 304, None)
+    Nk = 4 * width
+    pool = ((Nk, BS, KV, HD), BF)
+
+    def fn(q, kp, vp, tab, pos, val):
+        return ragged_paged_attention(q, kp, vp, tab, pos, val,
+                                      interpret=False, window=window,
+                                      ring=window is not None)
+
+    txt = _compile(fn, [one] * 6, ((R, Pq, Hk, HD), BF), pool, pool,
+                   ((R, width), jnp.int32), ((R, Pq), jnp.int32),
+                   ((R, Pq), jnp.bool_))
+    call = next(line for line in txt.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    n = attn_grid_steps(R, Pq, width, **gqa_tiling_args(*pool, heads=Hk))
+    Pt = min(Pq, 64)
+    assert f"s32[{R},{width}]{{1,0}}, s32[{R},{Pq // Pt}]{{1,0}}, " \
+        + f"s32[{n}]{{0}}, " * 3 in call, call[:600]
+    assert f"bf16[{R},{Pq // Pt},{KV},{Pt * Hk // KV},{HD}]" in call
+    return txt
+
+
+def _gated_prefill_step(topo):
+    """The warm one-row prefill forward of the 128 bucket at
+    `laguna-s-ep4`'s widths (`paged.forward_paged` over the kinded pool,
+    both kernels, the gate, the leading dense group and the period's
+    scan): inside a step program the compiler also gives the kernel's
+    small operands scoped VMEM, and a tile of 128 queries at 48 heads,
+    which compiles alone, took 17.71 MiB of 16.75 there (PR 43's first
+    chip call)."""
+    from benchmark.harness import manifest
+    from benchmark.models import gated_window_moe_decoder as fam
+    from paddle_tpu.nlp import paged
+    one = SingleDeviceSharding(topo.devices[0])
+    spec = manifest.config(manifest.ROOT, "laguna-s-ep4")
+    d, cfg, eng = fam.dims(spec), fam.program_config(spec), spec["engine"]
+    width = eng["max_total_len"] // BS
+    ring = paged.ring_blocks(cfg.sliding_window, 512, BS)
+    lay = paged.KVLayout(full_layers=2, window_layers=3,
+                         full_blocks=eng["max_batch"] * width,
+                         window_blocks=eng["max_batch"] * ring, width=width,
+                         ring=ring)
+    pool = jax.eval_shape(lambda: paged.init_pool(cfg, 0, BS, layout=lay))
+    R, Pq = 1, 128
+
+    def fn(params, pool, table, tokens, positions, valid):
+        cache = paged.PagedKVCache(pool[0], pool[1], table,
+                                   jnp.zeros((R,), jnp.int32))
+        logits, cache = paged.forward_paged(
+            params, tokens, cache, positions, valid, cfg, False, "pallas",
+            layout=lay)
+        return logits, cache.k, cache.v
+
+    on = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)  # noqa: E731
+    i32 = jax.ShapeDtypeStruct((R, Pq), jnp.int32, sharding=one)
+    with _tpu_backend():
+        txt = jax.jit(fn).lower(
+            jax.tree.map(on, fam.params_shape(d, BF)),
+            jax.tree.map(on, pool[:2]),
+            jax.ShapeDtypeStruct((R, width + ring), jnp.int32, sharding=one),
+            i32, i32, jax.ShapeDtypeStruct((R, Pq), jnp.bool_, sharding=one)
+        ).compile().as_text()
+    for name in ("%ragged_paged_attention", "%ragged_window_attention",
+                 "%grouped_gemm"):
+        assert name in txt, name
+    assert txt.count(" while(") >= 2        # the lead group, the periods
+    return txt
+
+
 def _flash(topo, mesh=False):
     from paddle_tpu.kernels import flash_attention as fa
     S = 2048
@@ -342,13 +422,15 @@ def _gqa_uniform_decode(topo, kv_dtype="fp"):
 
 # held experts n of E routed, Lm expert layers in the stack, D x F
 EXPERT_SHAPES = {"axk1-ep16": (12, 192, 2, 7168, 2048),
-                 "mellum2-l8": (64, 64, 8, 2304, 896)}
+                 "mellum2-l8": (64, 64, 8, 2304, 896),
+                 "laguna-s-ep4": (64, 256, 4, 3072, 1024)}
 
 
-def _expert_share(topo, T, short, shape="axk1-ep16"):
+def _expert_share(topo, T, short, shape="axk1-ep16", k=8):
     """The dropless expert layer at a served configuration's widths
     (`axk1-ep16`: 12 held experts under a 192-wide router; `mellum2-l8`:
-    all 64 of 64, 8 layers in the stack): the three grouped GEMMs must be
+    all 64 of 64, 8 layers in the stack; `laguna-s-ep4`: 64 of 256, top
+    10, 4 layers): the three grouped GEMMs must be
     the repo's own kernel, each handed the WHOLE stack of every layer's
     experts (a bitcast of the argument: no slice or copy of it anywhere
     in the module) and its list of items, over the `short` sorted buffer
@@ -362,7 +444,7 @@ def _expert_share(topo, T, short, shape="axk1-ep16"):
     def fn(h, router, g, u, d):
         lp = {"router": router, "experts_gate": g, "experts_up": u,
               "experts_down": d}
-        return moe.expert_share_ffn(h, lp, k=8, first=0, scale=2.5,
+        return moe.expert_share_ffn(h, lp, k=k, first=0, scale=2.5,
                                     layer=1)[0]
 
     with _tpu_backend():
@@ -442,6 +524,22 @@ CASES = {
         t, 4, 128, 97, True),
     "ragged-full-table-800-prefill-4x128": lambda t: _ragged_window(
         t, 4, 128, 800, False),
+    # gated window + full GQA layers whose kinds differ in query heads (72
+    # and 48 over 8 KV heads): a decode step's 32 rows and a fused step's
+    # prefill rows, each kind; the expert layer at 64 of 256 held, top 10
+    "ragged-gated-window-72-heads-decode": lambda t: _ragged_gated(
+        t, 32, 1, "window"),
+    "ragged-gated-window-72-heads-prefill-4x512": lambda t: _ragged_gated(
+        t, 4, 512, "window"),
+    "ragged-gated-full-48-heads-decode": lambda t: _ragged_gated(
+        t, 32, 1, "full"),
+    "ragged-gated-full-48-heads-prefill-4x512": lambda t: _ragged_gated(
+        t, 4, 512, "full"),
+    "gated-window-moe-prefill-step-1x128": _gated_prefill_step,
+    "expert-share-laguna-decode-32-tokens": lambda t: _expert_share(
+        t, 32, 320, "laguna-s-ep4", k=10),
+    "expert-share-laguna-fused-544-tokens": lambda t: _expert_share(
+        t, 544, 2944, "laguna-s-ep4", k=10),
     # a uniform GQA decoder's decode forward: a layer's blocks addressed
     # in place in the pool stacked by layer, bf16 and int8
     "gqa-uniform-decode-step-in-place": _gqa_uniform_decode,
