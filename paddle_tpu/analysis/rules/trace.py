@@ -10,7 +10,9 @@ this; the checker rejects it outright.
 A function counts as traced when it is
   * decorated with `jax.jit` / `jax.pmap` / `paddle_tpu.jit.to_static`
     (directly, called, or through `functools.partial`),
-  * wrapped by name later (`g = jax.jit(f)`, `self._f = jax.jit(f)`), or
+  * wrapped by name later (`g = jax.jit(f)`, `self._f = jax.jit(f)`; the
+    batcher's own `self._step_jit(f)`, which is `jax.jit` with the KV
+    pool donated), or
   * passed as a traced function of `jax.lax.scan` / `while_loop` /
     `fori_loop` / `cond` (at that primitive's function arg positions).
 """
@@ -25,6 +27,7 @@ from ..core import FileContext, Finding, Project, Rule, dotted
 TRACING_WRAPPERS = {
     "jax.jit", "jax.pmap", "jax.experimental.pjit.pjit",
     "paddle_tpu.jit.to_static", "jit.to_static",
+    "self._step_jit",       # nlp/paged.py: the step programs' jit
 }
 # control-flow primitives whose function-valued args are traced, with
 # the positional indices those functions sit at

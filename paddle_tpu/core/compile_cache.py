@@ -76,7 +76,11 @@ class CompileLog:
     StableHLO, Pallas's lowerings inside it), `executable_s` (the
     backend's compile, or the persistent cache's read), `cache` ("hit",
     "miss", or "off": no request to the cache was seen), `cache_read_s`
-    on a hit, and `key` where an ahead-of-time site gave its memo's.
+    on a hit, and, where an ahead-of-time site gave them, `key` (its
+    memo's) and `alias_bytes` (the bytes of the program's results that
+    live in a donated argument's buffer, the compiler's
+    `alias_size_in_bytes`: a step program that writes the KV pool in
+    place reads at least the pool's bytes, one that copies it 0).
 
     A jit called inside another's trace or lowering (a kernel's
     module-level `jax.jit`, `jnp`'s own) compiles to no program of its
@@ -175,13 +179,14 @@ class CompileLog:
                 since: Optional[float] = None,
                 until: Optional[float] = None) -> Dict[str, Any]:
         """Counts and seconds by stage over `records(...)`: `count`,
-        `trace_s`, `lower_s`, `executable_s`, `cache_read_s`, `hits`,
-        `misses` (every program the cache did not hold, "off" too) and
-        `last_miss` (its `name` and `t`, or None)."""
+        `trace_s`, `lower_s`, `executable_s`, `cache_read_s`,
+        `alias_bytes`, `hits`, `misses` (every program the cache did not
+        hold, "off" too) and `last_miss` (its `name` and `t`, or None)."""
         recs = self.records(programs, since, until)
         out: Dict[str, Any] = {"count": len(recs)}
         for f in (*_STAGES.values(), "cache_read_s"):
             out[f] = sum(r.get(f, 0.0) for r in recs)
+        out["alias_bytes"] = sum(r.get("alias_bytes", 0) for r in recs)
         missed = [r for r in recs if r["cache"] != "hit"]
         out["hits"] = len(recs) - len(missed)
         out["misses"] = len(missed)

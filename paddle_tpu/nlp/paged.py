@@ -90,6 +90,23 @@ class PagedKVCache(NamedTuple):
     def block_size(self) -> int:
         return self.k.shape[2]
 
+    @property
+    def pools(self):
+        """(k, v, k_scale, v_scale): the pool's device buffers, what a
+        step program that writes the pool is handed as ONE donated
+        argument and returns (`ContinuousBatcher._step_jit`); the table and
+        the lengths beside them are never donated."""
+        return self.k, self.v, self.k_scale, self.v_scale
+
+    def with_pools(self, pools) -> "PagedKVCache":
+        k, v, ks, vs = pools
+        return self._replace(k=k, v=v, k_scale=ks, v_scale=vs)
+
+    @classmethod
+    def of(cls, pools, table, lengths) -> "PagedKVCache":
+        k, v, ks, vs = pools
+        return cls(k, v, table, lengths, ks, vs)
+
 
 class BlockAllocator:
     """Host-side free-list allocator over the pool's block ids.
@@ -290,6 +307,16 @@ class RefcountingBlockAllocator(BlockAllocator):
         0 they park on the cached LRU instead of the free list."""
         self._cacheable.update(blocks)
 
+    def drop_cached(self) -> None:
+        """Every cached block goes back to the free list and no block is
+        cacheable any more: the pool was rebuilt and their contents are
+        gone (the caller empties the prefix index). No eviction is
+        counted: this is not pool pressure."""
+        self._free.extend(self._cached)
+        self._free_set.update(self._cached)
+        self._cached.clear()
+        self._cacheable.clear()
+
     def stats(self) -> Dict[str, int]:
         in_use = self.num_blocks - len(self._free) - len(self._cached)
         return {
@@ -475,17 +502,21 @@ def init_pool(cfg, num_blocks: int, block_size: int,
                           cfg.kv_row_width), cfg.dtype), None, None, None
     L, KV, hd = (cfg.num_hidden_layers, cfg.num_key_value_heads,
                  cfg.head_dim)
+    # K and V (and their scales) are buffers of their OWN from the start:
+    # a step program is handed all of them donated, and one buffer cannot
+    # be donated twice in a call
     if layout is not None:
         _refuse_kinded(kv_dtype=kv_dtype)
-        z = jnp.zeros((1, layout.total_blocks, block_size, KV, hd),
-                      cfg.dtype)
-        return z, z, None, None
+        shape = (1, layout.total_blocks, block_size, KV, hd)
+        return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype), \
+            None, None
+    shape = (L, num_blocks, block_size, KV, hd)
     if kvq.resolve_kv_dtype(kv_dtype) == "int8":
-        z = jnp.zeros((L, num_blocks, block_size, KV, hd), jnp.int8)
-        s = jnp.zeros((L, num_blocks), jnp.float32)
-        return z, z, s, s
-    z = jnp.zeros((L, num_blocks, block_size, KV, hd), cfg.dtype)
-    return z, z, None, None
+        return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
+                jnp.zeros((L, num_blocks), jnp.float32),
+                jnp.zeros((L, num_blocks), jnp.float32))
+    return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype), \
+        None, None
 
 
 def build_table(allocator: BlockAllocator, lengths, max_len: int,
@@ -1514,6 +1545,7 @@ class _Tick:
                        "commit": 0.0}
         self.t_dispatch: Optional[float] = None
         self.t_synced: Optional[float] = None
+        self.adopted = False
         self.noted: Dict[str, Any] = {}
 
     def note(self, fields: Optional[Dict[str, Any]]) -> None:
@@ -1561,6 +1593,16 @@ class _Tick:
         if name == "wait":
             self.t_synced = t1
 
+    def adopt(self, pools) -> None:
+        """The pool the call just issued returns becomes the batcher's
+        AT DISPATCH, whatever the kind of tick: its argument was donated
+        and is deleted by now, so nobody may hold the old one while the
+        tokens travel. `lengths` and the slot state commit after the
+        wait, as ever; rows the call wrote past a slot's committed length
+        are dead data until then."""
+        self.cb.cache = self.cb.cache.with_pools(pools)
+        self.adopted = True
+
     def fence(self, outputs) -> None:
         """Inside an armed capture window (`arm_capture`,
         `capture_profile()`, `POST /debug/profile`) drain the call just
@@ -1589,9 +1631,13 @@ class _Tick:
 
     def __exit__(self, etype, exc, tb) -> bool:
         self._span.end()
-        if etype is not None:
-            return False            # the record stays unclosed
         cb, st = self.cb, self.stamps
+        if etype is not None:
+            # the record stays unclosed. A call that failed between its
+            # dispatch and its read-back leaves a pool that is the failed
+            # program's result: nothing in it is kept
+            cb._drop_lost_pool(self.adopted and self.t_synced is None)
+            return False
         cb.flight.close(
             pack_s=st["pack"], dispatch_s=st["dispatch"],
             wait_s=st["wait"], commit_s=st["commit"],
@@ -1979,6 +2025,7 @@ class ContinuousBatcher:
         # exported/imported through this batcher plus a host count of
         # prefill rows actually computed — the disaggregation tests'
         # "decode replica ran ZERO prefill chunks" gate reads these
+        self.pool_rebuilds = 0      # `_drop_lost_pool`
         self.exported_kv = 0
         self.imported_kv = 0
         self.imported_kv_bytes = 0
@@ -2055,14 +2102,7 @@ class ContinuousBatcher:
         else:
             self._pcache = None
             self.alloc = BlockAllocator(nb)
-        kp, vp, ksc, vsc = init_pool(cfg, nb, block_size,
-                                     kv_dtype=self.kv_dtype,
-                                     layout=self._layout)
-        self.cache = PagedKVCache(
-            kp, vp, jnp.zeros((max_batch, self._table_width), jnp.int32),
-            jnp.zeros((max_batch,), jnp.int32), ksc, vsc)
-        if self._mesh is not None:
-            self.cache = self._pin_cache_shardings(self.cache)
+        self.cache = self._empty_kv()
         self.active = [False] * max_batch
         self.slot_req: List[Optional[int]] = [None] * max_batch
         self.slot_blocks: List[Optional[List[int]]] = [None] * max_batch
@@ -2769,16 +2809,16 @@ class ContinuousBatcher:
         cfg, impl = self.cfg, self.attention_impl
         mesh, max_, layout = self._mesh, self._mesh_axis(), self._layout
 
-        def serve_prefill_step(params, rows, k, v, ks, vs, table, positions,
+        def serve_prefill_step(params, pools, rows, table, positions,
                                valid, lengths):
-            sub = PagedKVCache(k, v, table, lengths, ks, vs)
+            sub = PagedKVCache.of(pools, table, lengths)
             logits, sub = forward_paged(params, rows, sub, positions,
                                         valid, cfg, is_prefill=cold,
                                         attention_impl=impl, mesh=mesh,
                                         mesh_axis=max_, layout=layout)
-            return logits, sub.k, sub.v, sub.k_scale, sub.v_scale
+            return sub.pools, logits
 
-        return jax.jit(serve_prefill_step)
+        return self._step_jit(serve_prefill_step)
 
     def _prefill_exe(self, G: int, Pb: int, cold: bool):
         """Memoized COMPILED prefill per (group, bucket, phase) shape,
@@ -2796,9 +2836,6 @@ class ContinuousBatcher:
             sds, i32 = self._aval, jnp.int32
             exe = self._aot(
                 key, fn, sds((G, Pb), i32),
-                self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
-                self._scale_aval(self.cache.k_scale),
-                self._scale_aval(self.cache.v_scale),
                 sds((G, self._table_width), i32), sds((G, Pb), i32),
                 sds((G, Pb), jnp.bool_), sds((G,), i32))
             self._prefill_cache[key] = exe
@@ -2817,24 +2854,46 @@ class ContinuousBatcher:
             shape, dtype,
             sharding=self._shard_repl if sharding is None else sharding)
 
+    def _step_jit(self, fn, writes_pool: bool = True):
+        """The jit of step program `fn(params, pools, *rest)`, and THE
+        place that decides donation, for every step program alike (no
+        builder writes `donate_argnums`): a program that writes the pool
+        returns it first among its results and is compiled with `pools`
+        (`PagedKVCache.pools`: K, V and the int8 pool's scales) DONATED,
+        so the compiler aliases the returned pool onto the argument and
+        the scatter writes in the caller's buffer: no whole-pool copy at
+        the program's start, one generation of the pool live. Its
+        caller's handle is deleted at dispatch (`_Tick.adopt`). The
+        table, the lengths and the slot state are never donated; a
+        program that only reads the pool (`writes_pool=False`: the
+        speculative draft) donates nothing. No option turns this off."""
+        return jax.jit(fn, donate_argnums=(1,) if writes_pool else ())
+
     def _aot(self, key: Tuple, fn, *avals):
-        """The executable of step program `fn` at the parameters' avals
-        and `avals`, for the memo under `key`: ahead-of-time compilation's
-        three public stages run apart in ONE `serve.compile` span and
-        timed into the program's `compile_log` record (its `key`: what
-        precedes the backend in the memo's, shapes and phase)."""
+        """The executable of step program `fn` (`_step_jit`'s) at the
+        parameters' avals, the pool's and `avals`, for the memo under
+        `key`: ahead-of-time compilation's three public stages run apart
+        in ONE `serve.compile` span and timed into the program's
+        `compile_log` record (its `key`: what precedes the backend in the
+        memo's, shapes and phase). The record's `alias_bytes` says that
+        the donation engaged: the bytes of the results that live in an
+        argument's buffer, at least the pool's for a program that writes
+        it (under a mesh a device's share of it; 0 where the backend
+        gives no analysis)."""
         name, clock = program_name(fn.__name__), compile_log.clock
         short = "/".join(map(str, key[:key.index(self.attention_impl)]))
         with RecordEvent("serve.compile", program=name, key=short), \
                 compile_log.program(name, key=short) as rec:
             t0 = clock()
-            traced = fn.trace(self._pstruct(), *avals)
+            traced = fn.trace(self._pstruct(), self._pools_aval(), *avals)
             t1 = clock()
             lowered = traced.lower()
             t2 = clock()
             exe = lowered.compile()
             t3 = rec["t"] = clock()
-            rec.update(trace_s=t1 - t0, lower_s=t2 - t1, executable_s=t3 - t2)
+            rec.update(trace_s=t1 - t0, lower_s=t2 - t1, executable_s=t3 - t2,
+                       alias_bytes=int(getattr(
+                           exe.memory_analysis(), "alias_size_in_bytes", 0)))
         return exe
 
     def _pstruct(self):
@@ -2849,15 +2908,24 @@ class ContinuousBatcher:
                                               sharding=s),
             self.params, self._shard_params)
 
-    def _cstruct(self):
-        """PagedKVCache aval tree: pools on the head axis, block
-        table / lengths / int8 scale pools replicated."""
+    def _pools_aval(self):
+        """Aval tree of `PagedKVCache.pools`: K and V on the head axis,
+        the int8 scale pools replicated."""
         c = self.cache
-        return PagedKVCache(
-            self._pool_aval(c.k), self._pool_aval(c.v),
-            self._aval(c.table.shape, c.table.dtype),
-            self._aval(c.lengths.shape, c.lengths.dtype),
-            self._scale_aval(c.k_scale), self._scale_aval(c.v_scale))
+        return (self._pool_aval(c.k), self._pool_aval(c.v),
+                self._scale_aval(c.k_scale), self._scale_aval(c.v_scale))
+
+    def _empty_kv(self) -> PagedKVCache:
+        """A zeroed pool with an empty table (construction, and
+        `_drop_lost_pool`'s rebuild), pinned to the serving mesh's
+        shardings where there is one."""
+        cache = PagedKVCache.of(
+            init_pool(self.cfg, self.alloc.num_blocks, self.bs,
+                      kv_dtype=self.kv_dtype, layout=self._layout),
+            jnp.zeros((self.B, self._table_width), jnp.int32),
+            jnp.zeros((self.B,), jnp.int32))
+        return cache if self._mesh is None \
+            else self._pin_cache_shardings(cache)
 
     def _pin_cache_shardings(self, cache: PagedKVCache) -> PagedKVCache:
         """Pin a fresh cache's leaves to their serving-mesh shardings
@@ -3090,17 +3158,16 @@ class ContinuousBatcher:
 
     def _prefill_call(self, packed, cold: bool):
         """Issue ONE compiled standalone prefill over a unit's packed
-        rows (`_pack_prefill_rows`). Returns logits [Gp, Pb, V]."""
+        rows (`_pack_prefill_rows`). Returns (the pool it wrote, which
+        the caller adopts: this batcher's own is deleted by now; logits
+        [Gp, Pb, V])."""
         rows, pos, val, tab, _li = packed
         Gp, Pb = rows.shape
         exe = self._prefill_exe(Gp, Pb, cold)
-        logits, k, v, ks, vs = exe(
-            self.params, jnp.asarray(rows), self.cache.k, self.cache.v,
-            self.cache.k_scale, self.cache.v_scale, jnp.asarray(tab),
-            jnp.asarray(pos), jnp.asarray(val),
+        return exe(
+            self.params, self.cache.pools, jnp.asarray(rows),
+            jnp.asarray(tab), jnp.asarray(pos), jnp.asarray(val),
             jnp.zeros((Gp,), jnp.int32))
-        self.cache = self.cache._replace(k=k, v=v, k_scale=ks, v_scale=vs)
-        return logits
 
     def _units(self,
                recs: Sequence[_Admission]) -> List[List[_Admission]]:
@@ -3284,7 +3351,8 @@ class ContinuousBatcher:
                 self._apply_cow([e[0] for e in entries if e[1] == 0])
                 packed = self._pack_prefill_rows(items, bucket, Gp)
             with tick.phase("dispatch"):
-                logits = self._prefill_call(packed, cold)
+                pools, logits = self._prefill_call(packed, cold)
+                tick.adopt(pools)
                 if final:
                     # ragged last-token logits per row — li came packed
                     # with the rows
@@ -3292,7 +3360,7 @@ class ContinuousBatcher:
                     last = jnp.argmax(
                         logits[jnp.arange(g), jnp.asarray(packed[4][:g])],
                         axis=-1)
-            tick.fence((logits, self.cache.k, self.cache.v))
+            tick.fence((logits, pools))
             if final:
                 with tick.phase("wait"):
                     # ONE readback per unit: every first token at once
@@ -3327,6 +3395,34 @@ class ContinuousBatcher:
         # requeue counts against health()["requests_requeued"]
         self.queue[:0] = [(v.rid, v.toks, v.stop, v.mn) for v in victims]
 
+    def _drop_lost_pool(self, in_flight: bool) -> None:
+        """What the batcher does when a device call fails AFTER dispatch
+        (`in_flight`: the call's returned pool was adopted and its result
+        never read back; or the pool it holds is deleted, a call having
+        raised once it had the donated buffers): the pool it holds is a
+        failed program's result and there is no older one, the argument
+        having been donated. No attempt is made to save it. The pool is
+        rebuilt empty (`init_pool`), the prefix index emptied, every
+        pending admission rolled back onto the queue (`_fail_pending`)
+        and every decoding slot dropped with its blocks, unregistered:
+        `export_kv` then finds no slot, so the engine's quarantine takes
+        its requeue path for every live request, which re-prefills
+        `prompt + tokens` and decodes on to the tokens an unfaulted run
+        gives. A failure BEFORE dispatch (the fault injector's gate, a
+        builder that raises) leaves the pool live and does nothing
+        here."""
+        if not (in_flight or self.cache.k.is_deleted()):
+            return
+        self._fail_pending()
+        for slot in range(self.B):
+            if self.active[slot]:
+                self._retire(slot, lost=True)
+        if self._pcache is not None:
+            self._pcache.clear()
+            self.alloc.drop_cached()
+        self.cache = self._empty_kv()
+        self.pool_rebuilds += 1
+
     def _prefill_pending(self) -> None:
         """Drain the pending pipeline with standalone prefill calls
         (chunked records stream their remaining chunks back to back).
@@ -3352,35 +3448,54 @@ class ContinuousBatcher:
             raise
 
     # -- quarantine probes (engine-thread only, failure path only) --------
+    @contextlib.contextmanager
+    def _probing(self):
+        """A probe's device call, from dispatch to its drained result: a
+        failure in between loses the pool like a tick's (`_Tick.__exit__`,
+        `_drop_lost_pool`)."""
+        try:
+            yield
+        except Exception:
+            self._drop_lost_pool(True)
+            raise
+
     def probe_decode_slot(self, slot: int) -> None:
         """Re-run the failed tick's decode chunk for ONE slot in
         isolation: the chunk executable runs with every other slot
         masked inactive, so only this slot's computation can raise.
-        Commits NOTHING — the returned cache/tokens are discarded (the
-        engine requeues the innocent for a warm re-prefill instead),
-        and per-request paged attention makes the masked run exercise
-        exactly this slot's math. Raises whatever the device (or the
-        fault injector) raises; returning means the slot is clean.
-        Failure-path only: never called on the hot path."""
+        Commits NOTHING but the pool the run returns, which it must
+        keep (the pool it probed was donated to the run): the rows the
+        probe wrote lie past the slot's committed length, dead data that
+        the slot's next decode writes again, and the returned tokens are
+        discarded (the engine requeues the innocent for a warm
+        re-prefill instead). Per-request paged attention makes the masked
+        run exercise exactly this slot's math. Raises whatever the device
+        (or the fault injector, before any dispatch) raises; returning
+        means the slot is clean. Failure-path only: never called on the
+        hot path."""
         rid = self.slot_req[slot]
         self._probe_gate(rid)
         act = [False] * self.B
         act[slot] = True
-        out = self._chunk_exe()(
-            self.params, self.cache, self.cur_tok, jnp.asarray(act),
-            self.cache.lengths, jnp.asarray(self.budget, jnp.int32),
-            jnp.asarray(self.stop, jnp.int32))
-        # force the async dispatch so a data-dependent device failure
-        # surfaces HERE, attributed to this slot (probe verdicts are
-        # the one consumer of these arrays — nothing is kept)
-        jax.block_until_ready(out)
+        with self._probing():
+            pools, *out = self._chunk_exe()(
+                self.params, self.cache.pools, self.cache.table,
+                self.cur_tok, jnp.asarray(act), self.cache.lengths,
+                jnp.asarray(self.budget, jnp.int32),
+                jnp.asarray(self.stop, jnp.int32))
+            self.cache = self.cache.with_pools(pools)
+            # force the async dispatch so a data-dependent device failure
+            # surfaces HERE, attributed to this slot (probe verdicts are
+            # the one consumer of these arrays)
+            jax.block_until_ready(out)
 
     def probe_queued(self, rid: int) -> None:
         """Re-run a QUEUED request's first prefill chunk in isolation:
         prepare its blocks, run one standalone single-record prefill
         call (a warmed (1, bucket) ladder shape), then roll everything
-        back — the queue entry, the pool and the prefix index end
-        exactly as they were. A failed prefill/fused call requeues its
+        back — the queue entry and the prefix index end exactly as they
+        were, and the pool is the one the call returned: what it wrote
+        lies in blocks that are free again. A failed prefill/fused call requeues its
         pending records (`_fail_pending`), so this is how the engine's
         quarantine re-executes the failing tick's prefill units one
         record at a time. Raises what the device raises; a pool too
@@ -3399,10 +3514,12 @@ class ContinuousBatcher:
         try:
             start, end, bucket = rec.chunks[0]
             self._apply_cow([rec])
-            logits = self._prefill_call(
-                self._pack_prefill_rows([(rec, start, end)], bucket, 1),
-                cold=start == 0)
-            jax.block_until_ready(logits)
+            with self._probing():
+                pools, logits = self._prefill_call(
+                    self._pack_prefill_rows([(rec, start, end)], bucket, 1),
+                    cold=start == 0)
+                self.cache = self.cache.with_pools(pools)
+                jax.block_until_ready(logits)
         finally:
             self._rollback([rec])
 
@@ -3499,16 +3616,16 @@ class ContinuousBatcher:
                         self._dev_state = self._upload_slot_state()
                     active, budget, stop = self._dev_state
                 with tick.phase("dispatch"):
-                    (k, v, ks, vs, lengths, tok, budget, active, toks,
+                    (pools, lengths, tok, budget, active, toks,
                      pfirst, stats) = exe(
-                        self.params, self.cache.k, self.cache.v,
-                        self.cache.k_scale, self.cache.v_scale,
+                        self.params, self.cache.pools,
                         self.cache.table, self.cache.lengths,
                         self.cur_tok, active, budget, stop,
                         jnp.asarray(rows), jnp.asarray(pos),
                         jnp.asarray(val), jnp.asarray(tab),
                         jnp.asarray(li))
-                tick.fence((k, v, toks, pfirst))
+                    tick.adopt(pools)
+                tick.fence((pools, toks, pfirst))
                 with tick.phase("wait"):
                     # one host sync serves BOTH the decode chunk's
                     # tokens and the prefill rows' first tokens — and,
@@ -3518,12 +3635,12 @@ class ContinuousBatcher:
                     toks, pfirst, stats = got
                     self._note_counters(tick, stats, len(groups) * Gp,
                                         bucket)
-                # decode state untouched up to here: a failure rolls
-                # the pending units back (below)
+                # decode state untouched up to here but for the pool,
+                # adopted at dispatch: a failure rolls the pending units
+                # back (below)
                 committed = True
                 with tick.phase("commit"):
-                    self.cache = self.cache._replace(
-                        k=k, v=v, k_scale=ks, v_scale=vs, lengths=lengths)
+                    self.cache = self.cache._replace(lengths=lengths)
                     self.cur_tok = tok
                     self._dev_state = (active, budget, stop)
                     self.fused_steps += 1
@@ -3547,12 +3664,17 @@ class ContinuousBatcher:
                 self._fail_pending()
             raise
 
-    def _retire(self, slot: int) -> None:
+    def _retire(self, slot: int, lost: bool = False) -> None:
+        """Free a slot whose request finished or was aborted. `lost`: the
+        pool was rebuilt under it (`_drop_lost_pool`), so its blocks go
+        back unregistered and the request is dropped, not finished."""
         rid = self.slot_req[slot]
         blocks = self.slot_blocks[slot]
-        self._trace_emit(rid, "retired", slot=slot,
+        self._trace_emit(rid, "kv_lost" if lost else "retired", slot=slot,
                          generated=len(self.outputs.get(rid, [])))
-        if self._pcache is not None:
+        if lost:
+            self.alloc.release(blocks)
+        elif self._pcache is not None:
             # register the finished sequence's FULL blocks (prompt +
             # generated) before releasing: at refcount 0 they park on
             # the cached LRU instead of dying, so the next request with
@@ -3574,7 +3696,10 @@ class ContinuousBatcher:
             self.alloc.free(blocks)
         if self.slot_ring[slot]:
             self.walloc.free(self.slot_ring[slot])
-        self._just_finished.append(rid)
+        if lost:
+            self._delivered.pop(rid, None)
+        else:
+            self._just_finished.append(rid)
         self.active[slot] = False
         self.slot_req[slot] = None
         self.slot_blocks[slot] = None
@@ -3688,18 +3813,20 @@ class ContinuousBatcher:
     def _build_chunk(self):
         chunk = self.chunk
 
-        def serve_decode_step(params, cache, tok, active, lengths, budget,
-                              stop):
+        def serve_decode_step(params, pools, table, tok, active, lengths,
+                              budget, stop):
+            cache = PagedKVCache.of(pools, table, lengths)
             step = self._decode_step_body(params, stop)
             (cache, tok, lengths, budget, act), (toks, stats) = \
                 jax.lax.scan(step, (cache, tok, lengths, budget, active),
                              None, length=chunk)
             # act/budget go back to the caller so the next chunk can feed
             # them in again without a host round-trip
-            return (cache, tok, lengths, budget, act, toks.T,  # [B, chunk]
+            return (cache.pools, tok, lengths, budget, act,
+                    toks.T,                                # [B, chunk]
                     _sum_steps(stats))
 
-        return jax.jit(serve_decode_step)
+        return self._step_jit(serve_decode_step)
 
     def _chunk_exe(self):
         """Memoized COMPILED plain decode chunk, warmup-covered like
@@ -3714,9 +3841,9 @@ class ContinuousBatcher:
             sds, i32 = self._aval, jnp.int32
             B = self.B
             exe = self._aot(
-                key, self._chunk_fn, self._cstruct(), sds((B,), i32),
-                sds((B,), jnp.bool_), sds((B,), i32), sds((B,), i32),
-                sds((B,), i32))
+                key, self._chunk_fn, sds((B, self._table_width), i32),
+                sds((B,), i32), sds((B,), jnp.bool_), sds((B,), i32),
+                sds((B,), i32), sds((B,), i32))
             self._chunk_cache[key] = exe
         return exe
 
@@ -3744,16 +3871,16 @@ class ContinuousBatcher:
         impl = self.attention_impl
         mesh, max_, layout = self._mesh, self._mesh_axis(), self._layout
 
-        def serve_fused_step(params, k, v, ks, vs, table, lengths, tok,
+        def serve_fused_step(params, pools, table, lengths, tok,
                              active, budget, stop, prows, ppos, pval, ptab,
                              plast):
             Gp, Pb = prows.shape
-            x, (k, v, ks, vs), stats0 = _forward_groups(
+            x, pools, stats0 = _forward_groups(
                 params,
                 (_RowGroup(tok[:, None], table, lengths[:, None],
                            active[:, None]),
                  _RowGroup(prows, ptab, ppos, pval)),
-                (k, v, ks, vs), cfg, is_prefill=False,
+                pools, cfg, is_prefill=False,
                 attention_impl=impl, mesh=mesh, mesh_axis=max_,
                 layout=layout)
             with jax.named_scope("lm_head"):
@@ -3766,18 +3893,17 @@ class ContinuousBatcher:
                 pfirst = jnp.argmax(logits[B:], axis=-1).astype(jnp.int32)
             nxt, lengths, budget, active = self._emit_one(
                 logits[:B], tok, active, lengths, budget, stop)
-            cache = PagedKVCache(k, v, table, lengths, ks, vs)
+            cache = PagedKVCache.of(pools, table, lengths)
             step = self._decode_step_body(params, stop)
             (cache, tok, lengths, budget, active), (toks, stats) = \
                 jax.lax.scan(step, (cache, nxt, lengths, budget, active),
                              None, length=chunk - 1)
             toks = jnp.concatenate([nxt[None], toks], 0)
-            return (cache.k, cache.v, cache.k_scale, cache.v_scale,
-                    lengths, tok, budget, active,
+            return (cache.pools, lengths, tok, budget, active,
                     toks.T, pfirst,                       # toks [B, chunk]
                     _sum_steps(stats, stats0))
 
-        return jax.jit(serve_fused_step)
+        return self._step_jit(serve_fused_step)
 
     def _fused_exe(self, Gp: int, Pb: int):
         """Memoized COMPILED fused chunk per (prefill rows, bucket)
@@ -3795,9 +3921,6 @@ class ContinuousBatcher:
             B = self.B
             exe = self._aot(
                 key, self._fused_fn,
-                self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
-                self._scale_aval(self.cache.k_scale),
-                self._scale_aval(self.cache.v_scale),
                 sds((B, self._table_width), i32), sds((B,), i32),
                 sds((B,), i32),
                 sds((B,), jnp.bool_), sds((B,), i32), sds((B,), i32),
@@ -3865,9 +3988,9 @@ class ContinuousBatcher:
                                A[offs[j]:offs[j + 1]]])
                   for j in range(D)]
 
-        def serve_spec_draft(params, dlayers, k, v, ks, vs, table,
-                             lengths, tok, active):
-            cache = PagedKVCache(k, v, table, lengths, ks, vs)
+        def serve_spec_draft(params, pools, dlayers, table, lengths, tok,
+                             active):
+            cache = PagedKVCache.of(pools, table, lengths)
             layers = jax.tree_util.tree_map(
                 lambda x: x[:depth], params["layers"]) \
                 if dlayers is None else dlayers
@@ -3895,7 +4018,7 @@ class ContinuousBatcher:
                 toks = nxt
             return jnp.concatenate(out_levels, axis=1)   # [B, spec_k]
 
-        return jax.jit(serve_spec_draft)
+        return self._step_jit(serve_spec_draft, writes_pool=False)
 
     def _spec_dlayers_aval(self):
         """AOT-lowering aval tree for the draft-from-w8 stack (None —
@@ -3917,9 +4040,6 @@ class ContinuousBatcher:
             B = self.B
             exe = self._aot(
                 key, self._spec_draft_fn, self._spec_dlayers_aval(),
-                self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
-                self._scale_aval(self.cache.k_scale),
-                self._scale_aval(self.cache.v_scale),
                 sds((B, self.M), i32), sds((B,), i32), sds((B,), i32),
                 sds((B,), jnp.bool_))
             self._spec_cache[key] = exe
@@ -3957,8 +4077,9 @@ class ContinuousBatcher:
         A = jnp.asarray(sc.ancestor_mask())                   # [S, S]
         lv = jnp.asarray(sc.row_levels(), jnp.int32)          # [S]
 
-        def serve_spec_verify(params, k, v, ks, vs, table, lengths, tok,
+        def serve_spec_verify(params, pools, table, lengths, tok,
                               drafts, active, budget, stop, spec_ok):
+            k, v, ks, vs = pools
             cache = PagedKVCache(k, v, table, lengths, ks, vs)
             toks_in = jnp.concatenate([tok[:, None], drafts], axis=1)
             # every node sits at committed position lengths + level —
@@ -4043,10 +4164,10 @@ class ContinuousBatcher:
             budget2 = budget - n_emit
             active2 = active & (budget2 > 0) & (last != eos) \
                 & (last != stop)
-            return (k, v, ks2, vs2, lengths + n_emit, last, budget2,
+            return ((k, v, ks2, vs2), lengths + n_emit, last, budget2,
                     active2, jnp.where(emit, out_g, 0), n_emit, n_acc)
 
-        return jax.jit(serve_spec_verify)
+        return self._step_jit(serve_spec_verify)
 
     def _spec_verify_exe(self):
         """Memoized COMPILED verify step (AOT-lowered, warmup-covered)."""
@@ -4059,9 +4180,6 @@ class ContinuousBatcher:
             B = self.B
             exe = self._aot(
                 key, self._spec_verify_fn,
-                self._pool_aval(self.cache.k), self._pool_aval(self.cache.v),
-                self._scale_aval(self.cache.k_scale),
-                self._scale_aval(self.cache.v_scale),
                 sds((B, self.M), i32), sds((B,), i32), sds((B,), i32),
                 sds((B, self.spec_k), i32), sds((B,), jnp.bool_),
                 sds((B,), i32), sds((B,), i32),
@@ -4100,9 +4218,11 @@ class ContinuousBatcher:
                          and self.slot_req[s] not in self._no_spec
                          for s in range(self.B)])
             with tick.phase("dispatch"):
+                # the draft reads the pool and returns none: it donates
+                # nothing, and `c` stays the live pool for the verify
                 drafts = self._spec_draft_exe()(
-                    self.params, self._spec_dlayers, c.k, c.v, c.k_scale,
-                    c.v_scale, c.table, c.lengths, self.cur_tok, active)
+                    self.params, c.pools, self._spec_dlayers, c.table,
+                    c.lengths, self.cur_tok, active)
             tick.fence(drafts)
             draft_s = tick.call_s
         with _Tick(
@@ -4111,19 +4231,20 @@ class ContinuousBatcher:
                 compile_hit=self._spec_key("verify") in self._spec_cache
                 ) as tick:
             with tick.phase("dispatch"):
-                (pk, pv, ks, vs, lengths, last, budget, active2, out,
+                (pools, lengths, last, budget, active2, out,
                  n_emit, n_acc) = self._spec_verify_exe()(
-                    self.params, c.k, c.v, c.k_scale, c.v_scale, c.table,
+                    self.params, c.pools, c.table,
                     c.lengths, self.cur_tok, drafts, active, budget, stop,
                     self._spec_ok_dev)
-            tick.fence((pk, out, n_emit))
+                tick.adopt(pools)
+            tick.fence((pools, out, n_emit))
             with tick.phase("wait"):
                 # one host sync serves tokens, counts AND acceptance —
                 # and, dispatch being async, surfaces any device-side
                 # failure HERE, before the batcher state commits below
                 out, n_emit, n_acc = jax.device_get((out, n_emit, n_acc))  # ptlint: disable=SYNC001 — single per-step sync, token + acceptance readbacks coalesced
             with tick.phase("commit"):
-                self.cache = PagedKVCache(pk, pv, c.table, lengths, ks, vs)
+                self.cache = self.cache._replace(lengths=lengths)
                 self.cur_tok = last
                 self._dev_state = (active2, budget, stop)
                 spec_slots = [s for s in decoding
@@ -4225,11 +4346,12 @@ class ContinuousBatcher:
                     self._dev_state = self._upload_slot_state()
                 active, budget, stop = self._dev_state
             with tick.phase("dispatch"):
-                (self.cache, self.cur_tok, lengths, budget, active,
-                 toks, stats) = self._chunk_exe()(
-                    self.params, self.cache, self.cur_tok, active,
-                    self.cache.lengths, budget, stop)
-            tick.fence((self.cache.k, self.cur_tok, toks))
+                (pools, tok, lengths, budget, active, toks,
+                 stats) = self._chunk_exe()(
+                    self.params, self.cache.pools, self.cache.table,
+                    self.cur_tok, active, self.cache.lengths, budget, stop)
+                tick.adopt(pools)
+            tick.fence((pools, tok, toks))
             with tick.phase("wait"):
                 # one host sync per decode chunk — the per-token loop
                 # of the commit reads this numpy copy, never the device
@@ -4237,6 +4359,7 @@ class ContinuousBatcher:
                 self._note_counters(tick, stats)
             with tick.phase("commit"):
                 self.cache = self.cache._replace(lengths=lengths)
+                self.cur_tok = tok
                 # steady state: the chunk's own outputs are next chunk's
                 # inputs; _retire/_commit null this when the host diverges
                 self._dev_state = (active, budget, stop)

@@ -147,6 +147,13 @@ class PrefixCacheIndex:
             del node.parent[node.key]
         return True
 
+    def clear(self) -> None:
+        """Forget every block (the pool was rebuilt: nothing the index
+        names holds its tokens' KV any more). The admission counters
+        stay; no eviction is counted."""
+        self._children.clear()
+        self._by_block.clear()
+
     def note_admission(self, prompt_len: int, cached_tokens: int) -> None:
         """Record one admission's hit accounting (called by the batcher
         with the prefix length it actually reused)."""

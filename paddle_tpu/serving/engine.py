@@ -1601,12 +1601,16 @@ class ServingEngine:
             for rid, req in order:
                 snap = None
                 if rid not in culprits and not req.cancel_requested:
-                    # slot-in-place recovery (PR 8 follow-on): the
-                    # failed call committed NOTHING (commits happen
-                    # after the device call returns), so an innocent's
-                    # slot state is intact — export its KV now and
-                    # re-import below instead of requeueing it through
-                    # a full re-prefill of `prompt + tokens`
+                    # slot-in-place recovery (PR 8 follow-on): a call
+                    # that failed BEFORE its dispatch committed NOTHING
+                    # (commits happen after the device call returns), so
+                    # an innocent's slot state is intact — export its KV
+                    # now and re-import below instead of requeueing it
+                    # through a full re-prefill of `prompt + tokens`. A
+                    # call that failed AFTER dispatch took the donated
+                    # pool with it: the batcher rebuilt it empty and
+                    # dropped every slot (`_drop_lost_pool`), the export
+                    # raises, and the request takes the requeue path
                     try:
                         snap = b.export_kv(rid)
                     # ptlint: disable=EXC001 — per-request boundary: an

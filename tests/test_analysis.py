@@ -1473,6 +1473,22 @@ def test_key_bookkeeping_dicts_not_policed():
     assert fs == []
 
 
+def test_trace001_sees_every_step_program_as_traced():
+    """The batcher's step programs are jitted by its own `_step_jit`
+    (`jax.jit` with the KV pool donated), not by a bare `jax.jit` at the
+    builder: the rule has to know that wrapper, or the five functions
+    that ARE the served path drop out of TRACE001's and SYNC001's sight
+    in silence."""
+    from paddle_tpu.analysis.rules.trace import find_traced_functions
+    (ctx,) = [f for f in real_tree().files
+              if f.relpath.endswith("nlp/paged.py")]
+    traced = {fn.name: why for fn, why in find_traced_functions(ctx)}
+    for name in ("serve_prefill_step", "serve_decode_step",
+                 "serve_fused_step", "serve_spec_draft",
+                 "serve_spec_verify"):
+        assert traced.get(name) == "wrapped by self._step_jit", name
+
+
 def test_key001_discovers_every_paged_cache():
     """Coverage floor, same idiom as the SYNC001 superset pin: every
     `self._*_cache` attribute in nlp/paged.py must be discovered (and

@@ -75,6 +75,31 @@ def _tpu_backend():
         jax.default_backend = default_backend
 
 
+def _assert_pool_aliased(exe, pools):
+    """A step program compiled with its pool donated, as the batcher
+    compiles every one that returns a pool (`ContinuousBatcher._step_jit`):
+    each of the pool's buffers comes back in its argument's own
+    (`input_output_alias`, `alias_size_in_bytes`), and no `copy`,
+    `copy-start` or `copy-done` of a pool's shape is left ANYWHERE, the
+    entry computation included, where the undonated program copies K and
+    V whole before it does anything."""
+    txt = exe.as_text()
+    shapes = [",".join(map(str, p.shape)) for p in pools]
+    made = re.findall(
+        r"^\s*(?:ROOT )?%\S+ = \(*\w+\[([\d,]+)\]\S* (copy\S*)\(", txt,
+        re.M)
+    assert not [m for m in made if m[0] in shapes], made
+    header = txt.split("\n", 1)[0]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    entry = txt[txt.index("\nENTRY "):]
+    of = {int(n): shape for shape, n in re.findall(
+        r"= \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)}
+    assert sorted(of[n] for n in aliased) == sorted(shapes), (header[:400])
+    assert exe.memory_analysis().alias_size_in_bytes >= sum(
+        int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+
+
 def _list_grid(txt, R, Pq, width, pool, windowed=False):
     """The kernel's call in a compiled program, its grid a work list: the
     bound is an operand (the scalar that leads them), then the table,
@@ -187,14 +212,15 @@ def _ragged_gated(topo, R, Pq, kind):
     return txt
 
 
-def _gated_prefill_step(topo):
+def _gated_prefill_step(topo, donated=False):
     """The warm one-row prefill forward of the 128 bucket at
     `laguna-s-ep4`'s widths (`paged.forward_paged` over the kinded pool,
     both kernels, the gate, the leading dense group and the period's
     scan): inside a step program the compiler also gives the kernel's
     small operands scoped VMEM, and a tile of 128 queries at 48 heads,
     which compiles alone, took 17.71 MiB of 16.75 there (PR 43's first
-    chip call)."""
+    chip call). `donated`: the pool donated as the batcher donates it,
+    `_assert_pool_aliased`."""
     from benchmark.harness import manifest
     from benchmark.models import gated_window_moe_decoder as fam
     from paddle_tpu.nlp import paged
@@ -221,12 +247,15 @@ def _gated_prefill_step(topo):
     on = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)  # noqa: E731
     i32 = jax.ShapeDtypeStruct((R, Pq), jnp.int32, sharding=one)
     with _tpu_backend():
-        txt = jax.jit(fn).lower(
+        exe = jax.jit(fn, donate_argnums=(1,) if donated else ()).lower(
             jax.tree.map(on, fam.params_shape(d, BF)),
             jax.tree.map(on, pool[:2]),
             jax.ShapeDtypeStruct((R, width + ring), jnp.int32, sharding=one),
             i32, i32, jax.ShapeDtypeStruct((R, Pq), jnp.bool_, sharding=one)
-        ).compile().as_text()
+        ).compile()
+    txt = exe.as_text()
+    if donated:
+        _assert_pool_aliased(exe, pool[:2])
     for name in ("%ragged_paged_attention", "%ragged_window_attention",
                  "%grouped_gemm"):
         assert name in txt, name
@@ -360,7 +389,7 @@ def _mla(topo, R, Pq):
     return txt
 
 
-def _gqa_uniform_decode(topo, kv_dtype="fp"):
+def _gqa_uniform_decode(topo, kv_dtype="fp", donated=False):
     """The decode forward of a uniform GQA decoder at Mistral-7B's widths
     (`paged.forward_paged`, 16 rows of one token, the Pallas kernel) over
     a pool stacked by layer: a layer's blocks are written and read IN the
@@ -370,7 +399,9 @@ def _gqa_uniform_decode(topo, kv_dtype="fp"):
     bitcasts, tuple plumbing and the scatters alone: no copy, no slice
     out, no write back. The stack is 128 MiB a pool (4 layers of bf16, 8
     of int8): one of 64 MiB the compiler keeps in its fast memory space
-    and copies whole a layer, which no served pool is small enough for."""
+    and copies whole a layer, which no served pool is small enough for.
+    `donated`: the pool donated as the batcher donates it, and then no
+    copy of the stack in the entry either (`_assert_pool_aliased`)."""
     from paddle_tpu.nlp import llama, paged
     one = SingleDeviceSharding(topo.devices[0])
     L, R = (8 if kv_dtype == "int8" else 4), 16
@@ -392,11 +423,14 @@ def _gqa_uniform_decode(topo, kv_dtype="fp"):
     on = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)  # noqa: E731
     i32 = jax.ShapeDtypeStruct((R, 1), jnp.int32, sharding=one)
     with _tpu_backend():
-        txt = jax.jit(fn).lower(
+        exe = jax.jit(fn, donate_argnums=(1,) if donated else ()).lower(
             jax.tree.map(on, params), jax.tree.map(on, pool),
             jax.ShapeDtypeStruct((R, M), jnp.int32, sharding=one), i32, i32,
             jax.ShapeDtypeStruct((R, 1), jnp.bool_, sharding=one)
-        ).compile().as_text()
+        ).compile()
+    txt = exe.as_text()
+    if donated:
+        _assert_pool_aliased(exe, [p for p in pool if p is not None])
     assert " while(" in txt
     row = f"{BS},{KV},{HD}"
     layer = (f"{N},{row}", f"1,{N},{row}", f"{N * BS},{KV},{HD}")
@@ -545,6 +579,14 @@ CASES = {
     "gqa-uniform-decode-step-in-place": _gqa_uniform_decode,
     "gqa-uniform-decode-step-in-place-int8": lambda t: _gqa_uniform_decode(
         t, "int8"),
+    # the same whole steps with the pool DONATED, as the batcher compiles
+    # every program that returns one: no whole-pool copy at the entry
+    "gqa-uniform-decode-step-donated": lambda t: _gqa_uniform_decode(
+        t, donated=True),
+    "gqa-uniform-decode-step-donated-int8": lambda t: _gqa_uniform_decode(
+        t, "int8", donated=True),
+    "gated-window-moe-prefill-step-1x128-donated": lambda t:
+        _gated_prefill_step(t, donated=True),
     "flash-fwd-bwd-2048": _flash,
     "flash-fwd-bwd-4096-q192-v128": _flash_mla,
     "mhc-mla-moe-train-step-4x4096": _mhc_train_step,

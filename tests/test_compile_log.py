@@ -253,6 +253,8 @@ def _synthetic():
                        executable_s=4.0, cache="hit" if hit else "miss")
             if hit:
                 rec["cache_read_s"] = 0.5
+            if name != "jit_c":         # a program that says nothing of it
+                rec["alias_bytes"] = 1024
     return log
 
 
@@ -282,6 +284,8 @@ def test_summary_over_names_and_times(kw, count, hits, last_miss):
     assert (s["trace_s"], s["lower_s"], s["executable_s"]) == \
         (1.0 * count, 2.0 * count, 4.0 * count)
     assert s["cache_read_s"] == 0.5 * hits
+    assert s["alias_bytes"] == 1024 * sum(
+        r["name"] != "jit_c" for r in _synthetic().records(**kw))
     assert (s["last_miss"] or {}).get("name") == last_miss
 
 
@@ -379,3 +383,57 @@ def test_engine_idle_opens_only_with_nothing_live(model, monkeypatch):
     names = [n for n, _, _ in spans.opened]
     assert set(names) == {"engine.housekeeping", "engine.deliver",
                           "engine.idle"}
+
+
+# ---- the counter that says donation engaged -----------------------------
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_alias_bytes_of_every_program_that_returns_a_pool(model, kv_dtype,
+                                                         capsys):
+    """`_aot` writes the compiler's `alias_size_in_bytes` into each step
+    program's record: a program that returns the pool holds it in the
+    donated argument's buffer (at least the pool's bytes, the int8
+    pool's scales with it), the speculative draft, which reads the pool
+    and returns none, aliases nothing; `summary()` adds them up and
+    `tools/setup_phases.py` prints them."""
+    cfg, params = model
+    cb = paged.ContinuousBatcher(
+        params, cfg, max_batch=2, block_size=4, max_total_len=48,
+        max_new_tokens=4, chunk=2, max_prefill_bucket=8, speculative=True,
+        spec_k=2, kv_dtype=kv_dtype)
+    t0 = compile_log.clock()
+    cb.warmup_prefill()
+    recs = compile_log.records(["^jit_serve_"], since=t0)
+    pool = sum(p.nbytes for p in cb.cache.pools if p is not None)
+    assert pool >= cb.kv_pool_bytes() > 0
+    by_name = {}
+    for r in recs:
+        by_name.setdefault(r["name"], []).append(r["alias_bytes"])
+    assert set(by_name) == STEP_NAMES | {"jit_serve_spec_draft",
+                                         "jit_serve_spec_verify"}
+    assert by_name.pop("jit_serve_spec_draft") == [0]
+    for name, got in by_name.items():
+        assert all(b >= pool for b in got), (name, got, pool)
+    total = compile_log.summary(["^jit_serve_"], since=t0)["alias_bytes"]
+    assert total == sum(r["alias_bytes"] for r in recs) \
+        >= (len(recs) - 1) * pool
+
+    import importlib.util
+    import os
+    spec = importlib.util.spec_from_file_location(
+        "setup_phases", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "setup_phases.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    log = CompileLog()
+    for r in recs:
+        log._close(dict(r))
+    tool.print_compile_log(log, pool)
+    lines = capsys.readouterr().out.splitlines()
+    mib = pool / 2 ** 20
+    assert f"(the pool: {mib:.1f})" in lines[0]
+    assert len(lines) == 1 + len(recs) + 2
+    for line in lines[1:-2]:
+        aliased = float(line.rsplit(" ", 1)[1])
+        assert aliased == 0.0 if "spec_draft" in line \
+            else aliased >= round(mib, 1)
+    assert f"{total / 2 ** 20:.1f} MiB aliased" in lines[-2]

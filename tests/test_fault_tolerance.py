@@ -206,6 +206,9 @@ class TestQuarantine:
         # innocents keep their KV via export/import ("restored") instead
         # of requeueing through a full re-prefill
         assert h["quarantines"] >= 1 and h["requests_restored"] >= 1
+        # ... and the injector's gate stands before dispatch, so the
+        # donated pool never left the batcher's hands
+        assert eng.batcher.pool_rebuilds == 0
         restored = [r for i, r in enumerate(reqs) if i != 1
                     and "restored" in _kinds(eng.trace.timeline(r.trace_id))]
         assert restored, "no innocent timeline recorded its restore"
@@ -291,6 +294,168 @@ class TestQuarantine:
             with pytest.raises(serving.RequestFailed):
                 r.result(timeout=300)
         assert eng.last_flight_dump is not None
+        eng.shutdown()
+
+
+# ---- the donated pool under faults --------------------------------------
+class _FailsAtTheWait:
+    """Stands in for a step program's tokens: the read-back raises, as a
+    device failure does once the call was dispatched."""
+
+    def __array__(self, *a, **k):
+        raise InjectedFault("device failure at the wait", transient=True)
+
+
+class TestDonatedPoolFaults:
+    """Every step program donates its pool (`ContinuousBatcher._step_jit`):
+    what a fault before dispatch, a probe and a fault after dispatch
+    each leave in the batcher's hands."""
+
+    def _batcher(self, setup, **kw):
+        from paddle_tpu.nlp.paged import ContinuousBatcher
+        cfg, params = setup
+        return ContinuousBatcher(
+            params, cfg, max_batch=2, block_size=4, max_total_len=64,
+            max_new_tokens=10, chunk=2, prefill_buckets=(8,),
+            prefix_cache=True, **kw)
+
+    def test_injected_fault_leaves_the_pool_live(self, setup):
+        """(a) The injector's gate is in `_Tick.__enter__`, before
+        dispatch: the failed tick donated nothing, the handle taken
+        before it is the batcher's still, and an innocent's KV exports
+        and imports as `_quarantine` does it, decoding on to the tokens
+        of an unfaulted run."""
+        base = self._batcher(setup)
+        rb = base.submit(PROMPTS[0])
+        want = base.run()[rb]
+
+        inj = FaultInjector()
+        cb = self._batcher(setup, fault_injector=inj)
+        rid = cb.submit(PROMPTS[0])
+        cb.step()
+        cb.step()
+        k0 = cb.cache.k
+        inj.fail_on_step(inj.stats()["calls"] + 1)
+        with pytest.raises(InjectedFault):
+            cb.step()
+        assert cb.cache.k is k0 and not k0.is_deleted()
+        assert cb.pool_rebuilds == 0 and len(cb._pcache) > 0
+        got = list(cb.outputs[rid])
+        snap = cb.export_kv(rid)
+        cb.abort(rid)
+        rid2 = cb.import_kv(snap)
+        assert cb.run()[rid2] == want and got == want[:len(got)]
+        assert cb.alloc.stats()["blocks_in_use"] == 0
+
+    def test_probes_keep_the_pool_they_ran_on(self, setup):
+        """(b) `probe_decode_slot` and `probe_queued` run a step program
+        and commit nothing BUT the pool it returns: the pool they probed
+        was donated to the run. The next ticks run on it and give the
+        tokens of a run nobody probed."""
+        base = self._batcher(setup)
+        rids = [base.submit(p) for p in PROMPTS[:3]]
+        out = base.run()
+        want = [out[r] for r in rids]
+
+        cb = self._batcher(setup)
+        r0, r1 = cb.submit(PROMPTS[0]), cb.submit(PROMPTS[1])
+        cb.step()
+        cb.step()
+        for slot in (0, 1):
+            k0, lengths = cb.cache.k, np.array(cb.cache.lengths)
+            cb.probe_decode_slot(slot)
+            assert k0.is_deleted() and not cb.cache.k.is_deleted()
+            assert (np.array(cb.cache.lengths) == lengths).all()
+        r2 = cb.submit(PROMPTS[2])              # queued: both slots busy
+        free, indexed = cb.alloc.free_blocks, len(cb._pcache)
+        k0 = cb.cache.k
+        cb.probe_queued(r2)
+        assert k0.is_deleted() and not cb.cache.k.is_deleted()
+        assert (cb.alloc.free_blocks, len(cb._pcache)) == (free, indexed)
+        assert [e[0] for e in cb.queue] == [r2]
+        out = cb.run()
+        assert [out[r] for r in (r0, r1, r2)] == want
+        assert cb.pool_rebuilds == 0
+
+    @pytest.mark.parametrize("mode", ["decode", "fused"])
+    def test_failure_after_dispatch_rebuilds_the_pool(self, setup, mode):
+        """(c) A failure the device raises at the WAIT leaves a pool
+        that is the failed program's result (its argument was donated):
+        the batcher rebuilds it empty with the prefix index, drops every
+        slot, and the engine re-admits every live request through its
+        queue; their tokens are an unfaulted run's."""
+        def engine():
+            cfg, params = setup
+            return serving.ServingEngine(
+                params, cfg, max_batch=2, block_size=4, max_total_len=64,
+                max_new_tokens=16, chunk=2, prefill_buckets=(8,),
+                start=False, retry_backoff_s=0.01)
+
+        def serve(eng, first_token=None):
+            eng.warmup()
+            eng.start()
+            eng.generate(PROMPTS[0], timeout=300)   # fills the index
+            reqs = [eng.submit(PROMPTS[0], max_new_tokens=BUDGETS[0],
+                               on_token=first_token)]
+            reqs += [eng.submit(p, max_new_tokens=mn)
+                     for p, mn in zip(PROMPTS[1:], BUDGETS[1:])]
+            assert eng.drain(timeout=300)
+            return reqs
+
+        eng0 = engine()
+        want = [r.result(timeout=5) for r in serve(eng0)]
+        eng0.shutdown()
+
+        eng = engine()
+        b = eng.batcher
+        name = {"decode": "_chunk_exe", "fused": "_fused_exe"}[mode]
+        getter, armed, after = getattr(b, name), [], []
+
+        def faulty(*shape):
+            exe = getter(*shape)
+
+            def call(*args):
+                out = list(exe(*args))
+                if armed and armed.pop():
+                    out[5] = _FailsAtTheWait()      # the tokens [B, chunk]
+                return tuple(out)
+            return call
+        setattr(b, name, faulty)
+        drop = b._drop_lost_pool
+
+        def dropped(in_flight):
+            rebuilds = b.pool_rebuilds
+            drop(in_flight)
+            if b.pool_rebuilds > rebuilds:
+                after.append((len(b._pcache), any(b.active),
+                              len(b._pending), b.alloc.stats(),
+                              b.cache.k.is_deleted(),
+                              float(np.abs(np.asarray(
+                                  b.cache.k, np.float32)).max())))
+        b._drop_lost_pool = dropped
+        once = []
+
+        def arm(tok):               # at the first streamed token, once
+            if not once:
+                once.append(tok)
+                armed.append(True)
+        reqs = serve(eng, first_token=arm)
+        # ONE failure after dispatch, ONE rebuild: an empty live pool, an
+        # empty index, no slot and no block held when it was done
+        assert b.pool_rebuilds == 1 and len(after) == 1
+        indexed, active, pending, st, deleted, top = after[0]
+        assert (indexed, active, pending, deleted, top) == \
+            (0, False, 0, False, 0.0)
+        assert st["blocks_in_use"] == 0 and st["cached_blocks"] == 0
+        # every live request went back through the queue (a suspect of
+        # the failed tick as a retry, the others requeued; none restored
+        # from the lost pool) and finished on an unfaulted run's tokens
+        assert [r.state for r in reqs] == [RequestState.FINISHED] * 4
+        assert [r.result(timeout=5) for r in reqs] == want
+        h = eng.health()
+        assert h["requests_restored"] == 0
+        assert h["requests_requeued"] + sum(r.retries for r in reqs) >= 1
+        assert b.alloc.stats()["blocks_in_use"] == 0
         eng.shutdown()
 
 

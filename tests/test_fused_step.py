@@ -289,7 +289,7 @@ class TestFusedRunsOnlyItsTokens:
         M, T, i32 = cb.M, B + Gp * Pb, np.int32
         z = lambda shape, dt=i32: jax.ShapeDtypeStruct(shape, dt)  # noqa: E731
         jaxpr = jax.make_jaxpr(cb._build_fused())(
-            params, cb.cache.k, cb.cache.v, None, None, z((B, M)),
+            params, cb.cache.pools, z((B, M)),
             z((B,)), z((B,)), z((B,), np.bool_), z((B,)), z((B,)),
             z((Gp, Pb)), z((Gp, Pb)), z((Gp, Pb), np.bool_), z((Gp, M)),
             z((Gp,))).jaxpr

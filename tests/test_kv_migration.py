@@ -98,7 +98,7 @@ class TestSnapshotRoundTrip:
         slot = dst.slot_req.index(rid2)
         chain = dst.slot_blocks[slot]
         nw = snap.n_blocks
-        ks = np.asarray(dst.cache.k_scale)
+        ks = np.array(dst.cache.k_scale)    # a copy: the ticks donate the pool
         np.testing.assert_array_equal(ks[:, chain[:nw]],
                                       np.asarray(snap.k_scale))
         assert np.all(ks[:, chain[nw:]] == 0.0)

@@ -344,11 +344,12 @@ class TestSpecInt8KV:
         assert cb.active[0]
         while cb.active[0]:
             len0 = int(np.asarray(cb.cache.lengths)[0])
-            pre = np.asarray(cb.cache.k_scale)
+            # a copy on the host: the tick donates the pool, scales too
+            pre = np.array(cb.cache.k_scale)
             chain = cb.slot_blocks[0]
             out, n_emit = cb._step_spec([0])
             n = int(n_emit[0])
-            post = np.asarray(cb.cache.k_scale)
+            post = np.array(cb.cache.k_scale)
             touched = {chain[p // cb.bs]
                        for p in range(len0, len0 + n)}
             changed = set(np.argwhere(
@@ -502,11 +503,12 @@ class TestTreeSpecParity:
         cb._admit()
         while cb.active[0]:
             len0 = int(np.asarray(cb.cache.lengths)[0])
-            pre = np.asarray(cb.cache.k_scale)
+            # a copy on the host: the tick donates the pool, scales too
+            pre = np.array(cb.cache.k_scale)
             chain = cb.slot_blocks[0]
             out, n_emit = cb._step_spec([0])
             n = int(n_emit[0])
-            post = np.asarray(cb.cache.k_scale)
+            post = np.array(cb.cache.k_scale)
             touched = {chain[p // cb.bs]
                        for p in range(len0, len0 + n)}
             changed = set(np.argwhere(

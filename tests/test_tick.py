@@ -289,7 +289,8 @@ def _lowered_decode_step(cb):
     cb._chunk_exe()                                 # builds the jit
     sds, i32, B = cb._aval, jnp.int32, cb.B
     return cb._chunk_fn.lower(
-        cb._pstruct(), cb._cstruct(), sds((B,), i32), sds((B,), jnp.bool_),
+        cb._pstruct(), cb._pools_aval(), sds((B, cb._table_width), i32),
+        sds((B,), i32), sds((B,), jnp.bool_),
         sds((B,), i32), sds((B,), i32), sds((B,), i32)
     ).as_text(debug_info=True)
 

@@ -180,6 +180,37 @@ class TestMemoKeys:
                 assert k[-len(mk):] == mk
 
 
+class TestDonatedShardedPool:
+    @pytest.mark.parametrize("tp,kv_dtype", [(2, None), (4, "int8")])
+    def test_sharded_pool_is_donated_whole(self, setup, tp, kv_dtype):
+        """Under a mesh the returned pool takes the donated pool's
+        sharding, so every device's shard is written in place: each step
+        program that returns a pool aliases a device's whole share of it
+        (`alias_bytes`, a device's bytes), the handle taken before a
+        tick is deleted after it, and the pool the batcher holds keeps
+        the pinned sharding."""
+        from paddle_tpu.core.compile_cache import compile_log
+        cb = _batcher(setup, mesh=MeshConfig(tp=tp), kv_dtype=kv_dtype,
+                      speculative=True, spec_k=2)
+        t0 = compile_log.clock()
+        cb.warmup_prefill()
+        share = sum(p.addressable_shards[0].data.nbytes
+                    for p in cb.cache.pools if p is not None)
+        recs = compile_log.records(["^jit_serve_"], since=t0)
+        assert {r["alias_bytes"] for r in recs
+                if r["name"] != "jit_serve_spec_draft"} == {share}
+        pinned = [p.sharding for p in cb.cache.pools if p is not None]
+        rids = [cb.submit(p) for p in PROMPTS[:2]]
+        cb.step()
+        before = [p for p in cb.cache.pools if p is not None]
+        out = cb.run()
+        assert all(p.is_deleted() for p in before)
+        after = [p for p in cb.cache.pools if p is not None]
+        assert all(a.sharding.is_equivalent_to(s, a.ndim)
+                   for a, s in zip(after, pinned))
+        assert all(len(out[r]) == MAX_NEW for r in rids)
+
+
 class TestTPServing:
     def test_tp2_engine_bit_identity_zero_recompiles(self, setup,
                                                      baselines):

@@ -20,6 +20,26 @@ T0 = time.time()
 sys.path.insert(0, os.getcwd())
 
 
+def print_compile_log(log, pool_bytes: int) -> None:
+    """The log's step programs, one line each, and its sums."""
+    steps = ["^jit_serve_"]         # the batcher's step programs, by name
+    print("  program, key: trace + lowering + executable s (of it the "
+          "cache's read), cache, MiB of results aliased onto donated "
+          f"arguments (the pool: {pool_bytes / 2**20:.1f})")
+    for r in log.records(steps):
+        print(f"  {r['name']} {r['key']}: {r['trace_s']:.2f} + "
+              f"{r['lower_s']:.2f} + {r['executable_s']:.2f} "
+              f"({r.get('cache_read_s', 0.0):.2f}) {r['cache']} "
+              f"{r.get('alias_bytes', 0) / 2**20:.1f}")
+    for what, s in (("the step programs", log.summary(steps)),
+                    ("every program", log.summary())):
+        print(f"  {what}: {s['count']}, trace {s['trace_s']:.2f} lowering "
+              f"{s['lower_s']:.2f} executable {s['executable_s']:.2f} (the "
+              f"cache's read {s['cache_read_s']:.2f}) s, {s['hits']} hits, "
+              f"{s['misses']} misses, {s['alias_bytes'] / 2**20:.1f} MiB "
+              f"aliased")
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -76,19 +96,7 @@ def main(argv=None):
           f"{t[1] - t[0]:.2f}  engine {t[2] - t[1]:.2f}  warm-up "
           f"{t[3] - t[2]:.2f} ({warmed} programs)  start and pre-roll "
           f"{t[4] - t[3]:.2f}  total {t[4] - T0:.2f} s")
-    steps = ["^jit_serve_"]         # the batcher's step programs, by name
-    print("  program, key: trace + lowering + executable s (of it the "
-          "cache's read), cache")
-    for r in compile_log.records(steps):
-        print(f"  {r['name']} {r['key']}: {r['trace_s']:.2f} + "
-              f"{r['lower_s']:.2f} + {r['executable_s']:.2f} "
-              f"({r.get('cache_read_s', 0.0):.2f}) {r['cache']}")
-    for what, s in (("the step programs", compile_log.summary(steps)),
-                    ("every program", compile_log.summary())):
-        print(f"  {what}: {s['count']}, trace {s['trace_s']:.2f} lowering "
-              f"{s['lower_s']:.2f} executable {s['executable_s']:.2f} (the "
-              f"cache's read {s['cache_read_s']:.2f}) s, {s['hits']} hits, "
-              f"{s['misses']} misses")
+    print_compile_log(compile_log, eng.batcher.kv_pool_bytes())
     return 0
 
 
